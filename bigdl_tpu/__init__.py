@@ -27,9 +27,29 @@ capabilities (see SURVEY.md):
   see docs/RELIABILITY.md.
 """
 
-from bigdl_tpu.version import __version__
-from bigdl_tpu.utils.engine import Engine, init_engine, get_mesh
-from bigdl_tpu.utils.table import Table, T
+import os as _os
+
+
+def _place_compile_cache() -> None:
+    """Persistent XLA compile cache, placed before anything compiles.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already honours it
+    and nothing is set here; otherwise the cache lives in
+    ``<checkout>/.jax_cache`` — a path fixed by where the package sits,
+    so every process started from one checkout finds what another one
+    compiled."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    package = _os.path.dirname(_os.path.abspath(__file__))
+    jax.config.update("jax_compilation_cache_dir", _os.path.join(
+        _os.path.dirname(package), ".jax_cache"))
+
+
+_place_compile_cache()
+
+from bigdl_tpu.version import __version__  # noqa: E402
+from bigdl_tpu.utils.engine import Engine, init_engine, get_mesh  # noqa: E402
+from bigdl_tpu.utils.table import Table, T  # noqa: E402
 
 __all__ = [
     "__version__",

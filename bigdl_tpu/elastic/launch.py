@@ -20,6 +20,14 @@ becomes elastic unmodified::
 
     python -m bigdl_tpu.elastic.launch --nprocs 2 -- python train.py
 
+Chip ownership: a chip belongs to one process at a time, and a parent
+that has touched JAX keeps it. This launcher never does — importing
+``bigdl_tpu`` initialises no backend (tests/test_bringup.py) — so its
+workers can take the devices. It assigns no chips itself: ``nprocs``
+workers on one TPU host each see every local chip, so more than one
+worker per host needs the caller's ``env`` to narrow what each sees
+(not exercised on a chip yet; the tests run CPU workers).
+
 Restart budget: ``bigdl.elastic.max.restarts`` generations beyond the
 first; exhausting it raises :class:`ElasticJobFailed` with the tail of
 every worker log.

@@ -24,7 +24,8 @@ llama.cpp-family C kernels, SURVEY.md §3.4 hot loop). TPU design:
   dot against ``s_exp``; prefill (m large, MXU-bound) subtracts 8 on
   the VPU instead, trading VPU ops for a third of the MXU work.
 - float16 never enters the kernel: this Mosaic build cannot load fp16
-  (verified on chip: "Unsupported cast"-class remote-compile failures),
+  (verified on chip before PR 1: "Unsupported cast"-class compile
+  failures; not re-checked under jax 0.9),
   so ggml's fp16 scales are converted to f32 on the host.
 
 Measured on TPU v5 lite (1 chip, 819 GB/s HBM), (1, 4096)x(4096, 11008)
@@ -97,8 +98,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from bigdl_tpu.utils.jax_compat import tpu_compiler_params
 
 from bigdl_tpu.llm.ggml.quantize import QK
 
@@ -274,7 +273,7 @@ def _int4_matmul_jit(x, q_t, scale_t, bm: int, bn: int,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(xe, xo, qc, sc)
@@ -321,7 +320,7 @@ def asym_int4_matmul(x, q_t, scale_t, zero_t, bm: int = 128, bn: int = 256,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(xe, xo, qc, sc, zc)
@@ -368,7 +367,7 @@ def int8_matmul(x, q_t, scale_t, bm: int = 128, bn: int = 256,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(xc, qc, sc)

@@ -38,8 +38,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.utils.jax_compat import tpu_compiler_params
-
 LANE = 128          # score-tile lane width: pages per block × page_size
 
 
@@ -328,7 +326,7 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
                               scale=scale, window=sliding_window),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), jnp.float32),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(lengths.astype(jnp.int32),
@@ -362,7 +360,7 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths,
                           window=sliding_window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
@@ -446,7 +444,7 @@ def paged_attention_decode_stats(q, k_pages, v_pages, block_tables,
                 jax.ShapeDtypeStruct((b, hkv, gp, d), jnp.float32),
                 jax.ShapeDtypeStruct((b, hkv, gp, LANE), jnp.float32),
                 jax.ShapeDtypeStruct((b, hkv, gp, LANE), jnp.float32)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(lengths.astype(jnp.int32),
@@ -489,7 +487,7 @@ def paged_attention_decode_stats(q, k_pages, v_pages, block_tables,
         out_shape=[jax.ShapeDtypeStruct((b, hkv, gp, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, hkv, gp, LANE), jnp.float32),
                    jax.ShapeDtypeStruct((b, hkv, gp, LANE), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),

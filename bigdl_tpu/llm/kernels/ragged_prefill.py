@@ -43,13 +43,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.utils.jax_compat import tpu_compiler_params
-
 from bigdl_tpu.llm.kernels.paged_attention import LANE
 
-# scratch budget: acc/m/l rows are hkv * qt * g fp32 vectors; cap the
-# row count so the three accumulators stay within a few MB of VMEM at
-# production head counts (7B: hkv=32, g=1 -> qt=128)
+# query-tile cap: acc/m/l rows are hkv * qt * g fp32 vectors, so this
+# bounds the accumulators (7B MHA: hkv=32, g=1 -> qt=128; GQA-8: qt=128,
+# 512 rows per kv head). The resulting VMEM need is computed per call in
+# ragged_prefill_attention and handed to Mosaic as its limit.
 _MAX_SCRATCH_ROWS = 4096
 
 
@@ -257,6 +256,30 @@ def ragged_prefill_attention(q, k_suf, v_suf, k_pages, v_pages,
     nblk_suf = ts // LANE
     nkv = nblk_pages + nblk_suf
 
+    # VMEM this call needs. Pallas double-buffers every BlockSpec
+    # operand; scratch is allocated once:
+    #   q block       2 * hkv*rows*d * q.itemsize
+    #   out block     2 * hkv*rows*d * 4
+    #   ks, vs blocks 2 * 2 * hkv*LANE*d * kv.itemsize
+    #   kbuf, vbuf    2 * hkv*LANE*d * pool.itemsize   (ppb*page = LANE)
+    #   acc           hkv*rows*d * 4
+    #   m, l          2 * hkv*rows*LANE * 4
+    # plus values live inside the body: one head's (rows, d) / (rows,
+    # LANE) f32 score, weight and product tiles, and the finish pass's
+    # normalised (hkv*rows, d) f32 output — bounded here by one more
+    # accumulator plus 4 MiB. At the row cap with d=128 and bf16
+    # operands that is 18.0 MiB of buffers for MHA-32 (llama2_7b) and
+    # 13.5 MiB for GQA-8 (mistral_7b) before the body's values: over,
+    # or too close to, the 16 MiB scoped default of a v5e core, which
+    # has 128 MiB. So the limit is stated, not defaulted.
+    tile = hkv * rows * d
+    kv_blk = hkv * LANE * d
+    vmem_need = (2 * tile * q.dtype.itemsize + 2 * tile * 4
+                 + 4 * kv_blk * k_suf.dtype.itemsize
+                 + 2 * kv_blk * k_pages.dtype.itemsize
+                 + tile * 4 + 2 * hkv * rows * LANE * 4)
+    vmem_limit = vmem_need + tile * 4 + (4 << 20)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, nqblk, nkv),
@@ -291,8 +314,9 @@ def ragged_prefill_attention(q, k_suf, v_suf, k_pages, v_pages,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, tq_pad * g, d),
                                        jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(offsets.astype(jnp.int32), seq_lens.astype(jnp.int32),
       block_tables.reshape(-1).astype(jnp.int32), qg, ks, vs, k_pages,

@@ -290,6 +290,64 @@ def init_params(cfg: LlamaConfig, seed: int = 0,
     return params
 
 
+def synthetic_q4_params(cfg: LlamaConfig, seed: int = 0) -> Dict[str, Any]:
+    """Seeded random ALREADY-quantized (sym_int4) params in the fused
+    layout :func:`quantize_params` produces, built directly on device:
+    a full-width model for chip_smoke.py and the benchmarks without
+    first materialising its float32 weights on the host (28 GB at 7B).
+    The lm_head is quantized too. Values are uniform nibbles and small
+    positive scales — well-formed, not trained."""
+    from bigdl_tpu.llm.ggml.quantize import QK
+
+    h = cfg.hidden_size
+    L = cfg.num_hidden_layers
+
+    def q4(key, n, k):
+        # k-major TPU kernel layout: q (K/2, N), scale (G, N) f32
+        k1, k2 = jax.random.split(key)
+        return {"q": jax.random.bits(k1, (k // 2, n), jnp.uint8),
+                "scale": jax.random.uniform(k2, (k // QK, n), jnp.float32,
+                                            0.001, 0.02)}
+
+    def one_layer(key):
+        keys = jax.random.split(key, len(_LAYER_LINEARS))
+        layer: Dict[str, Any] = {
+            name: q4(keys[i], *linear_shapes(cfg)[name])
+            for i, name in enumerate(_LAYER_LINEARS)}
+        layer["input_layernorm"] = jnp.ones((h,), jnp.bfloat16)
+        layer["post_attention_layernorm"] = jnp.ones((h,), jnp.bfloat16)
+        return fuse_decoder_params({"layers": layer})["layers"]
+
+    # The layers are made one at a time and written into the stacked
+    # arrays in place (donated): threefry spends two uint32 words per
+    # output BYTE, and run eagerly on a whole stacked 7B gate_proj that
+    # was 14 GB of intermediates — it ran a 16 GB chip out of memory.
+    # This way the peak is the finished params plus ONE layer's
+    # intermediates, whether or not XLA fuses them away.
+    @functools.partial(jax.jit, donate_argnums=0)
+    def put_layer(stack, l, key):
+        return jax.tree_util.tree_map(
+            lambda s, x: jax.lax.dynamic_update_index_in_dim(s, x, l, 0),
+            stack, one_layer(key))
+
+    @jax.jit
+    def ends(k_embed, k_head):
+        return {"embed_tokens": (jax.random.normal(
+                    k_embed, (cfg.vocab_size, h), jnp.float32) * 0.02
+                ).astype(jnp.bfloat16),
+                "norm": jnp.ones((h,), jnp.bfloat16),
+                "lm_head": q4(k_head, cfg.vocab_size, h)}
+
+    k_layers, k_embed, k_head = jax.random.split(
+        jax.random.PRNGKey(seed), 3)
+    layers = jax.tree_util.tree_map(
+        lambda a: jnp.zeros((L,) + a.shape, a.dtype),
+        jax.eval_shape(one_layer, k_layers))
+    for l, key in enumerate(jax.random.split(k_layers, L)):
+        layers = put_layer(layers, l, key)
+    return {**ends(k_embed, k_head), "layers": layers}
+
+
 def quantize_params(params: Dict[str, Any], qtype: str = "sym_int4",
                     quantize_lm_head: bool = False,
                     fuse: bool = True) -> Dict[str, Any]:
@@ -775,12 +833,11 @@ def decode_scan(params, cache, last_logits, key, temperature,
     """``num_tokens`` autoregressive steps as ONE compiled program.
 
     The reference decodes with a host-side python loop (stock HF
-    ``generate``, SURVEY.md §3.4) — one dispatch per token. On this
-    runtime a device roundtrip costs ~100 ms (BENCH_r02's 110 ms "sync
-    overhead" was exactly this), which would dominate a ~10 ms/token
-    model. Here the whole token loop is a ``lax.scan`` inside one jit
-    with a **donated** kv cache, so decode throughput tracks the HBM
-    weight-stream roofline instead of the dispatch rate.
+    ``generate``, SURVEY.md §3.4) — one dispatch and one device→host
+    fetch per token. Here the whole token loop is a ``lax.scan`` inside
+    one jit with a **donated** kv cache, so the host is out of the loop
+    and decode throughput is set by the device step, not the dispatch
+    rate.
 
     Returns (tokens (B, num_tokens), cache, last_logits, key, finished).
     After an EOS hit a row keeps emitting ``eos_token_id`` (HF padding

@@ -5,6 +5,7 @@ ctypes-into-libllama path, SURVEY.md §2.8)."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -18,22 +19,36 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 _SRC = os.path.join(os.path.dirname(__file__), "quant.cpp")
-_OUT = os.path.join(os.path.dirname(__file__), "libbigdl_tpu_quant.so")
+# no -march=native: the library is git-ignored and a working tree is
+# copied between machines as it stands, so the artefact must run on any
+# x86-64 host, and its name carries the hash of what it was built from
+# — a library left behind by other source or other flags is not loaded
+_CXXFLAGS = ["-O3", "-shared", "-fPIC"]
+
+
+def _out_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(_CXXFLAGS).encode())
+    return os.path.join(os.path.dirname(__file__),
+                        f"libbigdl_tpu_quant-{digest.hexdigest()[:12]}.so")
 
 
 def _build() -> Optional[str]:
-    if os.path.exists(_OUT) and \
-            os.path.getmtime(_OUT) >= os.path.getmtime(_SRC):
-        return _OUT
+    out = _out_path()
+    if os.path.exists(out):
+        return out
     for flags in (["-fopenmp"], []):   # openmp when available
-        cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-               *flags, _SRC, "-o", _OUT]
+        # build beside the target and rename: a concurrent process must
+        # never load a half-written library
+        tmp = f"{out}.{os.getpid()}.tmp"
+        cmd = ["g++", *_CXXFLAGS, *flags, _SRC, "-o", tmp]
         try:
             r = subprocess.run(cmd, capture_output=True, timeout=120)
             if r.returncode == 0:
-                logger.info("built %s (%s)", _OUT,
+                os.replace(tmp, out)
+                logger.info("built %s (%s)", out,
                             "openmp" if flags else "single-thread")
-                return _OUT
+                return out
             logger.debug("native build failed: %s", r.stderr.decode())
         except (OSError, subprocess.TimeoutExpired) as e:
             logger.debug("native build error: %s", e)

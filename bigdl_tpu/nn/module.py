@@ -54,15 +54,25 @@ def _auto_name(cls_name: str) -> str:
 
 
 class _GlobalRng:
-    """Deterministic global parameter-init RNG (ref: RandomGenerator)."""
+    """Deterministic global parameter-init RNG (ref: RandomGenerator).
+
+    The key is built on first use, not here: creating a PRNGKey
+    initialises the JAX backend, and the two module-level streams below
+    would make ``import bigdl_tpu.nn`` take the chip — a parent that
+    only imports the package must stay off it so its children can have
+    it (one process per chip)."""
 
     def __init__(self, seed: int = 0):
-        self._key = jax.random.PRNGKey(seed)
+        self._seed = seed
+        self._key = None
 
     def set_seed(self, seed: int):
-        self._key = jax.random.PRNGKey(seed)
+        self._seed = seed
+        self._key = None
 
     def next_key(self):
+        if self._key is None:
+            self._key = jax.random.PRNGKey(self._seed)
         self._key, sub = jax.random.split(self._key)
         return sub
 

@@ -65,12 +65,13 @@ def _ensure_standard_series():
         from bigdl_tpu.version import __version__ as version
     except Exception:
         version = "unknown"
-    try:
-        import jax
-        jax_version = jax.__version__
-        backend = jax.default_backend()
-    except Exception:
-        jax_version, backend = "unknown", "unknown"
+    import jax
+    from jax._src import xla_bridge
+    # a scrape must not take the chip: a router or launcher process
+    # that never ran JAX reports no backend instead of initialising one
+    backend = (jax.default_backend()
+               if xla_bridge.backends_are_initialized() else "none")
+    jax_version = jax.__version__
     g = REGISTRY.gauge(
         "bigdl_build_info",
         "Constant 1; the build identity lives in the labels",

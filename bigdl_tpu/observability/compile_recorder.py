@@ -252,7 +252,13 @@ class CompiledFunction:
                     "include the first execution)", self.name,
                     type(e).__name__, e)
             self._aot_broken = True
-            out = self._jit(*args, **kwargs)
+            try:
+                out = self._jit(*args, **kwargs)
+            except Exception as e2:
+                # not an AOT quirk: the program itself does not trace,
+                # lower or compile for this signature
+                e2._bigdl_first_call = True
+                raise
         dt = time.perf_counter() - t0
         self._record_compile(sig, dt, wall0, executable)
         with self._lock:
@@ -318,12 +324,30 @@ class CompiledFunction:
                              signature=sig_str, stage="xla",
                              recompile=is_recompile)
 
+    def executables(self) -> List[Tuple[str, Any]]:
+        """``(signature, executable)`` for every program this wrapper
+        compiled ahead of time — the very executables that serve its
+        calls (``executable.as_text()`` is their optimized HLO). A
+        signature served by plain jit dispatch (observability off, or
+        AOT unavailable) has no entry."""
+        with self._lock:
+            return [(format_signature(sig), exe)
+                    for sig, exe in self._executables.items()
+                    if exe is not None]
+
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             history = [dict(h) for h in self._history]
             return {"fn": self.name, "compiles": self._compiles,
                     "recompiles": self._recompiles,
                     "aot": not self._aot_broken, "history": history}
+
+
+def failed_first_call(exc: BaseException) -> bool:
+    """True when ``exc`` escaped the first call of a wrapped function
+    for a new signature — tracing, lowering or compilation failed, so
+    calling again with the same arguments fails the same way."""
+    return getattr(exc, "_bigdl_first_call", False)
 
 
 def compiled(fn: Callable, *, name: Optional[str] = None,

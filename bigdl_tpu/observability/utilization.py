@@ -20,10 +20,11 @@ derive:
   achieved tflops / GB/s, utilization fractions and whether it sits on
   the memory or compute side of the machine-balance line.
 
-Peak specs come from :data:`PEAK_SPECS` (public spec sheets, matched by
-PJRT ``device_kind`` substring) and are overridable — mandatory on
-platforms not in the table — via ``bigdl.device.peak.tflops`` /
-``bigdl.device.peak.gbps`` (``0`` = auto-detect).
+Peak specs come from :data:`PEAK_SPECS` (the one table in the repo,
+keyed by exact PJRT ``device_kind``; ``bench.py`` reads it too) and are
+overridable via ``bigdl.device.peak.tflops`` / ``bigdl.device.peak.gbps``
+(``0`` = look the device up). A TPU whose kind is not in the table is
+an error, not a default; a non-TPU backend has no peaks.
 
 Gated with the flight recorder (``bigdl.observability.flight.enabled``):
 disabled means :func:`observe` is one attribute check, no window, no
@@ -39,17 +40,20 @@ from typing import Any, Dict, List, Optional, Tuple
 from bigdl_tpu.observability import compile_recorder, flight
 from bigdl_tpu.utils.conf import conf
 
-#: (device_kind substring, peak dense bf16 TFLOP/s, peak HBM GB/s) per
-#: chip — public spec sheets; first substring match wins (lowercased).
-#: The flops column mirrors bench.py's ``_PEAK_BF16_FLOPS``.
-PEAK_SPECS: Tuple[Tuple[str, float, float], ...] = (
-    ("v6", 918.0, 1640.0),    # Trillium / v6e
-    ("v5p", 459.0, 2765.0),
-    ("v5", 197.0, 819.0),     # v5e / "TPU v5 lite"
-    ("v4", 275.0, 1228.0),
-    ("v3", 123.0, 900.0),
-    ("v2", 45.0, 700.0),
-)
+#: ``device_kind`` -> (peak dense bf16 TFLOP/s, peak HBM GB/s) per chip.
+#: Keys are the exact strings PJRT reports (the spellings are those of
+#: jax._src.pallas.mosaic.tpu_info); figures are from the Google Cloud
+#: TPU documentation page of each generation ("TPU v5e": 197 TFLOP/s
+#: bf16, 819 GB/s HBM). Only "TPU v5 lite" has been read back from a
+#: device by this repo (chip_smoke.py, PR 21).
+PEAK_SPECS: Dict[str, Tuple[float, float]] = {
+    "TPU v2": (45.0, 700.0),
+    "TPU v3": (123.0, 900.0),
+    "TPU v4": (275.0, 1228.0),
+    "TPU v5 lite": (197.0, 819.0),     # v5e
+    "TPU v5p": (459.0, 2765.0),
+    "TPU v6 lite": (918.0, 1640.0),    # v6e / Trillium
+}
 
 #: Gauges are derived over the most recent N sampled dispatches, so a
 #: long-idle engine converges to its *current* operating point instead
@@ -71,28 +75,37 @@ def _device_kind() -> str:
         return "unknown"
 
 
+def peak_spec(device) -> Optional[Tuple[float, float]]:
+    """(peak dense bf16 TFLOP/s, peak HBM GB/s) of ``device`` from
+    :data:`PEAK_SPECS`; None for a non-TPU device. A TPU whose
+    ``device_kind`` is not in the table raises: a ratio against a
+    guessed peak is worse than none."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return PEAK_SPECS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak figures for TPU device_kind "
+            f"{device.device_kind!r}: add it to utilization.PEAK_SPECS "
+            f"with its source (known: {sorted(PEAK_SPECS)})") from None
+
+
 def peaks() -> Tuple[Optional[float], Optional[float]]:
     """(peak flop/s, peak HBM GB/s) for this platform, or None per axis
-    when unknown (non-TPU backend with no conf override) — unknown
-    peaks suppress the ratio gauges rather than inventing a roofline."""
+    on a non-TPU backend with no conf override — the ratio gauges are
+    then suppressed rather than computed against an invented roofline."""
     tf = conf.get_float("bigdl.device.peak.tflops", 0.0) or 0.0
     gb = conf.get_float("bigdl.device.peak.gbps", 0.0) or 0.0
     peak_f = tf * 1e12 if tf > 0 else None
     peak_b = gb if gb > 0 else None
     if peak_f is not None and peak_b is not None:
         return peak_f, peak_b
-    try:
-        import jax
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", "").lower()
-        if "tpu" in kind or d.platform == "tpu":
-            for key, f, b in PEAK_SPECS:
-                if key in kind:
-                    peak_f = peak_f if peak_f is not None else f * 1e12
-                    peak_b = peak_b if peak_b is not None else b
-                    break
-    except Exception:
-        pass
+    import jax
+    spec = peak_spec(jax.devices()[0])
+    if spec is not None:
+        peak_f = peak_f if peak_f is not None else spec[0] * 1e12
+        peak_b = peak_b if peak_b is not None else spec[1]
     return peak_f, peak_b
 
 
@@ -235,6 +248,6 @@ def reset():
 
 
 __all__ = [
-    "PEAK_SPECS", "WINDOW", "observe", "peaks", "reset",
+    "PEAK_SPECS", "WINDOW", "observe", "peak_spec", "peaks", "reset",
     "roofline_table", "snapshot",
 ]

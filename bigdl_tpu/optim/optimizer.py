@@ -991,11 +991,10 @@ class DistriOptimizer(BaseOptimizer):
             new_params, new_opt = optim.step(params, grads, opt_state, lr)
             return new_params, new_states, new_opt, loss, tele
 
-        from bigdl_tpu.utils.jax_compat import shard_map
         rep, sh = P(), P(self.data_axis)
-        smap = shard_map(local_step, mesh=self.mesh,
-                         in_specs=(rep, rep, rep, sh, sh, rep, rep),
-                         out_specs=(rep, rep, rep, rep, rep))
+        smap = jax.shard_map(local_step, mesh=self.mesh,
+                             in_specs=(rep, rep, rep, sh, sh, rep, rep),
+                             out_specs=(rep, rep, rep, rep, rep))
         return obs.compiled(smap, name="optimizer/train_step_compressed",
                             donate_argnums=(0, 1, 2))
 
@@ -1028,8 +1027,14 @@ class Optimizer:
                 distributed: Optional[bool] = None, **kwargs):
         # tuple sugar handled once, in BaseOptimizer.__init__
         if distributed is None:
-            distributed = Engine.is_initialized() and \
-                len(jax.devices()) > 1
+            devices = jax.devices()
+            distributed = Engine.is_initialized() and len(devices) > 1
+            if len(devices) > 1 and not distributed:
+                logger.warning(
+                    "Engine.init() has not been called: training runs "
+                    "on %s alone although JAX sees %d devices; call "
+                    "Engine.init() first (or pass distributed=True) to "
+                    "train over all of them", devices[0], len(devices))
         if distributed:
             return DistriOptimizer(model, dataset, criterion, batch_size,
                                    end_trigger, **kwargs)

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from bigdl_tpu import observability as obs
-from bigdl_tpu.utils.jax_compat import axis_size as _axis_size
 
 
 def _count_collective(op: str, tree: Any, bytes_per_element=None):
@@ -112,7 +111,7 @@ def quantized_all_reduce(tree: Any, axis_name: str, mean: bool = False,
     # ~1 B/element int8 payload + 4 B per block of shared f32 scale
     _count_collective("quantized_all_reduce", tree,
                       bytes_per_element=1.0 + 4.0 / block)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
 
     def _qr(x):
         orig_dtype = x.dtype
@@ -162,7 +161,7 @@ def ppermute_next(x, axis_name: str, shift: int = 1):
     """Circular shift around the axis ring (ring attention's neighbor
     exchange; rides ICI nearest-neighbor links)."""
     _count_collective("ppermute", x)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

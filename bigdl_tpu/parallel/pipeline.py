@@ -21,8 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from bigdl_tpu.utils import jax_compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -42,7 +40,7 @@ def pipeline_stage_fn(stage_apply: Callable, axis_name: str = "pipe"):
     """
 
     def run(stage_params, microbatches):
-        n_stages = jax_compat.axis_size(axis_name)
+        n_stages = lax.axis_size(axis_name)
         idx = lax.axis_index(axis_name)
         n_micro = microbatches.shape[0]
         ticks = n_micro + n_stages - 1
@@ -99,8 +97,6 @@ class PipelineModule:
         self.mesh = mesh
         self.axis = axis
         self.n_stages = n_stages
-        from bigdl_tpu.utils.jax_compat import shard_map
-
         if remat:
             # recompute stage activations in the backward schedule instead
             # of storing every tick's outputs (GPipe's activation memory
@@ -110,18 +106,10 @@ class PipelineModule:
             lambda p, x: stage_apply(
                 jax.tree_util.tree_map(lambda l: l[0], p), x),
             axis_name=axis)
-        # 0.4.x's replication checker mis-types the cond in the tick body
-        # ("mismatched replication types"; the error text itself
-        # prescribes check_rep=False). Newer jax dropped the kwarg and
-        # types it correctly, so only disable where the kwarg exists.
-        import inspect
-        kw = {}
-        if "check_rep" in inspect.signature(shard_map).parameters:
-            kw["check_rep"] = False
-        self._fn = shard_map(
+        self._fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis), P()),
-            out_specs=P(), **kw)
+            out_specs=P())
 
     def __call__(self, stacked_params, microbatches):
         """microbatches: (n_micro, mb, ...) -> (n_micro, mb, ...)."""

@@ -20,8 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from bigdl_tpu.utils import jax_compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -29,16 +27,11 @@ NEG_INF = -1e30
 
 def _varying(x, like):
     """Make a locally-created array inherit ``like``'s varying-manual-axes
-    type — required by jax>=0.9 shard_map VMA typing when the array enters a
+    type — required by shard_map's VMA typing when the array enters a
     scan carry whose other leg went through a collective. Uses ``lax.pcast``
     (a pure type cast, no data dependence on ``like``'s values, so a
     poisoned inf/NaN in ``like`` cannot corrupt ``x``)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None or not hasattr(lax, "pcast"):
-        # pre-VMA jax (0.4.x): shard_map has no varying-axes typing, the
-        # cast is meaningless and the carry legs unify as-is
-        return x
-    vma = tuple(typeof(like).vma - typeof(x).vma)
+    vma = tuple(jax.typeof(like).vma - jax.typeof(x).vma)
     if not vma:
         return x
     return lax.pcast(x, vma, to="varying")
@@ -100,7 +93,7 @@ def ring_self_attention(q, k, v, axis_name: str = "seq",
                         scale: Optional[float] = None):
     """Per-device body: call inside ``shard_map`` with seq sharded on
     ``axis_name``. q/k/v: (B, S_local, H, D) local chunks."""
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     hkv = k.shape[2]
@@ -138,12 +131,11 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "seq",
                    batch_axis: Optional[str] = "data"):
     """Global entry: q/k/v are (B, S, H, D) arrays; S is sharded over
     ``axis`` (and optionally B over ``batch_axis``) by this wrapper."""
-    from bigdl_tpu.utils.jax_compat import shard_map
 
     baxis = batch_axis if (batch_axis and batch_axis in mesh.axis_names) \
         else None
     spec = P(baxis, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_self_attention, axis_name=axis,
                           causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

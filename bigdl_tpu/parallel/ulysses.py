@@ -17,8 +17,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from bigdl_tpu.utils import jax_compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -43,7 +41,7 @@ def ulysses_self_attention(q, k, v, axis_name: str = "seq",
     H divisible by the axis size."""
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if q.shape[2] % n != 0:
         raise ValueError(f"heads {q.shape[2]} not divisible by axis size {n}")
 
@@ -66,12 +64,11 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "seq",
                       causal: bool = False, scale: Optional[float] = None,
                       batch_axis: Optional[str] = "data"):
     """Global entry mirroring :func:`ring_attention`'s signature."""
-    from bigdl_tpu.utils.jax_compat import shard_map
 
     baxis = batch_axis if (batch_axis and batch_axis in mesh.axis_names) \
         else None
     spec = P(baxis, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_self_attention, axis_name=axis,
                           causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

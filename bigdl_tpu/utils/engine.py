@@ -128,9 +128,19 @@ class Engine:
                             "autodetect failed; continuing single-"
                             "process: %s", e)
 
-            backend = (engine_type or conf.get("bigdl.engine.type")
-                       or os.environ.get("BIGDL_ENGINE_TYPE",
-                                         jax.default_backend()))
+            requested = (engine_type or conf.get("bigdl.engine.type")
+                         or os.environ.get("BIGDL_ENGINE_TYPE"))
+            backend = requested or jax.default_backend()
+            if requested == "tpu" and jax.default_backend() != "tpu":
+                # the mesh below is built from jax.devices() whatever
+                # the label says: an explicit "tpu" on a host that lost
+                # (or never had) the chip must not train on a CPU mesh
+                # called "tpu"
+                raise RuntimeError(
+                    "engine type 'tpu' was requested but JAX's default "
+                    f"backend is {jax.default_backend()!r} "
+                    f"({jax.devices()[0].device_kind}); is the chip "
+                    "held by another process?")
             devices = jax.devices()
             local = jax.local_devices()
             if mesh_axes:

@@ -317,12 +317,23 @@ class TestUtilization:
         conf.set("bigdl.device.peak.gbps", "819")
         assert utilization.peaks() == (197e12, 819.0)
 
-    def test_peak_flops_table_mirrors_bench(self):
+    def test_peak_spec_keyed_by_exact_device_kind(self):
+        """One table (bench.py reads it too): exact ``device_kind``
+        lookup, None off-TPU, and a TPU the table does not hold is an
+        error rather than the nearest substring's figures."""
+        import types
+
         import bench
-        bench_table = dict(bench._PEAK_BF16_FLOPS)
-        for key, tflops, _gbps in utilization.PEAK_SPECS:
-            assert bench_table.get(key) == pytest.approx(tflops * 1e12), \
-                f"PEAK_SPECS[{key}] drifted from bench._PEAK_BF16_FLOPS"
+        v5e = types.SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v5 lite")
+        assert utilization.peak_spec(v5e) == (197.0, 819.0)
+        assert bench._peak_flops(v5e) == 197e12
+        cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu")
+        assert utilization.peak_spec(cpu) is None
+        unknown = types.SimpleNamespace(platform="tpu",
+                                        device_kind="TPU v5 litest")
+        with pytest.raises(ValueError, match="TPU v5 litest"):
+            utilization.peak_spec(unknown)
 
 
 class TestExplainTools:

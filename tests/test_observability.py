@@ -385,7 +385,7 @@ class TestCollectiveTelemetry:
 
         from bigdl_tpu.parallel import create_mesh
         from bigdl_tpu.parallel.collectives import all_reduce
-        from bigdl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         mesh = create_mesh({"data": 8})
         before = obs.REGISTRY.sample_value(

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from bigdl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 from bigdl_tpu.parallel import (
     all_gather, all_reduce, compressed_all_reduce, create_mesh,

@@ -3,20 +3,22 @@ interpret-only — a TPU lowering regression must fail a test, not surface
 in the bench).
 
 This suite runs with the real backend (no platform override, unlike
-tests/conftest.py) and skips itself entirely when no TPU is attached:
+tests/conftest.py), as one process that owns the chip:
 
-    python -m pytest tests_tpu/ -q        # on a TPU host
+    python -m pytest tests_tpu -q         # on a TPU host
 
-The driver's bench invocation also runs these via ``python bench.py
---tpu-smoke``.
+Without a TPU the run FAILS (exit code 1) before collecting anything: a
+suite that skips every test when the chip is lost reports green for
+kernels Mosaic has never compiled.
 """
 
 import jax
 import pytest
 
 
-def pytest_collection_modifyitems(config, items):
-    if jax.default_backend() != "tpu":
-        skip = pytest.mark.skip(reason="no TPU attached")
-        for item in items:
-            item.add_marker(skip)
+def pytest_sessionstart(session):
+    backend = jax.default_backend()
+    if backend != "tpu":
+        pytest.exit(
+            f"tests_tpu needs a TPU: JAX's default backend is "
+            f"{backend!r} ({jax.devices()[0].device_kind})", returncode=1)

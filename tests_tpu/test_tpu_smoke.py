@@ -56,6 +56,26 @@ class TestInt4OnChip:
         """N not a multiple of bn — exercises N padding."""
         self._check(3, 1000, 256)
 
+    @pytest.mark.parametrize("n,k", [(4096, 14336), (28672, 4096),
+                                     (6144, 4096)])
+    def test_mistral_7b_widths(self, n, k):
+        """The linears chip_smoke.py serves (``LlamaConfig.mistral_7b``,
+        fused layout): down_proj K=14336 (two 7168-row K chunks), fused
+        gate_up N=28672, fused qkv N=6144 — at decode (m=1, corr mode)
+        and at the largest prefill bucket (m=2048, sub8 mode), one
+        weight set for both."""
+        w, qd, td = _rand_quant(n, k, "sym_int4")
+        q, scale = jnp.asarray(td["q"]), jnp.asarray(td["scale"])
+        rs = np.random.RandomState(1)
+        for m in (1, 2048):
+            x = rs.randn(m, k).astype(np.float32)
+            ref = int4_matmul_reference(x, qd["q"], qd["scale"])
+            out = np.asarray(int4_matmul(
+                jnp.asarray(x, jnp.bfloat16), q, scale,
+                out_dtype=jnp.float32), np.float32)
+            rel = np.abs(out - ref).max() / (np.abs(ref).max() + 1e-6)
+            assert rel < 0.03, f"m={m} n={n} k={k}: rel={rel}"
+
 
 class TestOtherKernelsOnChip:
     def test_int8(self):
@@ -189,6 +209,50 @@ class TestPagedAttentionOnChip:
             sl = int(lens[bi])
             assert np.abs(ker[bi, :sl] - ref[bi, :sl]).max() < 0.05
 
+    @staticmethod
+    def _ragged_parity(seed, Hq, Hkv, Tq, maxp, offs, lens, window=None):
+        """Kernel vs XLA twin over each row's true suffix length, bf16
+        operands, head_dim 128, 16-token pages, a shuffled block table
+        (page 0 left out, as in the engine)."""
+        from bigdl_tpu.llm.kernels.ragged_prefill import (
+            ragged_prefill_attention, ragged_prefill_reference)
+        rs = np.random.RandomState(seed)
+        B, D, page = len(offs), 128, 16
+        P = B * maxp + 1
+        q = jnp.asarray(rs.randn(B, Tq, Hq, D), jnp.bfloat16)
+        ks = jnp.asarray(rs.randn(B, Tq, Hkv, D) * 0.5, jnp.bfloat16)
+        vs = jnp.asarray(rs.randn(B, Tq, Hkv, D) * 0.5, jnp.bfloat16)
+        kp = jnp.asarray(rs.randn(P, Hkv, page, D) * 0.5, jnp.bfloat16)
+        vp = jnp.asarray(rs.randn(P, Hkv, page, D) * 0.5, jnp.bfloat16)
+        bt = jnp.asarray(1 + rs.permutation(P - 1)[:B * maxp]
+                         .reshape(B, maxp), jnp.int32)
+        args = (q, ks, vs, kp, vp, bt, jnp.asarray(offs, jnp.int32),
+                jnp.asarray(lens, jnp.int32))
+        ker = np.asarray(ragged_prefill_attention(
+            *args, page_size=page, sliding_window=window), np.float32)
+        ref = np.asarray(ragged_prefill_reference(
+            *args, sliding_window=window), np.float32)
+        assert np.isfinite(ker).all()
+        for bi, sl in enumerate(lens):
+            assert np.abs(ker[bi, :sl] - ref[bi, :sl]).max() < 0.05
+
+    @pytest.mark.parametrize("Hq,Hkv", [(32, 32), (32, 8)])
+    @pytest.mark.parametrize("Tq,offs", [(128, (1900, 777)),
+                                         (2048, (2000,)), (2048, (0,))])
+    def test_ragged_prefill_served_tiles(self, Hq, Hkv, Tq, offs):
+        """The tiles the engine dispatches at 7B widths: 128-token
+        query tiles (hkv * qt * g = 4096 accumulator rows) for MHA-32
+        and GQA-8, at one tile (Tq 128) and at the largest prefill
+        bucket (Tq 2048, 16 query tiles), over long prefixes read in
+        place — the VMEM limit the kernel states must hold here."""
+        self._ragged_parity(3, Hq, Hkv, Tq, 128, offs,
+                            [Tq - 3 * i for i in range(len(offs))])
+
+    def test_ragged_prefill_sliding_window(self):
+        """mistral_7b serves with sliding_window=4096: the windowed
+        mask must lower too (a short window here, so it binds)."""
+        self._ragged_parity(4, 32, 8, 256, 64, (700, 40), (256, 200),
+                            window=300)
 
 
 def _tiny_serving_model():

@@ -36,9 +36,7 @@ from bigdl_tpu.llm.kernels.paged_attention import (
     LANE, merge_attention_partial, paged_attention_stats)
 from bigdl_tpu.llm.models.llama import (LlamaConfig, _linear,
                                         attention_qkv, mlp, rms_norm,
-                                        rope_cfg)
-
-import bench as _bench
+                                        rope_cfg, synthetic_q4_params)
 
 
 def build_step(cfg, bt, page, num_pages, mode: str):
@@ -96,7 +94,7 @@ def build_step(cfg, bt, page, num_pages, mode: str):
 
 def decode_gap(batch=8, ctx_len=256, page_size=16, cfg=None):
     cfg = cfg or LlamaConfig.llama2_7b()
-    params = _bench._synthetic_q4_llama_params(cfg)
+    params = synthetic_q4_params(cfg)
     ppb = LANE // page_size
     cap = -(-(ctx_len + 160) // page_size)
     pages_cap = -(-cap // ppb) * ppb
@@ -192,7 +190,7 @@ def prefill_gap(splits=None, page_size=16, cfg=None, repeats=8):
         limit = min(256, cfg.max_position_embeddings)
         splits = ((limit * 3 // 4, limit // 4),
                   (limit * 7 // 8, limit // 8))
-    params = _bench._synthetic_q4_llama_params(cfg)
+    params = synthetic_q4_params(cfg)
     nl, hkv, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                    cfg.head_dim)
     ppb = LANE // page_size
