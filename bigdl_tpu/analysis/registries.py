@@ -494,14 +494,24 @@ SPAN_NAMES.update({
         "completion: one HBM<->host migration job",
     "llm/decode":
         "per-request decode phase on the engine (PR 3)",
-    "llm/decode_step":
-        "one pipelined engine decode pass",
+    "llm/admit":
+        "engine pass phase: the admission sweep (prefills nest in it)",
+    "llm/dispatch":
+        "engine pass phase: mask build + the step program's jit call",
+    "llm/drain":
+        "engine pass phase: token bookkeeping after the fence",
+    "llm/fence_wait":
+        "engine pass phase: the device->host fetch of a step's tokens",
+    "llm/grant":
+        "engine pass phase: page ledger + block-table scatter",
     "llm/handoff_export":
         "KV chain serialized for disaggregated handoff",
     "llm/handoff_import":
         "KV handoff blob landed into pool/arena",
     "llm/mixed_step":
         "one unified mixed prefill+decode pass (decode rows + a chunk)",
+    "llm/pass":
+        "completion: one engine loop iteration, first phase to last",
     "llm/preempt":
         "completion: one lossless preemption of an in-flight decode",
     "llm/prefill":

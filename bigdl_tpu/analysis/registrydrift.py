@@ -43,7 +43,8 @@ _SITE_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_*?]+)+$")
 _METRIC_DECL_FUNCS = ("counter", "gauge", "histogram", "sketch",
                       "_count")
 _METRIC_USE_FUNCS = _METRIC_DECL_FUNCS + ("sample_value", "get")
-_SPAN_FUNCS = ("span", "add_complete")
+# ``_phase`` is LLMServer's wrapper around ``span`` for the pass phases
+_SPAN_FUNCS = ("span", "add_complete", "_phase")
 
 #: pytest's own marks plus plugin marks in use — never registry entries
 _BUILTIN_MARKS = frozenset({

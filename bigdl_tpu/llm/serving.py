@@ -444,11 +444,14 @@ class Request:
         # rides the handle into the engine thread (contextvars don't
         # cross threads); None when no trace / observability disabled
         self.trace = rc.to_wire(rc.current())
-        self.submitted_at = time.time() if self.trace else 0.0
         self.decode_started_at = 0.0
-        # always-on TTFT accounting (ISSUE 5 microbench): submit stamp
-        # here, first-token stamp at the engine's drain
+        # always-on stamps on ``time.perf_counter()``, the clock the
+        # trace ring's ``t0`` and a JAX profile's host side share:
+        # submit here, admission when the engine gives the request a
+        # slot (the first time, if it is preempted and resumed), first
+        # token at the engine's drain
         self.t_submit = time.perf_counter()
+        self.t_admit = 0.0
         self.t_first_token = 0.0
         # per-request SLO accounting (ISSUE 12, engine scope): last
         # token's drain stamp and the worst inter-token gap so far —
@@ -456,10 +459,11 @@ class Request:
         # exists
         self.t_last_token = 0.0
         self.itl_max = -1.0
-        # per-token drain stamps (ISSUE 14 microbenches): appended only
-        # when the SLO account exists — the exact fence-arrival clocks
-        # the ITL sketches observe, so tools can compute per-request
-        # gap percentiles without polling
+        # per-token fence stamps, always on: the clock read right after
+        # the device->host fetch that made the token host-visible (one
+        # read per drain, shared by every token of that drain) — the
+        # exact arrival times the ITL sketches observe, so a caller or
+        # a tool computes per-request gaps without polling
         self.t_tokens: List[float] = []
 
     def get(self, timeout: Optional[float] = None) -> List[int]:
@@ -687,9 +691,21 @@ class LLMServer:
         # host-vs-stall split tools/microbench_decode.py reads, plus the
         # prefill-token tally tools/microbench_prefix.py diffs cache
         # on/off (prefix reuse shows up as fewer prefilled tokens)
+        # (``host_seconds`` brackets the page grant and the step's
+        # dispatch only; what else the engine thread does for a pass —
+        # admission, the drain's bookkeeping — is in the ``llm/*`` phase
+        # spans, docs/OBSERVABILITY.md)
         self.host_seconds = 0.0
         self.stall_seconds = 0.0
         self.prefill_tokens_total = 0
+        # the phase spans of the pass the engine loop is in (None when a
+        # caller drives _admit/_step by hand: there is no pass then),
+        # the open llm/dispatch span, which _after_dispatch closes, and
+        # the open llm/admit span's args, which the admission paths
+        # count into
+        self._phases: Optional[List[Any]] = None
+        self._dispatch_ph: Optional[Any] = None
+        self._admit_args: Dict[str, Any] = {}
         # engine passes that raised (retried or failed): 0 on a healthy
         # run, readable without observability (chip_smoke.py asserts it)
         self.pass_errors = 0
@@ -1571,6 +1587,49 @@ class LLMServer:
             self._sched.push_entry(h)
         return out
 
+    def _phase(self, name: str, **args):
+        """One phase of an engine pass, as a span: ``llm/admit``,
+        ``llm/grant``, ``llm/dispatch``, ``llm/fence_wait``,
+        ``llm/drain``. The phases of a pass follow one another on the
+        engine thread and never overlap; ``llm/pass`` is the interval
+        from the first one's start to the last one's end, and what they
+        leave of it is the pass's self time. Each enters a profiler
+        annotation of its own name, so a JAX profile captured from a
+        serving process shows them beside the device's timeline."""
+        sp = obs.span(name, annotate=True, **args)
+        if self._phases is not None:
+            self._phases.append(sp)
+        return sp
+
+    def _record_pass(self, phases: List[Any]):
+        """``llm/pass`` for the loop iteration whose phases these were.
+        Ring only: an annotation that encloses the phases would be the
+        longest host event over every device-idle gap, and a profile
+        reader that names a gap by its longest overlap would then name
+        every gap ``llm/pass``."""
+        if phases[0].t0 is None or phases[-1].t1 is None:
+            return      # observability was off when the pass ran
+        args = {"step": self.steps, "rows": 0, "admitted": 0,
+                "prefills": 0, "fn": None}
+        for sp in phases:
+            for k in args.keys() & sp.args.keys():
+                args[k] = sp.args[k]
+        t0 = phases[0].t0
+        dur = phases[-1].t1 - t0
+        obs.add_complete("llm/pass", time.time() - (time.perf_counter()
+                                                    - t0), dur, t0,
+                         **args)
+
+    def _admit_waiting(self) -> bool:
+        """Anything an admission sweep could act on: a parked or landed
+        host-tier fetch, a held head, a queued or scheduled request."""
+        if self._fetch_wait or self._fetch_ready \
+                or not self._queue.empty():
+            return True
+        if getattr(self, "_pending_head", None) is not None:
+            return True
+        return self._sched is not None and len(self._sched) > 0
+
     def _admit(self):
         """Fill free slots from the queue; per-slot prefill. Paged mode
         additionally requires the request's worst-case page budget
@@ -1579,29 +1638,37 @@ class LLMServer:
         later one is admitted either. Host-tier hits (ISSUE 6) are
         PARKED while their pages upload — they hold their budget but no
         slot, so later requests admit and decode meanwhile; completed
-        fetches re-enter here first."""
-        if self._fetch_wait:
-            self._poll_fetches()
-        if self._sched is not None:
-            # class-ordered admission (ISSUE 17): drain the thread-safe
-            # intake queue into the scheduler heap, then admit in
-            # (class rank, arrival) order. The heap is engine-thread
-            # only; submit() bounds intake + heap together.
-            try:
-                while True:
-                    self._sched.push(self._queue.get_nowait())
-            except queue.Empty:
-                pass
-        for i in range(self.max_batch):
-            if self._slots[i] is not None:
-                continue
-            if not self._admit_into(i):
-                break
-        if self._sched is not None and self._sched.live():
-            # waiters remain after the sweep (no slot, or the best one
-            # is budget-blocked): lossless preemption of a lower-class
-            # decode is the relief valve
-            self._consider_preempt()
+        fetches re-enter here first. The sweep is the ``llm/admit``
+        phase of the pass (prefills, lookups and fetch waits nest in
+        it); a pass with nobody waiting has none."""
+        if not self._admit_waiting():
+            return
+        with self._phase("llm/admit", admitted=0, prefills=0,
+                         prompt_tokens=0, bucket_tokens=0) as ph:
+            self._admit_args = ph.args
+            if self._fetch_wait:
+                self._poll_fetches()
+            if self._sched is not None:
+                # class-ordered admission (ISSUE 17): drain the
+                # thread-safe intake queue into the scheduler heap,
+                # then admit in (class rank, arrival) order. The heap
+                # is engine-thread only; submit() bounds intake + heap
+                # together.
+                try:
+                    while True:
+                        self._sched.push(self._queue.get_nowait())
+                except queue.Empty:
+                    pass
+            for i in range(self.max_batch):
+                if self._slots[i] is not None:
+                    continue
+                if not self._admit_into(i):
+                    break
+            if self._sched is not None and self._sched.live():
+                # waiters remain after the sweep (no slot, or the best
+                # one is budget-blocked): lossless preemption of a
+                # lower-class decode is the relief valve
+                self._consider_preempt()
 
     def _admit_into(self, i: int) -> bool:
         """Admit one request into free slot ``i``. False stops the slot
@@ -1782,15 +1849,24 @@ class LLMServer:
         model dispatch here — the prompt is fed chunk by chunk in
         subsequent engine passes, interleaved with decode."""
         ctx = rc.from_wire(req.trace)
-        if ctx is not None and req.submitted_at:
-            # engine-side admission wait, parented to the submitter
-            args = ({"parent_span": ctx.span_id}
-                    if ctx.span_id else {})
-            obs.add_complete(
-                "llm/queue_wait", req.submitted_at,
-                time.time() - req.submitted_at, trace=ctx.trace_id,
-                stage="queue", request=req.id, **args)
+        now = time.perf_counter()
+        if not req.t_admit:
+            req.t_admit = now
+        # engine-side admission wait of every request; one that carries
+        # a trace context is parented to its submitter besides
+        args = {}
+        if ctx is not None:
+            args["trace"] = ctx.trace_id
+            if ctx.span_id:
+                args["parent_span"] = ctx.span_id
+        wait = now - req.t_submit
+        obs.add_complete("llm/queue_wait", time.time() - wait, wait,
+                         req.t_submit, stage="queue", request=req.id,
+                         **args)
         ids = self._prompt_of(req)
+        self._admit_args["admitted"] += 1
+        self._admit_args["prompt_tokens"] += \
+            len(ids) - (adm.matched_len if adm else 0)
         if flight.enabled:
             flight.record(
                 "admit", request_id=req.id, trace_id=_trace_of(req),
@@ -1834,6 +1910,7 @@ class LLMServer:
             req.done.set()
             raise
         req.decode_started_at = time.time()
+        self._admit_args["prefills"] += 1
         suffix = len(ids) - (adm.matched_len if adm else 0)
         self._record_prefill(suffix, time.perf_counter() - t0)
 
@@ -1898,6 +1975,7 @@ class LLMServer:
         cache_in["pos"] = jnp.asarray(start, jnp.int32)
         logits, new_cache = self._fwd(self.model.params, tokens=toks,
                                       cache=cache_in, positions=positions)
+        self._admit_args["bucket_tokens"] += t    # no padding here
         row = jnp.arange(self.max_batch) == i
         keep = row[None, :, None, None, None]
         old = self._cache
@@ -2048,6 +2126,7 @@ class LLMServer:
                 self.model.params, self._k_pages, self._v_pages,
                 toks_d, t_d, pids_d)
             self.prefill_dense_staged_tokens += bucket
+            self._admit_args["bucket_tokens"] += bucket
         except BaseException:
             self._kv.free_owned(ids)   # physical pages must not leak
             raise
@@ -2128,6 +2207,7 @@ class LLMServer:
             # of slack + the suffix bucket through a temp cache
             self.prefill_dense_staged_tokens += n_pp * page + page \
                 + bucket
+            self._admit_args["bucket_tokens"] += bucket
         except BaseException:
             self._kv.free_owned(own)
             raise
@@ -2210,6 +2290,7 @@ class LLMServer:
                 self.model.params, self._k_pages, self._v_pages,
                 toks_d, len_d, off_d, bt_d, phys_d, slots_d, fork_dst,
                 fork_src)
+            self._admit_args["bucket_tokens"] += bucket
         except BaseException:
             self._kv.free_owned(own)
             raise
@@ -2899,9 +2980,9 @@ class LLMServer:
         return obs.compiled(step, name="llm/decode_paged",
                             donate_argnums=(1, 2))
 
-    def _record_decode(self, n_active: int, applied: int, host_s: float,
-                       stall_s: float, finished: int,
-                       cancelled: int = 0, fn: Optional[str] = None):
+    def _record_decode(self, applied: int, host_s: float, stall_s: float,
+                       finished: int, cancelled: int = 0,
+                       fn: Optional[str] = None):
         """Per-step attribution (ISSUE 4 satellite): the old single wall
         number silently included the sync barrier and overstated device
         cost; host scheduling and the device-fence stall are now
@@ -2923,13 +3004,6 @@ class LLMServer:
         ins["decode_seconds"].observe(wall)
         ins["decode_host"].observe(host_s)
         ins["decode_stall"].observe(stall_s)
-        # the duration is already measured, so the span is appended
-        # directly rather than re-bracketing the step with a context
-        # manager
-        obs.tracing.add_complete(
-            "llm/decode_step", time.time() - wall, wall,
-            active=n_active, step=self.steps,
-            host_s=round(host_s, 6), stall_s=round(stall_s, 6))
         # live occupancy, not the drained record's pair count: a record
         # may carry speculative pairs for requests finished by an
         # earlier drain, which would leave a phantom nonzero gauge on
@@ -2974,8 +3048,10 @@ class LLMServer:
 
     def _after_dispatch(self, rec: dict, t0: float) -> bool:
         """Shared dispatch epilogue: account host time, push the record
-        onto the in-flight window, drain down to the depth bound (depth
-        1 drains immediately — the synchronous engine)."""
+        onto the in-flight window — there the pass's ``llm/dispatch``
+        phase ends, for every dispatch path alike — and drain down to
+        the depth bound (depth 1 drains immediately — the synchronous
+        engine); each drain is a phase pair of its own."""
         rec["host_s"] = time.perf_counter() - t0
         self.host_seconds += rec["host_s"]
         self.steps += 1
@@ -2983,6 +3059,7 @@ class LLMServer:
         ins = self._instruments()
         if ins is not None:
             ins["inflight"].set(len(self._inflight))
+        self._dispatch_ph.end(fn=rec["fn"], rows=len(rec["pairs"]))
         while len(self._inflight) >= self.pipeline_depth:
             self._drain_next()
         return True
@@ -2992,12 +3069,28 @@ class LLMServer:
         its (tokens ‖ fence) vector — the portable completion barrier —
         then EOS/max-token bookkeeping one step behind dispatch
         (mirroring the optimizer's ``_pending_loss`` drain). Slots whose
-        request finished meanwhile discard their speculative token."""
+        request finished meanwhile discard their speculative token.
+        Two phases of the pass: ``llm/fence_wait`` brackets the fetch
+        and nothing else, ``llm/drain`` everything after it."""
         rec = self._inflight.popleft()
         t0 = time.perf_counter()
-        vals = np.asarray(rec["out"])
-        stall = time.perf_counter() - t0
+        with self._phase("llm/fence_wait"):
+            vals = np.asarray(rec["out"])
+        # one clock read per drain, always: the tokens of this record
+        # became host-visible at this fetch, so it is the arrival time
+        # of every one of them (``Request.t_tokens``, the SLO stamps)
+        now = time.perf_counter()
+        stall = now - t0
         self.stall_seconds += stall
+        with self._phase("llm/drain") as ph:
+            ph.args["requests"], ph.args["finished"] = \
+                self._retire(rec, vals, now, stall)
+
+    def _retire(self, rec: dict, vals, now: float, stall: float):
+        """Everything a drain does once the record's values are on the
+        host: drop the references the fence was guarding, apply the
+        tokens, release finished slots, record the metrics. Returns the
+        ids of the requests that got a token and how many finished."""
         # the fence proves every computation enqueued before this step —
         # including the updates rec["pinned"] was holding buffers for —
         # has retired; the references may drop now, and so may the page
@@ -3006,10 +3099,7 @@ class LLMServer:
         for args in rec.pop("kv_release", ()):
             self._kv.release_slot(*args)
         finished = applied = cancelled = 0
-        # one clock read per drain, shared by every slot's SLO stamps
-        # (ISSUE 12): the tokens in this pass became host-visible at
-        # the same fence fetch, so one arrival time is the honest one
-        now = time.perf_counter() if self._slo is not None else 0.0
+        served: List[str] = []
         for i, req in rec["pairs"]:
             if self._slots[i] is not req:
                 continue   # speculative token for a finished request
@@ -3024,6 +3114,7 @@ class LLMServer:
                 continue
             tok = int(vals[i])
             applied += 1
+            served.append(req.id)
             if self._apply_token(i, req, tok, now):
                 finished += 1
         sp = rec.get("spec")
@@ -3061,6 +3152,8 @@ class LLMServer:
                         n_draft=sp["n_draft"], accepted=n_acc - 1,
                         emitted=n_acc)
                 base = self.max_batch + 1
+                if n_acc:
+                    served.append(req.id)
                 for j in range(n_acc):
                     applied += 1
                     if self._apply_token(i, req,
@@ -3079,21 +3172,21 @@ class LLMServer:
         ins = self._instruments()
         if ins is not None:
             ins["inflight"].set(len(self._inflight))
-        self._record_decode(len(rec["pairs"]), applied,
-                            rec.get("host_s", 0.0), stall, finished,
-                            cancelled, fn=rec.get("fn"))
+        self._record_decode(applied, rec.get("host_s", 0.0), stall,
+                            finished, cancelled, fn=rec.get("fn"))
+        return served, finished
 
     def _apply_token(self, i: int, req: Request, tok: int,
                      now: float) -> bool:
-        """Append one drained token to ``req`` with the SLO/TTFT
-        stamps, finishing the slot on EOS or budget exhaustion.
+        """Append one drained token to ``req`` with its fence stamp
+        and the SLO/TTFT stamps, finishing the slot on EOS or budget
+        exhaustion.
         Returns True when the request finished — the shared tail of
         the plain decode drain and the speculative accepted-prefix
         drain (ISSUE 19), which applies up to k+1 tokens per pass
         through this same path so EOS semantics cannot diverge."""
         req.tokens.append(tok)
-        if self._slo is not None:
-            req.t_tokens.append(now)
+        req.t_tokens.append(now)
         if len(req.tokens) == 1:
             req.t_first_token = time.perf_counter()  # TTFT stamp
             if self._slo is not None:
@@ -3317,14 +3410,18 @@ class LLMServer:
             # live, solo through the ragged-prefill program otherwise.
             # None = the chunk faulted (request already failed) or is
             # budget-stalled (decode continues; the chunk retries)
-            cargs = self._prepare_chunk(ci)
+            with self._phase("llm/grant") as ph:
+                cargs = self._prepare_chunk(ci)
+                ph.args["pages"] = len(cargs["new_pages"]) if cargs else 0
         if cargs is None and not disp:
             if self._inflight:
                 self._drain_next()
                 return True
             return False
         if cargs is not None and not disp:
-            self._dispatch_chunk_solo(cargs, t_step)
+            with self._phase("llm/dispatch", fn="llm/prefill_ragged",
+                             rows=0):
+                self._dispatch_chunk_solo(cargs, t_step)
             return True
         sargs = None
         if cargs is None and ci is None and self._spec_active:
@@ -3342,6 +3439,24 @@ class LLMServer:
                 if self._inflight:
                     self._drain_next()
                 return True
+        with self._phase("llm/grant") as ph:
+            ph.args["pages"] = self._grant_pages(disp, sargs, cargs)
+        with self._phase("llm/dispatch") as self._dispatch_ph:
+            mask = np.zeros(self.max_batch, bool)
+            mask[disp] = True
+            active = jnp.asarray(mask)
+            if sargs is not None:
+                return self._dispatch_spec(disp, active, sargs, t_step)
+            if cargs is not None:
+                return self._dispatch_mixed(disp, active, cargs, t_step)
+            return self._dispatch_decode(disp, active, t_step)
+
+    def _grant_pages(self, disp, sargs: Optional[dict],
+                     cargs: Optional[dict]) -> int:
+        """The pass's page grant (its ``llm/grant`` phase): one page for
+        every decode row at a page boundary, and a verify chunk's
+        pages, into the host ledger and — one incremental scatter —
+        the device-resident block table. Returns the pages granted."""
         page = self._page
         # the page for position lens[i] must exist before the step; the
         # grant is an incremental scatter into the device-resident block
@@ -3391,13 +3506,11 @@ class LLMServer:
             vals_d = jnp.asarray(vals)
             self._pin(self._bt_dev, vals_d)
             self._bt_dev = self._bt_dev.at[rows, cols].set(vals_d)
-        mask = np.zeros(self.max_batch, bool)
-        mask[disp] = True
-        active = jnp.asarray(mask)
-        if sargs is not None:
-            return self._dispatch_spec(disp, active, sargs, t_step)
-        if cargs is not None:
-            return self._dispatch_mixed(disp, active, cargs, t_step)
+        return len(allocs)
+
+    def _dispatch_decode(self, disp, active, t_step: float) -> bool:
+        """One plain decode pass over the page pool: every active row
+        advances one token."""
         if self._mixed_active:
             # pure-decode pass on a unified server: the batch-mix
             # series still tell the whole story
@@ -3439,31 +3552,34 @@ class LLMServer:
                 return True
             return False
         t_step = time.perf_counter()
-        step = self._slotted_step()
-        mask = np.zeros(self.max_batch, bool)
-        mask[disp] = True
-        active = jnp.asarray(mask)
-        k_in, v_in = self._cache["k"], self._cache["v"]
-        pos_in, last_in, key_in = (self._pos_dev, self._last,
-                                   self._sample_key)
-        out, logits, k_new, v_new, self._pos_dev, self._sample_key = \
-            step(self.model.params, k_in, v_in, pos_in, last_in, active,
-                 self._temp, key_in)
-        old = self._cache
-        self._cache = {"k": k_new, "v": v_new, "pos": old["pos"]}
-        self._last = logits
-        for i in disp:
-            self._pos[i] += 1
-            self._remaining[i] -= 1
-        # the old cache is NOT donated on this legacy path: it is an
-        # input of the in-flight step and must be pinned until its fence
-        rec = {"out": out, "fn": "llm/decode_slotted",
-               "pairs": [(i, self._slots[i]) for i in disp],
-               "refs": (k_in, v_in, pos_in, last_in, active, key_in),
-               "pinned": self._pending_release}
-        self._pending_release = []
-        del old
-        return self._after_dispatch(rec, t_step)
+        with self._phase("llm/dispatch") as self._dispatch_ph:
+            step = self._slotted_step()
+            mask = np.zeros(self.max_batch, bool)
+            mask[disp] = True
+            active = jnp.asarray(mask)
+            k_in, v_in = self._cache["k"], self._cache["v"]
+            pos_in, last_in, key_in = (self._pos_dev, self._last,
+                                       self._sample_key)
+            out, logits, k_new, v_new, self._pos_dev, \
+                self._sample_key = step(
+                    self.model.params, k_in, v_in, pos_in, last_in,
+                    active, self._temp, key_in)
+            old = self._cache
+            self._cache = {"k": k_new, "v": v_new, "pos": old["pos"]}
+            self._last = logits
+            for i in disp:
+                self._pos[i] += 1
+                self._remaining[i] -= 1
+            # the old cache is NOT donated on this legacy path: it is
+            # an input of the in-flight step and must be pinned until
+            # its fence
+            rec = {"out": out, "fn": "llm/decode_slotted",
+                   "pairs": [(i, self._slots[i]) for i in disp],
+                   "refs": (k_in, v_in, pos_in, last_in, active, key_in),
+                   "pinned": self._pending_release}
+            self._pending_release = []
+            del old
+            return self._after_dispatch(rec, t_step)
 
     def _slotted_step(self):
         """Build (once) the compiled slot-static decode step: on-device
@@ -3592,7 +3708,9 @@ class LLMServer:
         prev_exc = None
         while not self._stop.is_set():
             self._hb = time.monotonic()   # watchdog heartbeat: stale =
-            try:                          # wedged INSIDE this pass
+            # wedged INSIDE this pass
+            self._phases = phases = []
+            try:
                 with self._lock:
                     self._admit()
                     busy = self._step()
@@ -3618,5 +3736,8 @@ class LLMServer:
                 time.sleep(next(delays, 0.5))
                 continue
             delays = prev_exc = None   # healthy pass resets the backoff
+            if phases:
+                self._record_pass(phases)
             if not busy:
                 time.sleep(0.002)
+        self._phases = None

@@ -12,16 +12,24 @@ thread with a thread-local stack — the exported depth is what the trace
 viewers use to stack the flame graph, and ``parent`` in args keeps the
 relationship greppable in the raw JSON.
 
+One clock: every record also carries ``t0``, its start in seconds on
+``time.perf_counter()`` — the clock the host side of a JAX profile, the
+``Request`` stamps and any benchmark around the process read — beside
+the epoch ``ts`` the trace viewers want.
+
 Optional JAX profiler passthrough: ``configure(jax_passthrough=True)``
 additionally enters ``jax.profiler.StepTraceAnnotation`` for spans that
 carry a ``step`` arg and ``jax.profiler.TraceAnnotation`` otherwise, so
 the same ``span(...)`` sites label XLA's own device profile when one is
 being captured. Off by default (it is not free) and silently skipped
-when the profiler is unavailable.
+when the profiler is unavailable. A site that belongs in every captured
+profile (the LLM engine's pass phases) asks for its annotation itself,
+``span(name, annotate=True)``: a ``TraceAnnotation`` costs a fraction
+of a microsecond while no profile is being captured.
 
 Disabled mode (:func:`bigdl_tpu.observability.enabled` False): ``span``
-yields immediately — no clock reads, no buffer writes, no allocations
-beyond its own generator frame.
+reads no clock and writes no buffer (an ``annotate=True`` span still
+enters its annotation, which is the profiler's and not this ring's).
 """
 
 from __future__ import annotations
@@ -30,10 +38,10 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
 from bigdl_tpu.observability import _state
+from bigdl_tpu.observability import request_context as rc
 
 
 def _default_capacity() -> int:
@@ -152,82 +160,119 @@ def _jax_annotation(name: str, args: Dict[str, Any]):
     return None
 
 
-@contextmanager
-def span(name: str, **args: Any) -> Iterator[None]:
-    """Record a host-side phase. Nestable; thread-aware; a no-op when
+class span:
+    """``with span("train/step", step=i):`` records a host-side phase.
+    Nestable; thread-aware; reads no clock and writes nothing when
     observability is disabled.
+
+    ``with span(...) as sp`` hands the span itself: ``sp.args`` may be
+    filled in while it is open (a count known only at the end), and
+    ``sp.end()`` closes it before the block does, for a phase that ends
+    inside a callee; the block's own exit is then a no-op. ``sp.t0`` and
+    ``sp.t1`` are its bounds on ``time.perf_counter()`` (``None`` when
+    disabled). ``annotate=True`` enters a ``jax.profiler.
+    TraceAnnotation`` of the same name whatever ``configure`` says and
+    whether or not observability records.
 
     When a :mod:`~bigdl_tpu.observability.request_context` is active
     (``activate(ctx)``), the span is additionally tagged with the
     request's ``trace``/``span``/``parent_span`` ids and becomes the
     ambient parent for anything opened inside it — the mechanism that
     stitches existing ``span()`` sites into cross-process traces."""
-    if not _state.enabled:
-        yield
-        return
-    from bigdl_tpu.observability import request_context as rc
-    stack = _stack()
-    parent = stack[-1] if stack else None
-    stack.append(name)
-    ctx = rc.current()
-    token = None
-    if ctx is not None:
-        # this span's own identity; children parent to it via the
-        # contextvar for the duration of the block
-        ctx = ctx.child()
-        token = rc._current.set(ctx)
-    ann = _jax_annotation(name, args) if _jax_passthrough else None
-    if ann is not None:
-        try:
-            ann.__enter__()
-        except Exception:
-            # a profiler-state hiccup must not crash the instrumented
-            # loop or leak the stack entry we just pushed
-            ann = None
-    t0 = time.perf_counter()
-    wall0 = time.time()
-    try:
-        yield
-    finally:
-        dur = time.perf_counter() - t0
+
+    __slots__ = ("name", "args", "t0", "t1", "_annotate", "_ann",
+                 "_live", "_wall0", "_parent", "_ctx", "_token")
+
+    def __init__(self, name: str, annotate: bool = False, **args: Any):
+        self.name = name
+        self.args = args
+        self.t0 = self.t1 = None
+        self._annotate = annotate
+        self._ann = None
+        self._live = False
+
+    def __enter__(self) -> "span":
+        live = self._live = _state.enabled
+        if self._annotate or (live and _jax_passthrough):
+            ann = _jax_annotation(self.name,
+                                  {} if self._annotate else self.args)
+            if ann is not None:
+                try:
+                    ann.__enter__()
+                    self._ann = ann
+                except Exception:
+                    # a profiler-state hiccup must not crash the
+                    # instrumented loop
+                    pass
+        if not live:
+            return self
+        stack = _stack()
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        ctx = rc.current()
+        self._token = None
+        if ctx is not None:
+            # this span's own identity; children parent to it via the
+            # contextvar for the duration of the block
+            ctx = ctx.child()
+            self._token = rc._current.set(ctx)
+        self._ctx = ctx
+        self.t0 = time.perf_counter()
+        self._wall0 = time.time()
+        return self
+
+    def end(self, **args: Any) -> None:
+        """Close the span now, adding ``args``; later calls, and the
+        ``with`` block's exit, do nothing."""
+        live, self._live = self._live, False
+        if live:
+            self.t1 = time.perf_counter()
+        ann, self._ann = self._ann, None
         if ann is not None:
             try:
                 ann.__exit__(None, None, None)
             except Exception:
                 pass
-        stack.pop()
-        if token is not None:
-            rc._current.reset(token)
-        rec_args = {k: v for k, v in args.items()}
-        if parent is not None:
-            rec_args["parent"] = parent
+        if not live:
+            return
+        _stack().pop()
+        if self._token is not None:
+            rc._current.reset(self._token)
+        self.args.update(args)
+        rec_args = dict(self.args)
+        if self._parent is not None:
+            rec_args["parent"] = self._parent
+        ctx = self._ctx
         if ctx is not None:
             rec_args["trace"] = ctx.trace_id
             rec_args["span"] = ctx.span_id
             if ctx.parent_id:
                 rec_args["parent_span"] = ctx.parent_id
-        TRACE.append({
-            "name": name,
-            "ph": "X",
-            "ts": wall0 * 1e6,            # trace-event ts is microseconds
-            "dur": dur * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-            "args": rec_args,
-        })
+        TRACE.append(make_complete(self.name, self._wall0,
+                                   self.t1 - self.t0, self.t0,
+                                   **rec_args))
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
 
 
 def make_complete(name: str, start_wall: float, dur_s: float,
+                  t0: Optional[float] = None, /,
                   **args: Any) -> Dict[str, Any]:
     """Build (but do not record) a complete ("X") event record — the
     one schema owner, so hand-built dicts and shipped-across-processes
     spans can't drift from ``span``'s. ``start_wall`` is epoch
-    seconds."""
+    seconds; ``t0`` the same instant on ``time.perf_counter()``, worked
+    out from ``start_wall`` when the caller did not read it."""
+    if t0 is None:
+        t0 = time.perf_counter() - (time.time() - start_wall)
     return {
         "name": name,
         "ph": "X",
-        "ts": start_wall * 1e6,
+        "ts": start_wall * 1e6,           # trace-event ts is microseconds
         "dur": dur_s * 1e6,
+        "t0": t0,
         "pid": os.getpid(),
         "tid": threading.get_ident(),
         "args": dict(args),
@@ -235,13 +280,13 @@ def make_complete(name: str, start_wall: float, dur_s: float,
 
 
 def add_complete(name: str, start_wall: float, dur_s: float,
-                 **args: Any):
+                 t0: Optional[float] = None, /, **args: Any):
     """Record an already-measured phase as a complete ("X") event — for
     call sites that timed the work themselves and must not re-bracket
     it. No-op when disabled."""
     if not _state.enabled:
         return
-    TRACE.append(make_complete(name, start_wall, dur_s, **args))
+    TRACE.append(make_complete(name, start_wall, dur_s, t0, **args))
 
 
 def export_chrome_trace(path: Optional[str] = None) -> str:

@@ -1,6 +1,8 @@
 """Continuous-batching LLM serving worker (ref: P:llm/serving — the
 fastchat worker / vLLM integration row of SURVEY.md §2.8)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -292,3 +294,230 @@ class TestDecodeMicrobench:
             assert out[k]["host_ms_per_step"] >= 0
             assert out[k]["stall_ms_per_step"] >= 0
         assert "speedup_vs_depth1" in out
+
+
+# ---------------------------------------------------------------------------
+# the engine pass's own spans and stamps (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+PHASES = ("llm/admit", "llm/grant", "llm/dispatch", "llm/fence_wait",
+          "llm/drain")
+_EPS = 1e-7     # perf_counter arithmetic through float microseconds
+
+_ENGINES = {
+    "paged": dict(),
+    "slotted": dict(paged=False),
+    "mixed": dict(page_size=8, ragged_prefill=True, mixed=True,
+                  chunk_tokens=8),
+    "spec": dict(page_size=8, ragged_prefill=True, spec=True, spec_k=8),
+}
+
+
+def _end(rec):
+    return rec["t0"] + rec["dur"] / 1e6
+
+
+def _serve_traced(model, kind):
+    """Serve a few requests, none with a trace context, on a fresh
+    engine of ``kind``; returns the engine, the requests and the ring
+    records of its thread in start order."""
+    from bigdl_tpu import observability as obs
+
+    rs = np.random.RandomState(42)
+    pattern = rs.randint(0, 250, 5).astype(np.int32)
+    prompts = [np.tile(pattern, 6), rs.randint(0, 250, 7).astype(np.int32),
+               np.array([3, 1, 4], np.int32)]
+    lens = [24 if kind == "spec" else 6, 6, 4]
+    srv = LLMServer(model, max_batch=2, max_seq_len=64,
+                    **_ENGINES[kind]).start()
+    try:
+        # first use compiles: keep that out of the ring under test
+        srv.submit(prompts[0], max_new_tokens=2).get(timeout=600)
+        while not srv.engine_idle():
+            time.sleep(0.001)
+        time.sleep(0.02)    # the pass that went idle records itself
+        obs.TRACE.clear()
+        reqs = [srv.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, lens)]
+        for r in reqs:
+            r.get(timeout=600)
+        tid = srv._thread.ident
+    finally:
+        srv.stop()
+    recs = sorted((r for r in obs.TRACE.spans() if r["tid"] == tid),
+                  key=lambda r: r["t0"])
+    return srv, reqs, recs
+
+
+class TestPassSpans:
+    @pytest.fixture(scope="class")
+    def served(self, model):
+        return _serve_traced(model, "paged")
+
+    @pytest.mark.parametrize("kind", sorted(_ENGINES))
+    def test_phases_tile_their_pass(self, model, served, kind):
+        """Every dispatch path records the same bracket: the phases of
+        one loop iteration follow one another without overlap, and
+        ``llm/pass`` runs from the first one's start to the last one's
+        end."""
+        srv, _, recs = served if kind == "paged" \
+            else _serve_traced(model, kind)
+        passes = [r for r in recs if r["name"] == "llm/pass"]
+        phases = [r for r in recs if r["name"] in PHASES]
+        assert passes and phases
+        owned = 0
+        for p in passes:
+            mine = [r for r in phases
+                    if p["t0"] - _EPS <= r["t0"] <= _end(p) + _EPS]
+            assert mine, p
+            owned += len(mine)
+            assert abs(mine[0]["t0"] - p["t0"]) <= _EPS
+            assert abs(_end(mine[-1]) - _end(p)) <= _EPS
+            for a, b in zip(mine, mine[1:]):
+                assert _end(a) <= b["t0"] + _EPS, (a, b)
+            names = [r["name"] for r in mine]
+            assert names.count("llm/admit") <= 1
+            if "llm/admit" in names:
+                assert names[0] == "llm/admit"
+        assert owned == len(phases)     # no phase outside a pass
+        fns = {p["args"]["fn"] for p in passes} - {None}
+        want = {"paged": "llm/decode_paged", "slotted": "llm/decode_slotted",
+                "mixed": "llm/step_mixed", "spec": "llm/step_spec"}[kind]
+        assert want in fns
+        # a dispatched step has its llm/dispatch, and (paged) its grant
+        n_disp = sum(r["name"] == "llm/dispatch" for r in phases)
+        n_solo = sum(r["name"] == "llm/dispatch"
+                     and r["args"]["rows"] == 0 for r in phases)
+        assert n_disp - n_solo == sum(
+            p["args"]["fn"] is not None for p in passes if p["args"]["rows"])
+        if kind != "slotted":
+            assert sum(r["name"] == "llm/grant" for r in phases) >= n_disp
+        assert passes[-1]["args"]["step"] == srv.steps
+
+    def test_fence_wait_brackets_only_the_fetch(self, served):
+        """The fence stamp is read between the end of ``llm/fence_wait``
+        and the start of ``llm/drain``: nothing but the fetch is in the
+        one, everything after it in the other."""
+        srv, reqs, recs = served
+        waits = [r for r in recs if r["name"] == "llm/fence_wait"]
+        drains = [r for r in recs if r["name"] == "llm/drain"]
+        assert len(waits) == len(drains) > 0
+        stamps = sorted({t for r in reqs for t in r.t_tokens})
+        slots = [(_end(w), d["t0"]) for w, d in zip(waits, drains)]
+        for t in stamps:
+            assert any(lo - _EPS <= t <= hi + _EPS for lo, hi in slots), t
+        assert all(not w["args"] for w in waits)
+        nested = [r for r in recs for w in waits
+                  if r is not w and r["name"] != "llm/pass"
+                  and w["t0"] <= r["t0"] < _end(w)]
+        assert nested == []
+        assert sum(w["dur"] for w in waits) / 1e6 <= srv.stall_seconds
+
+    def test_stamps_are_taken_with_slo_off(self, served):
+        srv, reqs, _ = served
+        assert srv._slo is None
+        for r in reqs:
+            assert len(r.t_tokens) == len(r.tokens) > 0
+            assert all(a <= b for a, b in zip(r.t_tokens, r.t_tokens[1:]))
+            assert r.t_submit <= r.t_admit <= r.t_tokens[0] \
+                <= r.t_first_token
+
+    def test_queue_wait_and_admission_args(self, served):
+        """``llm/queue_wait`` for requests that carry no trace context,
+        on the request's own submit stamp; the admit and drain phases
+        account for every request and every token."""
+        _, reqs, recs = served
+        by = {}
+        for r in recs:
+            by.setdefault(r["name"], []).append(r)
+        waits = {r["args"]["request"]: r for r in by["llm/queue_wait"]}
+        for r in reqs:
+            w = waits[r.id]
+            assert "trace" not in w["args"]
+            assert w["t0"] == r.t_submit
+            assert abs(_end(w) - r.t_admit) <= _EPS
+        admits = by["llm/admit"]
+        assert sum(a["args"]["admitted"] for a in admits) == len(reqs)
+        assert sum(a["args"]["prefills"] for a in admits) == len(reqs)
+        real = sum(a["args"]["prompt_tokens"] for a in admits)
+        assert real == sum(len(r.prompt_ids) for r in reqs)
+        assert sum(a["args"]["bucket_tokens"] for a in admits) >= real
+        for pf in by["llm/prefill"]:
+            assert pf["args"]["parent"] == "llm/admit"
+            assert any(a["t0"] <= pf["t0"] and _end(pf) <= _end(a) + _EPS
+                       for a in admits)
+        assert sum(p["args"]["admitted"] for p in by["llm/pass"]) \
+            == len(reqs)
+        served_ids = [i for d in by["llm/drain"]
+                      for i in d["args"]["requests"]]
+        for r in reqs:
+            assert served_ids.count(r.id) == len(r.tokens)
+        assert sum(d["args"]["finished"] for d in by["llm/drain"]) \
+            == len(reqs)
+
+    def test_disabled_ring_stays_empty_stamps_still_taken(self, model):
+        from bigdl_tpu import observability as obs
+        obs.disable()
+        try:
+            obs.TRACE.clear()
+            srv = LLMServer(model, max_batch=2, max_seq_len=32).start()
+            try:
+                req = srv.submit(np.array([3, 1, 4], np.int32),
+                                 max_new_tokens=5)
+                req.get(timeout=120)
+            finally:
+                srv.stop()
+            assert len(obs.TRACE) == 0
+            assert len(req.t_tokens) == 5
+            assert req.t_submit <= req.t_admit <= req.t_tokens[0]
+        finally:
+            obs.enable()
+
+    def test_phases_reach_a_captured_jax_profile(self, model, tmp_path):
+        """No switch: whoever captures a JAX profile of a serving
+        process finds the five phases on the engine thread's line, and
+        not the enclosing ``llm/pass`` (it would be the longest host
+        event over every device-idle gap)."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        srv = LLMServer(model, max_batch=2, max_seq_len=32).start()
+        try:
+            ids = np.array([3, 1, 4], np.int32)
+            srv.submit(ids, max_new_tokens=2).get(timeout=120)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                srv.submit(ids, max_new_tokens=4).get(timeout=120)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            srv.stop()
+        path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))[-1]
+        lines = []
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                names = {ev.name for ev in line.events
+                         if ev.name.startswith("llm/")}
+                if names:
+                    lines.append(names)
+        assert lines == [set(PHASES)]
+
+    def test_span_names_pass_the_registry_gate(self):
+        """The new names are registered and emitted, the span this PR
+        took out is gone from both sides."""
+        import os
+
+        from bigdl_tpu.analysis import ProjectIndex, registries
+        from bigdl_tpu.analysis import registrydrift
+        names = set(PHASES) | {"llm/pass", "llm/queue_wait"}
+        assert names <= set(registries.SPAN_NAMES)
+        assert "llm/decode_step" not in registries.SPAN_NAMES
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        emitted = registrydrift.collect_literals(
+            ProjectIndex.scan(root, ("bigdl_tpu",))).span
+        assert names <= set(emitted)
+        assert "llm/decode_step" not in emitted

@@ -319,6 +319,80 @@ class TestDeadlineHeaderCasing:
             fe.stop()
 
 
+class TestSpanObject:
+    """ISSUE 25: one clock on every record, args filled in while a span
+    is open, an early ``end()``, an annotation of the span's own."""
+
+    def test_every_record_carries_its_perf_counter_start(self):
+        before = time.perf_counter()
+        with obs.span("train/step", step=1) as sp:
+            pass
+        obs.add_complete("llm/queue_wait", time.time() - 0.5, 0.5)
+        obs.add_complete("llm/pass", time.time() - 0.25, 0.25,
+                         before - 0.25, step=3)
+        after = time.perf_counter()
+        first, derived, given = obs.TRACE.spans()
+        assert before <= first["t0"] == sp.t0 <= sp.t1 <= after
+        assert first["dur"] == pytest.approx((sp.t1 - sp.t0) * 1e6)
+        # worked out from the epoch start where the caller read none
+        assert derived["t0"] == pytest.approx(after - 0.5, abs=0.05)
+        assert given["t0"] == before - 0.25 and given["args"] == {"step": 3}
+        json.dumps(obs.TRACE.spans())       # still plain trace events
+
+    def test_args_filled_while_open_and_early_end(self):
+        with obs.span("llm/dispatch", slot=1) as sp:
+            sp.args["rows"] = 2
+            sp.end(fn="llm/decode_paged")
+            t1 = sp.t1
+            with obs.span("llm/fence_wait"):
+                pass            # after the early end: a sibling, not a child
+        assert sp.t1 == t1      # the block's exit did nothing more
+        sp.end(fn="again")
+        disp, wait = obs.TRACE.spans()
+        assert disp["name"] == "llm/dispatch" and len(obs.TRACE) == 2
+        assert disp["args"] == {"slot": 1, "rows": 2,
+                                "fn": "llm/decode_paged"}
+        assert "parent" not in wait["args"]
+        assert disp["t0"] + disp["dur"] / 1e6 <= wait["t0"]
+
+    def test_span_propagates_exceptions_and_still_records(self):
+        with pytest.raises(KeyError):
+            with obs.span("llm/grant"):
+                raise KeyError("x")
+        assert [r["name"] for r in obs.TRACE.spans()] == ["llm/grant"]
+        with obs.span("llm/drain"):     # the stack was popped
+            pass
+        assert "parent" not in obs.TRACE.spans()[-1]["args"]
+
+    @pytest.mark.parametrize("enabled", [True, False])
+    def test_annotate_enters_the_profiler_annotation(self, enabled,
+                                                     monkeypatch):
+        """``annotate=True`` needs no ``configure(jax_passthrough=)`` and
+        holds with observability off; a plain span enters none."""
+        import jax
+        seen = []
+
+        class Fake:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Fake)
+        if not enabled:
+            obs.disable()
+        with obs.span("llm/admit", annotate=True, step=7) as sp:
+            with obs.span("llm/prefill"):
+                pass
+        assert seen == [("enter", "llm/admit"), ("exit", "llm/admit")]
+        assert len(obs.TRACE) == (2 if enabled else 0)
+        assert (sp.t0 is not None) == enabled
+
+
 class TestLLMTraceStitching:
     @pytest.fixture(scope="class")
     def served(self):
