@@ -8,8 +8,9 @@ Two implementations live here:
   composes with its own layer math — the suffix attends the prefix
   pages WHERE THEY SIT via the Mosaic ragged kernel
   (llm/kernels/ragged_prefill.py), the COW tail fork is one
-  page-to-page copy inside the same dispatch, and ONE post-scan scatter
-  writes the suffix K/V into the request's pages. No dense temp cache,
+  page-to-page copy inside the same dispatch, and after the scan the
+  suffix K/V is written into the request's pages in place, page by
+  page (llm/kvcache/write.py). No dense temp cache,
   and the prefix page count is runtime block-table data — the compile
   grid is O(suffix-buckets) only;
 - the **dense staging path** (:func:`make_partial_prefill`, the ISSUE 5
@@ -111,16 +112,15 @@ def ragged_prefill_attend(k_pages, v_pages, bt_row, offset, seq_len, *,
 
 
 def scatter_suffix_kv(k_pages, v_pages, phys, slots, k_new, v_new):
-    """ONE vectorized scatter of every layer's suffix K/V into the
-    (donated) pools — the write half of the old dense sandwich, kept;
-    the gather half is gone. ``k_new``/``v_new`` are the layer-scan ys
-    ``(L, Tq, Hkv, D)``; token ``j`` lands in ``(phys[j], slots[j])``
-    (entries the request must not write route to trash page 0)."""
-    k_pages = k_pages.at[:, phys, :, slots].set(
-        k_new.transpose(1, 0, 2, 3).astype(k_pages.dtype))
-    v_pages = v_pages.at[:, phys, :, slots].set(
-        v_new.transpose(1, 0, 2, 3).astype(v_pages.dtype))
-    return k_pages, v_pages
+    """Every layer's suffix K/V into the (donated) pools, in place and
+    page by page (:func:`kvcache.write.write_kv_run`). ``k_new``/
+    ``v_new`` are the layer-scan ys ``(L, Tq, Hkv, D)``; token ``j``
+    lands in ``(phys[j], slots[j])``, consecutive positions of one
+    sequence (the run's tail that the request must not write routes to
+    trash page 0)."""
+    from bigdl_tpu.llm.kvcache.write import write_kv_run
+    return (write_kv_run(k_pages, phys, slots, k_new),
+            write_kv_run(v_pages, phys, slots, v_new))
 
 
 def make_mixed_step(fam_step, fam_ragged):

@@ -957,11 +957,12 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
     DIRECTLY from the page pool (llm/kernels/ragged_prefill.py) — no
     dense temp cache, no prefix gather. Same structure as
     :func:`serving.paged_decode_step`: rolled layer scan, read-only
-    pools inside the scan, one post-scan scatter into the donated
-    pools; the COW tail fork is a single page copy fused ahead of the
-    scan. ``bt_row`` (pages_cap,), ``offset``/``length`` and the
-    ``phys``/``slots`` scatter targets are all runtime data — the only
-    compile-relevant shape is the suffix bucket ``toks.shape[1]``.
+    pools inside the scan, the suffix K/V written into the donated
+    pools after it, in place and page by page; the COW tail fork is a
+    single page copy fused ahead of the scan. ``bt_row`` (pages_cap,),
+    ``offset``/``length`` and the ``phys``/``slots`` scatter targets
+    are all runtime data — the only compile-relevant shape is the
+    suffix bucket ``toks.shape[1]``.
     Returns ``(k_pages, v_pages, last_logits (V,) f32)``; with
     ``full_logits=True`` (the speculative verify leg, ISSUE 19) the
     logits for ALL bucket positions come back as ``(bucket, V)`` f32

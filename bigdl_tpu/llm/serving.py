@@ -321,20 +321,17 @@ def paged_attend(k_pages, v_pages, bt, lens, *, page: int,
 
 def scatter_new_kv(k_pages, v_pages, bt, lens, k_new, v_new, *,
                    page: int):
-    """ONE vectorized scatter of every layer's new-token K/V into the
-    (donated) pools — shared by every family's decode step. ``k_new``/
-    ``v_new`` are the layer-scan ys ``(L, B, Hkv, D)``; pools are
-    ``(L, P, Hkv, page, D)`` (advanced indices on P/page with slices
-    between put the broadcast (B,) dim first)."""
-    b = lens.shape[0]
-    pidx = lens // page
+    """Every layer's new-token K/V into the (donated) pools, in place —
+    shared by every family's decode step. ``k_new``/``v_new`` are the
+    layer-scan ys ``(L, B, Hkv, D)``; row ``b``'s token lands in page
+    ``bt[b, lens[b] // page]`` at slot ``lens[b] % page`` through
+    :func:`kvcache.write.write_kv`, which leaves the pool in its own
+    layout (no pool-sized copy in the compiled step)."""
+    from bigdl_tpu.llm.kvcache.write import write_kv
+    phys = bt[jnp.arange(lens.shape[0]), lens // page]        # (B,)
     slot = lens % page
-    phys = bt[jnp.arange(b), pidx]                            # (B,)
-    k_pages = k_pages.at[:, phys, :, slot].set(
-        k_new.transpose(1, 0, 2, 3).astype(k_pages.dtype))
-    v_pages = v_pages.at[:, phys, :, slot].set(
-        v_new.transpose(1, 0, 2, 3).astype(v_pages.dtype))
-    return k_pages, v_pages
+    return (write_kv(k_pages, phys, slot, k_new),
+            write_kv(v_pages, phys, slot, v_new))
 
 
 def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
@@ -349,17 +346,22 @@ def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
     - layers run in a **rolled ``lax.scan``** over the stacked weight
       pytree — the per-layer weight stream pipelines best this way;
     - the page pools stay **read-only inside the scan** (scan-invariant
-      closures, never carried — a carried pool would be copied wholesale
-      every token). Attention over the existing ``lens`` tokens comes
-      from the stats kernel, and the current token's own K/V is folded
-      in with the flash combine (`merge_attention_partial`) — exactly
-      the write-then-attend math, without the write;
+      closures, never carried). Attention over the existing ``lens``
+      tokens comes from the stats kernel, and the current token's own
+      K/V is folded in with the flash combine
+      (`merge_attention_partial`) — exactly the write-then-attend math,
+      without the write;
     - per-layer pools are addressed WITHOUT slicing (a `pool[l]` slice
       would copy 2×pool_bytes/L per layer): the pool is viewed as one
       flat ``(L·P, H, page, D)`` page array and block tables are offset
       by ``l·P`` inside the scan. Layer ``l``'s trash page is ``l·P``;
-    - after the scan, ONE vectorized scatter writes all ``L`` layers'
-      new-token K/V into the donated pools in place.
+    - after the scan, :func:`scatter_new_kv` writes all ``L`` layers'
+      new-token K/V into the donated pools in place: one
+      ``dynamic_update_slice`` of an ``(L, 1, H, 1, D)`` slab per row,
+      in the pools' own layout. (Not one vectorised scatter on the
+      ``P`` and ``page`` dimensions: XLA compiles that in another
+      layout and copies both whole pools there and back every step,
+      which was about half of the 7B step's device time — PERF.md §6.)
 
     ``params`` must be the stacked-layer llama pytree; ``bt`` (B, maxp)
     int32 block tables; ``lens`` (B,) int32 lengths EXCLUDING the token
@@ -495,10 +497,12 @@ class LLMServer:
     decode can never deadlock on an empty pool; physical pages are only
     taken when tokens actually land. Attention over the pool runs the
     Mosaic paged kernel on TPU (kernels/paged_attention.py) and its XLA
-    gather twin elsewhere. Decode keeps the layers in a **python loop**
-    (not lax.scan) over donated pools: page writes then compile to
-    in-place scatters and page reads to views — a scanned pool would be
-    copied wholesale every token.
+    gather twin elsewhere. The decode step runs the layers in a rolled
+    ``lax.scan`` that only READS the donated pools (as one flat page
+    array, block tables offset per layer); the new tokens' K/V are
+    written after the scan, in place and in the pools' own layout
+    (:mod:`bigdl_tpu.llm.kvcache.write`), so no compiled step or
+    prefill holds a copy of a whole pool (:func:`paged_decode_step`).
 
     ``paged=False`` keeps the round-3 slot-static cache (one
     ``max_seq_len`` window per slot).

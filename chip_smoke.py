@@ -179,16 +179,24 @@ def train_phase(depth: int = 50, image: int = 224, classes: int = 1000,
 # serve
 # ---------------------------------------------------------------------------
 
-def pallas_calls_in_engine_programs() -> None:
-    """Read the HLO of the executables the engine compiled and count
-    the Mosaic custom calls in them."""
+def pallas_calls_in_engine_programs(pool_shape) -> None:
+    """Read the HLO of the executables the engine compiled: count the
+    Mosaic custom calls in them, and the copies of a whole page pool
+    (a step that writes new K/V in place makes none)."""
+    from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
     from bigdl_tpu.llm.serving import compiled_steps
     found = {}
     for kind, detail, fn in compiled_steps():
         for _, exe in fn.executables():
-            n = exe.as_text().count("tpu_custom_call")
+            text = exe.as_text()
+            n = text.count("tpu_custom_call")
+            copies = pool_shaped_copies(text, pool_shape)
             fact(f"serve: {fn.name} {detail} has {n} tpu_custom_call "
-                 "site(s) in its compiled HLO")
+                 f"site(s) and {len(copies)} copies shaped like the page "
+                 f"pool {tuple(pool_shape)} in its compiled HLO")
+            check(not copies,
+                  f"{fn.name} {detail} copies the whole page pool: "
+                  f"{[c[:200] for c in copies[:1]]}")
             found[kind] = min(found.get(kind, n), n)
     for kind in ("decode", "prefill_ragged"):
         check(kind in found,
@@ -265,6 +273,7 @@ def serve_phase(cfg=None, max_seq_len: int = 2048, first=FIRST_WAVE,
         again = srv.submit(prompts[0],
                            max_new_tokens=budgets[0]).get(timeout=600)
         pass_errors = srv.pass_errors
+        pool_shape = srv._k_pages.shape
     finally:
         srv.stop()
 
@@ -294,7 +303,7 @@ def serve_phase(cfg=None, max_seq_len: int = 2048, first=FIRST_WAVE,
           "sigmas below the dense reference's best")
 
     if expect_pallas:
-        pallas_calls_in_engine_programs()
+        pallas_calls_in_engine_programs(pool_shape)
     fact(f"phase serve wall {time.perf_counter() - t0:.1f} s")
 
 

@@ -320,3 +320,97 @@ class TestPagedServingOnChip:
             stop.set()
             for t in threads:
                 t.join(timeout=10)
+
+
+class TestPoolWrittenInPlaceOnChip:
+    """ISSUE 26: the decode step and a prefill bucket, compiled at
+    Mistral-7B widths over a pool of the served size (two layers deep),
+    make no array of the pool's shape by ``copy`` or ``transpose`` —
+    the vectorised scatter they used before cost two such copies a pool
+    in every step — and the in-place writers put the same bits in the
+    same places as that scatter."""
+
+    LAYERS, PAGES, PAGE, BATCH, MAXP = 2, 2049, 16, 16, 128
+
+    def _operands(self):
+        import dataclasses
+        from bigdl_tpu.llm.models.llama import (LlamaConfig,
+                                                synthetic_q4_params)
+        cfg = dataclasses.replace(LlamaConfig.mistral_7b(),
+                                  num_hidden_layers=self.LAYERS)
+        params = jax.eval_shape(lambda: synthetic_q4_params(cfg, seed=0))
+        shape = (self.LAYERS, self.PAGES, cfg.num_key_value_heads,
+                 self.PAGE, cfg.hidden_size // cfg.num_attention_heads)
+        return cfg, params, shape
+
+    def _no_pool_copy(self, compiled, shape):
+        from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        copies = pool_shaped_copies(text, shape)
+        assert not copies, copies[0][:300]
+
+    def test_decode_step_holds_no_pool_copy(self):
+        import functools
+        from bigdl_tpu.llm.serving import paged_decode_step_sampled
+        cfg, params, shape = self._operands()
+        pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        B = self.BATCH
+        fn = jax.jit(functools.partial(paged_decode_step_sampled,
+                                       page=self.PAGE),
+                     static_argnums=1, donate_argnums=(2, 3))
+        compiled = fn.lower(
+            params, cfg, pool, pool,
+            jax.ShapeDtypeStruct((B, self.MAXP), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, cfg.vocab_size), jnp.float32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.random.PRNGKey(0)).compile()
+        self._no_pool_copy(compiled, shape)
+
+    def test_prefill_bucket_holds_no_pool_copy(self):
+        import functools
+        from bigdl_tpu.llm.models.llama import paged_prefill_ragged
+        cfg, params, shape = self._operands()
+        pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        bucket = 256
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        fn = jax.jit(functools.partial(paged_prefill_ragged,
+                                       page=self.PAGE),
+                     static_argnums=1, donate_argnums=(2, 3))
+        compiled = fn.lower(
+            params, cfg, pool, pool,
+            jax.ShapeDtypeStruct((1, bucket), jnp.int32), i32, i32,
+            jax.ShapeDtypeStruct((self.MAXP,), jnp.int32),
+            jax.ShapeDtypeStruct((bucket,), jnp.int32),
+            jax.ShapeDtypeStruct((bucket,), jnp.int32), i32, i32).compile()
+        self._no_pool_copy(compiled, shape)
+
+    @pytest.mark.parametrize("T,off", [(16, None), (256, 19), (3, 31)])
+    def test_writers_match_the_scatter_on_chip(self, T, off):
+        """Bit parity with ``.at[:, phys, :, slots].set`` on the chip's
+        own tiled layout: decode rows (``off`` None) and runs that
+        enter and leave a page mid-way."""
+        from bigdl_tpu.llm.kvcache.write import write_kv, write_kv_run
+        L, P, H, page, D = 2, 64, 8, self.PAGE, 128
+        rs = np.random.RandomState(T)
+        pool = jnp.asarray(rs.randn(L, P, H, page, D), jnp.bfloat16)
+        new = jnp.asarray(rs.randn(L, T, H, D), jnp.float32)
+        if off is None:
+            phys = rs.permutation(np.arange(1, P))[:T].astype(np.int32)
+            slots = rs.randint(0, page, T).astype(np.int32)
+            phys[[0, 5]], slots[[0, 5]] = 0, 0
+            writer = write_kv
+        else:
+            pos = off + np.arange(T)
+            real = pos < off + T - 2            # two padded positions
+            phys = np.where(real, 1 + pos // page, 0).astype(np.int32)
+            slots = (pos % page).astype(np.int32)
+            writer = write_kv_run
+        want = np.asarray(pool.at[:, phys, :, slots].set(
+            new.transpose(1, 0, 2, 3).astype(pool.dtype)), np.float32)
+        got = np.asarray(jax.jit(writer)(pool, jnp.asarray(phys),
+                                         jnp.asarray(slots), new),
+                         np.float32)
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
