@@ -573,7 +573,8 @@ def paged_attention_stats(q, k_pages, v_pages, block_tables, lengths,
         interpret=interpret, sliding_window=sliding_window)
 
 
-def merge_attention_partial(acc, m, l, q, k_new, v_new):
+def merge_attention_partial(acc, m, l, q, k_new, v_new,
+                            scale: Optional[float] = None):
     """Fold one extra key/value token into a flash-style partial state.
 
     ``(acc, m, l)`` from :func:`paged_attention_stats` (acc (B, Hq, D)
@@ -583,11 +584,14 @@ def merge_attention_partial(acc, m, l, q, k_new, v_new):
     ``paged_attention`` after writing the token, but with the pool
     untouched (what lets the serving decode scan keep the page pool
     read-only and defer all layers' page writes to one post-scan
-    scatter)."""
+    scatter). ``scale`` defaults to ``1/sqrt(D)``; the value may be
+    narrower than the key (the latent cache: ``v_new`` the first
+    columns of ``k_new``)."""
     b, hq, d = q.shape
     hkv = k_new.shape[1]
     g = hq // hkv
-    scale = 1.0 / float(np.sqrt(d))
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(d))
     kr = jnp.repeat(k_new.astype(jnp.float32), g, axis=1)     # (B, Hq, D)
     vr = jnp.repeat(v_new.astype(jnp.float32), g, axis=1)
     s_self = jnp.sum(q.astype(jnp.float32) * kr, axis=-1) * scale
@@ -648,3 +652,166 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
                                   lengths, page_size=page_size,
                                   interpret=interpret,
                                   sliding_window=sliding_window)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) cache: one row a token, whose first columns are the value
+# ---------------------------------------------------------------------------
+
+def _latent_decode_kernel(len_ref, bt_ref, q_ref, kv_hbm, o_ref, mo_ref,
+                          lo_ref, buf, sem, acc_ref, m_ref, l_ref, *,
+                          page: int, ppb: int, pages_max: int, dv: int,
+                          scale: float):
+    """One (batch row b, block of ``ppb`` pages) step of absorbed-form
+    multi-query attention: every head scores the same ``(ppb·page, W)``
+    rows, and the value is the first ``dv`` columns of the rows just
+    read, so a page is fetched once. q_ref (1, Hp, W) VMEM; kv_hbm
+    (P, 1, page, W) in HBM; acc (Hp, dv), m/l (Hp, LANE) scratch."""
+    b = pl.program_id(0)
+    blk = pl.program_id(1)
+    nblk = pl.num_programs(1)
+
+    @pl.when(blk == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    seq = len_ref[b]
+    base_tok = blk * (ppb * page)
+
+    @pl.when(base_tok < seq)
+    def _compute():
+        copies = []
+        for i in range(ppb):                    # static unroll
+            # a table shorter than a whole block: the pages past its
+            # end are past every length too, any valid page will do
+            col = jnp.minimum(blk * ppb + i, pages_max - 1)
+            pid = bt_ref[b * pages_max + col]
+            c = pltpu.make_async_copy(kv_hbm.at[pid, 0], buf.at[i], sem)
+            c.start()
+            copies.append(c)
+        for c in copies:
+            c.wait()
+        hp, w = q_ref.shape[1], q_ref.shape[2]
+        n = ppb * page
+        q = q_ref[0].astype(jnp.float32)                   # (Hp, W)
+        kv = buf[...].reshape(n, w).astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (Hp, n)
+        pos = base_tok + jax.lax.broadcasted_iota(jnp.int32, (hp, n), 1)
+        s = jnp.where(pos < seq, s, -1e30)
+        m_prev = m_ref[...]
+        l_prev = l_ref[...]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+        alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
+        p_ = jnp.exp(s - m_new[:, :1])
+        l_new = alpha * l_prev[:, :1] + jnp.sum(p_, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p_, kv[:, :dv], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # (Hp, dv)
+        m_ref[...] = m_new
+        l_ref[...] = jnp.broadcast_to(l_new, l_prev.shape)
+
+    @pl.when(blk == nblk - 1)
+    def _finish():
+        o_ref[0] = acc_ref[...]
+        mo_ref[0] = m_ref[...]
+        lo_ref[0] = l_ref[...]
+
+
+# cached tokens a grid step of the latent kernel fetches and scores
+LATENT_BLOCK_TOKENS = 512
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "dv", "scale",
+                                             "interpret"))
+def latent_attention_decode_stats(q, kv_pages, block_tables, lengths, *,
+                                  page_size: int, dv: int, scale: float,
+                                  interpret: bool = False):
+    """:func:`paged_attention_decode_stats` for a latent cache. ``q``
+    (B, H, W) queries in the cache's own coordinates (for MLA the
+    absorbed ``q_nope W_uk`` beside ``q_rope``, zero where the row is
+    padding); ``kv_pages`` (P, 1, page, W) with ``W`` a multiple of 128
+    (the pool is never padded by a copy: the engine allocates it that
+    wide); the value of a row is its first ``dv`` columns; ``scale`` is
+    the model's, not ``1/sqrt(W)``. Returns ``(acc (B, H, dv) float32
+    unnormalised, m (B, H), l (B, H))`` over the first ``lengths``
+    tokens, the identity ``(0, -1e30, 0)`` where that is none."""
+    b, h, w = q.shape
+    _, one, page, wp = kv_pages.shape
+    if one != 1 or page != page_size or wp != w or w % LANE or dv % LANE:
+        raise ValueError(
+            f"latent pool {kv_pages.shape} / queries {q.shape}: want "
+            f"(P, 1, {page_size}, W) and (B, H, W), W and dv multiples "
+            f"of {LANE}")
+    pages_max = block_tables.shape[1]
+    ppb = max(1, min(LATENT_BLOCK_TOKENS // page, pages_max))
+    nblk = -(-pages_max // ppb)
+    hp = -(-h // 8) * 8
+    if hp != h:
+        q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
+    row = lambda b_, k_, *_: (b_, 0, 0)
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, page=page, ppb=ppb,
+                          pages_max=pages_max, dv=dv, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, nblk),
+            in_specs=[pl.BlockSpec((1, hp, w), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, hp, dv), row),
+                       pl.BlockSpec((1, hp, LANE), row),
+                       pl.BlockSpec((1, hp, LANE), row)],
+            scratch_shapes=[
+                pltpu.VMEM((ppb, page, w), kv_pages.dtype),
+                pltpu.SemaphoreType.DMA,
+                pltpu.VMEM((hp, dv), jnp.float32),
+                pltpu.VMEM((hp, LANE), jnp.float32),
+                pltpu.VMEM((hp, LANE), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, hp, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((b, hp, LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((b, hp, LANE), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(lengths.astype(jnp.int32),
+      block_tables.reshape(-1).astype(jnp.int32), q, kv_pages)
+    return acc[:, :h], m[:, :h, 0], l[:, :h, 0]
+
+
+def latent_attention_reference_stats(q, kv_pages, block_tables, lengths,
+                                     *, dv: int, scale: float,
+                                     max_live_tokens: Optional[int] = None):
+    """XLA twin of :func:`latent_attention_decode_stats` (same
+    contract): a gather of the live pages and masked scores."""
+    b, h, w = q.shape
+    page = kv_pages.shape[2]
+    block_tables = _sliced_tables(block_tables, lengths, page,
+                                  max_live_tokens)
+    s_max = block_tables.shape[1] * page
+    kv = kv_pages[block_tables][:, :, 0].reshape(b, s_max, w) \
+        .astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), kv) * scale
+    mask = (jnp.arange(s_max)[None, :] < lengths[:, None])[:, None, :]
+    s = jnp.where(mask, s, -1e30)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)
+    acc = jnp.einsum("bhs,bsv->bhv", p, kv[..., :dv])
+    m = jnp.where(jnp.any(mask, axis=-1), m, -1e30)
+    return acc, m, jnp.sum(p, axis=-1)
+
+
+def latent_attention_stats(q, kv_pages, block_tables, lengths, *,
+                           page_size: int, dv: int, scale: float,
+                           interpret: Optional[bool] = None):
+    """Backend dispatch: Mosaic kernel on TPU, XLA gather elsewhere."""
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return latent_attention_reference_stats(
+                q, kv_pages, block_tables, lengths, dv=dv, scale=scale)
+        interpret = False
+    return latent_attention_decode_stats(
+        q, kv_pages, block_tables, lengths, page_size=page_size, dv=dv,
+        scale=scale, interpret=interpret)

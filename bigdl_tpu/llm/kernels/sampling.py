@@ -59,6 +59,8 @@ def fence_token(*arrays):
     """
     acc = jnp.float32(0.0)
     for a in arrays:
+        if a is None:       # a family with one pool has no second one
+            continue
         acc = acc + a.ravel()[0].astype(jnp.float32)
     acc = jnp.clip(jnp.nan_to_num(acc), -1e9, 1e9)
     return acc.astype(jnp.int32)[None]
@@ -119,7 +121,10 @@ def make_sampled_step(fam_step):
       re-uploads the length vector);
     - returns ``(out, logits, k_pages, v_pages, new_lens, key)`` where
       ``out`` is ``(B+1,)`` int32: the B sampled ids plus a
-      :func:`fence_token` element bounding the pool writes.
+      :func:`fence_token` element bounding the pool writes. A family
+      step that returns a fourth value, an int32 vector of the step's
+      own counts (its module names them in ``STEP_STATS``), gets it
+      appended after the fence, so the drain's one fetch brings it.
 
     Each family module exposes ``paged_decode_step_sampled =
     make_sampled_step(paged_decode_step)`` so the engine dispatches one
@@ -134,7 +139,7 @@ def make_sampled_step(fam_step):
                              temperature=temperature, top_k=top_k)
         bt_eff = jnp.where(active[:, None], bt, 0)
         lens_eff = jnp.where(active, lens, 0)
-        logits, k_pages, v_pages = fam_step(
+        logits, k_pages, v_pages, *stats = fam_step(
             params, cfg, k_pages, v_pages, bt_eff, lens_eff, toks,
             page=page)
         # inactive rows carry their previous logits forward instead of
@@ -145,7 +150,7 @@ def make_sampled_step(fam_step):
         logits = jnp.where(active[:, None], logits, last)
         new_lens = lens + active.astype(lens.dtype)
         out = jnp.concatenate(
-            [toks, fence_token(k_pages, v_pages, logits)])
+            [toks, fence_token(k_pages, v_pages, logits), *stats])
         return out, logits, k_pages, v_pages, new_lens, key
 
     return sampled_step

@@ -261,8 +261,11 @@ def _sync_barrier(*arrays):
     the step's own (tokens ‖ fence) vector, which delivers the data AND
     the barrier in one transfer (kernels.sampling.fence_token).
     """
+    arrays = [a for a in arrays if a is not None]   # a one-pool family
     jax.block_until_ready(arrays)
-    np.asarray(jnp.stack([a.ravel()[0].astype(jnp.float32)
+    # (the first element by index: an eager ``ravel()`` of a page pool
+    # copies the pool, 2.7 GB beside a 13 GB engine at stop())
+    np.asarray(jnp.stack([a[(0,) * a.ndim].astype(jnp.float32)
                           for a in arrays]))
 
 
@@ -622,6 +625,7 @@ class LLMServer:
             self._fam_mixed_step = _llama_mod.paged_step_mixed
             self._fam_spec_step = _llama_mod.paged_step_spec
             self._family = "llama"
+            fam_mod = None
         else:
             self._fam_forward = fam_forward
             self._fam_init_cache = type(model)._init_cache
@@ -653,6 +657,18 @@ class LLMServer:
                     "the slot-static (paged=False) engine is Llama-stack "
                     "only; non-llama families serve through the paged "
                     "path")
+        # what else a family may say of itself: the page pools it
+        # caches in (default: a K and a V pool of per-head rows), the
+        # int32 counts its decode step appends to the fetched token
+        # vector, and the counts the host can add at dispatch
+        self._fam_page_pools = getattr(fam_mod, "page_pools", None)
+        self._fam_step_stats = tuple(getattr(fam_mod, "STEP_STATS", ()))
+        self._fam_host_stats = getattr(fam_mod, "host_step_stats", None)
+        self.step_counters: Dict[str, int] = dict.fromkeys(
+            self._fam_step_stats, 0)
+        if self._fam_host_stats is not None:
+            self.step_counters.update(dict.fromkeys(
+                self._fam_host_stats(self.cfg, np.zeros(0, np.int32)), 0))
         self.max_batch = max_batch
         self.max_seq_len = (min(max_seq_len, model.max_cache_len)
                             if not paged else
@@ -782,10 +798,40 @@ class LLMServer:
             # page 0 is the trash page: inactive rows and prefill padding
             # write there; no live sequence ever owns it
             self._num_pages = num_pages or (1 + max_batch * cap)
-            shape = (cfg.num_hidden_layers, self._num_pages,
-                     cfg.num_key_value_heads, page_size, cfg.head_dim)
-            self._k_pages = jnp.zeros(shape, model.cache_dtype)
-            self._v_pages = jnp.zeros(shape, model.cache_dtype)
+            if self._fam_page_pools is not None:
+                self._k_pages, self._v_pages = self._fam_page_pools(
+                    cfg, self._num_pages, page_size, model.cache_dtype)
+            else:
+                shape = (cfg.num_hidden_layers, self._num_pages,
+                         cfg.num_key_value_heads, page_size, cfg.head_dim)
+                self._k_pages = jnp.zeros(shape, model.cache_dtype)
+                self._v_pages = jnp.zeros(shape, model.cache_dtype)
+            if self._v_pages is None:
+                # one pool of another row than per-head K and V (a
+                # latent cache): what reads or moves pages as a K/V
+                # pair refuses the family, as the dense-staged prefill
+                # does, whose temp cache it cannot pageify
+                def on(arg, key):
+                    return arg if arg is not None else \
+                        conf.get_bool(key, False)
+                asked = [name for name, yes in (
+                    ("the prefix cache (bigdl.llm.kvcache)",
+                     on(kvcache, "bigdl.llm.kvcache.enabled")),
+                    ("the host tier and KV handoff (bigdl.llm.kvtier)",
+                     on(kvtier, "bigdl.llm.kvtier.enabled")),
+                    ("mixed dispatch (bigdl.llm.mixed)",
+                     on(mixed, "bigdl.llm.mixed.enabled")),
+                    ("speculation (bigdl.llm.spec)",
+                     on(spec, "bigdl.llm.spec.enabled")),
+                    ("priority preemption (bigdl.llm.priority)",
+                     on(priority, "bigdl.llm.priority.enabled")),
+                    ("the dense-staged prefill (ragged_prefill=False)",
+                     ragged_prefill is False)) if yes]
+                if asked:
+                    raise NotImplementedError(
+                        f"{type(model).__name__} caches one latent pool "
+                        f"and no V pool; {', '.join(asked)} assume a "
+                        "K pool and a V pool of per-head rows")
             # the page pool now lives in the kvcache subsystem (ISSUE 5
             # tentpole): refcounted pages + admission budget; with the
             # prefix cache on, a radix index keeps finished requests'
@@ -816,7 +862,8 @@ class LLMServer:
                     rag = _jax.default_backend() == "tpu"
                 else:
                     rag = conf.get_bool("bigdl.llm.prefill.ragged")
-            self._ragged = rag and self._fam_ragged_prefill is not None
+            self._ragged = (rag or self._v_pages is None) \
+                and self._fam_ragged_prefill is not None
             # unified mixed prefill+decode dispatch (ISSUE 14): one
             # compiled step serves every active decode row PLUS one
             # page-aligned prefill chunk, so a long admission is fed in
@@ -3063,7 +3110,8 @@ class LLMServer:
         ins = self._instruments()
         if ins is not None:
             ins["inflight"].set(len(self._inflight))
-        self._dispatch_ph.end(fn=rec["fn"], rows=len(rec["pairs"]))
+        self._dispatch_ph.end(fn=rec["fn"], rows=len(rec["pairs"]),
+                              **rec.get("host_stats", {}))
         while len(self._inflight) >= self.pipeline_depth:
             self._drain_next()
         return True
@@ -3089,6 +3137,16 @@ class LLMServer:
         with self._phase("llm/drain") as ph:
             ph.args["requests"], ph.args["finished"] = \
                 self._retire(rec, vals, now, stall)
+            # the family's own counts of this step: the device's,
+            # fetched with its tokens (kernels.sampling.make_sampled_
+            # step), and the host's from its dispatch, both counted here
+            # so that they always cover the same steps
+            stats = dict(zip(rec.get("stats", ()),
+                             vals[self.max_batch + 1:].tolist()))
+            ph.args.update(stats)
+            for name, n in (*stats.items(),
+                            *rec.get("host_stats", {}).items()):
+                self.step_counters[name] += n
 
     def _retire(self, rec: dict, vals, now: float, stall: float):
         """Everything a drain does once the record's values are on the
@@ -3540,7 +3598,12 @@ class LLMServer:
         rec = {"out": out, "fn": "llm/decode_paged",
                "pairs": [(i, self._slots[i]) for i in disp],
                "refs": (bt_in, lens_in, last_in, active, key_in),
-               "pinned": self._pending_release}
+               "pinned": self._pending_release,
+               "stats": self._fam_step_stats}
+        if self._fam_host_stats is not None:
+            # lens were advanced above: the step attended one fewer
+            rec["host_stats"] = self._fam_host_stats(
+                self.cfg, self._lens[disp] - 1)
         self._pending_release = []
         return self._after_dispatch(rec, t_step)
 

@@ -414,3 +414,129 @@ class TestPoolWrittenInPlaceOnChip:
                                          jnp.asarray(slots), new),
                          np.float32)
         np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+
+
+class TestLatentFamilyOnChip:
+    """ISSUE 27: the deepseek_v3 family at the published widths of
+    Kanana-2-30B-A3B, 8 layers, over the pool the benchmark's engine
+    holds (``P`` = 1 + 32 x 512 pages of 16): the decode program and
+    three prefill buckets compile, hold their Mosaic calls and make no
+    copy shaped like the latent pool; the latent kernel and the expert
+    product agree with their ``jnp`` twins on the chip's own layout."""
+
+    PAGES, PAGE, BATCH, MAXP = 1 + 32 * 512, 16, 32, 512
+
+    def _operands(self):
+        from bigdl_tpu.llm.models import deepseek
+        cfg = deepseek.DeepseekConfig(num_hidden_layers=8)
+        params = jax.eval_shape(lambda: deepseek.init_params(cfg, 0))
+        pool = jax.eval_shape(lambda: deepseek.page_pools(
+            cfg, self.PAGES, self.PAGE, jnp.bfloat16)[0])
+        assert pool.shape == (8, self.PAGES, 1, 16, 640)
+        return deepseek, cfg, params, pool
+
+    def _holds_kernels_and_no_pool_copy(self, compiled, pool, calls):
+        from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= calls
+        copies = pool_shaped_copies(text, pool.shape)
+        assert not copies, copies[0][:300]
+
+    def test_decode_program(self):
+        import functools
+        deepseek, cfg, params, pool = self._operands()
+        B = self.BATCH
+        fn = jax.jit(functools.partial(deepseek.paged_decode_step_sampled,
+                                       page=self.PAGE),
+                     static_argnums=(1, 3), donate_argnums=(2,))
+        compiled = fn.lower(
+            params, cfg, pool, None,
+            jax.ShapeDtypeStruct((B, self.MAXP), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, cfg.vocab_size), jnp.float32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.random.PRNGKey(0)).compile()
+        # the latent kernel in layer 0 and in the scan, the expert
+        # product in the scan
+        self._holds_kernels_and_no_pool_copy(compiled, pool, 3)
+
+    @pytest.mark.parametrize("bucket", [128, 1024, 4096])
+    def test_prefill_program(self, bucket):
+        import functools
+        deepseek, cfg, params, pool = self._operands()
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        fn = jax.jit(functools.partial(deepseek.paged_prefill_ragged,
+                                       page=self.PAGE),
+                     static_argnums=(1, 3), donate_argnums=(2,))
+        compiled = fn.lower(
+            params, cfg, pool, None,
+            jax.ShapeDtypeStruct((1, bucket), jnp.int32), i32, i32,
+            jax.ShapeDtypeStruct((self.MAXP,), jnp.int32),
+            jax.ShapeDtypeStruct((bucket,), jnp.int32),
+            jax.ShapeDtypeStruct((bucket,), jnp.int32), i32, i32).compile()
+        self._holds_kernels_and_no_pool_copy(compiled, pool, 1)
+
+    @pytest.mark.parametrize("longest", [500, 2000],
+                             ids=["one_block", "four_blocks"])
+    def test_latent_kernel_matches_its_twin(self, longest):
+        """Contexts inside one block of ``LATENT_BLOCK_TOKENS`` (512)
+        cached tokens, and over four."""
+        from bigdl_tpu.llm.kernels import paged_attention as pa
+        rs = np.random.RandomState(0)
+        b, h, w, dv, pages = 4, 32, 640, 512, 300
+        q = jnp.asarray(rs.randn(b, h, w), jnp.float32)
+        pool = jnp.asarray(rs.randn(pages, 1, self.PAGE, w), jnp.bfloat16)
+        bt = jnp.asarray(rs.randint(1, pages, (b, 128)), jnp.int32)
+        lens = jnp.asarray([0, 1, 517, longest], jnp.int32)
+        scale = 192 ** -0.5
+        with jax.default_matmul_precision("highest"):
+            want = pa.latent_attention_reference_stats(
+                q, pool, bt, lens, dv=dv, scale=scale)
+        got = pa.latent_attention_decode_stats(
+            q, pool, bt, lens, page_size=self.PAGE, dv=dv, scale=scale)
+        # the kernel's float32 products take fewer passes than XLA's
+        # "highest": 1.4 % of the largest sum was seen
+        for g, w_, tol in zip(got, want, (3e-2, 1e-3, 3e-2)):
+            w_ = np.asarray(w_)
+            np.testing.assert_allclose(np.asarray(g), w_, rtol=tol,
+                                       atol=tol * np.abs(w_).max())
+
+    @pytest.mark.parametrize("t", [32, 640])
+    def test_expert_product_matches_its_twin(self, t):
+        from bigdl_tpu.llm.kernels import moe
+        rs = np.random.RandomState(t)
+        k, g, hid, width, layers = 8, 130, 2048, 768, 2
+        x = jnp.asarray(rs.randn(t, hid), jnp.bfloat16)
+        groups = jnp.asarray(np.stack(
+            [rs.permutation(g)[:k] for _ in range(t)]), jnp.int32)
+        w = jnp.asarray(rs.rand(t, k), jnp.float32)
+        live = jnp.asarray(rs.rand(t) > 0.1)
+        key = jax.random.PRNGKey(t)
+        wgu = (jax.random.normal(key, (layers * g, hid, 2 * width),
+                                 jnp.float32) / 45).astype(jnp.bfloat16)
+        wd = (jax.random.normal(key, (layers * g, width, hid),
+                                jnp.float32) / 28).astype(jnp.bfloat16)
+        got, sizes = jax.jit(lambda *a: moe.grouped_ffn(*a, 1, g))(
+            x, groups, w, live, wgu, wd)
+        # the same sums in plain XLA: every group over every token,
+        # kept where the token was assigned to it
+        table = np.zeros((t, g), np.float32)
+        np.put_along_axis(table, np.asarray(groups), np.asarray(w), 1)
+        table = jnp.asarray(table * np.asarray(live)[:, None])
+
+        @jax.jit
+        def twin(x, table, wgu, wd):
+            def one(y, e):
+                gu = x.astype(jnp.float32) @ wgu[g + e].astype(jnp.float32)
+                act = (jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
+                    .astype(jnp.bfloat16).astype(jnp.float32)
+                return y + table[:, e, None] * (
+                    act @ wd[g + e].astype(jnp.float32)), None
+            with jax.default_matmul_precision("highest"):
+                return jax.lax.scan(one, jnp.zeros((t, hid), jnp.float32),
+                                    jnp.arange(g))[0]
+        want = np.asarray(twin(x, table, wgu, wd))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-2,
+                                   atol=2e-2 * np.abs(want).max())
+        assert int(sizes.sum()) == int(live.sum()) * k
