@@ -83,6 +83,32 @@ the full b1 7B decode bench with DEFAULT_BN 256 vs 512 (2 reps each)
 measured 29.83/29.83 vs 29.87/29.77 tok/s — dead even. bn stays 256;
 b1 decode is not kernel-tile-bound.
 
+1 Oct 2026 ledger entry (ISSUE 28, PERF.md §5-6; v5e, Mistral-7B
+shapes, m = 16, the served cell traced): the **stacked form**. A model
+holds each linear as one ``(L, K/2, N)`` / ``(L, K/QK, N)`` stack and
+walks it in a rolled scan; a ``stack[l]`` slice that feeds this kernel
+is an operand of a Mosaic call, which XLA cannot fuse into, so it was a
+real copy of every layer's packed weights: 7.3 ms of a 28.0 ms decode
+step (``dynamic-slice_bitcast_fusion[2048x28672]`` 2.53,
+``[7168x4096]`` 1.28 and nine smaller ones, the second slice of
+``down_proj``'s two K chunks among them) and as much of every prefill.
+``int4_matmul`` now takes the stack and a traced ``layer``: the index
+is a scalar-prefetch operand, the weight and scale blocks are
+``(None, half, bn)`` / ``(None, g, bn)`` at ``(layer, c, j)`` and a K
+chunk is the block index ``c``, not a slice. What the chip said: the
+slices are gone from the trace (``kvcache.write.weight_slices`` finds
+0 in every engine program, 8-9 in the parent's), the step is 19.4 ms,
+and the kernel's OWN time fell too, 16.79 -> 15.97 ms a step (gate_up
+9.33 -> 8.77, o + down 5.46 -> 5.27, qkv 2.01 -> 1.93; 31.7 -> 33.3 %
+of its roofline) with the same tiles and bytes: it now streams from
+the parameter buffer and not from a temporary written a moment
+before. What is left around it: the even/odd split of the
+activations (``x[:, k0:k0+kc:2]``, a stride-2 lane gather XLA runs as
+``fusion[3584x16]`` / ``fusion[2048x16]``) costs 1.6 ms a step, more
+than attention. Not done: Llama-2's ``down_proj`` (K = 11,008: two
+chunks of g = 172, not 8-aligned) keeps the sliced 2-D path; a full-K
+block at bn = 128 would need its VMEM measured first.
+
 ``interpret=True`` runs the same kernel on CPU for tests (SURVEY.md §4:
 golden parity against an independent implementation — here the numpy
 dequant reference).
@@ -154,6 +180,14 @@ def _int4_kernel(xe_ref, xo_ref, q_ref, scale_ref, o_ref, *, sub8: bool,
     o_ref[:] = acc.astype(o_ref.dtype)
 
 
+def _int4_stacked_kernel(layer_ref, *refs, **kw):
+    """:func:`_int4_kernel` behind a scalar-prefetch operand: the layer
+    index is spent in the BlockSpecs' index maps, the body never reads
+    it."""
+    del layer_ref
+    _int4_kernel(*refs, **kw)
+
+
 def _asym_int4_kernel(xe_ref, xo_ref, q_ref, scale_ref, zero_ref, o_ref,
                       *, cdt=jnp.bfloat16):
     """q4_1: w = q * scale + zero (zero = per-group minimum)."""
@@ -218,7 +252,7 @@ DEFAULT_BN = 256
 
 def int4_matmul(x, q_t, scale_t, bm: int = 128, bn: Optional[int] = None,
                 interpret: bool = False, out_dtype=jnp.bfloat16,
-                mode: str = "auto"):
+                mode: str = "auto", layer=None):
     """y = x @ dequant_q4_0(q, scale) in TPU layout.
 
     x: (M, K) activations; q_t: (K/2, N) packed uint8 (low nibble =
@@ -226,9 +260,20 @@ def int4_matmul(x, q_t, scale_t, bm: int = 128, bn: Optional[int] = None,
     ``mode``: "corr" folds the -8 zero-point into an extra skinny dot
     (best for decode), "sub8" subtracts on the VPU (best for prefill),
     "auto" picks by M. ``bn=None`` resolves :data:`DEFAULT_BN` HERE,
-    outside the jit, so flipping the module default retraces."""
-    return _int4_matmul_jit(x, q_t, scale_t, bm=bm,
-                            bn=bn if bn is not None else DEFAULT_BN,
+    outside the jit, so flipping the module default retraces.
+
+    **Stacked form**: q_t ``(L, K/2, N)`` and scale_t ``(L, K/QK, N)``
+    with ``layer`` a (traced) int32 — the rank of ``q_t`` tells the two
+    apart. The kernel reads layer ``layer`` out of the whole stack in
+    place (:func:`_int4_matmul_stacked_jit`); a ``q_t[layer]`` slice
+    handed to a Mosaic call is a copy of the layer's weights."""
+    bn = bn if bn is not None else DEFAULT_BN
+    if q_t.ndim == 3:
+        return _int4_matmul_stacked_jit(
+            x, q_t, scale_t, jnp.asarray(layer, jnp.int32).reshape(1),
+            bm=bm, bn=bn, interpret=interpret, out_dtype=out_dtype,
+            mode=mode)
+    return _int4_matmul_jit(x, q_t, scale_t, bm=bm, bn=bn,
                             interpret=interpret, out_dtype=out_dtype,
                             mode=mode)
 
@@ -279,6 +324,92 @@ def _int4_matmul_jit(x, q_t, scale_t, bm: int, bn: int,
         )(xe, xo, qc, sc)
         out = part if out is None else out + part
     return out[:m, :n].astype(out_dtype)
+
+
+def _stack_blocks(k: int, n: int, bn: int):
+    """How one layer of a ``(L, K/2, N)`` stack is blocked IN PLACE:
+    ``(bn, chunks)``, or None where the shapes allow no such blocking.
+
+    A block's last two dims must be whole tiles (32 sublanes of uint8,
+    8 of float32, 128 lanes) or the whole array dim, and a block index
+    counts whole blocks: so N tiles by ``bn`` or by 128 where one
+    divides it (padding a stack would copy it), and K chunks are all
+    of one size with ``half % 32 == 0`` and ``g % 8 == 0``."""
+    if n % bn:
+        if n % 128 and n > bn:
+            return None
+        bn = 128 if n % 128 == 0 else n
+    chunks = _chunk_k(k)
+    if len(chunks) > 1:
+        kc = chunks[0][1]
+        if kc * len(chunks) != k or (kc // 2) % 32 or (kc // QK) % 8:
+            return None
+    return bn, chunks
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret",
+                                             "out_dtype", "mode"))
+def _int4_matmul_stacked_jit(x, q_t, scale_t, layer, bm: int, bn: int,
+                             interpret: bool, out_dtype, mode: str):
+    """:func:`_int4_matmul_jit` on layer ``layer[0]`` of a stack, the
+    stack left where it is: ``layer`` is a scalar-prefetch operand and
+    the weight and scale blocks are ``(None, half, bn)`` /
+    ``(None, g, bn)`` at block index ``(layer, c, j)``, ``c`` the K
+    chunk. Same kernel body, tiles, operands and accumulation as the
+    2-D form, so the two agree bit for bit. A stack whose shapes
+    cannot be blocked that way (:func:`_stack_blocks`) is sliced and
+    takes the 2-D path."""
+    m, k = x.shape
+    _, half_all, n = q_t.shape
+    if half_all * 2 != k:
+        raise ValueError(
+            f"q_t {q_t.shape} is not the (L, K/2, N) TPU layout for "
+            f"K={k}; convert ggml (N, K/2) dicts with to_tpu_layout() "
+            "first")
+    plan = _stack_blocks(k, n, bn)
+    if plan is None:
+        return _int4_matmul_jit(
+            x, jax.lax.dynamic_index_in_dim(q_t, layer[0], keepdims=False),
+            jax.lax.dynamic_index_in_dim(scale_t, layer[0], keepdims=False),
+            bm=bm, bn=bn, interpret=interpret, out_dtype=out_dtype,
+            mode=mode)
+    bn, chunks = plan
+    sub8 = (m >= 256) if mode == "auto" else (mode == "sub8")
+    scale_t = scale_t.astype(jnp.float32)
+    bm = _align_bm(bm, m)
+    m_pad = -m % bm
+    if m_pad:
+        x = jnp.pad(x, ((0, m_pad), (0, 0)))
+    mp = x.shape[0]
+    x = x.astype(jnp.bfloat16)
+
+    out = None
+    for c, (k0, kc) in enumerate(chunks):
+        xe = x[:, k0:k0 + kc:2]
+        xo = x[:, k0 + 1:k0 + kc:2]
+        half, g = kc // 2, kc // QK
+        part = pl.pallas_call(
+            functools.partial(_int4_stacked_kernel, sub8=sub8,
+                              cdt=jnp.float32 if interpret
+                              else jnp.bfloat16),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(mp // bm, n // bn),
+                in_specs=[
+                    pl.BlockSpec((bm, half), lambda i, j, l: (i, 0)),
+                    pl.BlockSpec((bm, half), lambda i, j, l: (i, 0)),
+                    pl.BlockSpec((None, half, bn),
+                                 lambda i, j, l, c=c: (l[0], c, j)),
+                    pl.BlockSpec((None, g, bn),
+                                 lambda i, j, l, c=c: (l[0], c, j)),
+                ],
+                out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j))),
+            out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=interpret,
+        )(layer, xe, xo, q_t, scale_t)
+        out = part if out is None else out + part
+    return out[:m].astype(out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret",

@@ -17,7 +17,7 @@ family, page size and cache dtype.
 from __future__ import annotations
 
 import re
-from typing import List, Sequence
+from typing import Any, Dict, List, Sequence
 
 import jax.numpy as jnp
 from jax import lax
@@ -94,5 +94,40 @@ def pool_shaped_copies(hlo_text: str, pool_shape: Sequence[int]) -> List[str]:
     hold the engine's programs to that."""
     dims = ",".join(str(d) for d in pool_shape)
     made = re.compile(r"=\s*\w+\[" + dims + r"\]\S*\s+(copy|transpose)\(")
+    return [line.strip() for line in hlo_text.splitlines()
+            if made.search(line)]
+
+
+def weight_slices(hlo_text: str, layers: Dict[str, Any]) -> List[str]:
+    """The instructions of an optimized HLO module that take ONE layer
+    out of a quantised stack in ``layers`` (a family's
+    ``params["layers"]``: every ``{"q", "scale"}`` stack's dtype and
+    shape less its ``L`` axis, read here and not written down): a
+    ``dynamic-slice`` or ``slice`` that makes ``[1, *one_layer]``,
+    alone or inside a fusion, whose body is in the text, and a ``copy``
+    (an asynchronous one at its ``copy-done``) of one layer's packed
+    ``q``. Copies of a ``scale``'s shape are not listed: float
+    activations take it too (``f32[1,128,4096]`` is a 128-token prefill
+    bucket at 7B), and a scale cannot be copied before it is sliced.
+    A program whose INT4 kernel reads its layer out of the stack in
+    place has none (ISSUE 28); one that hands the kernel a ``stack[l]``
+    has a slice a leaf, each a copy of that layer's weights every
+    step."""
+    def one_layer(leaf):
+        dt = jnp.dtype(leaf.dtype)
+        return ({"u": "u", "i": "s"}.get(dt.kind, "f")
+                + str(8 * dt.itemsize),
+                ",".join(map(str, leaf.shape[1:])))
+
+    stacks = [wd for wd in layers.values()
+              if isinstance(wd, dict) and "q" in wd]
+    if not stacks:
+        return []
+    sliced = "|".join(sorted({"%s\\[1,%s\\]" % one_layer(wd[k])
+                              for wd in stacks for k in ("q", "scale")}))
+    copied = "|".join(sorted({"%s\\[(1,)?%s\\]" % one_layer(wd["q"])
+                              for wd in stacks}))
+    made = re.compile(r"=\s*((" + sliced + r")\S*\s+(dynamic-slice|slice)|("
+                      + copied + r")\S*\s+(copy|copy-done))\(")
     return [line.strip() for line in hlo_text.splitlines()
             if made.search(line)]

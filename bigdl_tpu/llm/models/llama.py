@@ -467,12 +467,15 @@ def param_pspecs(params: Dict[str, Any],
 # compute
 # ---------------------------------------------------------------------------
 
-def _linear(wd: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
+def _linear(wd: Dict[str, Any], x: jnp.ndarray, layer=None) -> jnp.ndarray:
     """Dense or quantized matmul: x (..., K) → (..., N), plus an
     optional bias ``b`` (N,) — Qwen2's q/k/v carry one; biases stay
     dense even when weights are ggml-quantized (reference behavior).
     Quantized weights are the k-major TPU layout (q (K/2, N),
-    scale (G, N))."""
+    scale (G, N)) or, after :func:`hold_stacks`, the family's whole
+    stack (q (L, K/2, N), scale (L, G, N)) with ``layer`` the scan's
+    index: the rank of ``q`` tells them apart, and the kernel reads
+    its layer out of the stack in place."""
     if "w" in wd:
         y = x @ wd["w"].T.astype(x.dtype)
         if "b" in wd:
@@ -482,8 +485,11 @@ def _linear(wd: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
     x2 = x.reshape(-1, shape[-1])
     if jax.default_backend() == "tpu":
         from bigdl_tpu.llm.kernels import int4_matmul
-        y = int4_matmul(x2, wd["q"], wd["scale"], out_dtype=x.dtype)
+        y = int4_matmul(x2, wd["q"], wd["scale"], out_dtype=x.dtype,
+                        layer=layer)
     else:
+        if wd["q"].ndim == 3:
+            wd = {**wd, "q": wd["q"][layer], "scale": wd["scale"][layer]}
         y = (x2 @ _dequant_q4(wd, x.dtype)).astype(x.dtype)
     if "b" in wd:
         y = y + wd["b"].astype(y.dtype)
@@ -714,39 +720,74 @@ def _attention(q, k_all, v_all, q_positions, kv_len_mask, cfg,
     return out.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * d)
 
 
-def attention_qkv(lp: Dict[str, Any], h: jnp.ndarray,
-                  cfg: LlamaConfig) -> Tuple[jnp.ndarray, jnp.ndarray,
-                                             jnp.ndarray]:
+def attention_qkv(lp: Dict[str, Any], h: jnp.ndarray, cfg: LlamaConfig,
+                  layer=None) -> Tuple[jnp.ndarray, jnp.ndarray,
+                                       jnp.ndarray]:
     """q/k/v projections for one decoder layer, handling both the fused
     (``qkv_proj``, one weight stream) and unfused per-layer layouts.
-    Returns head-shaped (B, T, H*, D) arrays, pre-RoPE."""
+    ``layer`` goes to :func:`_linear` (the index into whole quantised
+    stacks). Returns head-shaped (B, T, H*, D) arrays, pre-RoPE."""
     b, t, _ = h.shape
     hd = cfg.head_dim
     qh = cfg.num_attention_heads * hd
     kvh = cfg.num_key_value_heads * hd
     if "qkv_proj" in lp:
-        qkv = _linear(lp["qkv_proj"], h)
+        qkv = _linear(lp["qkv_proj"], h, layer)
         q, k, v = (qkv[..., :qh], qkv[..., qh:qh + kvh],
                    qkv[..., qh + kvh:])
     else:
-        q = _linear(lp["q_proj"], h)
-        k = _linear(lp["k_proj"], h)
-        v = _linear(lp["v_proj"], h)
+        q = _linear(lp["q_proj"], h, layer)
+        k = _linear(lp["k_proj"], h, layer)
+        v = _linear(lp["v_proj"], h, layer)
     return (q.reshape(b, t, cfg.num_attention_heads, hd),
             k.reshape(b, t, cfg.num_key_value_heads, hd),
             v.reshape(b, t, cfg.num_key_value_heads, hd))
 
 
-def mlp(lp: Dict[str, Any], h2: jnp.ndarray, dtype) -> jnp.ndarray:
-    """SwiGLU FFN for one decoder layer (fused gate_up or unfused)."""
+def mlp(lp: Dict[str, Any], h2: jnp.ndarray, dtype,
+        layer=None) -> jnp.ndarray:
+    """SwiGLU FFN for one decoder layer (fused gate_up or unfused);
+    ``layer`` as in :func:`attention_qkv`."""
     if "gate_up_proj" in lp:
-        gu = _linear(lp["gate_up_proj"], h2).astype(jnp.float32)
+        gu = _linear(lp["gate_up_proj"], h2, layer).astype(jnp.float32)
         gate, up = jnp.split(gu, 2, axis=-1)
         gate = jax.nn.silu(gate)
     else:
-        gate = jax.nn.silu(_linear(lp["gate_proj"], h2).astype(jnp.float32))
-        up = _linear(lp["up_proj"], h2).astype(jnp.float32)
-    return _linear(lp["down_proj"], (gate * up).astype(dtype))
+        gate = jax.nn.silu(
+            _linear(lp["gate_proj"], h2, layer).astype(jnp.float32))
+        up = _linear(lp["up_proj"], h2, layer).astype(jnp.float32)
+    return _linear(lp["down_proj"], (gate * up).astype(dtype), layer)
+
+
+def hold_stacks(layers: Dict[str, Any]):
+    """The one way the llama family hands its stacked layers to a
+    ``lax.scan``: ``(xs_layers, with_stacks)``. ``xs_layers`` is what
+    the scan slices, every per-layer leaf (norms, biases, dense and
+    expert weights) but NOT the quantised ``q`` / ``scale`` stacks;
+    those stay whole and scan-invariant (closed over, like the page
+    pools), and ``with_stacks(lp)`` puts them back beside layer
+    ``l``'s slices, with their ``L`` axis, for ``_linear(wd, x, l)`` to
+    index. A slice of them is an operand of a Mosaic call, which XLA
+    cannot fuse into, so it was a copy of every layer's packed weights
+    every step (PERF.md §6, PR 28).
+
+    Every scan over ``params["layers"]`` does ``xs_layers, with_stacks
+    = hold_stacks(...)``, scans ``(xs_layers, jnp.arange(L), ...)`` and
+    starts its step with ``lp = with_stacks(lp)``. (Not a wrapper
+    around ``lax.scan``: two more frames under every trace moved the
+    prefill programs' set-up by 6 s, PERF.md §6.)"""
+    held = {name: {"q": wd["q"], "scale": wd["scale"]}
+            for name, wd in layers.items()
+            if isinstance(wd, dict) and "q" in wd}
+    xs_layers = {name: {k: v for k, v in wd.items()
+                        if k not in ("q", "scale")}
+                 if name in held else wd for name, wd in layers.items()}
+
+    def with_stacks(lp):
+        return {**lp, **{name: {**lp[name], **wd}
+                         for name, wd in held.items()}}
+
+    return xs_layers, with_stacks
 
 
 def forward(params: Dict[str, Any], cfg: LlamaConfig,
@@ -772,12 +813,15 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     s_max = cache["k"].shape[2]
     valid = jnp.arange(s_max)[None, :] < (start + tokens.shape[1])
 
+    xs_layers, with_stacks = hold_stacks(params["layers"])
+
     def layer_step(carry, inputs):
         x, = carry
-        lp, k_cache, v_cache = inputs
+        lp, l, k_cache, v_cache = inputs
+        lp = with_stacks(lp)
         h = rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
         b, t, _ = h.shape
-        q, k, v = attention_qkv(lp, h, cfg)
+        q, k, v = attention_qkv(lp, h, cfg, l)
         q = rope_cfg(q, positions, cfg)
         k = rope_cfg(k, positions, cfg)
         k_cache = jax.lax.dynamic_update_slice(
@@ -791,16 +835,18 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                          batch_axis=None).reshape(b, t, -1)
         else:
             attn = _attention(q, k_cache, v_cache, positions, valid, cfg)
-        x = x + _linear(lp["o_proj"], attn)
+        x = x + _linear(lp["o_proj"], attn, l)
         h2 = rms_norm(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)
         if cfg.num_experts:
             x = x + _moe_ffn(lp, h2, cfg)
         else:
-            x = x + mlp(lp, h2, x.dtype)
+            x = x + mlp(lp, h2, x.dtype, l)
         return (x,), (k_cache, v_cache)
 
     (x,), (k_new, v_new) = jax.lax.scan(
-        layer_step, (x,), (params["layers"], cache["k"], cache["v"]),
+        layer_step, (x,),
+        (xs_layers, jnp.arange(cfg.num_hidden_layers), cache["k"],
+         cache["v"]),
         unroll=min(unroll, cfg.num_hidden_layers) if unroll else 1)
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
@@ -981,11 +1027,14 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
                                    length, page=page,
                                    sliding_window=cfg.sliding_window)
 
+    xs_layers, with_stacks = hold_stacks(params["layers"])
+
     def layer_step(carry, inputs):
         x, = carry
         lp, l = inputs
+        lp = with_stacks(lp)
         h = rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
-        q, k, v = attention_qkv(lp, h, cfg)
+        q, k, v = attention_qkv(lp, h, cfg, l)
         q = rope_cfg(q, positions, cfg)
         k = rope_cfg(k, positions, cfg)
         # attend the suffix K/V at POOL precision — the dense sandwich
@@ -996,16 +1045,16 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
         k = k.astype(k_pages.dtype)
         v = v.astype(v_pages.dtype)
         attn = attend(l, q, k, v).astype(x.dtype)
-        x = x + _linear(lp["o_proj"], attn.reshape(b, bucket, -1))
+        x = x + _linear(lp["o_proj"], attn.reshape(b, bucket, -1), l)
         h2 = rms_norm(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)
         if cfg.num_experts:
             x = x + _moe_ffn(lp, h2, cfg)
         else:
-            x = x + mlp(lp, h2, x.dtype)
+            x = x + mlp(lp, h2, x.dtype, l)
         return (x,), (k[0], v[0])
 
     (x,), (k_new, v_new) = jax.lax.scan(
-        layer_step, (x,), (params["layers"], jnp.arange(L)))
+        layer_step, (x,), (xs_layers, jnp.arange(L)))
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:

@@ -346,8 +346,14 @@ def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
     which compiled for >20 min at 7B and measured -18% vs a rolled scan
     per the int4_matmul.py ledger):
 
-    - layers run in a **rolled ``lax.scan``** over the stacked weight
-      pytree — the per-layer weight stream pipelines best this way;
+    - layers run in a **rolled ``lax.scan``**
+      (over :func:`models.llama.hold_stacks`) — the per-layer weight stream
+      pipelines best this way. The scan slices the small per-layer
+      leaves (norms, biases); the quantised ``q``/``scale`` stacks stay
+      whole and scan-invariant, and the INT4 kernel reads layer ``l``
+      out of them in place (``l`` a scalar-prefetch operand of its
+      BlockSpecs). A ``stack[l]`` slice handed to a Mosaic call is a
+      copy: it was a quarter of the 7B step (PERF.md §6, PR 28);
     - the page pools stay **read-only inside the scan** (scan-invariant
       closures, never carried). Attention over the existing ``lens``
       tokens comes from the stats kernel, and the current token's own
@@ -373,8 +379,8 @@ def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
     ``donate_argnums`` on the pools.
     """
     from bigdl_tpu.llm.models.llama import (_linear, _moe_ffn,
-                                            attention_qkv, mlp, rms_norm,
-                                            rope_cfg)
+                                            attention_qkv, hold_stacks,
+                                            mlp, rms_norm, rope_cfg)
     b = toks.shape[0]
     L = cfg.num_hidden_layers
     x = params["embed_tokens"][toks][:, None]                 # (B, 1, H)
@@ -382,24 +388,27 @@ def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
     attend = paged_attend(k_pages, v_pages, bt, lens, page=page,
                           sliding_window=cfg.sliding_window)
 
+    xs_layers, with_stacks = hold_stacks(params["layers"])
+
     def layer_step(carry, inputs):
         x, = carry
         lp, l = inputs
+        lp = with_stacks(lp)
         h = rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
-        q, k, v = attention_qkv(lp, h, cfg)
+        q, k, v = attention_qkv(lp, h, cfg, l)
         q = rope_cfg(q, positions, cfg)
         k = rope_cfg(k, positions, cfg)
         attn = attend(l, q, k, v).astype(x.dtype)
-        x = x + _linear(lp["o_proj"], attn.reshape(b, 1, -1))
+        x = x + _linear(lp["o_proj"], attn.reshape(b, 1, -1), l)
         h2 = rms_norm(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)
         if cfg.num_experts:
             x = x + _moe_ffn(lp, h2, cfg)
         else:
-            x = x + mlp(lp, h2, x.dtype)
+            x = x + mlp(lp, h2, x.dtype, l)
         return (x,), (k[:, 0], v[:, 0])
 
     (x,), (k_new, v_new) = jax.lax.scan(
-        layer_step, (x,), (params["layers"], jnp.arange(L)))
+        layer_step, (x,), (xs_layers, jnp.arange(L)))
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:
@@ -3659,7 +3668,8 @@ class LLMServer:
                                                     sample_tokens)
         from bigdl_tpu.llm.models.llama import (_attention, _linear,
                                                 attention_qkv, mlp,
-                                                rms_norm, rope_cfg)
+                                                hold_stacks, rms_norm,
+                                                rope_cfg)
         cfg = self.cfg
         do_sample, top_k = self._do_sample, self.top_k
 
@@ -3675,12 +3685,15 @@ class LLMServer:
             valid = (jnp.arange(s_max)[None, :]
                      <= positions[:, 0][:, None])             # (B, S)
 
+            xs_layers, with_stacks = hold_stacks(params["layers"])
+
             def layer_step(carry, inputs):
                 x, = carry
-                lp, k_cache, v_cache = inputs
+                lp, l, k_cache, v_cache = inputs
+                lp = with_stacks(lp)
                 h = rms_norm(x, lp["input_layernorm"],
                              cfg.rms_norm_eps)
-                q, k, v = attention_qkv(lp, h, cfg)
+                q, k, v = attention_qkv(lp, h, cfg, l)
                 q = rope_cfg(q, positions, cfg)
                 k = rope_cfg(k, positions, cfg)
                 # scatter each slot's kv at ITS position
@@ -3694,19 +3707,20 @@ class LLMServer:
                     v.astype(v_cache.dtype), v_cache)
                 attn = _attention(q, k_cache, v_cache, positions,
                                   valid, cfg)
-                x = x + _linear(lp["o_proj"], attn)
+                x = x + _linear(lp["o_proj"], attn, l)
                 h2 = rms_norm(x, lp["post_attention_layernorm"],
                               cfg.rms_norm_eps)
                 if cfg.num_experts:
                     from bigdl_tpu.llm.models.llama import _moe_ffn
                     x = x + _moe_ffn(lp, h2, cfg)
                 else:
-                    x = x + mlp(lp, h2, x.dtype)
+                    x = x + mlp(lp, h2, x.dtype, l)
                 return (x,), (k_cache, v_cache)
 
             (x,), (k_new, v_new) = jax.lax.scan(
                 layer_step, (x,),
-                (params["layers"], cache_k, cache_v))
+                (xs_layers, jnp.arange(cfg.num_hidden_layers), cache_k,
+                 cache_v))
             x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
             head = params.get("lm_head")
             if head is None:

@@ -179,11 +179,14 @@ def train_phase(depth: int = 50, image: int = 224, classes: int = 1000,
 # serve
 # ---------------------------------------------------------------------------
 
-def pallas_calls_in_engine_programs(pool_shape) -> None:
+def pallas_calls_in_engine_programs(pool_shape, layers) -> None:
     """Read the HLO of the executables the engine compiled: count the
-    Mosaic custom calls in them, and the copies of a whole page pool
-    (a step that writes new K/V in place makes none)."""
-    from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
+    Mosaic custom calls in them, the copies of a whole page pool (a
+    step that writes new K/V in place makes none) and the slices of
+    one layer out of a quantised weight stack (a step whose INT4
+    kernel indexes the stack makes none)."""
+    from bigdl_tpu.llm.kvcache.write import (pool_shaped_copies,
+                                             weight_slices)
     from bigdl_tpu.llm.serving import compiled_steps
     found = {}
     for kind, detail, fn in compiled_steps():
@@ -191,12 +194,18 @@ def pallas_calls_in_engine_programs(pool_shape) -> None:
             text = exe.as_text()
             n = text.count("tpu_custom_call")
             copies = pool_shaped_copies(text, pool_shape)
+            slices = weight_slices(text, layers)
             fact(f"serve: {fn.name} {detail} has {n} tpu_custom_call "
-                 f"site(s) and {len(copies)} copies shaped like the page "
-                 f"pool {tuple(pool_shape)} in its compiled HLO")
+                 f"site(s), {len(copies)} copies shaped like the page "
+                 f"pool {tuple(pool_shape)} and {len(slices)} slices of "
+                 "one layer out of a quantised weight stack in its "
+                 "compiled HLO")
             check(not copies,
                   f"{fn.name} {detail} copies the whole page pool: "
                   f"{[c[:200] for c in copies[:1]]}")
+            check(not slices,
+                  f"{fn.name} {detail} copies a layer's weights out of "
+                  f"their stack: {[c[:200] for c in slices[:1]]}")
             found[kind] = min(found.get(kind, n), n)
     for kind in ("decode", "prefill_ragged"):
         check(kind in found,
@@ -303,7 +312,7 @@ def serve_phase(cfg=None, max_seq_len: int = 2048, first=FIRST_WAVE,
           "sigmas below the dense reference's best")
 
     if expect_pallas:
-        pallas_calls_in_engine_programs(pool_shape)
+        pallas_calls_in_engine_programs(pool_shape, params["layers"])
     fact(f"phase serve wall {time.perf_counter() - t0:.1f} s")
 
 

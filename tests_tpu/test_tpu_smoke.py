@@ -324,13 +324,21 @@ class TestPagedServingOnChip:
 
 class TestPoolWrittenInPlaceOnChip:
     """ISSUE 26: the decode step and a prefill bucket, compiled at
-    Mistral-7B widths over a pool of the served size (two layers deep),
-    make no array of the pool's shape by ``copy`` or ``transpose`` —
+    Mistral-7B widths and depth over a pool of the served size, make
+    no array of the pool's shape by ``copy`` or ``transpose`` —
     the vectorised scatter they used before cost two such copies a pool
     in every step — and the in-place writers put the same bits in the
-    same places as that scatter."""
+    same places as that scatter. ISSUE 28: the same two programs slice
+    no layer out of a quantised weight stack (the scan used to, one
+    copy of the layer's packed weights a linear) and hold the seven
+    Mosaic calls they held: qkv, o, gate_up, down in two K chunks, the
+    head, attention. All 32 layers, which cost the rolled scan no
+    compile time: two layers deep a whole stack is small enough that
+    XLA prefetches it into VMEM ahead of the kernel, layer by layer,
+    and those ``slice`` s are no part of the served program."""
 
-    LAYERS, PAGES, PAGE, BATCH, MAXP = 2, 2049, 16, 16, 128
+    LAYERS, PAGES, PAGE, BATCH, MAXP = 32, 2049, 16, 16, 128
+    MOSAIC_CALLS = 7
 
     def _operands(self):
         import dataclasses
@@ -343,12 +351,15 @@ class TestPoolWrittenInPlaceOnChip:
                  self.PAGE, cfg.hidden_size // cfg.num_attention_heads)
         return cfg, params, shape
 
-    def _no_pool_copy(self, compiled, shape):
-        from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
+    def _no_pool_copy(self, compiled, shape, layers):
+        from bigdl_tpu.llm.kvcache.write import (pool_shaped_copies,
+                                                 weight_slices)
         text = compiled.as_text()
-        assert "tpu_custom_call" in text
+        assert text.count("tpu_custom_call") == self.MOSAIC_CALLS
         copies = pool_shaped_copies(text, shape)
         assert not copies, copies[0][:300]
+        slices = weight_slices(text, layers)
+        assert not slices, slices[0][:300]
 
     def test_decode_step_holds_no_pool_copy(self):
         import functools
@@ -367,7 +378,7 @@ class TestPoolWrittenInPlaceOnChip:
             jax.ShapeDtypeStruct((B,), jnp.bool_),
             jax.ShapeDtypeStruct((), jnp.float32),
             jax.random.PRNGKey(0)).compile()
-        self._no_pool_copy(compiled, shape)
+        self._no_pool_copy(compiled, shape, params["layers"])
 
     def test_prefill_bucket_holds_no_pool_copy(self):
         import functools
@@ -385,7 +396,7 @@ class TestPoolWrittenInPlaceOnChip:
             jax.ShapeDtypeStruct((self.MAXP,), jnp.int32),
             jax.ShapeDtypeStruct((bucket,), jnp.int32),
             jax.ShapeDtypeStruct((bucket,), jnp.int32), i32, i32).compile()
-        self._no_pool_copy(compiled, shape)
+        self._no_pool_copy(compiled, shape, params["layers"])
 
     @pytest.mark.parametrize("T,off", [(16, None), (256, 19), (3, 31)])
     def test_writers_match_the_scatter_on_chip(self, T, off):
@@ -414,6 +425,33 @@ class TestPoolWrittenInPlaceOnChip:
                                          jnp.asarray(slots), new),
                          np.float32)
         np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+
+
+class TestStackedInt4OnChip:
+    """ISSUE 28: the stacked form of ``int4_matmul`` (the layer a
+    scalar-prefetch operand, the stack blocked in place) against the
+    2-D form on ``q[l], scale[l]``, at Mistral-7B's four linear shapes,
+    a decode batch (``corr``) and a prefill bucket (``sub8``): the same
+    bits, under the real Mosaic lowering."""
+
+    @pytest.mark.parametrize("m", [16, 512])
+    @pytest.mark.parametrize("n,k", [(6144, 4096), (4096, 4096),
+                                     (28672, 4096), (4096, 14336)])
+    def test_stacked_equals_2d(self, n, k, m):
+        L, layer = 3, 1
+        tds = [_rand_quant(n, k, "sym_int4", seed=s)[2] for s in range(L)]
+        q = jnp.asarray(np.stack([t["q"] for t in tds]))
+        scale = jnp.asarray(np.stack([t["scale"] for t in tds]))
+        x = jnp.asarray(np.random.RandomState(m).randn(m, k), jnp.bfloat16)
+        got = jax.jit(lambda x, q, s, l: int4_matmul(
+            x, q, s, layer=l, out_dtype=jnp.float32))(
+                x, q, scale, jnp.int32(layer))
+        want = int4_matmul(x, q[layer], scale[layer],
+                           out_dtype=jnp.float32)
+        assert float(jnp.abs(want).max()) > 0
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        other = int4_matmul(x, q[0], scale[0], out_dtype=jnp.float32)
+        assert not np.array_equal(np.asarray(got), np.asarray(other))
 
 
 class TestLatentFamilyOnChip:
