@@ -617,8 +617,8 @@ def bench_paged_decode_step(batch: int = 8, ctx_len: int = 256,
 
     from bigdl_tpu.llm.kernels.paged_attention import LANE
     from bigdl_tpu.llm.models.llama import (LlamaConfig,
+                                            paged_decode_step,
                                             synthetic_q4_params)
-    from bigdl_tpu.llm.serving import paged_decode_step
 
     cfg = {"7b": LlamaConfig.llama2_7b,
            "tiny": LlamaConfig.tiny}[model_size]()
@@ -826,20 +826,6 @@ def _compact_northstar(out: dict) -> dict:
             "fetches": (tb.get("tier_on") or {}).get("fetches"),
             "hit_rate": (tb.get("tier_on") or {}).get("hit_rate"),
         }
-    # ISSUE 8: ragged-prefill headline — partial-prefill TTFT dense vs
-    # in-place, and the dense-staging volume the ragged path deleted
-    # (staged_on must stay 0)
-    rb = ((ex.get("telemetry") or {}).get("microbench_ragged") or {})
-    if "error" in rb:
-        ns["ragged_prefill"] = {"error": str(rb["error"])[:80]}
-    else:
-        ns["ragged_prefill"] = {
-            "ttft_off_ms": (rb.get("ragged_off") or {}).get("ttft_ms"),
-            "ttft_on_ms": (rb.get("ragged_on") or {}).get("ttft_ms"),
-            "staged_off": rb.get("dense_staged_tokens_off"),
-            "staged_on": rb.get("dense_staged_tokens_on"),
-            "speedup": rb.get("ttft_speedup"),
-        }
     # ISSUE 14: unified-dispatch headline — the decode stream's p99
     # inter-token gap while a long prompt is admitted, split vs mixed
     # (the spike the chunked admission deletes), plus the TTFT trade
@@ -967,16 +953,6 @@ def _telemetry_block() -> dict:
         out["microbench_tier"] = run_tier_bench()
     except Exception as e:
         out["microbench_tier"] = {"error": repr(e)}
-    try:
-        # ISSUE 8: partial-prefill TTFT + dense-staging volume with the
-        # ragged in-place prefill off/on across prefix/suffix ratios —
-        # the ragged path must stage ZERO tokens through a dense temp
-        # cache (bench_regress diffs the ttft_ms pair and the staged
-        # tally)
-        from tools.microbench_ragged import run_ragged_bench
-        out["microbench_ragged"] = run_ragged_bench()
-    except Exception as e:
-        out["microbench_ragged"] = {"error": repr(e)}
     try:
         # ISSUE 14: mixed-load microbench — steady decode streams with
         # a long admission mid-run, unified dispatch off/on. The p99

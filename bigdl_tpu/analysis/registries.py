@@ -143,8 +143,6 @@ CONF_KEYS.update({
         "seconds a budget-starved chunked admission waits before shedding with a clean rollback",
     "bigdl.llm.prefill.chunk_tokens":
         "page-aligned prefill chunk size for the unified dispatch; 0 = auto (4 pages)",
-    "bigdl.llm.prefill.ragged":
-        "prefill attends cached prefix pages in place; auto = on where Mosaic runs",
     "bigdl.llm.priority.enabled":
         "SLO-class priority scheduling with lossless preemption; false = FIFO, structurally absent",
     "bigdl.llm.prober.interval":
@@ -515,7 +513,7 @@ SPAN_NAMES.update({
     "llm/preempt":
         "completion: one lossless preemption of an in-flight decode",
     "llm/prefill":
-        "prompt prefill (full/partial/ragged) on the engine",
+        "prompt prefill (the uncached suffix, ragged in place) on the engine",
     "llm/queue_wait":
         "request time between submit and slot admission",
     "llm/request":

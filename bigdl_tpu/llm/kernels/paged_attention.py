@@ -145,7 +145,7 @@ def _paged_decode_kernel_pm(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
     (page·D·2 bytes ≈ 4 KB) per grid cell over a (B, Hkv, nblk) grid —
     at 7B decode that is ~16k 4 KB copies per layer, and the measured
     cost is DMA-issue-bound: attention was 27.8 ms of the 55 ms paged
-    step (tools/exp_paged_gap.py) vs ~17 ms for the dense cache path.
+    step (a one-off experiment, round 5) vs ~17 ms for the dense cache path.
     Here the grid is (B, nblk) and each cell copies ``2·ppb`` blocks of
     ``(Hkv, page, D)`` (≈128 KB contiguous at 7B) — 32× fewer, 32×
     larger DMAs — then statically loops the Hkv heads in-register.

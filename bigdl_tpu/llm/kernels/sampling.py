@@ -126,9 +126,10 @@ def make_sampled_step(fam_step):
       own counts (its module names them in ``STEP_STATS``), gets it
       appended after the fence, so the drain's one fetch brings it.
 
-    Each family module exposes ``paged_decode_step_sampled =
-    make_sampled_step(paged_decode_step)`` so the engine dispatches one
-    compiled program per family with no per-family sampling code.
+    The engine wraps a family's ``paged_decode_step`` with this (a
+    family module may expose its own ``paged_decode_step_sampled``
+    instead), so it dispatches one compiled program per family with no
+    per-family sampling code.
     """
 
     def sampled_step(params, cfg, k_pages, v_pages, bt, lens, last,

@@ -7,9 +7,10 @@
   copy-on-write fork semantics and the admission-budget ledger;
 - :mod:`~bigdl_tpu.llm.kvcache.radix` — radix prefix index keyed on
   page-size token chunks, leaf-first LRU eviction;
-- :mod:`~bigdl_tpu.llm.kvcache.prefill` — the family-generic partial
-  prefill (gather prefix pages → run suffix at a position offset →
-  scatter back, with the COW tail fork fused into the scatter);
+- :mod:`~bigdl_tpu.llm.kvcache.prefill` — the closures every family's
+  ragged in-place prefill and decode step share (attention over the
+  pool where it sits, the COW tail fork, the suffix write) and the
+  mixed / speculative steps composed from a family's two programs;
 - :class:`KVCacheManager` (here) — the engine-facing façade: admission
   lookup + suffix-only budget charging, adoption refcounts/pins,
   chain insertion at prefill and EOS, on-demand LRU eviction (the
@@ -31,8 +32,6 @@ import threading
 from typing import Any, Dict, List, Optional
 
 from bigdl_tpu.llm.kvcache.pool import PagePool, PagePoolError
-from bigdl_tpu.llm.kvcache.prefill import (make_partial_prefill,
-                                           make_spec_step)
 from bigdl_tpu.llm.kvcache.radix import PrefixMatch, RadixIndex
 
 
@@ -567,5 +566,4 @@ class KVCacheManager:
 
 
 __all__ = ["Admission", "KVCacheManager", "PagePool", "PagePoolError",
-           "PrefixMatch", "RadixIndex", "make_partial_prefill",
-           "make_spec_step"]
+           "PrefixMatch", "RadixIndex"]

@@ -1,57 +1,23 @@
-"""Partial prefill over a pre-populated block-table prefix (ISSUE 5/8).
-
-Two implementations live here:
-
-- the **ragged in-place path** (ISSUE 8, the default): shared closures
-  (:func:`ragged_prefill_attend`, :func:`fork_tail_pages`,
-  :func:`scatter_suffix_kv`) that each family's ``paged_prefill_ragged``
-  composes with its own layer math — the suffix attends the prefix
-  pages WHERE THEY SIT via the Mosaic ragged kernel
-  (llm/kernels/ragged_prefill.py), the COW tail fork is one
-  page-to-page copy inside the same dispatch, and after the scan the
-  suffix K/V is written into the request's pages in place, page by
-  page (llm/kvcache/write.py). No dense temp cache,
-  and the prefix page count is runtime block-table data — the compile
-  grid is O(suffix-buckets) only;
-- the **dense staging path** (:func:`make_partial_prefill`, the ISSUE 5
-  original): kept as the fallback for families without a ragged entry
-  point and for the ``bigdl.llm.prefill.ragged=false`` escape hatch.
+"""Attention over the page pool and the programs composed from a
+family's two (ISSUE 5/8/14/19).
 
 When admission finds a cached prefix, only the uncached suffix must run
 through the model — but the suffix's attention still needs the prefix's
-K/V. :func:`make_partial_prefill` lifts a family ``forward`` into a
-prefill that:
+K/V. Each family's ``paged_prefill_ragged`` composes the shared closures
+here (:func:`ragged_prefill_attend`, :func:`fork_tail_pages`,
+:func:`scatter_suffix_kv`) with its own layer math: the suffix attends
+the prefix pages WHERE THEY SIT via the Mosaic ragged kernel
+(llm/kernels/ragged_prefill.py), the COW tail fork is one page-to-page
+copy inside the same dispatch, and after the scan the suffix K/V is
+written into the request's pages in place, page by page
+(llm/kvcache/write.py). No dense temp cache, and the prefix page count
+is runtime block-table data — the compile grid is O(suffix-buckets)
+only. A full prompt is the offset-0 case of the same program.
 
-1. **gathers** the prefix pages (the request's pre-populated block-table
-   prefix) into the dense temp cache the family forward already expects,
-   at their absolute positions 0..offset;
-2. runs the family forward over the suffix tokens only, at a **position
-   offset** — the suffix attends to the gathered prefix plus itself
-   causally, exactly the math of the full prefill's later rows;
-3. **scatters** one page-aligned window back into the (donated) pools:
-   the suffix K/V into the request's own pages, PLUS the shared slots of
-   a partially-matched tail page into the request's fork target — the
-   copy-on-write fork fused into the same dispatch (no separate copy
-   kernel, no window where a half-forked page is visible).
-
-Shapes are static per ``(n_pp, bucket)`` — prefix pages padded to a
-power of two (pad ids point at trash page 0), suffix length padded like
-the full prefill's pow2 buckets — so the compile count stays
-logarithmic. The dynamic values (offset, true suffix length, page ids,
-per-token scatter targets) are runtime arguments.
-
-Why the garbage in pad pages / beyond-offset slots is harmless: every
-temp-cache slot at index >= offset is either overwritten by the
-suffix's own in-forward cache write (indices offset..offset+bucket) or
-masked by the forward's validity bound (indices >= offset+bucket), and
-causal masking orders real queries before any padding position.
-
-Each paged family module exposes::
-
-    paged_prefill_partial = make_partial_prefill(forward, init_cache)
-
-mirroring ``paged_decode_step_sampled = make_sampled_step(...)`` — one
-entry point per family, zero per-family math here.
+:func:`paged_attend` is the decode step's counterpart (one token a row
+over the pool), and :func:`make_mixed_step` / :func:`make_spec_step`
+lift a family's ``(paged_decode_step, paged_prefill_ragged)`` pair into
+the engine's unified and speculative steps, zero per-family math here.
 """
 
 from __future__ import annotations
@@ -80,13 +46,45 @@ def fork_tail_pages(k_pages, v_pages, fork_dst, fork_src):
     return k_pages, v_pages
 
 
+def paged_attend(k_pages, v_pages, bt, lens, *, page: int,
+                 sliding_window: Optional[int] = None):
+    """Shared paged-attention closure for every family's decode step.
+
+    Owns the divergence-prone conventions in ONE place (review r5):
+    the pools are viewed as one flat ``(L·P, H, page, D)`` page array
+    (a ``pool[l]`` slice would copy 2·pool_bytes/L per layer), block
+    tables are offset by ``l·P`` inside the layer scan (layer ``l``'s
+    trash page is ``l·P``), the kernel sees lengths EXCLUDING the
+    current token with the window shrunk by one, and the token's own
+    K/V is folded in with the flash combine. Returns
+    ``attend(l, q, k, v) -> (B, Hq, D)`` for head-shaped ``(B, 1, H*,
+    D)`` current-token projections."""
+    from bigdl_tpu.llm.kernels.paged_attention import (
+        merge_attention_partial, paged_attention_stats)
+    L_times_P = k_pages.shape[0] * k_pages.shape[1]
+    num_pages = k_pages.shape[1]
+    kp_flat = k_pages.reshape((L_times_P,) + k_pages.shape[2:])
+    vp_flat = v_pages.reshape((L_times_P,) + v_pages.shape[2:])
+    win_excl = (None if sliding_window is None
+                else max(sliding_window - 1, 0))
+
+    def attend(l, q, k, v):
+        acc, m, lsum = paged_attention_stats(
+            q[:, 0], kp_flat, vp_flat, bt + l * num_pages, lens,
+            page_size=page, sliding_window=win_excl)
+        return merge_attention_partial(acc, m, lsum, q[:, 0], k[:, 0],
+                                       v[:, 0])
+
+    return attend
+
+
 def ragged_prefill_attend(k_pages, v_pages, bt_row, offset, seq_len, *,
                           page: int,
                           sliding_window: Optional[int] = None,
                           interpret: Optional[bool] = None):
     """Shared ragged-attention closure for every family's prefill.
 
-    Mirrors ``serving.paged_attend``'s conventions: the pools are
+    Mirrors :func:`paged_attend`'s conventions: the pools are
     viewed as one flat ``(L·P, H, page, D)`` page array, the block
     table is offset by ``l·P`` inside the layer scan (layer ``l``'s
     trash page is ``l·P``), and the kernel reads only prefix positions
@@ -263,71 +261,3 @@ def make_spec_step(fam_step, fam_ragged):
         return out, logits, k_pages, v_pages, new_lens, key
 
     return spec_step
-
-
-def make_partial_prefill(forward_fn, init_cache_fn):
-    """Lift a family ``forward``/``init_cache`` pair into the engine's
-    partial-prefill shape.
-
-    The lifted function (jitted by the engine with the pools donated)::
-
-        partial_prefill(params, cfg, k_pages, v_pages, toks, length,
-                        offset, prefix_ids, phys, slots, *,
-                        page, n_pp, bucket, cache_dtype)
-        -> (k_pages, v_pages, last_logits)
-
-    - ``toks`` (1, bucket) int32 suffix tokens (zero-padded);
-    - ``length`` () int32 true suffix length (>= 1);
-    - ``offset`` () int32 cached-prefix length (the position offset);
-    - ``prefix_ids`` (n_pp,) int32 physical pages holding positions
-      ``0..offset`` in order (pad entries 0 = trash page);
-    - ``phys``/``slots`` (page + bucket,) int32 scatter targets for the
-      window starting at position ``(offset // page) * page``: token
-      ``j`` of the window lands in ``(phys[j], slots[j])``; entries the
-      request must not write route to trash page 0. The leading
-      sub-page slots (window start .. offset) target the COW fork page,
-      re-writing the adopted tail's shared slots into a page the
-      request owns.
-    """
-
-    def partial_prefill(params, cfg, k_pages, v_pages, toks, length,
-                        offset, prefix_ids, phys, slots, *, page: int,
-                        n_pp: int, bucket: int, cache_dtype):
-        L = k_pages.shape[0]
-        # one page of slack past the gathered prefix: the scatter window
-        # below is page-aligned, so with a page-aligned offset it starts
-        # AT the prefix end and must slice page+bucket in-bounds tokens
-        s_temp = n_pp * page + page + bucket
-        cache = init_cache_fn(cfg, 1, s_temp, dtype=cache_dtype)
-
-        def gathered(pages):
-            g = pages[:, prefix_ids]                 # (L,n_pp,H,page,D)
-            g = g.transpose(0, 1, 3, 2, 4)           # (L,n_pp,page,H,D)
-            return g.reshape(L, n_pp * page, *g.shape[3:])
-
-        cache["k"] = cache["k"].at[:, 0, :n_pp * page].set(
-            gathered(k_pages).astype(cache_dtype))
-        cache["v"] = cache["v"].at[:, 0, :n_pp * page].set(
-            gathered(v_pages).astype(cache_dtype))
-        cache["pos"] = offset.astype(jnp.int32)
-        positions = (offset + jnp.arange(bucket, dtype=jnp.int32))[None]
-        logits, cache2 = forward_fn(params, cfg, toks, cache, positions)
-
-        # page-aligned write-back window: [window0, window0+page+bucket)
-        # covers the fork page's shared slots AND every suffix token
-        window0 = (offset // page) * page
-        ks, vs = cache2["k"][:, 0], cache2["v"][:, 0]  # (L,s_temp,H,D)
-
-        def scatter(pages, vals):
-            w = jax.lax.dynamic_slice_in_dim(vals, window0,
-                                             page + bucket, axis=1)
-            return pages.at[:, phys, :, slots].set(
-                w.transpose(1, 0, 2, 3).astype(pages.dtype))
-
-        k_pages = scatter(k_pages, ks)
-        v_pages = scatter(v_pages, vs)
-        last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0,
-                                            keepdims=False)
-        return k_pages, v_pages, last.astype(jnp.float32)
-
-    return partial_prefill

@@ -86,6 +86,20 @@ def write_kv_run(pool, phys, slots, new):
     return lax.fori_loop(0, nvis, visit, pool)
 
 
+def scatter_new_kv(k_pages, v_pages, bt, lens, k_new, v_new, *,
+                   page: int):
+    """Every layer's new-token K/V into the (donated) pools, in place —
+    shared by every family's decode step. ``k_new``/``v_new`` are the
+    layer-scan ys ``(L, B, Hkv, D)``; row ``b``'s token lands in page
+    ``bt[b, lens[b] // page]`` at slot ``lens[b] % page`` through
+    :func:`write_kv`, which leaves the pool in its own
+    layout (no pool-sized copy in the compiled step)."""
+    phys = bt[jnp.arange(lens.shape[0]), lens // page]        # (B,)
+    slot = lens % page
+    return (write_kv(k_pages, phys, slot, k_new),
+            write_kv(v_pages, phys, slot, v_new))
+
+
 def pool_shaped_copies(hlo_text: str, pool_shape: Sequence[int]) -> List[str]:
     """The instructions of an optimized HLO module that make a new
     array of the pool's shape by ``copy`` or ``transpose`` (fused ones

@@ -492,7 +492,7 @@ def host_step_stats(cfg: DeepseekConfig, ctx_lens) -> Dict[str, int]:
 def paged_decode_step(params, cfg: DeepseekConfig, kv_pages, _none, bt,
                       lens, toks, *, page: int):
     """One decode step over the latent pool: as
-    ``serving.paged_decode_step`` (pool read-only inside the layers,
+    ``llama.paged_decode_step`` (pool read-only inside the layers,
     the current token folded in by the flash combine, one in-place
     write after them), with absorbed-form attention. Rows with ``lens
     == 0`` are the sampled step's masked lanes: they route to no expert

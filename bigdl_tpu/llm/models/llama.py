@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from bigdl_tpu.llm.kernels.sampling import make_sampled_step
+
 
 @dataclasses.dataclass
 class LlamaConfig:
@@ -623,7 +625,7 @@ def rope(x, positions, theta: float, mode: str = "half",
 
 def rope_cfg(x, positions, cfg: "LlamaConfig"):
     """cfg-driven dispatch shared by every Llama-stack call site (the
-    prefill scan, the paged serving step, the slot-static decode)."""
+    prefill scan, the paged decode step and ragged prefill)."""
     return rope(x, positions, cfg.rope_theta, cfg.rope_mode,
                 cfg.partial_rotary_factor)
 
@@ -949,6 +951,95 @@ def pageify_cache(cache: Dict[str, jnp.ndarray], page: int = 16
     return pageify(k), pageify(v), bt
 
 
+def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
+                      *, page: int):
+    """One paged-KV decode step: next-token logits for every row plus
+    the pools with each row's new K/V written at position ``lens``.
+
+    Structure (round 5 — replaces the 32-layer python-unrolled graph,
+    which compiled for >20 min at 7B and measured -18% vs a rolled scan
+    per the int4_matmul.py ledger):
+
+    - layers run in a **rolled ``lax.scan``**
+      (over :func:`hold_stacks`) — the per-layer weight stream
+      pipelines best this way. The scan slices the small per-layer
+      leaves (norms, biases); the quantised ``q``/``scale`` stacks stay
+      whole and scan-invariant, and the INT4 kernel reads layer ``l``
+      out of them in place (``l`` a scalar-prefetch operand of its
+      BlockSpecs). A ``stack[l]`` slice handed to a Mosaic call is a
+      copy: it was a quarter of the 7B step (PERF.md §6, PR 28);
+    - the page pools stay **read-only inside the scan** (scan-invariant
+      closures, never carried). Attention over the existing ``lens``
+      tokens comes from the stats kernel, and the current token's own
+      K/V is folded in with the flash combine
+      (`merge_attention_partial`) — exactly the write-then-attend math,
+      without the write;
+    - per-layer pools are addressed WITHOUT slicing (a `pool[l]` slice
+      would copy 2×pool_bytes/L per layer): the pool is viewed as one
+      flat ``(L·P, H, page, D)`` page array and block tables are offset
+      by ``l·P`` inside the scan. Layer ``l``'s trash page is ``l·P``;
+    - after the scan, :func:`kvcache.write.scatter_new_kv` writes all
+      ``L`` layers'
+      new-token K/V into the donated pools in place: one
+      ``dynamic_update_slice`` of an ``(L, 1, H, 1, D)`` slab per row,
+      in the pools' own layout. (Not one vectorised scatter on the
+      ``P`` and ``page`` dimensions: XLA compiles that in another
+      layout and copies both whole pools there and back every step,
+      which was about half of the 7B step's device time — PERF.md §6.)
+
+    ``params`` must be the stacked-layer llama pytree; ``bt`` (B, maxp)
+    int32 block tables; ``lens`` (B,) int32 lengths EXCLUDING the token
+    being decoded; ``toks`` (B,) int32. Returns
+    ``(logits (B, V) f32, k_pages, v_pages)``. Callers jit this with
+    ``donate_argnums`` on the pools.
+    """
+    from bigdl_tpu.llm.kvcache.prefill import paged_attend
+    from bigdl_tpu.llm.kvcache.write import scatter_new_kv
+    b = toks.shape[0]
+    L = cfg.num_hidden_layers
+    x = params["embed_tokens"][toks][:, None]                 # (B, 1, H)
+    positions = lens[:, None].astype(jnp.int32)
+    attend = paged_attend(k_pages, v_pages, bt, lens, page=page,
+                          sliding_window=cfg.sliding_window)
+
+    xs_layers, with_stacks = hold_stacks(params["layers"])
+
+    def layer_step(carry, inputs):
+        x, = carry
+        lp, l = inputs
+        lp = with_stacks(lp)
+        h = rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
+        q, k, v = attention_qkv(lp, h, cfg, l)
+        q = rope_cfg(q, positions, cfg)
+        k = rope_cfg(k, positions, cfg)
+        attn = attend(l, q, k, v).astype(x.dtype)
+        x = x + _linear(lp["o_proj"], attn.reshape(b, 1, -1), l)
+        h2 = rms_norm(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)
+        if cfg.num_experts:
+            x = x + _moe_ffn(lp, h2, cfg)
+        else:
+            x = x + mlp(lp, h2, x.dtype, l)
+        return (x,), (k[:, 0], v[:, 0])
+
+    (x,), (k_new, v_new) = jax.lax.scan(
+        layer_step, (x,), (xs_layers, jnp.arange(L)))
+    x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
+    head = params.get("lm_head")
+    if head is None:
+        logits = x @ params["embed_tokens"].T.astype(x.dtype)
+    else:
+        logits = _linear(head, x)
+    k_pages, v_pages = scatter_new_kv(k_pages, v_pages, bt, lens,
+                                      k_new, v_new, page=page)
+    return logits[:, 0].astype(jnp.float32), k_pages, v_pages
+
+
+# pipelined-engine step shape for the llama family (ISSUE 4): greedy/
+# temperature/top-k sampling folded into the compiled step, lens carried
+# on device, fence element folded onto the token vector
+paged_decode_step_sampled = make_sampled_step(paged_decode_step)
+
+
 def decode_scan_paged(params, k_pages, v_pages, bt, pos, last_logits, key,
                       temperature, finished=None, *, cfg, page: int,
                       num_tokens: int, do_sample: bool = False,
@@ -963,7 +1054,6 @@ def decode_scan_paged(params, k_pages, v_pages, bt, pos, last_logits, key,
     ``pos`` is the shared position scalar (generate is rectangular);
     returns ``(tokens (B, T), k_pages, v_pages, pos, last, key,
     finished)``."""
-    from bigdl_tpu.llm.serving import paged_decode_step
     b = last_logits.shape[0]
     if finished is None:
         finished = jnp.zeros((b,), bool)
@@ -986,14 +1076,6 @@ def decode_scan_paged(params, k_pages, v_pages, bt, pos, last_logits, key,
     return toks.T, k_pages, v_pages, pos, last, key, finished
 
 
-# prefix-cache partial prefill (ISSUE 5): run only the uncached suffix
-# at a position offset over a pre-populated block-table prefix, with the
-# COW tail fork fused into the write-back — see llm/kvcache/prefill.py
-from bigdl_tpu.llm.kvcache.prefill import make_partial_prefill  # noqa: E402
-
-paged_prefill_partial = make_partial_prefill(forward, init_cache)
-
-
 def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
                          offset, bt_row, phys, slots, fork_dst,
                          fork_src, *, page: int,
@@ -1002,7 +1084,7 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
     the llama layer math while attention reads the cached prefix
     DIRECTLY from the page pool (llm/kernels/ragged_prefill.py) — no
     dense temp cache, no prefix gather. Same structure as
-    :func:`serving.paged_decode_step`: rolled layer scan, read-only
+    :func:`paged_decode_step`: rolled layer scan, read-only
     pools inside the scan, the suffix K/V written into the donated
     pools after it, in place and page by page; the COW tail fork is a
     single page copy fused ahead of the scan. ``bt_row`` (pages_cap,),
@@ -1037,9 +1119,9 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
         q, k, v = attention_qkv(lp, h, cfg, l)
         q = rope_cfg(q, positions, cfg)
         k = rope_cfg(k, positions, cfg)
-        # attend the suffix K/V at POOL precision — the dense sandwich
-        # attends them from the cache_dtype temp cache, and a later
-        # suffix re-prefill reads them back from the pages, so greedy
+        # attend the suffix K/V at POOL precision — generate() attends
+        # them from its cache_dtype cache, and a later suffix
+        # re-prefill reads them back from the pages, so greedy
         # bit-parity needs the cast BEFORE attention, not just at the
         # scatter
         k = k.astype(k_pages.dtype)
@@ -1068,43 +1150,6 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
     last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0,
                                         keepdims=False)
     return k_pages, v_pages, last.astype(jnp.float32)
-
-
-def paged_step_mixed(params, cfg, k_pages, v_pages, bt, lens, last,
-                     active, temperature, key, ctoks, clen, coff,
-                     cbt_row, cphys, cslots, fork_dst, fork_src, *,
-                     page: int, do_sample: bool = False,
-                     top_k: int = 0):
-    """Unified mixed prefill+decode engine step (ISSUE 14): one
-    compiled program whose batch carries every active decode row PLUS
-    one suffix-prefill chunk — the composition of
-    :func:`serving.paged_decode_step` (sampled) and
-    :func:`paged_prefill_ragged`, see
-    :func:`bigdl_tpu.llm.kvcache.prefill.make_mixed_step`."""
-    from bigdl_tpu.llm.kvcache.prefill import make_mixed_step
-    from bigdl_tpu.llm.serving import paged_decode_step
-    return make_mixed_step(paged_decode_step, paged_prefill_ragged)(
-        params, cfg, k_pages, v_pages, bt, lens, last, active,
-        temperature, key, ctoks, clen, coff, cbt_row, cphys, cslots,
-        fork_dst, fork_src, page=page, do_sample=do_sample, top_k=top_k)
-
-
-def paged_step_spec(params, cfg, k_pages, v_pages, bt, lens, last,
-                    active, temperature, key, srow, ctoks, n_draft,
-                    cbt_row, cphys, cslots, *, page: int,
-                    do_sample: bool = False, top_k: int = 0):
-    """Speculative verify engine step (ISSUE 19): one compiled program
-    whose batch carries every active decode row PLUS one row's draft
-    tokens run as a verify chunk with fused greedy accept — the
-    composition of :func:`serving.paged_decode_step` (sampled) and
-    :func:`paged_prefill_ragged` (``full_logits=True``), see
-    :func:`bigdl_tpu.llm.kvcache.prefill.make_spec_step`."""
-    from bigdl_tpu.llm.kvcache.prefill import make_spec_step
-    from bigdl_tpu.llm.serving import paged_decode_step
-    return make_spec_step(paged_decode_step, paged_prefill_ragged)(
-        params, cfg, k_pages, v_pages, bt, lens, last, active,
-        temperature, key, srow, ctoks, n_draft, cbt_row, cphys, cslots,
-        page=page, do_sample=do_sample, top_k=top_k)
 
 
 # ---------------------------------------------------------------------------

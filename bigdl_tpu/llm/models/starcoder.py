@@ -191,9 +191,10 @@ def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
     """StarCoder paged-KV decode step — learned position embeddings,
     MQA (the paged stats kernel's GQA grouping handles Hkv=1), LN with
     bias, sequential residual, tied head; same structure as
-    serving.paged_decode_step (rolled scan, read-only pools, one
+    llama.paged_decode_step (rolled scan, read-only pools, one
     post-scan scatter). Lets the paged LLMServer serve GPTBigCode."""
-    from bigdl_tpu.llm.serving import paged_attend, scatter_new_kv
+    from bigdl_tpu.llm.kvcache.prefill import paged_attend
+    from bigdl_tpu.llm.kvcache.write import scatter_new_kv
     b = toks.shape[0]
     L = cfg.num_hidden_layers
     nh, hd = cfg.num_attention_heads, cfg.head_dim
@@ -227,19 +228,6 @@ def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
     k_pages, v_pages = scatter_new_kv(k_pages, v_pages, bt, lens,
                                       k_new, v_new, page=page)
     return logits[:, 0].astype(jnp.float32), k_pages, v_pages
-
-
-# pipelined-engine step shape (ISSUE 4): sampling folded on device,
-# device-resident lens carry, fence element — see kernels.sampling
-from bigdl_tpu.llm.kernels.sampling import make_sampled_step  # noqa: E402
-
-paged_decode_step_sampled = make_sampled_step(paged_decode_step)
-
-# prefix-cache partial prefill (ISSUE 5): suffix-only prefill over a
-# pre-populated block-table prefix — see llm/kvcache/prefill.py
-from bigdl_tpu.llm.kvcache.prefill import make_partial_prefill  # noqa: E402
-
-paged_prefill_partial = make_partial_prefill(forward, init_cache)
 
 
 def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
@@ -276,8 +264,8 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
         q = _linear_b(lp["q_proj"], h1).reshape(b, bucket, nh, hd)
         k = _linear_b(lp["k_proj"], h1).reshape(b, bucket, kvh, hd)
         v = _linear_b(lp["v_proj"], h1).reshape(b, bucket, kvh, hd)
-        # pool-precision K/V before attention (bit-parity with the
-        # dense temp-cache path — see llama.paged_prefill_ragged)
+        # pool-precision K/V before attention (bit-parity with
+        # generate() — see llama.paged_prefill_ragged)
         k = k.astype(k_pages.dtype)
         v = v.astype(v_pages.dtype)
         attn = attend(l, q, k, v).astype(x.dtype)
@@ -301,35 +289,6 @@ def paged_prefill_ragged(params, cfg, k_pages, v_pages, toks, length,
     last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0,
                                         keepdims=False)
     return k_pages, v_pages, last.astype(jnp.float32)
-
-
-def paged_step_mixed(params, cfg, k_pages, v_pages, bt, lens, last,
-                     active, temperature, key, ctoks, clen, coff,
-                     cbt_row, cphys, cslots, fork_dst, fork_src, *,
-                     page: int, do_sample: bool = False,
-                     top_k: int = 0):
-    """Unified mixed prefill+decode step (ISSUE 14) — the StarCoder
-    decode and ragged-chunk legs fused into one program (see
-    :func:`bigdl_tpu.llm.kvcache.prefill.make_mixed_step`)."""
-    from bigdl_tpu.llm.kvcache.prefill import make_mixed_step
-    return make_mixed_step(paged_decode_step, paged_prefill_ragged)(
-        params, cfg, k_pages, v_pages, bt, lens, last, active,
-        temperature, key, ctoks, clen, coff, cbt_row, cphys, cslots,
-        fork_dst, fork_src, page=page, do_sample=do_sample, top_k=top_k)
-
-
-def paged_step_spec(params, cfg, k_pages, v_pages, bt, lens, last,
-                    active, temperature, key, srow, ctoks, n_draft,
-                    cbt_row, cphys, cslots, *, page: int,
-                    do_sample: bool = False, top_k: int = 0):
-    """Speculative verify step (ISSUE 19) — the StarCoder decode and
-    full-logits ragged-chunk legs fused with the greedy accept kernel
-    (see :func:`bigdl_tpu.llm.kvcache.prefill.make_spec_step`)."""
-    from bigdl_tpu.llm.kvcache.prefill import make_spec_step
-    return make_spec_step(paged_decode_step, paged_prefill_ragged)(
-        params, cfg, k_pages, v_pages, bt, lens, last, active,
-        temperature, key, srow, ctoks, n_draft, cbt_row, cphys, cslots,
-        page=page, do_sample=do_sample, top_k=top_k)
 
 
 class StarCoderForCausalLM(CausalLMFacade):

@@ -10,12 +10,11 @@ here is a TPU-shaped **continuous batching** loop:
   batch slots (static shapes: one compiled decode step serves every
   composition of active requests);
 - each engine step decodes ONE token for every active slot via the
-  fused scan step (llm.models.llama.forward under jit, donated cache);
-  finished sequences (EOS or max_tokens) free their slot immediately and
-  a queued request takes it over — per-slot prefill writes its prompt
-  into the shared cache at the slot's rows (the "continuous" part:
-  no waiting for the whole batch to drain, the vLLM scheduling idea on
-  a slot-static cache);
+  family's paged decode step (under jit, donated page pools);
+  finished sequences (EOS or max_tokens) free their slot and pages
+  immediately and a queued request takes the slot over — its prefill
+  writes the prompt's K/V into pages of the shared pool (the
+  "continuous" part: no waiting for the whole batch to drain);
 - steps are dispatched PIPELINED (ISSUE 4): sampling runs on device
   inside the compiled step, and up to ``bigdl.llm.pipeline_depth``
   steps are in flight before the oldest's tokens are drained — host
@@ -32,10 +31,10 @@ deployment shim over exactly this object.
 from __future__ import annotations
 
 import collections
-import functools
 import heapq
 import logging
 import queue
+import sys
 import threading
 import time
 import uuid
@@ -49,6 +48,7 @@ from bigdl_tpu import observability as obs
 from bigdl_tpu import reliability
 from bigdl_tpu.llm.kernels.sampling import make_sampled_step
 from bigdl_tpu.llm.kvcache import KVCacheManager
+from bigdl_tpu.llm.kvcache.prefill import make_mixed_step, make_spec_step
 from bigdl_tpu.observability import flight
 from bigdl_tpu.observability import request_context as rc
 from bigdl_tpu.observability import utilization
@@ -290,142 +290,6 @@ def compiled_steps() -> List[tuple]:
             for key, fn in list(_PAGED_STEP_CACHE.items())]
 
 
-def paged_attend(k_pages, v_pages, bt, lens, *, page: int,
-                 sliding_window: Optional[int] = None):
-    """Shared paged-attention closure for every family's decode step.
-
-    Owns the divergence-prone conventions in ONE place (review r5):
-    the pools are viewed as one flat ``(L·P, H, page, D)`` page array
-    (a ``pool[l]`` slice would copy 2·pool_bytes/L per layer), block
-    tables are offset by ``l·P`` inside the layer scan (layer ``l``'s
-    trash page is ``l·P``), the kernel sees lengths EXCLUDING the
-    current token with the window shrunk by one, and the token's own
-    K/V is folded in with the flash combine. Returns
-    ``attend(l, q, k, v) -> (B, Hq, D)`` for head-shaped ``(B, 1, H*,
-    D)`` current-token projections."""
-    from bigdl_tpu.llm.kernels.paged_attention import (
-        merge_attention_partial, paged_attention_stats)
-    L_times_P = k_pages.shape[0] * k_pages.shape[1]
-    num_pages = k_pages.shape[1]
-    kp_flat = k_pages.reshape((L_times_P,) + k_pages.shape[2:])
-    vp_flat = v_pages.reshape((L_times_P,) + v_pages.shape[2:])
-    win_excl = (None if sliding_window is None
-                else max(sliding_window - 1, 0))
-
-    def attend(l, q, k, v):
-        acc, m, lsum = paged_attention_stats(
-            q[:, 0], kp_flat, vp_flat, bt + l * num_pages, lens,
-            page_size=page, sliding_window=win_excl)
-        return merge_attention_partial(acc, m, lsum, q[:, 0], k[:, 0],
-                                       v[:, 0])
-
-    return attend
-
-
-def scatter_new_kv(k_pages, v_pages, bt, lens, k_new, v_new, *,
-                   page: int):
-    """Every layer's new-token K/V into the (donated) pools, in place —
-    shared by every family's decode step. ``k_new``/``v_new`` are the
-    layer-scan ys ``(L, B, Hkv, D)``; row ``b``'s token lands in page
-    ``bt[b, lens[b] // page]`` at slot ``lens[b] % page`` through
-    :func:`kvcache.write.write_kv`, which leaves the pool in its own
-    layout (no pool-sized copy in the compiled step)."""
-    from bigdl_tpu.llm.kvcache.write import write_kv
-    phys = bt[jnp.arange(lens.shape[0]), lens // page]        # (B,)
-    slot = lens % page
-    return (write_kv(k_pages, phys, slot, k_new),
-            write_kv(v_pages, phys, slot, v_new))
-
-
-def paged_decode_step(params, cfg, k_pages, v_pages, bt, lens, toks,
-                      *, page: int):
-    """One paged-KV decode step: next-token logits for every row plus
-    the pools with each row's new K/V written at position ``lens``.
-
-    Structure (round 5 — replaces the 32-layer python-unrolled graph,
-    which compiled for >20 min at 7B and measured -18% vs a rolled scan
-    per the int4_matmul.py ledger):
-
-    - layers run in a **rolled ``lax.scan``**
-      (over :func:`models.llama.hold_stacks`) — the per-layer weight stream
-      pipelines best this way. The scan slices the small per-layer
-      leaves (norms, biases); the quantised ``q``/``scale`` stacks stay
-      whole and scan-invariant, and the INT4 kernel reads layer ``l``
-      out of them in place (``l`` a scalar-prefetch operand of its
-      BlockSpecs). A ``stack[l]`` slice handed to a Mosaic call is a
-      copy: it was a quarter of the 7B step (PERF.md §6, PR 28);
-    - the page pools stay **read-only inside the scan** (scan-invariant
-      closures, never carried). Attention over the existing ``lens``
-      tokens comes from the stats kernel, and the current token's own
-      K/V is folded in with the flash combine
-      (`merge_attention_partial`) — exactly the write-then-attend math,
-      without the write;
-    - per-layer pools are addressed WITHOUT slicing (a `pool[l]` slice
-      would copy 2×pool_bytes/L per layer): the pool is viewed as one
-      flat ``(L·P, H, page, D)`` page array and block tables are offset
-      by ``l·P`` inside the scan. Layer ``l``'s trash page is ``l·P``;
-    - after the scan, :func:`scatter_new_kv` writes all ``L`` layers'
-      new-token K/V into the donated pools in place: one
-      ``dynamic_update_slice`` of an ``(L, 1, H, 1, D)`` slab per row,
-      in the pools' own layout. (Not one vectorised scatter on the
-      ``P`` and ``page`` dimensions: XLA compiles that in another
-      layout and copies both whole pools there and back every step,
-      which was about half of the 7B step's device time — PERF.md §6.)
-
-    ``params`` must be the stacked-layer llama pytree; ``bt`` (B, maxp)
-    int32 block tables; ``lens`` (B,) int32 lengths EXCLUDING the token
-    being decoded; ``toks`` (B,) int32. Returns
-    ``(logits (B, V) f32, k_pages, v_pages)``. Callers jit this with
-    ``donate_argnums`` on the pools.
-    """
-    from bigdl_tpu.llm.models.llama import (_linear, _moe_ffn,
-                                            attention_qkv, hold_stacks,
-                                            mlp, rms_norm, rope_cfg)
-    b = toks.shape[0]
-    L = cfg.num_hidden_layers
-    x = params["embed_tokens"][toks][:, None]                 # (B, 1, H)
-    positions = lens[:, None].astype(jnp.int32)
-    attend = paged_attend(k_pages, v_pages, bt, lens, page=page,
-                          sliding_window=cfg.sliding_window)
-
-    xs_layers, with_stacks = hold_stacks(params["layers"])
-
-    def layer_step(carry, inputs):
-        x, = carry
-        lp, l = inputs
-        lp = with_stacks(lp)
-        h = rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
-        q, k, v = attention_qkv(lp, h, cfg, l)
-        q = rope_cfg(q, positions, cfg)
-        k = rope_cfg(k, positions, cfg)
-        attn = attend(l, q, k, v).astype(x.dtype)
-        x = x + _linear(lp["o_proj"], attn.reshape(b, 1, -1), l)
-        h2 = rms_norm(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)
-        if cfg.num_experts:
-            x = x + _moe_ffn(lp, h2, cfg)
-        else:
-            x = x + mlp(lp, h2, x.dtype, l)
-        return (x,), (k[:, 0], v[:, 0])
-
-    (x,), (k_new, v_new) = jax.lax.scan(
-        layer_step, (x,), (xs_layers, jnp.arange(L)))
-    x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        logits = x @ params["embed_tokens"].T.astype(x.dtype)
-    else:
-        logits = _linear(head, x)
-    k_pages, v_pages = scatter_new_kv(k_pages, v_pages, bt, lens,
-                                      k_new, v_new, page=page)
-    return logits[:, 0].astype(jnp.float32), k_pages, v_pages
-
-
-# pipelined-engine step shape for the llama family (ISSUE 4): greedy/
-# temperature/top-k sampling folded into the compiled step, lens carried
-# on device, fence element folded onto the token vector
-paged_decode_step_sampled = make_sampled_step(paged_decode_step)
-
-
 class Request:
     """Handle returned by :meth:`LLMServer.submit`."""
 
@@ -492,13 +356,15 @@ class Request:
 
 
 class LLMServer:
-    """Continuous-batching engine over a Llama-family model.
+    """Continuous-batching engine over a paged model family.
 
-    ``model`` is a LlamaForCausalLM (quantized or dense). ``max_batch``
-    fixes the compiled batch width; ``max_seq_len`` the per-request
-    token bound.
+    ``model`` is a causal LM of a family whose module defines the two
+    paged programs (``paged_decode_step``, ``paged_prefill_ragged``:
+    docs/KVCACHE.md "What a family gives the engine"), quantized or
+    dense. ``max_batch`` fixes the compiled batch width;
+    ``max_seq_len`` the per-request token bound.
 
-    **Paged KV cache (default).** KV lives in a page pool
+    **Paged KV cache.** KV lives in a page pool
     ``(L, num_pages, H_kv, page_size, D)``; each request owns
     ``ceil(tokens/page)`` pages named by its block-table row, allocated
     as decode advances and freed the moment the request finishes — HBM
@@ -514,10 +380,11 @@ class LLMServer:
     array, block tables offset per layer); the new tokens' K/V are
     written after the scan, in place and in the pools' own layout
     (:mod:`bigdl_tpu.llm.kvcache.write`), so no compiled step or
-    prefill holds a copy of a whole pool (:func:`paged_decode_step`).
-
-    ``paged=False`` keeps the round-3 slot-static cache (one
-    ``max_seq_len`` window per slot).
+    prefill holds a copy of a whole pool
+    (:func:`bigdl_tpu.llm.models.llama.paged_decode_step`). A prompt
+    is prefilled in place on the pool by ONE program per suffix bucket
+    (the family's ``paged_prefill_ragged``): a full prompt is the
+    offset-0 case of a prefix hit, on every platform.
 
     **Pipelined dispatch (ISSUE 4).** Decode no longer round-trips to
     the host per token: sampling is folded into the compiled step (next
@@ -546,7 +413,7 @@ class LLMServer:
     longest cached prefix — the budget is charged only for the uncached
     suffix, prefill runs only over the suffix at a position offset, and
     a partially-matched tail page is copy-on-write forked into the
-    request's own first page by the same fused scatter. EOS releases
+    request's own first page inside the same dispatch. EOS releases
     DECREMENT refcounts instead of freeing; index-only chains are
     LRU-evicted under pool pressure. Disabled, the manager degenerates
     to the old free-list (same allocation order, full-prompt budgets,
@@ -572,16 +439,16 @@ class LLMServer:
     to the PR 5 engine. See docs/KVCACHE.md ("Host tier").
 
     **Unified mixed prefill+decode dispatch (ISSUE 14,
-    ``bigdl.llm.mixed.enabled`` / ``mixed=`` ctor arg; default off;
-    needs the ragged prefill).** The two dispatch paths merge: a
-    prompt whose uncached suffix exceeds
-    ``bigdl.llm.prefill.chunk_tokens`` (``chunk_tokens=``; 0 = 4
-    pages) is fed in page-aligned chunks, each fused with the pass's
-    decode rows into ONE compiled step (the family's
-    ``paged_step_mixed`` — the sampled decode body and the ragged
-    chunk body verbatim, so each leg stays bit-identical to the split
-    program). A long admission therefore never stalls in-flight
-    decodes for a whole prefill pass — the mixed-load microbench's
+    ``bigdl.llm.mixed.enabled`` / ``mixed=`` ctor arg; default
+    off).** The two dispatch paths merge: a prompt whose uncached
+    suffix exceeds ``bigdl.llm.prefill.chunk_tokens``
+    (``chunk_tokens=``; 0 = 4 pages) is fed in page-aligned chunks,
+    each fused with the pass's decode rows into ONE compiled step
+    (``kvcache.prefill.make_mixed_step`` over the family's two
+    programs — the sampled decode body and the ragged chunk body
+    verbatim, so each leg stays bit-identical to the split program).
+    A long admission therefore never stalls in-flight decodes for a
+    whole prefill pass — the mixed-load microbench's
     stream p99 ITL no longer spikes at admission. Chunks charge the
     page ledger incrementally (final chunk tops up the decode budget;
     a chunk that cannot charge within ``bigdl.llm.prefill.chunk.wait``
@@ -593,7 +460,7 @@ class LLMServer:
     """
 
     def __init__(self, model, max_batch: int = 4, max_seq_len: int = 256,
-                 eos_token_id: Optional[int] = None, paged: bool = True,
+                 eos_token_id: Optional[int] = None,
                  page_size: int = 16, num_pages: Optional[int] = None,
                  max_queue: int = 0,
                  pipeline_depth: Optional[int] = None,
@@ -603,7 +470,6 @@ class LLMServer:
                  kvtier: Optional[bool] = None,
                  host_pages: Optional[int] = None,
                  watchdog_timeout: Optional[float] = None,
-                 ragged_prefill: Optional[bool] = None,
                  slo: Optional[bool] = None,
                  mixed: Optional[bool] = None,
                  chunk_tokens: Optional[int] = None,
@@ -611,61 +477,31 @@ class LLMServer:
                  priority: Optional[bool] = None,
                  spec: Optional[bool] = None,
                  spec_k: Optional[int] = None):
-        import inspect
-
-        from bigdl_tpu.llm.models.llama import forward, init_cache
+        from bigdl_tpu.llm.kernels.paged_attention import LANE
         from bigdl_tpu.utils.conf import conf
 
         self.model = model
-        self.cfg = model.config
-        # family dispatch: Llama-stack models (incl. Mistral/Qwen2/GLM/
-        # MoE) use the llama functions; CausalLMFacade families expose
-        # _forward/_init_cache and their module's paged_decode_step
-        # (gptneox, starcoder — bloom's ALiBi has no paged kernel hook
-        # yet, so it stays generate()-only)
-        fam_forward = getattr(type(model), "_forward", None)
-        if fam_forward is None:
-            from bigdl_tpu.llm.models import llama as _llama_mod
-            self._fam_forward, self._fam_init_cache = forward, init_cache
-            self._fam_paged_step = paged_decode_step
-            self._fam_sampled_step = paged_decode_step_sampled
-            self._fam_partial_prefill = _llama_mod.paged_prefill_partial
-            self._fam_ragged_prefill = _llama_mod.paged_prefill_ragged
-            self._fam_mixed_step = _llama_mod.paged_step_mixed
-            self._fam_spec_step = _llama_mod.paged_step_spec
-            self._family = "llama"
-            fam_mod = None
-        else:
-            self._fam_forward = fam_forward
-            self._fam_init_cache = type(model)._init_cache
-            fam_mod = inspect.getmodule(fam_forward)
-            self._fam_paged_step = getattr(fam_mod, "paged_decode_step",
-                                           None)
-            self._fam_sampled_step = getattr(
-                fam_mod, "paged_decode_step_sampled", None)
-            if self._fam_sampled_step is None and \
-                    self._fam_paged_step is not None:
-                self._fam_sampled_step = make_sampled_step(
-                    self._fam_paged_step)
-            self._fam_partial_prefill = getattr(
-                fam_mod, "paged_prefill_partial", None)
-            self._fam_ragged_prefill = getattr(
-                fam_mod, "paged_prefill_ragged", None)
-            self._fam_mixed_step = getattr(
-                fam_mod, "paged_step_mixed", None)
-            self._fam_spec_step = getattr(
-                fam_mod, "paged_step_spec", None)
-            self._family = fam_mod.__name__.rsplit(".", 1)[-1]
-            if paged and self._fam_paged_step is None:
-                raise NotImplementedError(
-                    f"{type(model).__name__} has no paged decode step "
-                    "(ALiBi needs a kernel bias hook); use "
-                    "generate() or another family")
-            if not paged:
-                raise NotImplementedError(
-                    "the slot-static (paged=False) engine is Llama-stack "
-                    "only; non-llama families serve through the paged "
-                    "path")
+        self.cfg = cfg = model.config
+        # a family is the module that defines the model's class: it
+        # gives the engine two programs, paged_decode_step and
+        # paged_prefill_ragged; the engine composes the sampled step
+        # (unless the family writes its own), the mixed and the
+        # speculative step from them (docs/KVCACHE.md "What a family
+        # gives the engine")
+        fam_mod = sys.modules[type(model).__module__]
+        self._family = fam_mod.__name__.rsplit(".", 1)[-1]
+        self._fam_paged_step = getattr(fam_mod, "paged_decode_step", None)
+        self._fam_ragged_prefill = getattr(fam_mod,
+                                           "paged_prefill_ragged", None)
+        if self._fam_paged_step is None or \
+                self._fam_ragged_prefill is None:
+            raise NotImplementedError(
+                f"{type(model).__name__} has no paged decode step and "
+                "ragged prefill (ALiBi needs a kernel bias hook); use "
+                "generate() or another family")
+        self._fam_sampled_step = getattr(
+            fam_mod, "paged_decode_step_sampled", None) or \
+            make_sampled_step(self._fam_paged_step)
         # what else a family may say of itself: the page pools it
         # caches in (default: a K and a V pool of per-head rows), the
         # int32 counts its decode step appends to the fetched token
@@ -679,12 +515,8 @@ class LLMServer:
             self.step_counters.update(dict.fromkeys(
                 self._fam_host_stats(self.cfg, np.zeros(0, np.int32)), 0))
         self.max_batch = max_batch
-        self.max_seq_len = (min(max_seq_len, model.max_cache_len)
-                            if not paged else
-                            min(max_seq_len,
-                                self.cfg.max_position_embeddings))
+        self.max_seq_len = min(max_seq_len, cfg.max_position_embeddings)
         self.eos_token_id = eos_token_id
-        self.paged = paged
         # bounded admission (ISSUE 2): max_queue > 0 caps WAITING
         # requests; submit on a full queue raises OverloadError (the
         # worker's 503 + Retry-After shed) instead of growing forever
@@ -738,11 +570,6 @@ class LLMServer:
         # engine passes that raised (retried or failed): 0 on a healthy
         # run, readable without observability (chip_smoke.py asserts it)
         self.pass_errors = 0
-        # tokens that round-tripped through a dense temp cache during
-        # prefill (the ISSUE 8 staging cost: gathered prefix + slack +
-        # suffix bucket). The ragged in-place path adds ZERO here —
-        # tools/microbench_ragged.py asserts exactly that.
-        self.prefill_dense_staged_tokens = 0
         # unified-dispatch accounting (ISSUE 14, always-on plain ints):
         # chunks dispatched and passes that fused decode rows with a
         # prefill chunk — tools/microbench_mixed.py and the parity
@@ -762,13 +589,6 @@ class LLMServer:
         self.spec_emitted_total = 0
         self.spec_passes = 0
         self._spec_ins = None
-        # ISSUE 3 flight recorder: every jit entry point of the engine
-        # is wrapped so compiles/recompiles (the per-length prefill
-        # buckets, a batch-width drift on the decode step) are counted,
-        # timed and HBM-attributed on /metrics
-        self._fwd = obs.compiled(
-            functools.partial(self._fam_forward, cfg=self.cfg),
-            name="llm/forward")
         self._thread: Optional[threading.Thread] = None
         self.steps = 0
         self._ins = None     # declared lazily: see _instruments()
@@ -793,258 +613,181 @@ class LLMServer:
         self._watchdog_stop = threading.Event()
         self._watchdog_thread: Optional[threading.Thread] = None
 
-        if paged:
-            from bigdl_tpu.llm.kernels.paged_attention import LANE
-            cfg = self.cfg
-            if page_size <= 0 or LANE % page_size:
-                raise ValueError(
-                    f"page_size {page_size} must divide the kernel lane "
-                    f"width {LANE} (8/16/32/64/128)")
-            self._page = page_size
-            ppb = LANE // page_size
-            cap = -(-self.max_seq_len // page_size)
-            self._pages_cap = -(-cap // ppb) * ppb    # kernel block mult
-            # page 0 is the trash page: inactive rows and prefill padding
-            # write there; no live sequence ever owns it
-            self._num_pages = num_pages or (1 + max_batch * cap)
-            if self._fam_page_pools is not None:
-                self._k_pages, self._v_pages = self._fam_page_pools(
-                    cfg, self._num_pages, page_size, model.cache_dtype)
-            else:
-                shape = (cfg.num_hidden_layers, self._num_pages,
-                         cfg.num_key_value_heads, page_size, cfg.head_dim)
-                self._k_pages = jnp.zeros(shape, model.cache_dtype)
-                self._v_pages = jnp.zeros(shape, model.cache_dtype)
-            if self._v_pages is None:
-                # one pool of another row than per-head K and V (a
-                # latent cache): what reads or moves pages as a K/V
-                # pair refuses the family, as the dense-staged prefill
-                # does, whose temp cache it cannot pageify
-                def on(arg, key):
-                    return arg if arg is not None else \
-                        conf.get_bool(key, False)
-                asked = [name for name, yes in (
-                    ("the prefix cache (bigdl.llm.kvcache)",
-                     on(kvcache, "bigdl.llm.kvcache.enabled")),
-                    ("the host tier and KV handoff (bigdl.llm.kvtier)",
-                     on(kvtier, "bigdl.llm.kvtier.enabled")),
-                    ("mixed dispatch (bigdl.llm.mixed)",
-                     on(mixed, "bigdl.llm.mixed.enabled")),
-                    ("speculation (bigdl.llm.spec)",
-                     on(spec, "bigdl.llm.spec.enabled")),
-                    ("priority preemption (bigdl.llm.priority)",
-                     on(priority, "bigdl.llm.priority.enabled")),
-                    ("the dense-staged prefill (ragged_prefill=False)",
-                     ragged_prefill is False)) if yes]
-                if asked:
-                    raise NotImplementedError(
-                        f"{type(model).__name__} caches one latent pool "
-                        f"and no V pool; {', '.join(asked)} assume a "
-                        "K pool and a V pool of per-head rows")
-            # the page pool now lives in the kvcache subsystem (ISSUE 5
-            # tentpole): refcounted pages + admission budget; with the
-            # prefix cache on, a radix index keeps finished requests'
-            # chains warm for reuse. Disabled (the default) allocates
-            # bit-identically to the embedded free-list it replaces.
-            kv_on = (kvcache if kvcache is not None else
-                     conf.get_bool("bigdl.llm.kvcache.enabled", False))
-            if kv_on and self._fam_partial_prefill is None:
-                raise NotImplementedError(
-                    f"{type(model).__name__} has no partial-prefill "
-                    "entry point; the prefix cache needs one per family")
-            # ragged in-place prefill (ISSUE 8): prefill attends cached
-            # prefix pages where they sit via the ragged kernel instead
-            # of staging the context through a dense temp cache. The
-            # default is "auto": ON where the Mosaic kernel runs (TPU),
-            # dense elsewhere — under jit the XLA twin would gather the
-            # full worst-case table per layer, which the dense paths
-            # never did. true/false (conf or ctor) force a path; the
-            # dense path also stays as the per-family fallback
-            # (docs/PERFORMANCE.md "Ragged paged prefill")
-            if ragged_prefill is not None:
-                rag = bool(ragged_prefill)
-            else:
-                rag_conf = str(conf.get("bigdl.llm.prefill.ragged",
-                                        "auto")).lower()
-                if rag_conf == "auto":
-                    import jax as _jax
-                    rag = _jax.default_backend() == "tpu"
-                else:
-                    rag = conf.get_bool("bigdl.llm.prefill.ragged")
-            self._ragged = (rag or self._v_pages is None) \
-                and self._fam_ragged_prefill is not None
-            # unified mixed prefill+decode dispatch (ISSUE 14): one
-            # compiled step serves every active decode row PLUS one
-            # page-aligned prefill chunk, so a long admission is fed in
-            # chunk_tokens slices interleaved with decode instead of
-            # monopolizing a pass. Chunking needs the ragged in-place
-            # prefill (the chunk attends the prefix and its own earlier
-            # chunks where they sit in the pool): under the dense
-            # escape hatch (bigdl.llm.prefill.ragged=false) the gate is
-            # inert and admissions prefill whole through the split
-            # paths — documented + tested, see docs/PERFORMANCE.md.
-            mx = (mixed if mixed is not None else
-                  conf.get_bool("bigdl.llm.mixed.enabled", False))
-            self._mixed = bool(mx)
-            ct = (chunk_tokens if chunk_tokens is not None else
-                  conf.get_int("bigdl.llm.prefill.chunk_tokens", 0))
-            if ct <= 0:
-                ct = 4 * page_size          # "a few pages" default
-            self._chunk_tokens = max(
-                page_size, -(-ct // page_size) * page_size)
-            self._chunk_wait = (
-                chunk_wait if chunk_wait is not None else
-                conf.get_float("bigdl.llm.prefill.chunk.wait", 30.0))
-            self._mixed_active = (self._mixed and self._ragged
-                                  and self._fam_mixed_step is not None)
-            # per-slot chunked-admission state (None entries = slot not
-            # chunking); the list itself exists only when the unified
-            # dispatch is live — bigdl.llm.mixed.enabled off keeps the
-            # engine structurally identical to the split one
-            self._chunk_state: Optional[List[Optional[dict]]] = (
-                [None] * max_batch if self._mixed_active else None)
-            # model-free self-speculative decoding (ISSUE 19): a pass
-            # may carry one row's n-gram drafts as a verify chunk and
-            # emit up to k+1 tokens for it (llm/spec.py + the family's
-            # paged_step_spec). Needs the ragged in-place path (the
-            # verify chunk IS a ragged chunk) and greedy sampling (the
-            # accept rule is exact-match; the rejection-sampling hook
-            # for temperature > 0 is gated off). Disabled (the
-            # default) is structurally absent: no proposer state, no
-            # bigdl_llm_spec_* series, no new code on the step path.
-            sp = (spec if spec is not None else
-                  conf.get_bool("bigdl.llm.spec.enabled", False))
-            if sp and self._do_sample:
-                raise ValueError(
-                    "bigdl.llm.spec is greedy-only (temperature == 0): "
-                    "the rejection-sampling verify hook for sampled "
-                    "decode is gated off")
-            self._spec_active = (bool(sp) and self._ragged
-                                 and self._fam_spec_step is not None)
-            self._spec_state: Optional[List[Optional[dict]]] = (
-                [None] * max_batch if self._spec_active else None)
-            # slots whose in-flight spec verify has not drained: their
-            # host lens advance is data-dependent (accepted length), so
-            # they sit out dispatch until the record retires
-            self._spec_pending: set = set()
-            if self._spec_active:
-                from bigdl_tpu.llm.spec import NGramProposer
-                self._spec_proposer_cls = NGramProposer
-                self._spec_k = max(1, int(
-                    spec_k if spec_k is not None else
-                    conf.get_int("bigdl.llm.spec.k", 4)))
-                self._spec_min_match = max(1, conf.get_int(
-                    "bigdl.llm.spec.min_match", 2))
-                self._spec_backoff = conf.get_float(
-                    "bigdl.llm.spec.backoff", 0.5)
-            self._kv = KVCacheManager(self._num_pages, page_size,
-                                      enabled=bool(kv_on))
-            # host spill tier (ISSUE 6): constructed ONLY when enabled —
-            # disabled mode must be structurally absent (no arena, no
-            # migration thread, no bigdl_kvtier_* series)
-            tier_on = (kvtier if kvtier is not None else
-                       conf.get_bool("bigdl.llm.kvtier.enabled", False))
-            self._tier = None
-            if tier_on:
-                if not kv_on:
-                    raise ValueError(
-                        "bigdl.llm.kvtier extends the prefix cache: "
-                        "enable bigdl.llm.kvcache too")
-                from bigdl_tpu.llm.kvtier import KVTier
-                hp = (host_pages if host_pages is not None else
-                      conf.get_int("bigdl.llm.kvtier.host_pages", 0))
-                self._tier = KVTier(
-                    hp or 4 * self._num_pages, page_size,
-                    synchronous=conf.get_bool(
-                        "bigdl.llm.kvtier.sync", False),
-                    fetch_timeout=conf.get_float(
-                        "bigdl.llm.kvtier.fetch.timeout", 30.0))
-                self._kv.attach_tier(self._tier,
-                                     reader=self._read_page_kv,
-                                     writer=self._write_pages_kv)
-            # host-tier admissions parked while their pages upload, and
-            # the landed ones waiting for a slot (engine thread only)
-            self._fetch_wait: List[dict] = []
-            self._fetch_ready: List[tuple] = []
-            self._bt = np.zeros((max_batch, self._pages_cap), np.int32)
-            self._lens = np.zeros(max_batch, np.int32)
-            # device-resident twins (ISSUE 4): the step reads/advances
-            # these on device; the host applies incremental scatters
-            # (page grants, prefills, freed-row resets) instead of
-            # re-uploading the whole tables every token. The np arrays
-            # above remain the host's dispatch-time bookkeeping view.
-            self._bt_dev = jnp.asarray(self._bt)
-            self._lens_dev = jnp.asarray(self._lens)
-            self._slot_pages: List[List[int]] = [[] for _ in
-                                                 range(max_batch)]
-            # per-slot cache grant (suffix budget charge + adopted
-            # shared pages) — release decrements refcounts at EOS
-            self._slot_adm: List[Optional[Any]] = [None] * max_batch
-            # SLO-class priority scheduling + lossless preemption
-            # (ISSUE 17): constructed ONLY when enabled — disabled mode
-            # is structurally absent (no scheduler object, no parked-
-            # blob map, no bigdl_llm_preemptions_total / class-gauge
-            # series, admission stays FIFO off the intake queue)
-            pr = (priority if priority is not None else
-                  conf.get_bool("bigdl.llm.priority.enabled", False))
-            self._sched = _PriorityScheduler() if pr else None
-            # exported-on-preempt KV handoff blobs keyed by request id,
-            # dropped at resume (the parked chain survives radix
-            # eviction under pool pressure)
-            self._parked: Optional[Dict[str, bytes]] = {} if pr else None
-            # fence record of the most recent preemption: at most one
-            # preemption per in-flight window (its pages free at this
-            # fence — preempting again before it drains could not admit
-            # the waiter anyway)
-            self._preempt_rec: Optional[dict] = None
-            self._pri_ins = None
-            self.preemptions_total = 0
-            self.preempt_resumes_total = 0
+        if page_size <= 0 or LANE % page_size:
+            raise ValueError(
+                f"page_size {page_size} must divide the kernel lane "
+                f"width {LANE} (8/16/32/64/128)")
+        self._page = page_size
+        ppb = LANE // page_size
+        cap = -(-self.max_seq_len // page_size)
+        self._pages_cap = -(-cap // ppb) * ppb    # kernel block mult
+        # page 0 is the trash page: inactive rows and prefill padding
+        # write there; no live sequence ever owns it
+        self._num_pages = num_pages or (1 + max_batch * cap)
+        if self._fam_page_pools is not None:
+            self._k_pages, self._v_pages = self._fam_page_pools(
+                cfg, self._num_pages, page_size, model.cache_dtype)
         else:
-            if kvtier:
-                raise ValueError("the host tier is page-pool only; "
-                                 "the slot-static cache has no pages")
-            if mixed:
-                raise ValueError("unified mixed dispatch is page-pool "
-                                 "only; the slot-static cache has no "
-                                 "chunked prefill")
-            if priority:
-                raise ValueError("priority scheduling is page-pool "
-                                 "only; lossless preemption needs the "
-                                 "paged KV chain to park and resume")
-            if spec:
-                raise ValueError("self-speculative decoding is "
-                                 "page-pool only; the verify chunk is "
-                                 "a ragged chunk over pool pages")
-            self._spec_active = False
-            self._spec_state = None
-            self._spec_pending = set()
-            self._sched = None
-            self._parked = None
-            self._preempt_rec = None
-            self._pri_ins = None
-            self.preemptions_total = 0
-            self.preempt_resumes_total = 0
-            self._mixed = self._mixed_active = False
-            self._chunk_state = None
-            self._kv = None       # the slot-static cache has no pages
-            self._tier = None
-            self._fetch_wait, self._fetch_ready = [], []
-            self._cache = init_cache(self.cfg, max_batch, self.max_seq_len,
-                                     dtype=model.cache_dtype)
-            # per-slot write positions (the shared scalar cache["pos"] is
-            # replaced by a vector so slots advance independently); the
-            # device twin advances inside the compiled step (ISSUE 4)
-            self._pos = np.zeros(max_batch, np.int32)
-            self._pos_dev = jnp.asarray(self._pos)
+            shape = (cfg.num_hidden_layers, self._num_pages,
+                     cfg.num_key_value_heads, page_size, cfg.head_dim)
+            self._k_pages = jnp.zeros(shape, model.cache_dtype)
+            self._v_pages = jnp.zeros(shape, model.cache_dtype)
+        if self._v_pages is None:
+            # one pool of another row than per-head K and V (a
+            # latent cache): what reads or moves pages as a K/V
+            # pair refuses the family
+            def on(arg, key):
+                return arg if arg is not None else \
+                    conf.get_bool(key, False)
+            asked = [name for name, yes in (
+                ("the prefix cache (bigdl.llm.kvcache)",
+                 on(kvcache, "bigdl.llm.kvcache.enabled")),
+                ("the host tier and KV handoff (bigdl.llm.kvtier)",
+                 on(kvtier, "bigdl.llm.kvtier.enabled")),
+                ("mixed dispatch (bigdl.llm.mixed)",
+                 on(mixed, "bigdl.llm.mixed.enabled")),
+                ("speculation (bigdl.llm.spec)",
+                 on(spec, "bigdl.llm.spec.enabled")),
+                ("priority preemption (bigdl.llm.priority)",
+                 on(priority, "bigdl.llm.priority.enabled"))) if yes]
+            if asked:
+                raise NotImplementedError(
+                    f"{type(model).__name__} caches one latent pool "
+                    f"and no V pool; {', '.join(asked)} assume a "
+                    "K pool and a V pool of per-head rows")
+        # the page pool now lives in the kvcache subsystem (ISSUE 5
+        # tentpole): refcounted pages + admission budget; with the
+        # prefix cache on, a radix index keeps finished requests'
+        # chains warm for reuse. Disabled (the default) allocates
+        # bit-identically to the embedded free-list it replaces.
+        kv_on = (kvcache if kvcache is not None else
+                 conf.get_bool("bigdl.llm.kvcache.enabled", False))
+        # unified mixed prefill+decode dispatch (ISSUE 14): one
+        # compiled step serves every active decode row PLUS one
+        # page-aligned prefill chunk, so a long admission is fed in
+        # chunk_tokens slices interleaved with decode instead of
+        # monopolizing a pass (the chunk attends the prefix and its
+        # own earlier chunks where they sit in the pool).
+        mx = (mixed if mixed is not None else
+              conf.get_bool("bigdl.llm.mixed.enabled", False))
+        ct = (chunk_tokens if chunk_tokens is not None else
+              conf.get_int("bigdl.llm.prefill.chunk_tokens", 0))
+        if ct <= 0:
+            ct = 4 * page_size          # "a few pages" default
+        self._chunk_tokens = max(
+            page_size, -(-ct // page_size) * page_size)
+        self._chunk_wait = (
+            chunk_wait if chunk_wait is not None else
+            conf.get_float("bigdl.llm.prefill.chunk.wait", 30.0))
+        self._mixed_active = bool(mx)
+        # per-slot chunked-admission state (None entries = slot not
+        # chunking); the list itself exists only when the unified
+        # dispatch is live — bigdl.llm.mixed.enabled off keeps the
+        # engine structurally identical to the split one
+        self._chunk_state: Optional[List[Optional[dict]]] = (
+            [None] * max_batch if self._mixed_active else None)
+        # model-free self-speculative decoding (ISSUE 19): a pass
+        # may carry one row's n-gram drafts as a verify chunk and
+        # emit up to k+1 tokens for it (llm/spec.py +
+        # kvcache.prefill.make_spec_step: the verify chunk IS a
+        # ragged chunk). Needs greedy sampling (the
+        # accept rule is exact-match; the rejection-sampling hook
+        # for temperature > 0 is gated off). Disabled (the
+        # default) is structurally absent: no proposer state, no
+        # bigdl_llm_spec_* series, no new code on the step path.
+        sp = (spec if spec is not None else
+              conf.get_bool("bigdl.llm.spec.enabled", False))
+        if sp and self._do_sample:
+            raise ValueError(
+                "bigdl.llm.spec is greedy-only (temperature == 0): "
+                "the rejection-sampling verify hook for sampled "
+                "decode is gated off")
+        self._spec_active = bool(sp)
+        self._spec_state: Optional[List[Optional[dict]]] = (
+            [None] * max_batch if self._spec_active else None)
+        # slots whose in-flight spec verify has not drained: their
+        # host lens advance is data-dependent (accepted length), so
+        # they sit out dispatch until the record retires
+        self._spec_pending: set = set()
+        if self._spec_active:
+            from bigdl_tpu.llm.spec import NGramProposer
+            self._spec_proposer_cls = NGramProposer
+            self._spec_k = max(1, int(
+                spec_k if spec_k is not None else
+                conf.get_int("bigdl.llm.spec.k", 4)))
+            self._spec_min_match = max(1, conf.get_int(
+                "bigdl.llm.spec.min_match", 2))
+            self._spec_backoff = conf.get_float(
+                "bigdl.llm.spec.backoff", 0.5)
+        self._kv = KVCacheManager(self._num_pages, page_size,
+                                  enabled=bool(kv_on))
+        # host spill tier (ISSUE 6): constructed ONLY when enabled —
+        # disabled mode must be structurally absent (no arena, no
+        # migration thread, no bigdl_kvtier_* series)
+        tier_on = (kvtier if kvtier is not None else
+                   conf.get_bool("bigdl.llm.kvtier.enabled", False))
+        self._tier = None
+        if tier_on:
+            if not kv_on:
+                raise ValueError(
+                    "bigdl.llm.kvtier extends the prefix cache: "
+                    "enable bigdl.llm.kvcache too")
+            from bigdl_tpu.llm.kvtier import KVTier
+            hp = (host_pages if host_pages is not None else
+                  conf.get_int("bigdl.llm.kvtier.host_pages", 0))
+            self._tier = KVTier(
+                hp or 4 * self._num_pages, page_size,
+                synchronous=conf.get_bool(
+                    "bigdl.llm.kvtier.sync", False),
+                fetch_timeout=conf.get_float(
+                    "bigdl.llm.kvtier.fetch.timeout", 30.0))
+            self._kv.attach_tier(self._tier,
+                                 reader=self._read_page_kv,
+                                 writer=self._write_pages_kv)
+        # host-tier admissions parked while their pages upload, and
+        # the landed ones waiting for a slot (engine thread only)
+        self._fetch_wait: List[dict] = []
+        self._fetch_ready: List[tuple] = []
+        self._bt = np.zeros((max_batch, self._pages_cap), np.int32)
+        self._lens = np.zeros(max_batch, np.int32)
+        # device-resident twins (ISSUE 4): the step reads/advances
+        # these on device; the host applies incremental scatters
+        # (page grants, prefills, freed-row resets) instead of
+        # re-uploading the whole tables every token. The np arrays
+        # above remain the host's dispatch-time bookkeeping view.
+        self._bt_dev = jnp.asarray(self._bt)
+        self._lens_dev = jnp.asarray(self._lens)
+        self._slot_pages: List[List[int]] = [[] for _ in
+                                             range(max_batch)]
+        # per-slot cache grant (suffix budget charge + adopted
+        # shared pages) — release decrements refcounts at EOS
+        self._slot_adm: List[Optional[Any]] = [None] * max_batch
+        # SLO-class priority scheduling + lossless preemption
+        # (ISSUE 17): constructed ONLY when enabled — disabled mode
+        # is structurally absent (no scheduler object, no parked-
+        # blob map, no bigdl_llm_preemptions_total / class-gauge
+        # series, admission stays FIFO off the intake queue)
+        pr = (priority if priority is not None else
+              conf.get_bool("bigdl.llm.priority.enabled", False))
+        self._sched = _PriorityScheduler() if pr else None
+        # exported-on-preempt KV handoff blobs keyed by request id,
+        # dropped at resume (the parked chain survives radix
+        # eviction under pool pressure)
+        self._parked: Optional[Dict[str, bytes]] = {} if pr else None
+        # fence record of the most recent preemption: at most one
+        # preemption per in-flight window (its pages free at this
+        # fence — preempting again before it drains could not admit
+        # the waiter anyway)
+        self._preempt_rec: Optional[dict] = None
+        self._pri_ins = None
+        self.preemptions_total = 0
+        self.preempt_resumes_total = 0
 
     @property
     def pages_in_use(self) -> int:
         """Physical pages currently owned by live requests (the
         proportional-HBM claim, testable) — including the partial
         chains of chunked admissions still mid-prompt (ISSUE 14)."""
-        if not self.paged:
-            return -1
         n = sum(len(p) for p in self._slot_pages)
         if self._chunk_state is not None:
             n += sum(len(st["own"]) for st in self._chunk_state
@@ -1065,7 +808,7 @@ class LLMServer:
     def prefix_tokens_saved(self) -> int:
         """Prompt tokens served from the prefix cache instead of being
         prefilled (always-on; 0 with the cache disabled)."""
-        return self._kv.prefix_tokens_reused if self._kv else 0
+        return self._kv.prefix_tokens_reused
 
     # -- client API ----------------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens: int = 32,
@@ -1080,19 +823,17 @@ class LLMServer:
                       priority=normalize_priority(priority))
         if len(req.prompt_ids) + max_new_tokens > self.max_seq_len:
             raise ValueError("prompt + max_new_tokens exceeds max_seq_len")
-        pages = None
-        if self.paged:
-            # post-lookup suffix cost (ISSUE 5 satellite): a request
-            # whose prefix is cached is charged only for the uncached
-            # suffix, so feasibility and the shed diagnostics below
-            # must be judged on that cost, not the full prompt
-            pages = self._kv.peek(req.prompt_ids, req.max_new_tokens)
-            if pages["pages_needed"] > self._num_pages - 1:
-                raise ValueError(
-                    f"request needs {pages['pages_needed']} pages "
-                    f"(uncached suffix of prompt + max_new_tokens) but "
-                    f"the pool holds {self._num_pages - 1}; it could "
-                    "never be admitted")
+        # post-lookup suffix cost (ISSUE 5 satellite): a request
+        # whose prefix is cached is charged only for the uncached
+        # suffix, so feasibility and the shed diagnostics below
+        # must be judged on that cost, not the full prompt
+        pages = self._kv.peek(req.prompt_ids, req.max_new_tokens)
+        if pages["pages_needed"] > self._num_pages - 1:
+            raise ValueError(
+                f"request needs {pages['pages_needed']} pages "
+                f"(uncached suffix of prompt + max_new_tokens) but "
+                f"the pool holds {self._num_pages - 1}; it could "
+                "never be admitted")
         if self._draining.is_set():
             reliability.count_shed("llm_server", request_id=req.id,
                                    trace_id=_trace_of(req),
@@ -1133,26 +874,22 @@ class LLMServer:
             shed_detail = dict(
                 request_id=req.id, trace_id=_trace_of(req),
                 queue_depth=self._queue.qsize(),
-                pages_needed=pages["pages_needed"] if pages else None,
-                pages_free=pages["pages_free"] if pages else None)
-            if pages is not None and \
-                    pages["pages_needed"] > pages["pages_free"]:
+                pages_needed=pages["pages_needed"],
+                pages_free=pages["pages_free"])
+            if pages["pages_needed"] > pages["pages_free"]:
                 reliability.count_shed("llm_server_pages",
                                        reason="page_pressure",
                                        **shed_detail)
             else:
                 reliability.count_shed("llm_server",
                                        reason="queue_full", **shed_detail)
-            msg = (f"request queue full ({self.max_queue} waiting); "
-                   "retry later")
-            if pages is not None:
-                msg += (f" [needs {pages['pages_needed']} pages for the "
-                        f"uncached suffix, {pages['pages_free']} "
-                        "budget-free]")
-            err = reliability.OverloadError(msg)
-            if pages is not None:
-                err.pages_needed = pages["pages_needed"]
-                err.pages_free = pages["pages_free"]
+            err = reliability.OverloadError(
+                f"request queue full ({self.max_queue} waiting); "
+                f"retry later [needs {pages['pages_needed']} pages for "
+                f"the uncached suffix, {pages['pages_free']} "
+                "budget-free]")
+            err.pages_needed = pages["pages_needed"]
+            err.pages_free = pages["pages_free"]
             raise err from None
         if flight.enabled:
             flight.record(
@@ -1160,8 +897,8 @@ class LLMServer:
                 prompt_tokens=len(req.prompt_ids),
                 max_new_tokens=req.max_new_tokens,
                 queue_depth=self._queue.qsize(),
-                pages_needed=pages["pages_needed"] if pages else None,
-                pages_free=pages["pages_free"] if pages else None)
+                pages_needed=pages["pages_needed"],
+                pages_free=pages["pages_free"])
         return req
 
     def retry_depth(self, priority: Optional[str] = None) -> float:
@@ -1304,7 +1041,7 @@ class LLMServer:
         ships every prefix page with it). The drain coordinator
         migrates exactly these via :meth:`export_chain`. Empty when the
         prefix cache is off (nothing is warm by construction)."""
-        if not self.paged or self._kv is None or not self._kv.enabled:
+        if not self._kv.enabled:
             return []
         page = self._page
         chains: Dict[tuple, None] = {}
@@ -1526,13 +1263,8 @@ class LLMServer:
             # current device tables data-depend on every such update)
             # before the pinned references drop
             try:
-                if self.paged:
-                    _sync_barrier(self._k_pages, self._v_pages,
-                                  self._bt_dev, self._lens_dev,
-                                  self._last)
-                else:
-                    _sync_barrier(self._cache["k"], self._cache["v"],
-                                  self._pos_dev, self._last)
+                _sync_barrier(self._k_pages, self._v_pages,
+                              self._bt_dev, self._lens_dev, self._last)
             except Exception:
                 pass
             self._pending_release.clear()
@@ -1793,112 +1525,109 @@ class LLMServer:
                     continue
             ids = self._prompt_of(req)
             budget = self._budget_of(req)
-            adm = None
-            chunked = False
-            if self.paged:
-                t_lk = time.perf_counter()
-                chunk_first = None
-                if self._mixed_active and \
-                        len(ids) > self._chunk_tokens:
-                    # chunked-admission decision (ISSUE 14): a long
-                    # uncached DEVICE suffix is fed in page-aligned
-                    # chunks, charging only the first chunk now.
-                    # Arena-extending matches keep the unchunked fetch
-                    # path (their budget pre-charges at admit); the
-                    # peek→admit window is race-free — the engine
-                    # thread is the only index mutator. Prompts at or
-                    # under chunk_tokens skip the peek outright (no
-                    # second radix walk on the short-prompt hot path).
-                    pk = self._kv.peek(ids, budget)
-                    if pk["matched_tokens"] == pk["matched_device"] \
-                            and pk["pages_needed"] <= \
-                            self._num_pages - 1:
-                        # the pool-size guard keeps never-admittable
-                        # requests (cached prefix evicted since
-                        # submit) on the unchunked path, where admit
-                        # returns None and the permanent-failure
-                        # check below fires — a chunked admit would
-                        # loop charge→starve→"retriable" shed forever
-                        off0 = pk["matched_device"]
-                        suffix = len(ids) - off0
-                        if suffix > self._chunk_tokens:
-                            end0 = self._chunk_end(
-                                off0, len(ids))
-                            chunk_first = (-(-end0 // self._page)
-                                           - off0 // self._page)
-                try:
-                    # lookup + suffix-only budget charge + adoption refs
-                    # + pre-eviction for the prompt's own pages, in one
-                    # atomic manager call (ISSUE 5); chunked admissions
-                    # charge the first chunk only (ISSUE 14)
-                    adm = self._kv.admit(ids, budget,
-                                         chunk_pages=chunk_first)
-                    chunked = chunk_first is not None
-                except BaseException:
-                    # injected kvcache.evict fault: nothing was charged
-                    # or adopted — hold the head (or re-park the heap
-                    # entry in place), let the loop retry
-                    if ent is not None:
-                        self._sched.push_entry(ent)
-                    else:
-                        self._pending_head = req
-                    raise
-                if adm is None:
-                    peek = self._kv.peek(ids, budget)
-                    if peek["pages_needed"] > self._num_pages - 1:
-                        # the cached prefix that made this request
-                        # feasible at submit time has been evicted: it
-                        # can never be admitted now — fail it instead
-                        # of wedging the whole admission line
-                        req.error = (
-                            f"request needs {peek['pages_needed']} "
-                            f"pages but the pool holds "
-                            f"{self._num_pages - 1} (cached prefix "
-                            "evicted since submit)")
-                        req.done.set()
-                        continue
-                    if ent is not None:
-                        # budget-blocked: re-park in place, keep
-                        # sweeping nothing — the preempt pass at the
-                        # end of _admit is the relief valve
-                        self._sched.push_entry(ent)
-                    else:
-                        self._pending_head = req   # retry next pass
-                    return False
-                if self._kv.enabled:
-                    wall = time.perf_counter() - t_lk
-                    obs.add_complete(
-                        "kvcache/lookup", time.time() - wall, wall,
-                        request=req.id, matched_tokens=adm.matched_len,
-                        prompt_tokens=len(ids))
-                    if flight.enabled:
-                        flight.record(
-                            "radix_hit" if adm.matched_len else
-                            "radix_miss", request_id=req.id,
-                            trace_id=_trace_of(req),
-                            matched_tokens=adm.matched_len,
-                            device_matched=adm.device_matched,
-                            prompt_tokens=len(ids))
-                        if adm.tail_src is not None:
-                            flight.record(
-                                "cow_fork", request_id=req.id,
-                                trace_id=_trace_of(req),
-                                src_page=adm.tail_src,
-                                tail_tokens=adm.tail_len)
-                if adm.fetch:
-                    # host-tier hit: park until the upload lands; keep
-                    # filling this slot from the queue meanwhile
-                    if flight.enabled:
-                        flight.record(
-                            "park", request_id=req.id,
-                            trace_id=_trace_of(req),
-                            pages=len(adm.fetch))
-                    self._fetch_wait.append(
-                        {"req": req, "adm": adm,
-                         "t0": time.perf_counter()})
+            t_lk = time.perf_counter()
+            chunk_first = None
+            if self._mixed_active and \
+                    len(ids) > self._chunk_tokens:
+                # chunked-admission decision (ISSUE 14): a long
+                # uncached DEVICE suffix is fed in page-aligned
+                # chunks, charging only the first chunk now.
+                # Arena-extending matches keep the unchunked fetch
+                # path (their budget pre-charges at admit); the
+                # peek→admit window is race-free — the engine
+                # thread is the only index mutator. Prompts at or
+                # under chunk_tokens skip the peek outright (no
+                # second radix walk on the short-prompt hot path).
+                pk = self._kv.peek(ids, budget)
+                if pk["matched_tokens"] == pk["matched_device"] \
+                        and pk["pages_needed"] <= \
+                        self._num_pages - 1:
+                    # the pool-size guard keeps never-admittable
+                    # requests (cached prefix evicted since
+                    # submit) on the unchunked path, where admit
+                    # returns None and the permanent-failure
+                    # check below fires — a chunked admit would
+                    # loop charge→starve→"retriable" shed forever
+                    off0 = pk["matched_device"]
+                    suffix = len(ids) - off0
+                    if suffix > self._chunk_tokens:
+                        end0 = self._chunk_end(
+                            off0, len(ids))
+                        chunk_first = (-(-end0 // self._page)
+                                       - off0 // self._page)
+            try:
+                # lookup + suffix-only budget charge + adoption refs
+                # + pre-eviction for the prompt's own pages, in one
+                # atomic manager call (ISSUE 5); chunked admissions
+                # charge the first chunk only (ISSUE 14)
+                adm = self._kv.admit(ids, budget,
+                                     chunk_pages=chunk_first)
+            except BaseException:
+                # injected kvcache.evict fault: nothing was charged
+                # or adopted — hold the head (or re-park the heap
+                # entry in place), let the loop retry
+                if ent is not None:
+                    self._sched.push_entry(ent)
+                else:
+                    self._pending_head = req
+                raise
+            if adm is None:
+                peek = self._kv.peek(ids, budget)
+                if peek["pages_needed"] > self._num_pages - 1:
+                    # the cached prefix that made this request
+                    # feasible at submit time has been evicted: it
+                    # can never be admitted now — fail it instead
+                    # of wedging the whole admission line
+                    req.error = (
+                        f"request needs {peek['pages_needed']} "
+                        f"pages but the pool holds "
+                        f"{self._num_pages - 1} (cached prefix "
+                        "evicted since submit)")
+                    req.done.set()
                     continue
-                self._slot_adm[i] = adm
-            self._prefill_admitted(i, req, adm, chunked=chunked)
+                if ent is not None:
+                    # budget-blocked: re-park in place, keep
+                    # sweeping nothing — the preempt pass at the
+                    # end of _admit is the relief valve
+                    self._sched.push_entry(ent)
+                else:
+                    self._pending_head = req   # retry next pass
+                return False
+            if self._kv.enabled:
+                wall = time.perf_counter() - t_lk
+                obs.add_complete(
+                    "kvcache/lookup", time.time() - wall, wall,
+                    request=req.id, matched_tokens=adm.matched_len,
+                    prompt_tokens=len(ids))
+                if flight.enabled:
+                    flight.record(
+                        "radix_hit" if adm.matched_len else
+                        "radix_miss", request_id=req.id,
+                        trace_id=_trace_of(req),
+                        matched_tokens=adm.matched_len,
+                        device_matched=adm.device_matched,
+                        prompt_tokens=len(ids))
+                    if adm.tail_src is not None:
+                        flight.record(
+                            "cow_fork", request_id=req.id,
+                            trace_id=_trace_of(req),
+                            src_page=adm.tail_src,
+                            tail_tokens=adm.tail_len)
+            if adm.fetch:
+                # host-tier hit: park until the upload lands; keep
+                # filling this slot from the queue meanwhile
+                if flight.enabled:
+                    flight.record(
+                        "park", request_id=req.id,
+                        trace_id=_trace_of(req),
+                        pages=len(adm.fetch))
+                self._fetch_wait.append(
+                    {"req": req, "adm": adm,
+                     "t0": time.perf_counter()})
+                continue
+            self._slot_adm[i] = adm
+            self._prefill_admitted(i, req, adm,
+                                   chunked=chunk_first is not None)
             return True
 
     def _prefill_admitted(self, i: int, req: Request, adm,
@@ -1925,13 +1654,12 @@ class LLMServer:
                          **args)
         ids = self._prompt_of(req)
         self._admit_args["admitted"] += 1
-        self._admit_args["prompt_tokens"] += \
-            len(ids) - (adm.matched_len if adm else 0)
+        self._admit_args["prompt_tokens"] += len(ids) - adm.matched_len
         if flight.enabled:
             flight.record(
                 "admit", request_id=req.id, trace_id=_trace_of(req),
                 slot=i, chunked=chunked, prepaid=prepaid,
-                matched_tokens=adm.matched_len if adm else 0,
+                matched_tokens=adm.matched_len,
                 prompt_tokens=len(ids))
         if self._sched is not None and req.resume_ids is not None:
             # a preempted request re-took a slot (ISSUE 17): the resume
@@ -1956,23 +1684,21 @@ class LLMServer:
                     obs.span("llm/prefill", slot=i,
                              tokens=len(ids),
                              stage="llm_server", request=req.id):
-                (self._prefill_paged if self.paged
-                 else self._prefill_slot)(i, req)
+                self._prefill_ragged(i, req, adm)
         except BaseException as e:
             # a failing prefill must not leak its admission budget
             # or adoption refcounts (the resilient _loop would
             # otherwise shrink the pool forever) nor leave the
             # client blocked until timeout
-            if self.paged and adm is not None:
-                self._kv.cancel(adm)
-                self._slot_adm[i] = None
+            self._kv.cancel(adm)
+            self._slot_adm[i] = None
             req.error = f"{type(e).__name__}: {e}"
             req.done.set()
             raise
         req.decode_started_at = time.time()
         self._admit_args["prefills"] += 1
-        suffix = len(ids) - (adm.matched_len if adm else 0)
-        self._record_prefill(suffix, time.perf_counter() - t0)
+        self._record_prefill(len(ids) - adm.matched_len,
+                             time.perf_counter() - t0)
 
     def _instruments(self):
         """None when observability is off; declared on first use so
@@ -2002,12 +1728,11 @@ class LLMServer:
             for cls, depth in self._sched.depths().items():
                 pri["queue_class"].labels(**{"class": cls}).set(depth)
             pri["parked"].set(self._sched.parked())
-        if self.paged:
-            ins["kv_pages"].set(self.pages_in_use)
-            # page 0 is the reserved trash page, never allocatable
-            ins["kv_occupancy"].set(
-                self.pages_in_use / max(self._num_pages - 1, 1))
-            self._kv.record_gauges()   # bigdl_kvcache_* (enabled only)
+        ins["kv_pages"].set(self.pages_in_use)
+        # page 0 is the reserved trash page, never allocatable
+        ins["kv_occupancy"].set(
+            self.pages_in_use / max(self._num_pages - 1, 1))
+        self._kv.record_gauges()   # bigdl_kvcache_* (enabled only)
 
     def _record_prefill(self, n_tokens: int, seconds: float):
         self.prefill_tokens_total += n_tokens   # always-on (microbench)
@@ -2016,58 +1741,6 @@ class LLMServer:
             ins["prefill_tokens"].inc(n_tokens)
             ins["prefill_seconds"].observe(seconds)
             self._record_kv_gauges(ins)
-
-    def _prefill_slot(self, i: int, req: Request):
-        """Run the prompt through the model writing kv at slot i only.
-
-        Implementation detail: forward() operates on the whole batch, so
-        the prompt is broadcast into a (max_batch, T) token block but
-        only slot i's cache rows are kept (the other slots' K/V pages
-        are restored from the pre-call cache) — one compiled shape per
-        prompt length, fully static."""
-        t = len(req.prompt_ids)
-        toks = jnp.asarray(
-            np.broadcast_to(req.prompt_ids, (self.max_batch, t)))
-        start = int(self._pos[i])
-        positions = jnp.broadcast_to(jnp.arange(start, start + t),
-                                     (self.max_batch, t))
-        cache_in = dict(self._cache)
-        cache_in["pos"] = jnp.asarray(start, jnp.int32)
-        logits, new_cache = self._fwd(self.model.params, tokens=toks,
-                                      cache=cache_in, positions=positions)
-        self._admit_args["bucket_tokens"] += t    # no padding here
-        row = jnp.arange(self.max_batch) == i
-        keep = row[None, :, None, None, None]
-        old = self._cache
-        self._cache = {
-            "k": jnp.where(keep, new_cache["k"], old["k"]),
-            "v": jnp.where(keep, new_cache["v"], old["v"]),
-            "pos": old["pos"],
-        }
-        # RACE FIX (round 4, pipelined in ISSUE 4): the buffers consumed
-        # by the dispatches above must outlive them. Under jax's async
-        # dispatch, dropping the previous cache while the computation
-        # consuming it is still in flight lets the runtime recycle those
-        # buffers for CONCURRENT jax work on other threads, and the
-        # in-flight computation then reads overwritten memory
-        # (reproduced: 14/30 greedy-parity mismatches with 4 hammer
-        # threads; 0/30 with the barrier — see the stress test in
-        # tests/test_llm_serving.py). At depth 1 we barrier exactly like
-        # the synchronous engine; at depth > 1 the references are pinned
-        # until the next drained step's fence instead of blocking.
-        self._pin(old["k"], old["v"], cache_in["pos"], toks, positions,
-                  logits, new_cache["k"], new_cache["v"], self._last,
-                  self._pos_dev)
-        self._last = self._last.at[i].set(logits[i, -1])
-        self._pos[i] = start + t
-        self._pos_dev = self._pos_dev.at[i].set(start + t)
-        if self.pipeline_depth == 1:
-            _sync_barrier(self._cache["k"], self._cache["v"], self._last,
-                          self._pos_dev)
-            self._pending_release.clear()
-        del old
-        self._slots[i] = req
-        self._remaining[i] = req.max_new_tokens
 
     # -- paged engine --------------------------------------------------------
     def _step_cache_key(self) -> tuple:
@@ -2079,49 +1752,10 @@ class LLMServer:
         return (self._family, dataclasses.astuple(self.cfg), self._page,
                 str(jnp.dtype(self.model.cache_dtype)))
 
-    def _build_paged_prefill(self, bucket: int):
-        """Compile a prompt prefill for one padded length ``bucket``:
-        run the prompt through forward() with a temporary dense cache of
-        exactly ``bucket`` tokens (small, request-local), then scatter
-        the resulting K/V into the page pool at this request's physical
-        pages. Pad pages beyond ceil(len/page) land in trash page 0."""
-        cfg = self.cfg
-        page = self._page
-        hkv, hd = cfg.num_key_value_heads, cfg.head_dim
-        nl = cfg.num_hidden_layers
-
-        cache_dtype = self.model.cache_dtype
-        fam_forward, fam_init_cache = self._fam_forward, self._fam_init_cache
-
-        def build(params, k_pages, v_pages, toks, length, page_ids):
-            # the temp cache must match the pool dtype: a bf16 default
-            # would round f32-cache models' prompt KV before it reaches
-            # the f32 pool, diverging served tokens from generate()
-            cache = fam_init_cache(cfg, 1, bucket, dtype=cache_dtype)
-            positions = jnp.arange(bucket)[None, :]
-            logits, cache2 = fam_forward(params, cfg, toks, cache,
-                                         positions)
-            ks, vs = cache2["k"][:, 0], cache2["v"][:, 0]  # (L,bucket,H,D)
-
-            def pageify(a):
-                return a.reshape(nl, bucket // page, page, hkv,
-                                 hd).transpose(0, 1, 3, 2, 4)
-
-            k_pages = k_pages.at[:, page_ids].set(
-                pageify(ks).astype(k_pages.dtype))
-            v_pages = v_pages.at[:, page_ids].set(
-                pageify(vs).astype(v_pages.dtype))
-            last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0,
-                                                keepdims=False)
-            return k_pages, v_pages, last.astype(jnp.float32)
-
-        return obs.compiled(build, name="llm/prefill_paged",
-                            donate_argnums=(1, 2))
-
     def _finish_prefill(self, i: int, req: Request, row_pages, own,
                         last, pins, adm=None):
-        """Shared epilogue of the three paged prefill paths (full /
-        dense-partial / ragged): pin every buffer the dispatch consumed
+        """Shared epilogue of a whole-prompt prefill and a chunked
+        admission's final chunk: pin every buffer the dispatch consumed
         (the PR 4 buffer-lifetime invariant, docs/PERFORMANCE.md), land
         the slot's block table + length host- and device-side,
         reproduce the synchronous cadence at depth 1, drop the
@@ -2153,139 +1787,12 @@ class LLMServer:
         self._remaining[i] = self._budget_of(req)
         self._index_prompt(i, req)
 
-    def _prefill_paged(self, i: int, req: Request):
-        # the slot's admission grant was stored by _admit; the ragged
-        # in-place path (ISSUE 8) serves BOTH the full and the
-        # partial-prefix case — offset is runtime data there; the
-        # dense-staging paths below are the fallback
-        adm = self._slot_adm[i]
-        if self._ragged:
-            return self._prefill_ragged(i, req, adm)
-        if adm is not None and adm.matched_len:
-            return self._prefill_paged_partial(i, req, adm)
-        prompt = self._prompt_of(req)
-        t = len(prompt)
-        page = self._page
-        npages = -(-t // page)
-        ids = self._kv.alloc(npages)
-        try:
-            bucket = max(page, 1 << (t - 1).bit_length())  # pow2, >= page
-            key = self._step_cache_key() + ("prefill", bucket)
-            fn = _PAGED_STEP_CACHE.get(key)
-            if fn is None:
-                fn = _PAGED_STEP_CACHE[key] = \
-                    self._build_paged_prefill(bucket)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :t] = prompt
-            pids = np.zeros(bucket // page, np.int32)
-            pids[:npages] = ids
-            toks_d = jnp.asarray(toks)
-            t_d = jnp.asarray(t, jnp.int32)
-            pids_d = jnp.asarray(pids)
-            self._k_pages, self._v_pages, last = fn(
-                self.model.params, self._k_pages, self._v_pages,
-                toks_d, t_d, pids_d)
-            self.prefill_dense_staged_tokens += bucket
-            self._admit_args["bucket_tokens"] += bucket
-        except BaseException:
-            self._kv.free_owned(ids)   # physical pages must not leak
-            raise
-        # shared epilogue: pin + slot bookkeeping + depth-1 barrier
-        self._finish_prefill(i, req, ids, ids, last,
-                             (toks_d, t_d, pids_d))
-
-    def _build_partial_prefill(self, n_pp: int, bucket: int):
-        """Compile the family's partial prefill for one (prefix-pages,
-        suffix-length) bucket pair — see llm/kvcache/prefill.py for the
-        gather → offset-forward → fused-COW-scatter structure."""
-        cfg, page = self.cfg, self._page
-        fam = self._fam_partial_prefill
-        cache_dtype = self.model.cache_dtype
-
-        def build(params, k_pages, v_pages, toks, length, offset,
-                  prefix_ids, phys, slots):
-            return fam(params, cfg, k_pages, v_pages, toks, length,
-                       offset, prefix_ids, phys, slots, page=page,
-                       n_pp=n_pp, bucket=bucket, cache_dtype=cache_dtype)
-
-        return obs.compiled(build, name="llm/prefill_partial",
-                            donate_argnums=(1, 2))
-
-    def _prefill_paged_partial(self, i: int, req: Request, adm):
-        """Prefill only the uncached suffix (ISSUE 5): the block-table
-        prefix is pre-populated with adopted shared pages, the suffix
-        runs at position offset ``matched_len``, and a partially-matched
-        tail page is copy-on-write forked into the request's own first
-        suffix page by the same scatter."""
-        page = self._page
-        prompt = self._prompt_of(req)
-        T = len(prompt)
-        off = adm.matched_len
-        koff = off // page
-        own = self._kv.alloc(-(-T // page) - koff)
-        try:
-            row_pages = list(adm.shared_pages) + own
-            gsrc = list(adm.shared_pages)
-            if adm.tail_src is not None:
-                gsrc.append(adm.tail_src)
-            n_pp = 1 << (len(gsrc) - 1).bit_length()     # pow2 bucket
-            t_suf = T - off
-            bucket = max(page, 1 << (t_suf - 1).bit_length())
-            key = self._step_cache_key() + ("prefill_partial", n_pp,
-                                            bucket)
-            fn = _PAGED_STEP_CACHE.get(key)
-            if fn is None:
-                fn = _PAGED_STEP_CACHE[key] = \
-                    self._build_partial_prefill(n_pp, bucket)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :t_suf] = prompt[off:]
-            pids = np.zeros(n_pp, np.int32)
-            pids[:len(gsrc)] = gsrc
-            # scatter targets for the page-aligned window at koff*page:
-            # leading sub-page slots re-write the adopted tail into the
-            # fork page the request owns; suffix tokens land in their
-            # own pages; padding routes to trash page 0
-            W = page + bucket
-            p0 = koff * page
-            phys = np.zeros(W, np.int32)
-            slots = np.zeros(W, np.int32)
-            for j in range(W):
-                p = p0 + j
-                if p < T:
-                    phys[j] = row_pages[p // page]
-                slots[j] = p % page
-            toks_d = jnp.asarray(toks)
-            len_d = jnp.asarray(t_suf, jnp.int32)
-            off_d = jnp.asarray(off, jnp.int32)
-            pids_d = jnp.asarray(pids)
-            phys_d = jnp.asarray(phys)
-            slots_d = jnp.asarray(slots)
-            self._k_pages, self._v_pages, last = fn(
-                self.model.params, self._k_pages, self._v_pages,
-                toks_d, len_d, off_d, pids_d, phys_d, slots_d)
-            # the dense sandwich staged the gathered prefix + one page
-            # of slack + the suffix bucket through a temp cache
-            self.prefill_dense_staged_tokens += n_pp * page + page \
-                + bucket
-            self._admit_args["bucket_tokens"] += bucket
-        except BaseException:
-            self._kv.free_owned(own)
-            raise
-        # shared epilogue; the dispatch consumed the tail source in
-        # order, so _finish_prefill drops its transient ref/pin (the
-        # donated-pool dependency chain orders any later overwrite
-        # after the gather)
-        self._finish_prefill(i, req, row_pages, own, last,
-                             (toks_d, len_d, off_d, pids_d, phys_d,
-                              slots_d), adm=adm)
-
     def _build_ragged_prefill(self, bucket: int):
         """Compile the family's ragged in-place prefill for ONE suffix
         bucket (ISSUE 8). Prefix pages, the position offset and the
-        scatter targets are all runtime arguments — unlike the dense
-        partial prefill there is no ``n_pp`` in the static shape, so
-        the compile grid is O(suffix-buckets) (guarded by the
-        compile-recorder regression test)."""
+        scatter targets are all runtime arguments, so the compile grid
+        is O(suffix-buckets) (guarded by the compile-recorder
+        regression test)."""
         cfg, page = self.cfg, self._page
         fam = self._fam_ragged_prefill
 
@@ -2310,13 +1817,12 @@ class LLMServer:
         page = self._page
         prompt = self._prompt_of(req)
         T = len(prompt)
-        off = adm.matched_len if adm is not None else 0
+        off = adm.matched_len
         koff = off // page
-        shared = list(adm.shared_pages) if adm is not None else []
         own = self._kv.alloc(-(-T // page) - koff)
         try:
-            row_pages = shared + own
-            tail = adm is not None and adm.tail_src is not None
+            row_pages = list(adm.shared_pages) + own
+            tail = adm.tail_src is not None
             t_suf = T - off
             bucket = max(page, 1 << (t_suf - 1).bit_length())  # pow2
             key = self._step_cache_key() + ("prefill_ragged", bucket)
@@ -2355,8 +1861,9 @@ class LLMServer:
             self._kv.free_owned(own)
             raise
         # shared epilogue; the fork copy consumed the tail source in
-        # dispatch order, so the transient ref/pin drops there (same
-        # argument as the dense path's gather)
+        # dispatch order, so the transient ref/pin drops there (the
+        # donated-pool dependency chain orders any later overwrite
+        # after the copy)
         self._finish_prefill(i, req, row_pages, own, last,
                              (toks_d, len_d, off_d, bt_d, phys_d,
                               slots_d, fork_dst, fork_src), adm=adm)
@@ -2367,7 +1874,7 @@ class LLMServer:
         while this one is still decoding. The partially-filled prompt
         tail stays private — it is indexed at EOS, and adopters fork it
         (COW) rather than racing this request's decode writes."""
-        if self._kv is None or not self._kv.enabled:
+        if not self._kv.enabled:
             return
         prompt = self._prompt_of(req)
         nfull = len(prompt) // self._page
@@ -2671,15 +2178,17 @@ class LLMServer:
                 reason="cancelled" if msg is None else "error").inc()
 
     def _build_mixed_step(self):
-        """Compile the family's unified mixed step for ONE chunk-suffix
-        bucket (the chunk operand shapes fix it): the decode leg is the
-        family sampled step VERBATIM, the chunk leg the family ragged
-        prefill VERBATIM — see ``kvcache.prefill.make_mixed_step``.
+        """Compile the unified mixed step for ONE chunk-suffix bucket
+        (the chunk operand shapes fix it), composed here from the
+        family's two programs: the decode leg is the sampled step
+        VERBATIM, the chunk leg the family ragged prefill VERBATIM —
+        see ``kvcache.prefill.make_mixed_step``.
         Offsets, block tables and scatter targets are runtime data, so
         the mixed grid adds O(suffix-buckets) programs total (guarded
         by the compile-recorder test in tests/test_mixed_dispatch.py)."""
         cfg, page = self.cfg, self._page
-        fam = self._fam_mixed_step
+        fam = make_mixed_step(self._fam_paged_step,
+                              self._fam_ragged_prefill)
         do_sample, top_k = self._do_sample, self.top_k
 
         def step(params, k_pages, v_pages, bt, lens, last, active,
@@ -2924,16 +2433,18 @@ class LLMServer:
                 "match": prop.last_match}
 
     def _build_spec_step(self):
-        """Compile the family's speculative verify step for ONE chunk
-        bucket (the draft operand shape fixes it): the decode leg is
-        the family sampled step VERBATIM, the verify leg the family
-        ragged prefill VERBATIM (full logits) plus the fused accept —
-        see ``kvcache.prefill.make_spec_step``. Row index, drafts,
+        """Compile the speculative verify step for ONE chunk bucket
+        (the draft operand shape fixes it), composed here from the
+        family's two programs: the decode leg is the sampled step
+        VERBATIM, the verify leg the family ragged prefill VERBATIM
+        (full logits) plus the fused accept — see
+        ``kvcache.prefill.make_spec_step``. Row index, drafts,
         offsets and scatter targets are runtime data, so speculation
         adds O(k-buckets) programs total (guarded by the
         compile-recorder test in tests/test_spec_decode.py)."""
         cfg, page = self.cfg, self._page
-        fam = self._fam_spec_step
+        fam = make_spec_step(self._fam_paged_step,
+                             self._fam_ragged_prefill)
         do_sample, top_k = self._do_sample, self.top_k
 
         def step(params, k_pages, v_pages, bt, lens, last, active,
@@ -3235,10 +2746,7 @@ class LLMServer:
             # strict synchrony at depth 1: the freed-row resets above
             # must resolve before their consumed buffers drop (exactly
             # the old engine's per-step barrier cadence)
-            if self.paged:
-                _sync_barrier(self._bt_dev, self._lens_dev)
-            else:
-                _sync_barrier(self._pos_dev)
+            _sync_barrier(self._bt_dev, self._lens_dev)
             self._pending_release.clear()
         ins = self._instruments()
         if ins is not None:
@@ -3297,44 +2805,36 @@ class LLMServer:
         if self._spec_state is not None:
             self._spec_state[i] = None     # proposer state is per
             # request — the next occupant starts fresh
-        if self.paged:
-            adm = self._slot_adm[i]
-            owned = self._slot_pages[i]
-            adopted = adm.shared_pages if adm is not None else []
-            charge = adm.charge if adm is not None else 0
-            if self._kv.enabled:
-                # keep the chain warm (ISSUE 5): index the full pages of
-                # prompt+output plus the partial tail, THEN drop this
-                # request's refs — indexed pages survive at refcount 1
-                # (evictable), unindexed ones free immediately
-                toks = list(map(int, req.prompt_ids)) + \
-                    list(map(int, req.tokens))
-                self._kv.insert(toks,
-                                self._bt[i, :-(-len(toks) // self._page)])
-            self._slot_pages[i] = []
-            self._slot_adm[i] = None
-            if self._kv.enabled and self._inflight:
-                # pinned pages hold refcounts (the PR 4 buffer-pinning
-                # invariant extended): in-flight speculative steps still
-                # read these pages through their device block tables, so
-                # the decrefs run at the newest in-flight step's fence
-                self._inflight[-1].setdefault("kv_release", []).append(
-                    (charge, owned, adopted))
-            else:
-                self._kv.release_slot(charge, owned, adopted)
-            self._bt[i, :] = 0    # orphaned rows must point at trash:
-            self._lens[i] = 0     # a stale id could alias a reissued
-            # page and the inactive row's dummy write would clobber it
-            self._pin(self._bt_dev, self._lens_dev)
-            self._bt_dev = self._bt_dev.at[i].set(0)
-            self._lens_dev = self._lens_dev.at[i].set(0)
+        adm = self._slot_adm[i]
+        owned = self._slot_pages[i]
+        adopted = adm.shared_pages if adm is not None else []
+        charge = adm.charge if adm is not None else 0
+        if self._kv.enabled:
+            # keep the chain warm (ISSUE 5): index the full pages of
+            # prompt+output plus the partial tail, THEN drop this
+            # request's refs — indexed pages survive at refcount 1
+            # (evictable), unindexed ones free immediately
+            toks = list(map(int, req.prompt_ids)) + \
+                list(map(int, req.tokens))
+            self._kv.insert(toks,
+                            self._bt[i, :-(-len(toks) // self._page)])
+        self._slot_pages[i] = []
+        self._slot_adm[i] = None
+        if self._kv.enabled and self._inflight:
+            # pinned pages hold refcounts (the PR 4 buffer-pinning
+            # invariant extended): in-flight speculative steps still
+            # read these pages through their device block tables, so
+            # the decrefs run at the newest in-flight step's fence
+            self._inflight[-1].setdefault("kv_release", []).append(
+                (charge, owned, adopted))
         else:
-            # freed slot restarts at position 0: stale kv beyond the
-            # next request's own positions is masked by the causal
-            # valid test and overwritten as it advances
-            self._pos[i] = 0
-            self._pin(self._pos_dev)
-            self._pos_dev = self._pos_dev.at[i].set(0)
+            self._kv.release_slot(charge, owned, adopted)
+        self._bt[i, :] = 0    # orphaned rows must point at trash:
+        self._lens[i] = 0     # a stale id could alias a reissued
+        # page and the inactive row's dummy write would clobber it
+        self._pin(self._bt_dev, self._lens_dev)
+        self._bt_dev = self._bt_dev.at[i].set(0)
+        self._lens_dev = self._lens_dev.at[i].set(0)
 
     # -- lossless preemption (ISSUE 17) --------------------------------------
     def _consider_preempt(self):
@@ -3616,133 +3116,6 @@ class LLMServer:
         self._pending_release = []
         return self._after_dispatch(rec, t_step)
 
-    def _step_slotted(self):
-        """One pipelined decode step of the slot-static (paged=False)
-        engine: same dispatch/drain structure as the paged path, with
-        the per-slot position vector device-resident and advanced inside
-        the compiled step."""
-        disp = self._dispatchable()
-        if not disp:
-            if self._inflight:
-                self._drain_next()
-                return True
-            return False
-        t_step = time.perf_counter()
-        with self._phase("llm/dispatch") as self._dispatch_ph:
-            step = self._slotted_step()
-            mask = np.zeros(self.max_batch, bool)
-            mask[disp] = True
-            active = jnp.asarray(mask)
-            k_in, v_in = self._cache["k"], self._cache["v"]
-            pos_in, last_in, key_in = (self._pos_dev, self._last,
-                                       self._sample_key)
-            out, logits, k_new, v_new, self._pos_dev, \
-                self._sample_key = step(
-                    self.model.params, k_in, v_in, pos_in, last_in,
-                    active, self._temp, key_in)
-            old = self._cache
-            self._cache = {"k": k_new, "v": v_new, "pos": old["pos"]}
-            self._last = logits
-            for i in disp:
-                self._pos[i] += 1
-                self._remaining[i] -= 1
-            # the old cache is NOT donated on this legacy path: it is
-            # an input of the in-flight step and must be pinned until
-            # its fence
-            rec = {"out": out, "fn": "llm/decode_slotted",
-                   "pairs": [(i, self._slots[i]) for i in disp],
-                   "refs": (k_in, v_in, pos_in, last_in, active, key_in),
-                   "pinned": self._pending_release}
-            self._pending_release = []
-            del old
-            return self._after_dispatch(rec, t_step)
-
-    def _slotted_step(self):
-        """Build (once) the compiled slot-static decode step: on-device
-        sampling from the previous logits, per-slot kv scatter at each
-        row's own position, device position advance for active rows, and
-        the fence element on the token vector."""
-        if hasattr(self, "_scatter_step"):
-            return self._scatter_step
-        from bigdl_tpu.llm.kernels.sampling import (fence_token,
-                                                    sample_tokens)
-        from bigdl_tpu.llm.models.llama import (_attention, _linear,
-                                                attention_qkv, mlp,
-                                                hold_stacks, rms_norm,
-                                                rope_cfg)
-        cfg = self.cfg
-        do_sample, top_k = self._do_sample, self.top_k
-
-        def step(params, cache_k, cache_v, pos_vec, last, active, temp,
-                 key):
-            key, sub = jax.random.split(key)
-            toks = sample_tokens(last, sub, do_sample=do_sample,
-                                 temperature=temp, top_k=top_k)
-            x = params["embed_tokens"][toks][:, None]         # (B,1,H)
-            b = x.shape[0]
-            s_max = cache_k.shape[2]
-            positions = pos_vec[:, None].astype(jnp.int32)    # (B, 1)
-            valid = (jnp.arange(s_max)[None, :]
-                     <= positions[:, 0][:, None])             # (B, S)
-
-            xs_layers, with_stacks = hold_stacks(params["layers"])
-
-            def layer_step(carry, inputs):
-                x, = carry
-                lp, l, k_cache, v_cache = inputs
-                lp = with_stacks(lp)
-                h = rms_norm(x, lp["input_layernorm"],
-                             cfg.rms_norm_eps)
-                q, k, v = attention_qkv(lp, h, cfg, l)
-                q = rope_cfg(q, positions, cfg)
-                k = rope_cfg(k, positions, cfg)
-                # scatter each slot's kv at ITS position
-                onehot = (jnp.arange(s_max)[None, :]
-                          == positions[:, 0][:, None])        # (B, S)
-                k_cache = jnp.where(
-                    onehot[:, :, None, None],
-                    k.astype(k_cache.dtype), k_cache)
-                v_cache = jnp.where(
-                    onehot[:, :, None, None],
-                    v.astype(v_cache.dtype), v_cache)
-                attn = _attention(q, k_cache, v_cache, positions,
-                                  valid, cfg)
-                x = x + _linear(lp["o_proj"], attn, l)
-                h2 = rms_norm(x, lp["post_attention_layernorm"],
-                              cfg.rms_norm_eps)
-                if cfg.num_experts:
-                    from bigdl_tpu.llm.models.llama import _moe_ffn
-                    x = x + _moe_ffn(lp, h2, cfg)
-                else:
-                    x = x + mlp(lp, h2, x.dtype, l)
-                return (x,), (k_cache, v_cache)
-
-            (x,), (k_new, v_new) = jax.lax.scan(
-                layer_step, (x,),
-                (xs_layers, jnp.arange(cfg.num_hidden_layers), cache_k,
-                 cache_v))
-            x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
-            head = params.get("lm_head")
-            if head is None:
-                logits = x @ params["embed_tokens"].T.astype(x.dtype)
-            else:
-                logits = _linear(head, x)
-            logits = logits[:, 0].astype(jnp.float32)
-            new_pos = pos_vec + active.astype(pos_vec.dtype)
-            out = jnp.concatenate(
-                [toks, fence_token(k_new, v_new, logits)])
-            return out, logits, k_new, v_new, new_pos, key
-
-        # donate the cache like the paged pools: at depth > 1 each
-        # in-flight record would otherwise pin a full (L,B,S,H,D) cache
-        # generation until its fence — donation lets the runtime alias
-        # generations in place (the records still hold the refs for
-        # backends that decline donation; a donated ref holds no HBM)
-        self._scatter_step = obs.compiled(step,
-                                          name="llm/decode_slotted",
-                                          donate_argnums=(1, 2))
-        return self._scatter_step
-
     def _step(self):
         """Decode one token for every active slot."""
         reliability.inject("llm.step")
@@ -3754,9 +3127,7 @@ class LLMServer:
         # before any request is actually mid-step.
         if any(r is not None for r in self._slots):
             reliability.inject("worker.stall")
-        if self.paged:
-            return self._step_paged()
-        return self._step_slotted()
+        return self._step_paged()
 
     def _fail_pass(self, exc: BaseException):
         """Fail what the raising pass was working on — the held

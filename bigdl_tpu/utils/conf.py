@@ -94,19 +94,10 @@ _DEFAULTS: Dict[str, str] = {
     # prefix-aware KV cache (ISSUE 5): radix-indexed page reuse with
     # refcounts + COW. false = the pre-kvcache engine exactly
     "bigdl.llm.kvcache.enabled": "false",
-    # ragged in-place prefill (ISSUE 8): prefill attends cached prefix
-    # pages where they sit (Mosaic ragged kernel) instead of staging
-    # the context through a dense temp cache. auto = on where the
-    # Mosaic kernel runs (TPU), dense elsewhere (the XLA twin would
-    # gather the full worst-case table per layer under jit); true/false
-    # force a path on any backend. false = the dense-staging prefill
-    # paths exactly
-    "bigdl.llm.prefill.ragged": "auto",
     # unified mixed prefill+decode dispatch (ISSUE 14): one compiled
     # engine step serves decode rows AND one page-aligned prefill
     # chunk, so a long admission never stalls in-flight decodes for a
-    # whole pass. Requires the ragged in-place prefill (inert under
-    # the dense escape hatch). false = the split engine exactly
+    # whole pass. false = the split engine exactly
     "bigdl.llm.mixed.enabled": "false",
     "bigdl.llm.prefill.chunk_tokens": "0",    # 0 = auto (4 pages)
     "bigdl.llm.prefill.chunk.wait": "30.0",   # budget-starved chunk ->

@@ -430,7 +430,11 @@ class TestWorkerGateway:
         model, _, worker = served
         ids = [3, 1, 4, 1, 5]
         want = _generate(model, ids, 6)
-        stop_at = 2
+        # the stop token must not occur before its own index, or the
+        # stop fires earlier than the test expects
+        fresh = [i for i in range(1, len(want)) if want[i] not in want[:i]]
+        assert fresh, f"greedy run {want} never leaves its first token"
+        stop_at = fresh[0]
         st, body, _ = _req(worker.address, "POST", "/v1/completions",
                            {"prompt": ids, "max_tokens": 6,
                             "stop": [want[stop_at]]})

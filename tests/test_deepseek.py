@@ -295,7 +295,6 @@ def test_engine_allocates_one_latent_pool(params32):
                                   CFG.latent_width)
     assert CFG.latent_dim == 40 and CFG.latent_width == 128
     assert srv._k_pages.nbytes == CFG.num_hidden_layers * pages * 16 * 128 * 2
-    assert srv._ragged          # the family's own prefill, on any backend
 
 
 @pytest.mark.parametrize("kwargs,named", [
@@ -304,8 +303,6 @@ def test_engine_allocates_one_latent_pool(params32):
     ({"mixed": True}, "mixed dispatch"),
     ({"spec": True}, "speculation"),
     ({"priority": True}, "priority preemption"),
-    ({"ragged_prefill": False}, "dense-staged prefill"),
-    ({"paged": False}, "slot-static"),
 ])
 def test_features_that_assume_two_pools_refuse_the_family(params32, kwargs,
                                                           named):

@@ -752,7 +752,11 @@ class TestStreamEosWindow:
         generating spurious tokens — the bit-identical contract dies."""
         prompt = np.arange(1, 13, dtype=np.int32)
         toks = list(map(int, _generate(model, prompt, 6)))
-        eos = toks[2]          # greedy run hits "EOS" mid-generation
+        # "EOS" mid-generation: a token that does not occur before its
+        # own index, or the run ends earlier than the test expects
+        fresh = [i for i in range(1, len(toks)) if toks[i] not in toks[:i]]
+        assert fresh, f"greedy run {toks} never leaves its first token"
+        eos = toks[fresh[0]]
         srv = LLMServer(model, max_batch=2, max_seq_len=64, page_size=8,
                         eos_token_id=eos).start()
         w = LLMWorker(srv, role="decode").start()
@@ -790,7 +794,7 @@ class TestStreamEosWindow:
                         "non-terminal chunk carried the EOS token"
             assert chunks[-1]["done"]
             assert chunks[-1]["finish_reason"] == "stop"
-            assert chunks[-1]["output_ids"] == toks[:3]
+            assert chunks[-1]["output_ids"] == toks[:fresh[0] + 1]
         finally:
             rel.set_plan(None)
             w.stop()

@@ -175,7 +175,7 @@ def tiny_q4():
 def _run(program, tiny):
     cfg, params, kp, vp, bt, page = tiny
     if program == "decode":
-        from bigdl_tpu.llm.serving import paged_decode_step
+        from bigdl_tpu.llm.models.llama import paged_decode_step
         return paged_decode_step(
             params, cfg, kp, vp, bt, jnp.asarray([21, 5], jnp.int32),
             jnp.asarray([7, 200], jnp.int32), page=page)

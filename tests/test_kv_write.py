@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.llm.kvcache.prefill import scatter_suffix_kv
-from bigdl_tpu.llm.serving import scatter_new_kv
+from bigdl_tpu.llm.kvcache.write import scatter_new_kv
 
 L = 2
 

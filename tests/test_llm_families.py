@@ -183,6 +183,46 @@ class TestFamilyServing:
         with pytest.raises(NotImplementedError, match="paged decode"):
             LLMServer(model)
 
+    def test_family_without_a_ragged_prefill_is_refused(self, monkeypatch):
+        """A family is two programs. One that has a decode step and no
+        ragged prefill is refused at construction, in the sentence that
+        refuses bloom: the engine has no other prefill to serve it by."""
+        from bigdl_tpu.llm.models import (StarCoderConfig,
+                                          StarCoderForCausalLM, starcoder)
+        from bigdl_tpu.llm.serving import LLMServer
+        model = StarCoderForCausalLM.from_config(StarCoderConfig.tiny(),
+                                                 seed=0, max_cache_len=32)
+        monkeypatch.delattr(starcoder, "paged_prefill_ragged")
+        with pytest.raises(NotImplementedError,
+                           match="paged decode step and ragged prefill"):
+            LLMServer(model)
+
+    def test_model_layer_does_not_import_the_engine(self):
+        """The arrows point down: models, kernels and the KV cache are
+        what the engine is built from, so none of their files imports
+        ``bigdl_tpu.llm.serving``, at module level or inside a
+        function (where a cycle would hide)."""
+        import ast
+        import pathlib
+
+        import bigdl_tpu.llm as llm
+        root = pathlib.Path(llm.__file__).parent
+        found = []
+        for sub in ("models", "kernels", "kvcache"):
+            for path in sorted((root / sub).rglob("*.py")):
+                for node in ast.walk(ast.parse(path.read_text())):
+                    if isinstance(node, ast.Import):
+                        names = [a.name for a in node.names]
+                    elif isinstance(node, ast.ImportFrom):
+                        names = [node.module or ""] + [
+                            f"{node.module}.{a.name}" for a in node.names]
+                    else:
+                        continue
+                    found += [f"{path.relative_to(root)}:{node.lineno}"
+                              for n in names
+                              if n.startswith("bigdl_tpu.llm.serving")]
+        assert not found, found
+
 
 class TestChatGLM:
     def test_matches_hf_glm_numerics(self, tmp_path):

@@ -50,18 +50,6 @@ class TestLLMServer:
         # with max_batch=2 and 5 requests, slots must have been reused
         assert srv.steps >= max(lens)
 
-    def test_legacy_slot_static_mode(self, model):
-        """paged=False keeps the round-3 slot-static cache path."""
-        ids = np.array([3, 1, 4, 1, 5], np.int32)
-        want = model.generate(ids[None], max_new_tokens=6)[0, 5:]
-        srv = LLMServer(model, max_batch=2, max_seq_len=32,
-                        paged=False).start()
-        try:
-            got = srv.submit(ids, max_new_tokens=6).get(timeout=120)
-        finally:
-            srv.stop()
-        np.testing.assert_array_equal(np.asarray(got), want)
-
     def test_paged_16_mixed_length_requests(self, model):
         """The paged-cache north star (VERDICT r3 missing #1): 16
         concurrent mixed-length requests through 4 batch slots, each
@@ -112,7 +100,7 @@ class TestLLMServer:
         executions on OTHER threads let the async CPU runtime recycle
         the engine's just-dropped cache buffers while the step consuming
         them was still in flight (14/30 greedy-parity mismatches before
-        the block_until_ready barrier in _prefill_slot/_decode_scatter;
+        the block_until_ready barrier after prefill and decode scatters;
         0/30 after). Hammer threads + randomized submit timing. Re-run
         under pipelining (ISSUE 4): depth 4 replaces the per-step
         barrier with fence-pinned in-flight records, which must hold the
@@ -218,22 +206,6 @@ class TestPipelinedEngine:
             obs.enable()
         np.testing.assert_array_equal(np.asarray(req.tokens), want)
 
-    @pytest.mark.parametrize("depth", [2, 4])
-    def test_slotted_engine_pipelined_parity(self, model, depth):
-        """The legacy slot-static path under the same dispatch window
-        (device-resident positions, non-donated cache pinned per
-        record)."""
-        ids = np.array([3, 1, 4, 1, 5], np.int32)
-        want = model.generate(ids[None], max_new_tokens=6)[0, 5:]
-        srv = LLMServer(model, max_batch=2, max_seq_len=32, paged=False,
-                        pipeline_depth=depth).start()
-        try:
-            got = srv.submit(ids, max_new_tokens=6).get(timeout=120)
-        finally:
-            srv.stop()
-        np.testing.assert_array_equal(np.asarray(got), want)
-        assert not srv._inflight
-
     def test_small_pool_speculation_stays_inside_budget(self, model):
         """Speculative dispatch past a request's end must never allocate
         pages beyond the admission reserve: a pool barely larger than
@@ -306,10 +278,8 @@ _EPS = 1e-7     # perf_counter arithmetic through float microseconds
 
 _ENGINES = {
     "paged": dict(),
-    "slotted": dict(paged=False),
-    "mixed": dict(page_size=8, ragged_prefill=True, mixed=True,
-                  chunk_tokens=8),
-    "spec": dict(page_size=8, ragged_prefill=True, spec=True, spec_k=8),
+    "mixed": dict(page_size=8, mixed=True, chunk_tokens=8),
+    "spec": dict(page_size=8, spec=True, spec_k=8),
 }
 
 
@@ -381,17 +351,16 @@ class TestPassSpans:
                 assert names[0] == "llm/admit"
         assert owned == len(phases)     # no phase outside a pass
         fns = {p["args"]["fn"] for p in passes} - {None}
-        want = {"paged": "llm/decode_paged", "slotted": "llm/decode_slotted",
-                "mixed": "llm/step_mixed", "spec": "llm/step_spec"}[kind]
+        want = {"paged": "llm/decode_paged", "mixed": "llm/step_mixed",
+                "spec": "llm/step_spec"}[kind]
         assert want in fns
-        # a dispatched step has its llm/dispatch, and (paged) its grant
+        # a dispatched step has its llm/dispatch and its grant
         n_disp = sum(r["name"] == "llm/dispatch" for r in phases)
         n_solo = sum(r["name"] == "llm/dispatch"
                      and r["args"]["rows"] == 0 for r in phases)
         assert n_disp - n_solo == sum(
             p["args"]["fn"] is not None for p in passes if p["args"]["rows"])
-        if kind != "slotted":
-            assert sum(r["name"] == "llm/grant" for r in phases) >= n_disp
+        assert sum(r["name"] == "llm/grant" for r in phases) >= n_disp
         assert passes[-1]["args"]["step"] == srv.steps
 
     def test_fence_wait_brackets_only_the_fetch(self, served):

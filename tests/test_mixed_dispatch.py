@@ -4,9 +4,8 @@ depths × prefix cache on/off × chunked/unchunked admissions, COW
 correctness when a chunked admission forks a radix tail while another
 row live-decodes against the same prefix, the O(suffix-buckets)
 compile-grid invariant over a mixed-prefix replay, the
-shed-during-chunking ledger rollback, the ``llm.chunk`` fault contract,
-the dense-escape-hatch interaction and the disabled-mode structural
-absence of the gate.
+shed-during-chunking ledger rollback, the ``llm.chunk`` fault contract
+and the disabled-mode structural absence of the gate.
 """
 
 import numpy as np
@@ -35,7 +34,7 @@ def _generate(model, p, n):
 def _serve(model, prompts, lens, *, mixed, chunk_tokens=CHUNK,
            replay=1, max_seq_len=64, num_pages=None, **kw):
     srv = LLMServer(model, max_batch=2, max_seq_len=max_seq_len,
-                    page_size=PAGE, ragged_prefill=True, mixed=mixed,
+                    page_size=PAGE, mixed=mixed,
                     chunk_tokens=chunk_tokens, num_pages=num_pages,
                     **kw).start()
     try:
@@ -177,8 +176,8 @@ class TestEngineParity:
         want_c = _generate(model, P, 24)
         want_b = _generate(model, B, 4)
         srv = LLMServer(model, max_batch=2, max_seq_len=64,
-                        page_size=PAGE, ragged_prefill=True, mixed=True,
-                        chunk_tokens=CHUNK, kvcache=True,
+                        page_size=PAGE, mixed=True, chunk_tokens=CHUNK,
+                        kvcache=True,
                         pipeline_depth=2).start()
         try:
             # A indexes P (+ its output tail page) at EOS
@@ -212,8 +211,7 @@ class TestChunkLedger:
         # top-up and must shed while A is still decoding
         srv = LLMServer(model, max_batch=2, max_seq_len=64,
                         page_size=PAGE, num_pages=10, kvcache=False,
-                        ragged_prefill=True, mixed=True,
-                        chunk_tokens=CHUNK, chunk_wait=0.01,
+                        mixed=True, chunk_tokens=CHUNK, chunk_wait=0.01,
                         pipeline_depth=2).start()
         try:
             ra = srv.submit(a_prompt, max_new_tokens=40)
@@ -251,8 +249,8 @@ class TestChunkLedger:
         want = _generate(model, prompt, 4)
         srv = LLMServer(model, max_batch=2, max_seq_len=64,
                         page_size=PAGE, num_pages=24, kvcache=True,
-                        ragged_prefill=True, mixed=True,
-                        chunk_tokens=CHUNK, pipeline_depth=2).start()
+                        mixed=True, chunk_tokens=CHUNK,
+                        pipeline_depth=2).start()
         was = rel.enabled()
         if not was:
             rel.enable()
@@ -309,8 +307,8 @@ class TestCompileGrid:
         mixed_before = keys("mixed")
         srv = LLMServer(model, max_batch=2, max_seq_len=96,
                         page_size=PAGE, num_pages=64, kvcache=True,
-                        ragged_prefill=True, mixed=True,
-                        chunk_tokens=CHUNK, pipeline_depth=2).start()
+                        mixed=True, chunk_tokens=CHUNK,
+                        pipeline_depth=2).start()
         try:
             # a long-running decode row keeps passes FUSED (the mixed
             # program, not just the solo ragged-chunk route)
@@ -368,10 +366,8 @@ class TestGateAbsence:
             # other tests may have minted the series — the absence
             # contract here is a ZERO DELTA from this server
             srv = LLMServer(model, max_batch=2, max_seq_len=64,
-                            page_size=PAGE, ragged_prefill=True,
-                            kvcache=True).start()
+                            page_size=PAGE, kvcache=True).start()
             try:
-                assert srv._mixed is False
                 assert srv._mixed_active is False
                 assert srv._chunk_state is None
                 for p in prompts:
@@ -386,33 +382,3 @@ class TestGateAbsence:
         finally:
             if not was:
                 obs.disable()
-
-    def test_dense_escape_hatch_forces_unchunked(self, model):
-        """Chunking requires the ragged in-place prefill: under the
-        ``bigdl.llm.prefill.ragged=false`` escape hatch the mixed gate
-        is INERT (documented in docs/PERFORMANCE.md) — admissions
-        prefill whole through the dense split paths and outputs stay
-        correct."""
-        rs = np.random.RandomState(4)
-        prompt = rs.randint(0, 250, 26).astype(np.int32)
-        want = _generate(model, prompt, 4)
-        srv = LLMServer(model, max_batch=2, max_seq_len=64,
-                        page_size=PAGE, ragged_prefill=False,
-                        mixed=True, chunk_tokens=CHUNK,
-                        kvcache=True).start()
-        try:
-            assert srv._mixed is True
-            assert srv._mixed_active is False      # ragged off: inert
-            got = list(map(int,
-                           srv.submit(prompt, max_new_tokens=4)
-                           .get(timeout=600)))
-            assert got == want
-            assert srv.prefill_chunks_total == 0
-            assert srv.prefill_dense_staged_tokens > 0
-        finally:
-            srv.stop()
-
-    def test_mixed_rejects_slot_static_engine(self, model):
-        with pytest.raises(ValueError):
-            LLMServer(model, max_batch=2, max_seq_len=32, paged=False,
-                      mixed=True)

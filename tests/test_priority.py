@@ -312,8 +312,3 @@ class TestDisabledMode:
         # a priority-off server must declare no new series (registry is
         # process-global, so structural absence is a DELTA)
         assert len(obs.REGISTRY.collect()) == before
-
-    def test_priority_requires_paged(self, model):
-        with pytest.raises(ValueError, match="page-pool"):
-            LLMServer(model, max_batch=2, max_seq_len=32, paged=False,
-                      priority=True)

@@ -1,9 +1,12 @@
-"""Ragged in-place prefill through the ENGINE (ISSUE 8): greedy
-bit-parity ragged vs the dense-staging path vs the plain ``generate``
-golden — pipeline depths 1/2/4, prefix cache on/off, the COW tail fork,
-tier re-prefills — plus the compile-grid regression the ragged path
-exists to buy: partial-prefill signatures are O(suffix-buckets),
-independent of how many prefix-page buckets the traffic mixes.
+"""Ragged in-place prefill through the ENGINE (ISSUE 8), the engine's
+one prefill on every platform (ISSUE 29): greedy bit-parity against the
+plain ``generate`` golden — pipeline depths 1/2/4, prefix cache on/off,
+the COW tail fork, tier re-prefills — plus the compile-grid regression
+the ragged path exists to buy: partial-prefill signatures are
+O(suffix-buckets), independent of how many prefix-page buckets the
+traffic mixes; a default engine of every family compiles the two
+programs the chip runs and no other; the engine composes the
+speculative step for a family that defines only its two programs.
 (Kernel-level interpret parity lives in tests/test_paged_attention.py.)
 """
 
@@ -28,18 +31,17 @@ def _generate(model, p, n):
     return model.generate(np.asarray(p)[None], max_new_tokens=n)[0, len(p):]
 
 
-def _serve(model, prompts, lens, *, ragged, replay=1, max_seq_len=64,
-           **kw):
+def _serve(model, prompts, lens, *, replay=1, max_seq_len=64, **kw):
     """Run the workload ``replay`` times through one server; return the
-    LAST pass's outputs plus the staging/prefix counters."""
+    LAST pass's outputs and the (stopped) server for its counters."""
     srv = LLMServer(model, max_batch=2, max_seq_len=max_seq_len,
-                    page_size=PAGE, ragged_prefill=ragged, **kw).start()
+                    page_size=PAGE, **kw).start()
     try:
         for _ in range(replay):
             got = [r.get(timeout=600) for r in
                    [srv.submit(p, max_new_tokens=n)
                     for p, n in zip(prompts, lens)]]
-        return got, srv.prefill_dense_staged_tokens, srv
+        return got, srv
     finally:
         srv.stop()
 
@@ -54,50 +56,47 @@ def _workload():
     return prompts, [4, 3, 5, 2, 4]
 
 
-# computed once and shared across the parametrized matrix (dense-engine
-# behavior does not vary with the ragged flag, and its depth coverage
-# already lives in tests/test_kvcache.py / test_llm_serving.py — only
-# the RAGGED side needs the full depth sweep here)
-_REF_CACHE = {}
+@pytest.fixture(scope="module")
+def golden(model):
+    """``generate()`` over the workload, computed once for the matrix."""
+    return [_generate(model, p, n) for p, n in zip(*_workload())]
 
 
-def _references(model, kvcache):
-    if kvcache not in _REF_CACHE:
-        prompts, lens = _workload()
-        golden = [_generate(model, p, n) for p, n in zip(prompts, lens)]
-        dense, staged_dense, _ = _serve(
-            model, prompts, lens, ragged=False, replay=2,
-            kvcache=kvcache, pipeline_depth=1)
-        assert staged_dense > 0        # the sandwich really staged
-        _REF_CACHE[kvcache] = (golden, dense)
-    return _REF_CACHE[kvcache]
+def _family(name):
+    """``(model, has_v_pool)`` of a tiny model of the named family."""
+    if name == "llama":
+        return LlamaForCausalLM.from_config(LlamaConfig.tiny(), seed=0,
+                                            max_cache_len=64), True
+    if name == "gptneox":
+        from bigdl_tpu.llm.models.gptneox import (
+            GptNeoXConfig as C, GptNeoXForCausalLM as M)
+    elif name == "starcoder":
+        from bigdl_tpu.llm.models.starcoder import (
+            StarCoderConfig as C, StarCoderForCausalLM as M)
+    else:
+        from bigdl_tpu.llm.models.deepseek import (
+            DeepseekConfig as C, DeepseekForCausalLM as M)
+    return M.from_config(C.tiny(), seed=0, max_cache_len=64), \
+        name != "deepseek"
 
 
 class TestEngineParity:
-    """The acceptance matrix: ragged outputs must be bit-identical to
-    the dense-staging engine AND the plain generate golden, and the
-    ragged path must stage ZERO tokens through a dense temp cache."""
+    """The acceptance matrix: served outputs must be bit-identical to
+    the plain generate golden."""
 
-    # tier-1 keeps the full depth sweep with the cache ON (the ragged
-    # path's reason to exist) plus the cache-off representative at
-    # depth 1; the cache-off × pipelined corners ride the slow suite
+    # the full depth sweep with the cache on (every prefix-hit shape)
+    # and off (every prompt the offset-0 case of the same program)
     @pytest.mark.parametrize("kvcache,depth", [
         pytest.param(True, 1), pytest.param(True, 2),
         pytest.param(True, 4), pytest.param(False, 1),
-        pytest.param(False, 2, marks=pytest.mark.slow),
-        pytest.param(False, 4, marks=pytest.mark.slow)])
-    def test_parity_vs_dense_and_golden(self, model, depth, kvcache):
+        pytest.param(False, 2), pytest.param(False, 4)])
+    def test_parity_vs_golden(self, model, golden, depth, kvcache):
         prompts, lens = _workload()
-        want, dense = _references(model, kvcache)
-        rag, staged_rag, srv = _serve(
-            model, prompts, lens, ragged=True, replay=2,
-            kvcache=kvcache, pipeline_depth=depth)
-        for j, (r, d, w) in enumerate(zip(rag, dense, want)):
-            np.testing.assert_array_equal(np.asarray(r), np.asarray(d),
-                                          err_msg=f"request {j}")
+        rag, srv = _serve(model, prompts, lens, replay=2,
+                          kvcache=kvcache, pipeline_depth=depth)
+        for j, (r, w) in enumerate(zip(rag, golden)):
             np.testing.assert_array_equal(np.asarray(r), w,
                                           err_msg=f"request {j}")
-        assert staged_rag == 0         # the ragged path never stages
         if kvcache:
             assert srv._kv.hits > 0    # replay actually hit the prefix
             assert srv.prefix_tokens_saved > 0
@@ -110,16 +109,8 @@ class TestEngineParity:
         """The hand-written NeoX/StarCoder ragged layer scans at a
         NONZERO runtime offset — mid-page prefix (COW tail fork),
         position-dependent math (partial rotary / learned wpe) past the
-        offset: ragged must match the facade golden with zero dense
-        staging (dense == golden for these families is already held by
-        test_kvcache's family test, so only the ragged side runs)."""
-        if family == "gptneox":
-            from bigdl_tpu.llm.models.gptneox import (
-                GptNeoXConfig as C, GptNeoXForCausalLM as M)
-        else:
-            from bigdl_tpu.llm.models.starcoder import (
-                StarCoderConfig as C, StarCoderForCausalLM as M)
-        fam_model = M.from_config(C.tiny(), seed=0, max_cache_len=64)
+        offset: served must match the facade golden."""
+        fam_model, _ = _family(family)
         rs = np.random.RandomState(5)
         shared = rs.randint(0, 250, 20).astype(np.int32)  # 2.5 pages
         prompts = [np.concatenate(
@@ -128,20 +119,17 @@ class TestEngineParity:
         lens = [3, 3]
         want = [_generate(fam_model, p, n)
                 for p, n in zip(prompts, lens)]
-        rag, staged_rag, srv = _serve(
-            fam_model, prompts, lens, ragged=True, replay=2,
-            kvcache=True, max_seq_len=48)
+        rag, srv = _serve(fam_model, prompts, lens, replay=2,
+                          kvcache=True, max_seq_len=48)
         for j, (r, w) in enumerate(zip(rag, want)):
             np.testing.assert_array_equal(np.asarray(r), w,
                                           err_msg=f"request {j}")
         assert srv._kv.hits > 0          # offsets were really nonzero
-        assert staged_rag == 0
 
     def test_tier_reprefill_parity(self, model):
         """ISSUE 6 composition: chains spilled to the host arena are
         re-adopted by admission and attended WHERE THEY LAND — the tier
-        re-prefill rides the same ragged path (zero dense staging) and
-        stays bit-exact."""
+        re-prefill rides the same ragged path and stays bit-exact."""
         from bigdl_tpu.utils.conf import conf
         rs = np.random.RandomState(23)
         groups = [rs.randint(0, 250, 16).astype(np.int32)
@@ -153,9 +141,8 @@ class TestEngineParity:
         want = [_generate(model, p, n) for p, n in zip(prompts, lens)]
         conf.set("bigdl.llm.kvtier.sync", "true")
         try:
-            got, staged, srv = _serve(
-                model, prompts, lens, ragged=True, num_pages=9,
-                kvcache=True, kvtier=True, host_pages=32)
+            got, srv = _serve(model, prompts, lens, num_pages=9,
+                              kvcache=True, kvtier=True, host_pages=32)
             spills, fetches = srv._tier.spills, srv._tier.fetches
         finally:
             conf.unset("bigdl.llm.kvtier.sync")
@@ -163,31 +150,6 @@ class TestEngineParity:
             np.testing.assert_array_equal(np.asarray(g), w,
                                           err_msg=f"request {j}")
         assert spills > 0 and fetches > 0   # the tier actually cycled
-        assert staged == 0
-
-
-class TestAutoResolution:
-    def test_auto_is_dense_off_tpu_overrides_win(self, model):
-        """`bigdl.llm.prefill.ragged=auto` (default) resolves by
-        backend — dense here (CPU: the XLA twin would gather the full
-        worst-case table per layer under jit); an explicit ctor arg or
-        conf true/false forces the path."""
-        from bigdl_tpu.utils.conf import conf
-        kw = dict(max_batch=2, max_seq_len=64, page_size=PAGE,
-                  kvcache=True)
-        srv = LLMServer(model, **kw)
-        assert srv._ragged is False               # auto, cpu backend
-        srv.stop()
-        srv = LLMServer(model, ragged_prefill=True, **kw)
-        assert srv._ragged is True                # ctor override
-        srv.stop()
-        conf.set("bigdl.llm.prefill.ragged", "true")
-        try:
-            srv = LLMServer(model, **kw)
-            assert srv._ragged is True            # conf override
-            srv.stop()
-        finally:
-            conf.unset("bigdl.llm.prefill.ragged")
 
 
 class TestCompileGrid:
@@ -195,9 +157,8 @@ class TestCompileGrid:
         """The logarithmic-compile invariant (prefill.py docstring),
         post-ISSUE 8: prefix length is runtime block-table data, so a
         mixed-prefix replay adds ZERO new partial-prefill programs once
-        the suffix buckets are warm — while the dense path compiles one
-        program per (prefix-page-bucket, suffix-bucket) pair. Guarded
-        via the PR 3 compile recorder + the engine's step cache."""
+        the suffix buckets are warm. Guarded via the PR 3 compile
+        recorder + the engine's step cache."""
         from bigdl_tpu import observability as obs
         from bigdl_tpu.llm import serving as sv
         rs = np.random.RandomState(42)
@@ -222,10 +183,10 @@ class TestCompileGrid:
         obs.enable()
         ragged_before = keys("prefill_ragged")
         # pool roomy enough that no chain ever evicts: a miss would
-        # reroute to FULL prefill and understate the dense grid below
+        # rerun the FULL prompt, in another suffix bucket
         srv = LLMServer(model, max_batch=2, max_seq_len=64,
-                        page_size=PAGE, num_pages=40, kvcache=True,
-                        ragged_prefill=True).start()
+                        page_size=PAGE, num_pages=40,
+                        kvcache=True).start()
         try:
             # warmup: seed the chains (full prefill) + one partial each
             for p in list(chains) + tails(0):
@@ -250,22 +211,63 @@ class TestCompileGrid:
             srv.stop()
             if not was:
                 obs.disable()
-        # the dense path's grid: same traffic, one (n_pp, bucket)
-        # program per prefix-page bucket on TOP of the full-prefill
-        # buckets — this is exactly what the ragged path deleted
-        srv = LLMServer(model, max_batch=2, max_seq_len=64,
-                        page_size=PAGE, num_pages=40, kvcache=True,
-                        ragged_prefill=False).start()
-        try:
-            for p in list(chains) + tails(0):
-                srv.submit(p, max_new_tokens=2).get(timeout=600)
-        finally:
-            srv.stop()
-        # one program per (n_pp, bucket) pair — the key tail is
-        # (..., "prefill_partial", n_pp, bucket) — so at the single
-        # PAGE-sized suffix bucket the dense grid spans >= 3 n_pp
-        # buckets for the 1/2/3/4-page chains, where the ragged grid
-        # holds ONE partial program no matter the prefix mix
-        dense_npp = {k[-2] for k in keys("prefill_partial")
-                     if k[-1] == PAGE}
-        assert len(dense_npp) >= 3
+
+
+@pytest.mark.parametrize("family", ["llama", "gptneox", "starcoder",
+                                    "deepseek"])
+def test_default_engine_compiles_what_the_chip_runs(family):
+    """A default-constructed engine on the CPU (no prefill argument, no
+    conf key: there is none) serves two prompts that share 2.5 pages —
+    a full prefill, then a prefix hit with a COW tail fork where the
+    family has a V pool to cache prefixes in — bit-identically to
+    ``generate()``, through the two programs the benchmark's cells run
+    on the chip and no other kind."""
+    from bigdl_tpu.llm.serving import compiled_steps
+    fam_model, has_v = _family(family)
+    rs = np.random.RandomState(11)
+    shared = rs.randint(0, 250, 40).astype(np.int32)   # 2.5 pages of 16
+    prompts = [np.concatenate(
+        [shared, rs.randint(0, 250, 3 + j).astype(np.int32)])
+        for j in range(2)]
+    want = [_generate(fam_model, p, 4) for p in prompts]
+    before = {id(fn) for _, _, fn in compiled_steps()}
+    srv = LLMServer(fam_model, max_batch=2, max_seq_len=64,
+                    **({"kvcache": True} if has_v else {})).start()
+    try:
+        got = [srv.submit(p, max_new_tokens=4).get(timeout=600)
+               for p in prompts]
+    finally:
+        srv.stop()
+    for j, (g, w) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(np.asarray(g), w,
+                                      err_msg=f"request {j}")
+    if has_v:
+        assert srv.prefix_tokens_saved == 40    # the second one hit
+    # the step cache is process-global: the kinds THIS engine added
+    # are these two or, in a process that had them already, none
+    steps = compiled_steps()
+    assert {kind for kind, _, fn in steps if id(fn) not in before} \
+        <= {"prefill_ragged", "decode"}
+    assert {"prefill_ragged", "decode"} <= {kind for kind, _, _ in steps}
+
+
+@pytest.mark.parametrize("family", ["gptneox", "starcoder"])
+def test_engine_composes_spec_step_for_a_facade_family(family):
+    """``spec=True`` on a family whose module defines its two programs
+    and nothing else: the engine builds the verify step itself
+    (``make_spec_step``) and the served tokens stay bit-identical to
+    greedy ``generate()`` with speculation really engaged."""
+    fam_model, _ = _family(family)
+    rs = np.random.RandomState(42)
+    prompt = np.tile(rs.randint(0, 250, 5), 6).astype(np.int32)
+    want = _generate(fam_model, prompt, 24)
+    srv = LLMServer(fam_model, max_batch=2, max_seq_len=64,
+                    page_size=PAGE, spec=True, spec_k=8).start()
+    try:
+        got = srv.submit(prompt, max_new_tokens=24).get(timeout=600)
+    finally:
+        srv.stop()
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert srv.spec_passes > 0, "speculation never engaged"
+    assert srv.spec_emitted_total == \
+        srv.spec_passes + srv.spec_accepted_total

@@ -713,10 +713,10 @@ class TestLLMWorkerBackpressure:
         before_budget = srv._budget_avail
         before_pages = len(srv._free)
 
-        def boom(i, req):
+        def boom(i, req, adm):
             raise RuntimeError("prefill exploded")
 
-        srv._prefill_paged = boom
+        srv._prefill_ragged = boom
         req = srv.submit([1, 2, 3], max_new_tokens=2)
         with pytest.raises(RuntimeError, match="prefill exploded"):
             srv._admit()           # engine loop not started: call direct

@@ -39,7 +39,7 @@ def _generate(model, p, n):
 
 def _serve(model, prompts, lens, *, spec, max_seq_len=128, **kw):
     srv = LLMServer(model, max_batch=2, max_seq_len=max_seq_len,
-                    page_size=PAGE, ragged_prefill=True, spec=spec,
+                    page_size=PAGE, spec=spec,
                     **kw).start()
     try:
         got = [list(map(int, r.get(timeout=600))) for r in
@@ -114,7 +114,7 @@ class TestEngineParity:
             [prompts[0], rs.randint(0, 250, 17).astype(np.int32)])
         want = _golden(model) + [_generate(model, long, 4)]
         srv = LLMServer(model, max_batch=2, max_seq_len=128,
-                        page_size=PAGE, ragged_prefill=True, spec=True,
+                        page_size=PAGE, spec=True,
                         spec_k=8, kvcache=True, mixed=True,
                         chunk_tokens=PAGE, num_pages=64,
                         pipeline_depth=depth).start()
@@ -218,8 +218,7 @@ class TestGateAbsence:
             # tests may have minted the series — the absence contract
             # is a ZERO DELTA from this server
             srv = LLMServer(model, max_batch=2, max_seq_len=64,
-                            page_size=PAGE, ragged_prefill=True,
-                            kvcache=True).start()
+                            page_size=PAGE, kvcache=True).start()
             try:
                 assert srv._spec_active is False
                 assert srv._spec_state is None
@@ -237,14 +236,10 @@ class TestGateAbsence:
             if not was:
                 obs.disable()
 
-    def test_spec_is_greedy_and_paged_only(self, model):
+    def test_spec_is_greedy_only(self, model):
         with pytest.raises(ValueError, match="greedy-only"):
             LLMServer(model, max_batch=1, max_seq_len=64,
-                      page_size=PAGE, ragged_prefill=True, spec=True,
-                      temperature=0.7)
-        with pytest.raises(ValueError, match="page-pool only"):
-            LLMServer(model, max_batch=1, max_seq_len=64, paged=False,
-                      spec=True)
+                      page_size=PAGE, spec=True, temperature=0.7)
 
 
 class TestCompileGrid:
@@ -270,7 +265,7 @@ class TestCompileGrid:
         obs.enable()
         spec_before = keys("spec")
         srv = LLMServer(model, max_batch=2, max_seq_len=128,
-                        page_size=PAGE, ragged_prefill=True, spec=True,
+                        page_size=PAGE, spec=True,
                         spec_k=8, pipeline_depth=1).start()
         try:
             for p, n in zip(prompts, lens):

@@ -363,7 +363,7 @@ class TestPoolWrittenInPlaceOnChip:
 
     def test_decode_step_holds_no_pool_copy(self):
         import functools
-        from bigdl_tpu.llm.serving import paged_decode_step_sampled
+        from bigdl_tpu.llm.models.llama import paged_decode_step_sampled
         cfg, params, shape = self._operands()
         pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
         B = self.BATCH

@@ -165,20 +165,6 @@ def _flatten_full(rec: dict) -> Dict[str, float]:
         val = tb.get(field)
         if isinstance(val, (int, float)) and not isinstance(val, bool):
             flat[f"tier.{field}"] = float(val)
-    # ISSUE 8: the ragged-prefill pair + the dense-staging tally — a
-    # regression that silently re-routes prefill through the dense temp
-    # cache shows up as ragged.dense_staged_tokens_on leaving zero
-    rb = (((rec.get("extra") or {}).get("telemetry") or {})
-          .get("microbench_ragged") or {})
-    for mode in ("ragged_off", "ragged_on"):
-        val = _extra_field(rb.get(mode), "ttft_ms")
-        if val is not None:
-            flat[f"{mode}.ttft_ms"] = val
-    for field in ("dense_staged_tokens_on", "dense_staged_tokens_off",
-                  "ttft_speedup"):
-        val = rb.get(field)
-        if isinstance(val, (int, float)) and not isinstance(val, bool):
-            flat[f"ragged.{field}"] = float(val)
     # ISSUE 13: the static-analysis gate's per-pass finding counts — a
     # pass whose total creeps up between rounds means new baselined (or
     # worse, about-to-be-baselined) findings; surface the drift next to

@@ -301,7 +301,7 @@ def run_mixed_chaos(seed: int = 0, raises: int = 2) -> dict:
     def serve_all(resubmit: bool):
         srv = LLMServer(model, max_batch=2, max_seq_len=64, page_size=8,
                         num_pages=num_pages, kvcache=True, mixed=True,
-                        chunk_tokens=8, ragged_prefill=True).start()
+                        chunk_tokens=8).start()
         failed = 0
         try:
             reqs = [srv.submit(p, max_new_tokens=4) for p in prompts]
@@ -418,8 +418,7 @@ def run_spec_chaos(seed: int = 0, raises: int = 2) -> dict:
 
     def serve_all(sp: bool):
         srv = LLMServer(model, max_batch=2, max_seq_len=64, page_size=8,
-                        num_pages=num_pages, ragged_prefill=True,
-                        spec=sp, spec_k=4).start()
+                        num_pages=num_pages, spec=sp, spec_k=4).start()
         try:
             reqs = [srv.submit(p, max_new_tokens=n)
                     for p, n in zip(prompts, new_tokens)]

@@ -38,8 +38,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 def run_microbench(depths: Iterable[int] = (1, 2, 4), batch: int = 4,
                    tokens: int = 32, prompt_len: int = 8,
-                   model_size: str = "tiny", paged: bool = True,
-                   page_size: int = 16, warmup_tokens: int = 4,
+                   model_size: str = "tiny", page_size: int = 16,
+                   warmup_tokens: int = 4,
                    model=None) -> Dict:
     """Decode ``batch`` concurrent requests of ``tokens`` new tokens each
     at every pipeline depth; report per-step wall latency and the
@@ -64,8 +64,7 @@ def run_microbench(depths: Iterable[int] = (1, 2, 4), batch: int = 4,
     prompts = [rs.randint(0, vocab, prompt_len).astype(np.int32)
                for _ in range(batch)]
     out: Dict = {"batch": batch, "tokens": tokens,
-                 "prompt_len": prompt_len, "paged": paged,
-                 "model": model_size}
+                 "prompt_len": prompt_len, "model": model_size}
     from bigdl_tpu.observability.sketch import QuantileSketch
     for depth in depths:
         # slo=True makes the engine stamp every token's drain-fence
@@ -73,8 +72,8 @@ def run_microbench(depths: Iterable[int] = (1, 2, 4), batch: int = 4,
         # gaps the bigdl_llm_itl_seconds sketch would observe, read
         # here without touching the global registry
         srv = LLMServer(model, max_batch=batch, max_seq_len=max_seq,
-                        paged=paged, page_size=page_size,
-                        pipeline_depth=depth, slo=True).start()
+                        page_size=page_size, pipeline_depth=depth,
+                        slo=True).start()
         try:
             # warmup: compile prefill buckets + the decode step
             for r in [srv.submit(p, max_new_tokens=warmup_tokens)
@@ -157,9 +156,8 @@ def run_spec_bench(tokens: int = 48, spec_k: int = 8,
     got = {}
     for mode, sp in (("spec_off", False), ("spec_on", True)):
         srv = LLMServer(model, max_batch=1, max_seq_len=max_seq,
-                        page_size=page_size, ragged_prefill=True,
-                        pipeline_depth=1, slo=True, spec=sp,
-                        spec_k=spec_k).start()
+                        page_size=page_size, pipeline_depth=1, slo=True,
+                        spec=sp, spec_k=spec_k).start()
         try:
             # full-length warmup: the run is deterministic, so the
             # second pass replays the exact bucket/shape sequence —
@@ -228,13 +226,12 @@ def main(argv) -> int:
         batch=int(flag("--batch", "4")),
         tokens=int(flag("--tokens", "32")),
         prompt_len=int(flag("--prompt-len", "8")),
-        model_size=flag("--model", "tiny"),
-        paged="--slotted" not in argv)
+        model_size=flag("--model", "tiny"))
     if "--json" in argv:
         print(json.dumps(out))
         return 0
     print(f"decode microbench: batch={out['batch']} "
-          f"tokens={out['tokens']} paged={out['paged']}")
+          f"tokens={out['tokens']}")
     for k in sorted(k for k in out if k.startswith("depth")):
         d = out[k]
         print(f"  {k:<7} step={d['step_ms']:>8.3f} ms  "

@@ -97,9 +97,8 @@ def run_mixed_bench(batch: int = 4, stream_tokens: int = 40,
     for mode, mkey in ((False, "mixed_off"), (True, "mixed_on")):
         srv = LLMServer(model, max_batch=batch + 1, max_seq_len=max_seq,
                         page_size=page_size, num_pages=num_pages,
-                        pipeline_depth=pipeline_depth,
-                        ragged_prefill=True, slo=True, mixed=mode,
-                        chunk_tokens=chunk_tokens).start()
+                        pipeline_depth=pipeline_depth, slo=True,
+                        mixed=mode, chunk_tokens=chunk_tokens).start()
         try:
             # warmup: stream + long-prompt buckets (and, mode on, the
             # mixed/chunk programs) all compile outside the timed run
