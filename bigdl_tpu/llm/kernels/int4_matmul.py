@@ -13,16 +13,23 @@ llama.cpp-family C kernels, SURVEY.md §3.4 hot loop). TPU design:
   in-register transpose, and every BlockSpec dim is either 128-aligned
   or the full array dim (the r2 kernel's (bn, bk//QK) scale block
   violated Pallas's last-dim rule and never lowered on real TPU).
-- the per-32-group scale broadcast runs on the **MXU, not the VPU**: an
-  expansion matrix E (K/2, G) with E[i, g] = [i//16 == g] is built from
-  two iotas and ``s_exp = E @ scales`` expands group scales to per-row
-  scales as a matmul. The naive reshape-broadcast costs a Mosaic
-  relayout per weight and measured 3x slower on chip.
+- the per-32-group scale is broadcast on the sublanes: a group's
+  float32 scale row is replicated over the 16 sublanes its packed rows
+  occupy (a sublane replicate and a rotate a vreg), so the expansion
+  costs no matmul and the scale keeps its float32 bits. (Until PR 30 an expansion matrix E (K/2, G) was
+  rebuilt from two iotas every grid step and ``E @ bf16(scales)`` ran
+  on the MXU: as long as the block's own HBM time, see the 2 Oct 2026
+  entry. The naive whole-block reshape-broadcast measured 3x slower
+  than that on the earlier runtime.)
+- every weight enters the MXU as ``bf16(q * scale)``, the product taken
+  in float32 and rounded once; K is walked in slabs of ``_SLAB`` packed
+  rows with the float32 accumulator carried.
 - the q4_0 zero-point (-8) is algebraic, not elementwise:
   sum_k x_k*(q-8)*s = sum_k x_k*q*s - 8*sum_g (sum_{k in g} x_k)*s[g]
-  so decode (m small, bandwidth-bound) folds it into one extra skinny
-  dot against ``s_exp``; prefill (m large, MXU-bound) subtracts 8 on
-  the VPU instead, trading VPU ops for a third of the MXU work.
+  so decode (m small) folds it into one skinny float32 product of the
+  group sums of x, taken once per call outside the kernel, with the
+  scale block as it arrives; prefill (m large, MXU-bound) subtracts 8
+  on the VPU before the product instead.
 - float16 never enters the kernel: this Mosaic build cannot load fp16
   (verified on chip before PR 1: "Unsupported cast"-class compile
   failures; not re-checked under jax 0.9),
@@ -31,11 +38,11 @@ llama.cpp-family C kernels, SURVEY.md §3.4 hot loop). TPU design:
 Measured on TPU v5 lite (1 chip, 819 GB/s HBM), (1, 4096)x(4096, 11008)
 Llama-2-7B decode matvec: ~130 us — parity with XLA's dense bf16 matvec
 (~122 us, which runs at the full 740 GB/s HBM rate) while streaming
-3.2x fewer bytes. At m=1 both are bounded by per-weight compute/issue
-rate, not bandwidth: the kernel's VPU dequant (~7 ops/packed byte:
-widen, 2x mask/shift, 2x cast, 2x scale-mul) runs at the ~1.7 T op/s
-effective VPU rate, which lands within 10% of the dense matvec's
-bandwidth floor. Alternatives measured and rejected on chip: VPU-only
+3.2x fewer bytes. (That paragraph's account, "bounded by the VPU's
+dequant, ~7 ops/packed byte", did not survive PR 30's measurement: the
+PR 29 body issued 12,585 vector-ALU ops a (2048, 256) grid step, 2.4
+times what this one needs, and was bound by its MXU work, not by them;
+see the 2 Oct 2026 entry.) Alternatives measured and rejected on chip: VPU-only
 matvec (no MXU) 174 us; scale expansion via in-kernel expansion-matrix
 matmul vs pltpu.repeat — identical; int8 MXU dots offer no rate gain on
 this toolchain (1.09x), closing the W4A8 route. The win int4 keeps:
@@ -53,7 +60,9 @@ runtime; (b) unrolling the 32-layer scan is strictly worse (unroll=8:
 stream best; (c) bf16 scale storage is SLOWER than f32 (140 vs 115 us
 micro) despite 12% fewer bytes — the f32 DMA pipelines better and the
 kernel casts scales to bf16 in-register either way; (d) bn=512 blocks
-exceed the 16M scoped-vmem limit at full-K chunks. The in-context
+exceed the 16M scoped-vmem limit at full-K chunks (no longer: with the
+slab walk a (4096, 512) block compiles at bm = 128, and bn = 512 is the
+default where it divides N, 2 Oct 2026 entry). The in-context
 matmul-only decode floor is ~0.88 ms/layer (34.9 tok/s for 7B) — the
 per-layer cost in a live scan runs ~40% above the lone-kernel micro
 because consecutive distinct kernels cannot share the double-buffered
@@ -109,6 +118,73 @@ than attention. Not done: Llama-2's ``down_proj`` (K = 11,008: two
 chunks of g = 172, not 8-aligned) keeps the sliced 2-D path; a full-K
 block at bn = 128 would need its VMEM measured first.
 
+2 Oct 2026 ledger entry (ISSUE 30, PERF.md §5-6; v5e, Mistral-7B
+shapes, stacked form, m = 16 unless said; ``tools/exp_int4_body.py``,
+slope of a 400-iteration ``fori_loop``, us a call; "ops" = vector-ALU
+operations of one gate_up grid step in Mosaic's final LLO, counted
+with no chip by ``--count``). **Step 0, where a grid step's 2.45 us
+went.** gate_up ``(16, 4096) x (4096, 28672)``, 112 steps, 73.4 MB,
+89.6 us at 819 GB/s | down ``(16, 14336) x (14336, 4096)``, 2 x 16
+steps, 44.8 us:
+
+    body                                       ops   gate_up  down
+    dma      block DMA, trivial body            32    101.6   53.0
+    pr29     the body as it was             12,585    277.9  126.8
+    noscale  widen/mask/shift/convert+dots   3,808    129.7   62.3
+    nocorr   pr29 - the correction dot      11,633    234.4  114.9
+    e_in     pr29, E handed in               9,000    279.9  116.3
+    s1       float32 scale, round once       9,769    279.3  120.8
+    s12      s1 + E in + group sums          5,770    221.9  109.3
+    s123     s12 in slabs, E per slab        5,595    271.7  135.6
+    new      this file                       5,276    145.8   72.8
+    new, slabs of 256 | 1024 | whole K           -    144.9 | 144.6 | 144.0
+    new, bn = 512 | 1024                         -    132.2 | 126.5  72.1 | -
+    new - correction | one plane | no scale      -    144.0 | 127.5 | 133.5
+
+The block DMA alone runs at 88 / 85 % of the roofline, so the blocks
+were never the problem. **The body was MXU-bound, not VPU-bound**:
+taking 3,600 ALU ops out (e_in) or the bf16 round trips out (s1) moved
+nothing, while each MXU operand did: the expansion ``E @ scales`` is a
+(2048, 128) x (128, 256) product, 0.68 us at the MXU's peak against
+0.70 us of HBM for the block it serves (s12 -> new: -76 us), and the
+correction's third (half, bn) weight operand cost 43 us a call. What
+each of the issue's steps gave alone: 1 (float32, round once) nothing;
+2 (constants once) nothing for E itself, -58 us for the group sums;
+3 (slabs) nothing at any slab size, and with the expansion as a
+per-slab K = 32 matmul it LOST 50 us. What worked was taking the
+expansion off the MXU altogether: a (G, 1, bn) -> (G, 16, bn) broadcast
+of the scale rows, reshaped to (16 G, bn), lowers to a sublane
+replicate and a rotate (384 ops a step against the E product's 512 pops
++ 512 adds + 256 pushes), and the scale stays float32, one rounding
+fewer. (Written as a concatenate of per-group ``broadcast_to(
+scale_ref[g:g+1], (16, bn))`` it lowers to strided loads, 136 ALU ops
+fewer, the timings of the table; but 32 loads a slab and four to seven
+slabs a kernel made the 32 kernel traces of the engine's eight programs
+take 5.7 s against 1.4 s, +3.3 s of ``setup_s`` in six pairs, and the
+CPU tests' programs map four times the memory regions. The one-op form
+with slabs of 1,024 rows traces in 1.6-2.2 s.) Not available in this Mosaic:
+reading the packed tile as int32 words (8 nibble planes from shifts
+and masks, no widening unpack: 4,350 ops counted) because a uint8
+block is tiled (8,128)(4,1) in VMEM, so its word view is (2,128)-tiled
+and every use pays a sublane shuffle (12,379 ops counted); three-deep
+buffering (``pl.Buffered(3)`` is refused by ``pallas_call``). The rest
+of the gap (146 against the DMA's 102) is the ALU work that is left,
+5,276 ops = 1,320 cycles at four slots against a 1,050-cycle block,
+and a fixed ~0.25 us a grid step, which bn = 512 pays half as often.
+Other shapes, pr29 | new | new bn = 512: qkv 62.1 | 32.9 | 31.5, o
+43.0 | 23.3 | 23.6; prefill (``sub8``, bm = 128) m = 512: gate_up
+1,451 | 727 | 677, down 659 | 350 | 333, qkv 328 | 175 | 157, o 219 |
+118 | 105; m = 2,048: gate_up 5,801 | 3,064 | 2,741, down 2,600 |
+1,373 | 1,307. Error against the float32 product (max / max): 0.0050 ->
+0.0033 at m = 16, 0.0024 -> 0.0017 at m = 512. **In the served cell**
+(traced pair, seed 2444444447, P C C P): the kernel 16.31 -> 8.03 ms a
+decode step (gate_up 8.79 -> 4.13, o + down 5.27 -> 2.82, qkv 1.93 ->
+0.94, the head 0.33 -> 0.15), ``int4_decode_roofline`` 33.2 -> 67.5 %,
+the step 19.58 -> 11.34 ms, ``prefill_dev_tok_s`` 3,356 -> 6,046
+(the per-group-load form; the one-op form this file has, traced P C:
+the step 19.57 -> 11.19 ms, 33.2 -> 68.8 %, 3,354 -> 5,910), and
+``itl_p95_ms`` 25.08 -> 13.48 ms over nine same-seed pairs.
+
 ``interpret=True`` runs the same kernel on CPU for tests (SURVEY.md §4:
 golden parity against an independent implementation — here the numpy
 dequant reference).
@@ -151,32 +227,82 @@ def _scale_expand(scale_ref, half: int, cdt):
     return jnp.dot(e, sc, preferred_element_type=jnp.float32).astype(cdt)
 
 
-def _int4_kernel(xe_ref, xo_ref, q_ref, scale_ref, o_ref, *, sub8: bool,
-                 cdt=jnp.bfloat16):
+_SLAB = 1024            # packed rows a slab: 64 scale groups, eight
+                        # 128-deep MXU passes a nibble plane. Bounds
+                        # the float32 intermediates (what lets a
+                        # (4096, 512) block fit scoped VMEM); the time
+                        # is the same from 256 rows to the whole block,
+                        # and every slab is traced, so not smaller
+
+
+def _group_sums(x):
+    """float32 sums of ``x`` (m, K) over each scale group: (m, K/QK).
+    What the folded zero-point needs of the activations; the same for
+    every N tile, so taken once per call, outside the kernel."""
+    m, k = x.shape
+    return x.astype(jnp.float32).reshape(m, k // QK, QK).sum(-1)
+
+
+def _x_operands(x, sub8: bool):
+    """What the kernel takes of a K chunk of activations ``(m, kc)``:
+    the even and odd k-planes and, where the zero-point is folded
+    (``corr``), the group sums."""
+    xe, xo = x[:, 0::2], x[:, 1::2]
+    return (xe, xo) if sub8 else (xe, xo, _group_sums(x))
+
+
+def _x_specs(bm: int, kc: int, sub8: bool):
+    """BlockSpecs of :func:`_x_operands`; the index maps take the
+    stacked form's scalar-prefetch operand too."""
+    specs = [pl.BlockSpec((bm, kc // 2), lambda i, j, *_: (i, 0))] * 2
+    if not sub8:
+        specs.append(pl.BlockSpec((bm, kc // QK), lambda i, j, *_: (i, 0)))
+    return specs
+
+
+def _int4_kernel(xe_ref, xo_ref, *refs, sub8: bool, cdt=jnp.bfloat16):
     """One (bm, bn) output tile.
 
-    xe/xo: (bm, K/2) even/odd k-plane activations; q: (K/2, bn) packed
-    uint8 (low nibble = even k, high = odd k); scale: (G, bn).
+    xe/xo: (bm, K/2) even/odd k-plane activations; xs (``corr`` only):
+    (bm, G) float32 :func:`_group_sums` of x; q: (K/2, bn) packed
+    uint8 (low nibble = even k, high = odd k); scale: (G, bn) float32.
     ``cdt`` is the MXU operand dtype (f32 under interpret: the CPU thunk
     cannot execute bf16 x bf16 dots).
-    """
-    q = q_ref[:].astype(jnp.int32)
-    half, _ = q.shape
-    s_exp = _scale_expand(scale_ref, half, cdt)
-    xe = xe_ref[:].astype(cdt)
-    xo = xo_ref[:].astype(cdt)
+
+    K is walked in slabs of ``_SLAB`` packed rows (the last may be
+    shorter) with the float32 accumulator carried, so a slab's chain
+    unpack -> scale -> dot is all that is live. A group's float32 scale
+    row is replicated over the 16 sublanes of its packed rows (Mosaic
+    lowers the broadcast + reshape to a sublane replicate and a
+    rotate: no expansion matmul, nothing rebuilt per grid step) and
+    every weight is ``cdt(q * scale)``: the product taken in float32,
+    rounded ONCE."""
     if sub8:
-        lo = ((q & 0xF) - 8).astype(cdt) * s_exp
-        hi = ((q >> 4) - 8).astype(cdt) * s_exp
-        acc = jnp.dot(xe, lo, preferred_element_type=jnp.float32)
-        acc += jnp.dot(xo, hi, preferred_element_type=jnp.float32)
+        q_ref, scale_ref, o_ref = refs
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
     else:
-        lo = (q & 0xF).astype(cdt) * s_exp
-        hi = (q >> 4).astype(cdt) * s_exp
-        acc = jnp.dot(xe, lo, preferred_element_type=jnp.float32)
-        acc += jnp.dot(xo, hi, preferred_element_type=jnp.float32)
-        acc -= 8.0 * jnp.dot(xe + xo, s_exp,
-                             preferred_element_type=jnp.float32)
+        xs_ref, q_ref, scale_ref, o_ref = refs
+        # the q4_0 zero-point: -8 * sum_g (sum_{k in g} x_k) * s[g],
+        # float32 sums against float32 scales
+        acc = -8.0 * jnp.dot(xs_ref[:], scale_ref[:],
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)
+    half, bn = q_ref.shape
+    for r0 in range(0, half, _SLAB):
+        rows = min(_SLAB, half - r0)
+        q = q_ref[r0:r0 + rows, :].astype(jnp.int32)
+        sc = scale_ref[r0 // HALF:(r0 + rows) // HALF, :]
+        s_exp = jnp.broadcast_to(sc[:, None, :], (rows // HALF, HALF, bn)) \
+            .reshape(rows, bn)
+        lo, hi = q & 0xF, q >> 4
+        if sub8:
+            lo, hi = lo - 8, hi - 8
+        acc += jnp.dot(xe_ref[:, r0:r0 + rows].astype(cdt),
+                       (lo.astype(jnp.float32) * s_exp).astype(cdt),
+                       preferred_element_type=jnp.float32)
+        acc += jnp.dot(xo_ref[:, r0:r0 + rows].astype(cdt),
+                       (hi.astype(jnp.float32) * s_exp).astype(cdt),
+                       preferred_element_type=jnp.float32)
     o_ref[:] = acc.astype(o_ref.dtype)
 
 
@@ -244,10 +370,19 @@ def _chunk_k(k: int):
     return out
 
 
-# default N tile; module-level so A/B harnesses can flip it globally
-# (bn=512 fits scoped vmem for every 7B decode shape with the _MAX_BK
-# K-chunking; bn=1024 OOMs at 18.5M > 16M)
+# the narrow N tile; module-level so A/B harnesses can flip it globally
 DEFAULT_BN = 256
+
+
+def _default_bn(n: int) -> int:
+    """The N tile where the caller names none: ``2 * DEFAULT_BN`` where
+    that divides N, else ``DEFAULT_BN``. Since the body walks K in
+    slabs a (K/2, 512) block fits scoped VMEM at every chunk size, and
+    a grid step's fixed cost (~0.25 us) is paid half as often: gate_up
+    at m = 16 146 -> 132 us, every prefill shape 5-12 % faster, o and
+    down at m = 16 unchanged (header ledger, 2 Oct 2026). Padding N up
+    to 512 would copy the weights, so a head of 32,000 keeps 256."""
+    return 2 * DEFAULT_BN if n % (2 * DEFAULT_BN) == 0 else DEFAULT_BN
 
 
 def int4_matmul(x, q_t, scale_t, bm: int = 128, bn: Optional[int] = None,
@@ -259,7 +394,7 @@ def int4_matmul(x, q_t, scale_t, bm: int = 128, bn: Optional[int] = None,
     even k); scale_t: (K/QK, N) float32 (fp16 accepted, converted).
     ``mode``: "corr" folds the -8 zero-point into an extra skinny dot
     (best for decode), "sub8" subtracts on the VPU (best for prefill),
-    "auto" picks by M. ``bn=None`` resolves :data:`DEFAULT_BN` HERE,
+    "auto" picks by M. ``bn=None`` resolves :func:`_default_bn` HERE,
     outside the jit, so flipping the module default retraces.
 
     **Stacked form**: q_t ``(L, K/2, N)`` and scale_t ``(L, K/QK, N)``
@@ -267,7 +402,7 @@ def int4_matmul(x, q_t, scale_t, bm: int = 128, bn: Optional[int] = None,
     apart. The kernel reads layer ``layer`` out of the whole stack in
     place (:func:`_int4_matmul_stacked_jit`); a ``q_t[layer]`` slice
     handed to a Mosaic call is a copy of the layer's weights."""
-    bn = bn if bn is not None else DEFAULT_BN
+    bn = bn if bn is not None else _default_bn(q_t.shape[-1])
     if q_t.ndim == 3:
         return _int4_matmul_stacked_jit(
             x, q_t, scale_t, jnp.asarray(layer, jnp.int32).reshape(1),
@@ -300,8 +435,6 @@ def _int4_matmul_jit(x, q_t, scale_t, bm: int, bn: int,
 
     out = None
     for k0, kc in _chunk_k(k):
-        xe = x[:, k0:k0 + kc:2]
-        xo = x[:, k0 + 1:k0 + kc:2]
         qc = q_t[k0 // 2:(k0 + kc) // 2]
         sc = scale_t[k0 // QK:(k0 + kc) // QK]
         half, g = kc // 2, kc // QK
@@ -310,9 +443,7 @@ def _int4_matmul_jit(x, q_t, scale_t, bm: int, bn: int,
                               cdt=jnp.float32 if interpret
                               else jnp.bfloat16),
             grid=(mp // bm, np_ // bn),
-            in_specs=[
-                pl.BlockSpec((bm, half), lambda i, j: (i, 0)),
-                pl.BlockSpec((bm, half), lambda i, j: (i, 0)),
+            in_specs=_x_specs(bm, kc, sub8) + [
                 pl.BlockSpec((half, bn), lambda i, j: (0, j)),
                 pl.BlockSpec((g, bn), lambda i, j: (0, j)),
             ],
@@ -321,7 +452,7 @@ def _int4_matmul_jit(x, q_t, scale_t, bm: int, bn: int,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
-        )(xe, xo, qc, sc)
+        )(*_x_operands(x[:, k0:k0 + kc], sub8), qc, sc)
         out = part if out is None else out + part
     return out[:m, :n].astype(out_dtype)
 
@@ -385,8 +516,6 @@ def _int4_matmul_stacked_jit(x, q_t, scale_t, layer, bm: int, bn: int,
 
     out = None
     for c, (k0, kc) in enumerate(chunks):
-        xe = x[:, k0:k0 + kc:2]
-        xo = x[:, k0 + 1:k0 + kc:2]
         half, g = kc // 2, kc // QK
         part = pl.pallas_call(
             functools.partial(_int4_stacked_kernel, sub8=sub8,
@@ -394,9 +523,7 @@ def _int4_matmul_stacked_jit(x, q_t, scale_t, layer, bm: int, bn: int,
                               else jnp.bfloat16),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(mp // bm, n // bn),
-                in_specs=[
-                    pl.BlockSpec((bm, half), lambda i, j, l: (i, 0)),
-                    pl.BlockSpec((bm, half), lambda i, j, l: (i, 0)),
+                in_specs=_x_specs(bm, kc, sub8) + [
                     pl.BlockSpec((None, half, bn),
                                  lambda i, j, l, c=c: (l[0], c, j)),
                     pl.BlockSpec((None, g, bn),
@@ -407,7 +534,7 @@ def _int4_matmul_stacked_jit(x, q_t, scale_t, layer, bm: int, bn: int,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
-        )(layer, xe, xo, q_t, scale_t)
+        )(layer, *_x_operands(x[:, k0:k0 + kc], sub8), q_t, scale_t)
         out = part if out is None else out + part
     return out[:m].astype(out_dtype)
 

@@ -6,6 +6,8 @@ its illegal scale BlockSpec survived two rounds of green tests while the
 flagship bench errored on hardware. These tests pin the actual lowering.
 """
 
+import functools
+
 import jax
 import pytest
 import jax.numpy as jnp
@@ -452,6 +454,65 @@ class TestStackedInt4OnChip:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         other = int4_matmul(x, q[0], scale[0], out_dtype=jnp.float32)
         assert not np.array_equal(np.asarray(got), np.asarray(other))
+
+
+def _pr29_int4_twin(x, q, scale, sub8):
+    """The arithmetic of the INT4 kernel's body as PR 29 had it, in
+    plain ``jnp``: the scale rounded to bf16 (as the expansion matmul
+    took it), every weight ``bf16(bf16(q) * bf16(s))``, the -8 either
+    subtracted before the product (``sub8``) or folded through
+    ``bf16(xe + xo) @ s_exp`` (``corr``), float32 accumulation."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    qi = q.astype(jnp.int32)
+    s_exp = jnp.repeat(scale.astype(bf16), 16, axis=0)
+    xe, xo = x[:, 0::2], x[:, 1::2]
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+    lo, hi = qi & 0xF, qi >> 4
+    if sub8:
+        return (dot(xe, (lo - 8).astype(bf16) * s_exp)
+                + dot(xo, (hi - 8).astype(bf16) * s_exp))
+    return (dot(xe, lo.astype(bf16) * s_exp)
+            + dot(xo, hi.astype(bf16) * s_exp)
+            - 8.0 * dot(xe + xo, s_exp))
+
+
+class TestInt4BodyOnChip:
+    """ISSUE 30: the body that walks K in slabs, takes the scale in
+    float32 as a sublane broadcast and rounds ``q * scale`` once,
+    against PR 29's arithmetic (``_pr29_int4_twin``) and the float32
+    product, at Mistral-7B's four linear shapes, a decode batch
+    (``corr``) and a prefill bucket (``sub8``). The scale is no longer
+    rounded to bf16, so the two differ in the last bits by design: the
+    new body must sit as close to the float32 product as the old
+    arithmetic did, and near the old arithmetic itself."""
+
+    @pytest.mark.parametrize("m", [16, 512])
+    @pytest.mark.parametrize("n,k", [(6144, 4096), (4096, 4096),
+                                     (28672, 4096), (4096, 14336)])
+    def test_not_further_from_float32_than_pr29(self, n, k, m):
+        _, qd, td = _rand_quant(n, k, "sym_int4", seed=n + k)
+        q, scale = jnp.asarray(td["q"]), jnp.asarray(td["scale"])
+        x = jnp.asarray(np.random.RandomState(m).randn(m, k), jnp.bfloat16)
+        got = int4_matmul(x, q, scale, out_dtype=jnp.float32)
+        twin = jax.jit(_pr29_int4_twin, static_argnums=3)(
+            x, q, scale, m >= 256)
+        w = jnp.asarray(dequantize(qd).T)
+        ref = jnp.dot(x.astype(jnp.float32), w, precision="highest")
+        top = float(jnp.abs(ref).max())
+
+        def errs(y):
+            d = y - ref
+            return (float(jnp.abs(d).max()) / top,
+                    float(jnp.sqrt(jnp.mean(d * d))) / top)
+        new, old = errs(got), errs(twin)
+        apart = float(jnp.abs(got - twin).max()) / top
+        print(f"\nint4 body n={n} k={k} m={m}: max/rms error against "
+              f"float32 new {new[0]:.5f}/{new[1]:.6f}, PR 29 twin "
+              f"{old[0]:.5f}/{old[1]:.6f}; new against twin {apart:.5f}")
+        assert new[1] <= old[1], (new, old)
+        # the maximum over 1e5-1e7 outputs is an extreme value: 5 % of room
+        assert new[0] <= 1.05 * old[0], (new, old)
+        assert apart < 0.02
 
 
 class TestLatentFamilyOnChip:
