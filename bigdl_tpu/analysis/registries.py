@@ -345,6 +345,8 @@ METRICS.update({
         "Tokens decoded across all slots",
     "bigdl_llm_itl_seconds":
         "Engine gap between consecutive drained tokens of one request, mergeable quantile sketch",
+    "bigdl_llm_kv_class_pages_in_use":
+        "Physical KV pages owned by live requests, by page class (families that cache in several)",
     "bigdl_llm_kv_pages_in_use":
         "Physical KV pages owned by live requests",
     "bigdl_llm_kv_pool_occupancy":

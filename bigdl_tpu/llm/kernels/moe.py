@@ -23,6 +23,15 @@ A family's shared expert rides along as extra groups that every live
 token is assigned to with weight 1 (a SwiGLU of width ``S·I`` is the
 sum of ``S`` SwiGLUs of width ``I`` over the column blocks), so the
 whole expert layer but its router is this one named op.
+
+**An expert-parallel share** (ISSUE 31). A chip of a deployment that
+divides a layer's experts over several chips holds ``count`` of them
+from ``first`` on: :func:`grouped_ffn` is told that range (``held``),
+the router still chooses among all the experts, and an assignment to
+an expert held elsewhere takes no row, no tile and no weight read and
+adds nothing: the result is the partial sum of the held experts. With
+``held=None`` every group is held and the traced program is what it
+was.
 """
 
 from __future__ import annotations
@@ -57,14 +66,21 @@ def num_tiles(assignments: int, groups: int, tm: int) -> int:
     return -(-assignments // tm) + groups
 
 
+def _per_assignment(live):
+    """``live`` (T,) by token or (T, K) by assignment -> broadcastable
+    over (T, K)."""
+    return live if live.ndim == 2 else live[:, None]
+
+
 def dispatch(groups_of, live, n_groups: int, tm: int) -> Dispatch:
     """Sort the assignments ``groups_of`` (T, K) int32 by group and lay
     them out in tiles of ``tm`` rows, one group a tile. Tokens with
-    ``live`` (T,) False get no row anywhere."""
+    ``live`` (T,) False get no row anywhere; ``live`` (T, K) says it
+    of single assignments (an expert held on another chip)."""
     t, k = groups_of.shape
     a = t * k
     nt = num_tiles(a, n_groups, tm)
-    gid = jnp.where(live[:, None], groups_of, n_groups).reshape(a)
+    gid = jnp.where(_per_assignment(live), groups_of, n_groups).reshape(a)
     order = jnp.argsort(gid, stable=True).astype(jnp.int32)
     # rank of each group's first assignment, by counting (no scatter)
     edges = (gid[None, :] < jnp.arange(n_groups + 1, dtype=jnp.int32)
@@ -171,13 +187,24 @@ def tile_rows(tokens: int) -> int:
 
 
 def grouped_ffn(x, groups_of, weights, live, w_gate_up, w_down, layer,
-                n_groups: int, interpret=None):
+                n_groups: int, interpret=None, held=None):
     """The expert layer's sum for ``x`` (T, H): ``sum_j weights[t, j] *
     SwiGLU_{groups_of[t, j]}(x[t])`` over the ``n_groups`` groups of
     layer ``layer`` in the whole-stack weights ``(L·G, H, 2I)`` /
     ``(L·G, I, H)``. Returns ``(y (T, H) float32, group_sizes (G,))``.
-    Dead tokens (``live`` False) get zero and touch no group."""
+    Dead tokens (``live`` False) get zero and touch no group.
+
+    ``held = (first, count)``: the stack holds only the groups ``first
+    .. first + count - 1`` of the ``groups_of`` numbering (``n_groups``
+    is then ``count``); an assignment outside them is computed on
+    another chip and is left out here, whatever its token."""
     t, h = x.shape
+    if held is not None:
+        first, count = held
+        mine = (groups_of >= first) & (groups_of < first + count)
+        live = _per_assignment(live) & mine
+        groups_of = jnp.where(mine, groups_of - first, 0)
+        n_groups = count
     tm = tile_rows(t)
     d = dispatch(groups_of, live, n_groups, tm)
     x_ext = jnp.concatenate([x, jnp.zeros((1, h), x.dtype)])
@@ -189,6 +216,25 @@ def grouped_ffn(x, groups_of, weights, live, w_gate_up, w_down, layer,
     else:
         y_pad = moe_expert_ffn(x_pad, w_gate_up, w_down, tg, d.n_tiles,
                                tm=tm, interpret=bool(interpret))
-    w = jnp.where(live[:, None], weights.astype(jnp.float32), 0.0)
+    w = jnp.where(_per_assignment(live), weights.astype(jnp.float32), 0.0)
     y = jnp.where((w != 0)[..., None], w[..., None] * y_pad[d.pos], 0.0)
     return y.sum(axis=1), d.group_sizes
+
+
+def route_sigmoid(router, h, top_k: int, norm_topk: bool = True,
+                  scaling: float = 1.0):
+    """The ``noaux_tc`` router every sigmoid-routed family shares: (T,
+    H) -> chosen experts (T, k) int32 and their weights (T, k) float32.
+    ``s = sigmoid(h W^T)``; the ``k`` largest of ``s + bias`` are
+    chosen (the correction bias chooses and does not weigh); weights
+    ``s`` over their sum, times ``scaling``. Float32 at the highest
+    matmul precision, as published: a bf16 score flips choices whose
+    ``s + b`` lie close."""
+    s = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), router["w"].astype(jnp.float32).T,
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + router["bias"], top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scaling

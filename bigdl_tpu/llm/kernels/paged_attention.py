@@ -574,7 +574,7 @@ def paged_attention_stats(q, k_pages, v_pages, block_tables, lengths,
 
 
 def merge_attention_partial(acc, m, l, q, k_new, v_new,
-                            scale: Optional[float] = None):
+                            scale: Optional[float] = None, sink=None):
     """Fold one extra key/value token into a flash-style partial state.
 
     ``(acc, m, l)`` from :func:`paged_attention_stats` (acc (B, Hq, D)
@@ -586,7 +586,9 @@ def merge_attention_partial(acc, m, l, q, k_new, v_new,
     read-only and defer all layers' page writes to one post-scan
     scatter). ``scale`` defaults to ``1/sqrt(D)``; the value may be
     narrower than the key (the latent cache: ``v_new`` the first
-    columns of ``k_new``)."""
+    columns of ``k_new``). ``sink`` (Hq,), where a model has one, is a
+    learned score a query head that joins the denominator and carries
+    no value: it is folded in here, once, with the last token."""
     b, hq, d = q.shape
     hkv = k_new.shape[1]
     g = hq // hkv
@@ -596,9 +598,13 @@ def merge_attention_partial(acc, m, l, q, k_new, v_new,
     vr = jnp.repeat(v_new.astype(jnp.float32), g, axis=1)
     s_self = jnp.sum(q.astype(jnp.float32) * kr, axis=-1) * scale
     m_new = jnp.maximum(m, s_self)
+    if sink is not None:
+        m_new = jnp.maximum(m_new, sink.astype(jnp.float32)[None])
     alpha = jnp.exp(m - m_new)                                # (B, Hq)
     beta = jnp.exp(s_self - m_new)
     l_new = l * alpha + beta
+    if sink is not None:
+        l_new = l_new + jnp.exp(sink.astype(jnp.float32)[None] - m_new)
     out = (acc * alpha[..., None] + vr * beta[..., None]) \
         / jnp.maximum(l_new, 1e-30)[..., None]
     return out

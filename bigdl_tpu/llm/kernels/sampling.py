@@ -58,9 +58,9 @@ def fence_token(*arrays):
     dependence), NaN-scrubbed and clipped so the int cast is defined.
     """
     acc = jnp.float32(0.0)
-    for a in arrays:
-        if a is None:       # a family with one pool has no second one
-            continue
+    # a family with one pool has no second one (None: no leaf); one
+    # with several page classes hands a tuple of pools
+    for a in jax.tree_util.tree_leaves(arrays):
         acc = acc + a.ravel()[0].astype(jnp.float32)
     acc = jnp.clip(jnp.nan_to_num(acc), -1e9, 1e9)
     return acc.astype(jnp.int32)[None]
@@ -138,7 +138,9 @@ def make_sampled_step(fam_step):
         key, sub = jax.random.split(key)
         toks = sample_tokens(last, sub, do_sample=do_sample,
                              temperature=temperature, top_k=top_k)
-        bt_eff = jnp.where(active[:, None], bt, 0)
+        # one table, or one a page class (docs/KVCACHE.md)
+        bt_eff = jax.tree_util.tree_map(
+            lambda t: jnp.where(active[:, None], t, 0), bt)
         lens_eff = jnp.where(active, lens, 0)
         logits, k_pages, v_pages, *stats = fam_step(
             params, cfg, k_pages, v_pages, bt_eff, lens_eff, toks,

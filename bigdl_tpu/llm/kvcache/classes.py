@@ -1,0 +1,140 @@
+"""Page classes: the kinds of cache a family's layers keep, side by side
+(ISSUE 31; docs/KVCACHE.md "What a family gives the engine").
+
+A family's layers need not all cache alike. Some keep every token (a
+full-attention layer: its pages grow with the context), some the last
+``W`` positions (a sliding-window layer), and each kind may have its
+own number of KV heads and its own K and V widths. A family says so
+with ``page_classes(cfg)`` -> ``[PageClass, ...]``; one that does not is
+given ONE class from its configuration (:func:`page_classes_of`), so
+Llama and every latent family go through the same declaration.
+
+The engine builds one pool pair and one block table a class. The class
+that keeps every token is the one the :class:`KVCacheManager` has always
+managed (position-indexed table, admission by worst-case length, one
+page granted every ``page`` steps). A class that keeps a window is a
+**ring** (:class:`RingLedger`): ``ring_pages(W, page)`` table columns a
+request, the token at position ``p`` in column ``(p // page) % ring``,
+granted as the first ``ring * page`` positions arrive and never again:
+the pages a request holds in that class are bounded by the ring
+whatever its length, and all of them come back on release. (A ring,
+not pages freed as they leave the window: the table stays ``ring``
+columns wide instead of ``max_seq_len / page``, nothing is granted or
+freed per step once it is full, and the kernels rebuild positions from
+the length, :func:`kernels.hybrid_attention.ring_positions`.)
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import List, Optional, Tuple
+
+import numpy as np
+
+from bigdl_tpu.llm.kvcache.pool import PagePool
+
+
+@dataclasses.dataclass(frozen=True)
+class PageClass:
+    """One kind of cache. ``layers`` cache in it, each ``kv_heads`` rows
+    of ``k_width`` (and ``v_width``: None for one pool of rows and no V
+    pool, a latent cache) numbers a token, at the widths the pools are
+    held at; ``keeps`` None keeps every token, ``W`` the last ``W``
+    positions."""
+    name: str
+    layers: int
+    kv_heads: int
+    k_width: int
+    v_width: Optional[int]
+    keeps: Optional[int] = None
+
+    def pools(self, num_pages: int, page: int, dtype) -> Tuple:
+        import jax.numpy as jnp
+        shape = (self.layers, num_pages, self.kv_heads, page)
+        return (jnp.zeros(shape + (self.k_width,), dtype),
+                None if self.v_width is None
+                else jnp.zeros(shape + (self.v_width,), dtype))
+
+
+def page_classes_of(fam_mod, cfg) -> List[PageClass]:
+    """What the family declares, or the one class of a family that does
+    not: a K and a V pool of per-head rows for every layer. The class
+    that keeps every token comes first and there is exactly one."""
+    declare = getattr(fam_mod, "page_classes", None)
+    if declare is None:
+        return [PageClass("kv", cfg.num_hidden_layers,
+                          cfg.num_key_value_heads, cfg.head_dim,
+                          cfg.head_dim)]
+    classes = list(declare(cfg))
+    if [c.keeps for c in classes].count(None) != 1 \
+            or classes[0].keeps is not None:
+        raise ValueError(
+            f"{fam_mod.__name__}.page_classes: exactly one class keeps "
+            f"every token and it comes first; got {classes}")
+    return classes
+
+
+class RingLedger:
+    """Host bookkeeping of one window class: its own :class:`PagePool`
+    (page 0 the trash page, as everywhere), the ring table of every
+    engine slot and the pages each slot holds. The pool is sized for
+    every slot's whole ring, so admission never waits on it; the budget
+    is charged all the same, so that the ledger balances like the
+    full class's."""
+
+    def __init__(self, cls: PageClass, page: int, max_batch: int):
+        from bigdl_tpu.llm.kernels.hybrid_attention import ring_pages
+        self.cls = cls
+        self.page = page
+        self.ring = ring_pages(cls.keeps, page)
+        self.num_pages = 1 + max_batch * self.ring
+        self.pool = PagePool(self.num_pages, page)
+        self.bt = np.zeros((max_batch, self.ring), np.int32)
+        self.owned: List[List[int]] = [[] for _ in range(max_batch)]
+        self.charge = [0] * max_batch
+
+    def pages_for(self, tokens: int) -> int:
+        """Pages a request of ``tokens`` positions ever holds here."""
+        return min(self.ring, -(-tokens // self.page))
+
+    def admit(self, slot: int, tokens: int) -> bool:
+        n = self.pages_for(tokens)
+        if n > self.pool.budget_avail:
+            return False
+        self.pool.charge(n)
+        self.charge[slot] = n
+        return True
+
+    def grant(self, slot: int, tokens: int) -> List[Tuple[int, int]]:
+        """Pages for positions ``0 .. tokens - 1`` that the slot does
+        not hold yet: ``[(column, page id), ...]`` newly in its ring
+        table (none once the ring is full)."""
+        have = len(self.owned[slot])
+        new = []
+        for col in range(have, self.pages_for(tokens)):
+            pid = self.pool.take_free()
+            self.bt[slot, col] = pid
+            self.owned[slot].append(pid)
+            new.append((col, pid))
+        return new
+
+    def release(self, slot: int) -> int:
+        """Everything the slot holds goes back; returns how many pages."""
+        pages, self.owned[slot] = self.owned[slot], []
+        for pid in pages:
+            self.pool.decref(pid)
+        self.pool.release(self.charge[slot])
+        self.charge[slot] = 0
+        self.bt[slot, :] = 0
+        return len(pages)
+
+    def pages_in_use(self) -> int:
+        return sum(len(p) for p in self.owned)
+
+    def scatter_targets(self, slot: int, positions: np.ndarray,
+                        upto: int) -> np.ndarray:
+        """The page each of ``positions`` is written to (0, the trash
+        page, from position ``upto`` on)."""
+        cols = (positions // self.page) % self.ring
+        return np.where(positions < upto, self.bt[slot, cols],
+                        0).astype(np.int32)

@@ -335,16 +335,10 @@ def mla_attend_expanded(lp, q_nope, q_rope, c, k_r, length,
 
 def route(router, h, cfg: DeepseekConfig):
     """(T, H) -> chosen experts (T, k) int32 and their weights (T, k)
-    float32. Float32 at the highest matmul precision, as published: a
-    bf16 score flips choices whose ``s + b`` lie close."""
-    s = jax.nn.sigmoid(jnp.dot(
-        h.astype(jnp.float32), router["w"].astype(jnp.float32).T,
-        precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + router["bias"], cfg.num_experts_per_tok)
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    if cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
+    float32: :func:`kernels.moe.route_sigmoid` at this configuration's
+    ``k``, normalisation and scaling factor."""
+    return moe.route_sigmoid(router, h, cfg.num_experts_per_tok,
+                             cfg.norm_topk_prob, cfg.routed_scaling_factor)
 
 
 def moe_stats(group_sizes, cfg: DeepseekConfig):
@@ -468,11 +462,13 @@ def forward(params: Dict[str, Any], cfg: DeepseekConfig,
 # the paged engine's entry points
 # ---------------------------------------------------------------------------
 
-def page_pools(cfg: DeepseekConfig, num_pages: int, page: int, dtype):
-    """The engine's pools for this family: ONE latent pool and no V
-    pool (``LLMServer`` asks the family for them)."""
-    return jnp.zeros((cfg.num_hidden_layers, num_pages, 1, page,
-                      cfg.latent_width), dtype), None
+def page_classes(cfg: DeepseekConfig):
+    """The engine's cache for this family: ONE class of latent rows,
+    every layer, every token, and no V pool (``LLMServer`` asks the
+    family; docs/KVCACHE.md)."""
+    from bigdl_tpu.llm.kvcache.classes import PageClass
+    return [PageClass("latent", cfg.num_hidden_layers, 1,
+                      cfg.latent_width, None)]
 
 
 # the decode step's stats vector, appended to the fetched token vector:

@@ -48,6 +48,7 @@ from bigdl_tpu import observability as obs
 from bigdl_tpu import reliability
 from bigdl_tpu.llm.kernels.sampling import make_sampled_step
 from bigdl_tpu.llm.kvcache import KVCacheManager
+from bigdl_tpu.llm.kvcache.classes import RingLedger, page_classes_of
 from bigdl_tpu.llm.kvcache.prefill import make_mixed_step, make_spec_step
 from bigdl_tpu.observability import flight
 from bigdl_tpu.observability import request_context as rc
@@ -261,7 +262,9 @@ def _sync_barrier(*arrays):
     the step's own (tokens ‖ fence) vector, which delivers the data AND
     the barrier in one transfer (kernels.sampling.fence_token).
     """
-    arrays = [a for a in arrays if a is not None]   # a one-pool family
+    # a one-pool family has no second pool (None: no leaf); a family
+    # with several page classes hands a tuple of pools
+    arrays = jax.tree_util.tree_leaves(arrays)
     jax.block_until_ready(arrays)
     # (the first element by index: an eager ``ravel()`` of a page pool
     # copies the pool, 2.7 GB beside a 13 GB engine at stop())
@@ -502,11 +505,12 @@ class LLMServer:
         self._fam_sampled_step = getattr(
             fam_mod, "paged_decode_step_sampled", None) or \
             make_sampled_step(self._fam_paged_step)
-        # what else a family may say of itself: the page pools it
-        # caches in (default: a K and a V pool of per-head rows), the
-        # int32 counts its decode step appends to the fetched token
-        # vector, and the counts the host can add at dispatch
-        self._fam_page_pools = getattr(fam_mod, "page_pools", None)
+        # what else a family may say of itself: the page classes it
+        # caches in (default: one, a K and a V pool of per-head rows
+        # for every layer), the int32 counts its decode step appends to
+        # the fetched token vector, and the counts the host can add at
+        # dispatch
+        self._classes = page_classes_of(fam_mod, cfg)
         self._fam_step_stats = tuple(getattr(fam_mod, "STEP_STATS", ()))
         self._fam_host_stats = getattr(fam_mod, "host_step_stats", None)
         self.step_counters: Dict[str, int] = dict.fromkeys(
@@ -624,18 +628,34 @@ class LLMServer:
         # page 0 is the trash page: inactive rows and prefill padding
         # write there; no live sequence ever owns it
         self._num_pages = num_pages or (1 + max_batch * cap)
-        if self._fam_page_pools is not None:
-            self._k_pages, self._v_pages = self._fam_page_pools(
-                cfg, self._num_pages, page_size, model.cache_dtype)
+        # one pool pair and one block table a page class. The class
+        # that keeps every token is the one ``num_pages``, ``_kv``,
+        # ``_bt`` and ``_slot_pages`` are about; a class that keeps a
+        # window is a ring (kvcache.classes.RingLedger) with a table
+        # and a ledger of its own. A one-class family's programs get
+        # the pools and the table as arrays, as they always have; a
+        # family of several gets tuples, one entry a class.
+        self._rings = [RingLedger(c, page_size, max_batch)
+                       for c in self._classes if c.keeps is not None]
+        pools = [c.pools(n, page_size, model.cache_dtype)
+                 for c, n in zip(self._classes,
+                                 [self._num_pages]
+                                 + [r.num_pages for r in self._rings])]
+        if self._rings:
+            self._k_pages, self._v_pages = (tuple(p) for p in zip(*pools))
         else:
-            shape = (cfg.num_hidden_layers, self._num_pages,
-                     cfg.num_key_value_heads, page_size, cfg.head_dim)
-            self._k_pages = jnp.zeros(shape, model.cache_dtype)
-            self._v_pages = jnp.zeros(shape, model.cache_dtype)
-        if self._v_pages is None:
-            # one pool of another row than per-head K and V (a
-            # latent cache): what reads or moves pages as a K/V
-            # pair refuses the family
+            self._k_pages, self._v_pages = pools[0]
+        self._ring_bt_dev = [jnp.asarray(r.bt) for r in self._rings]
+        if self._rings:
+            self.step_counters.update(
+                {"decode_rows_total": 0,
+                 **{r.cls.name + "_pages_held_total": 0
+                    for r in self._rings}})
+        self._class_ins = None
+        if self._v_pages is None or self._rings:
+            # one pool of another row than per-head K and V (a latent
+            # cache), or several page classes: what reads or moves
+            # pages as a K/V pair of one class refuses the family
             def on(arg, key):
                 return arg if arg is not None else \
                     conf.get_bool(key, False)
@@ -651,10 +671,14 @@ class LLMServer:
                 ("priority preemption (bigdl.llm.priority)",
                  on(priority, "bigdl.llm.priority.enabled"))) if yes]
             if asked:
+                how = ("one latent pool and no V pool"
+                       if self._v_pages is None else
+                       f"{len(self._classes)} page classes ("
+                       + ", ".join(c.name for c in self._classes) + ")")
                 raise NotImplementedError(
-                    f"{type(model).__name__} caches one latent pool "
-                    f"and no V pool; {', '.join(asked)} assume a "
-                    "K pool and a V pool of per-head rows")
+                    f"{type(model).__name__} caches {how}; "
+                    f"{', '.join(asked)} assume a K pool and a V pool "
+                    "of per-head rows in one class")
         # the page pool now lives in the kvcache subsystem (ISSUE 5
         # tentpole): refcounted pages + admission budget; with the
         # prefix cache on, a radix index keeps finished requests'
@@ -750,6 +774,10 @@ class LLMServer:
         self._fetch_ready: List[tuple] = []
         self._bt = np.zeros((max_batch, self._pages_cap), np.int32)
         self._lens = np.zeros(max_batch, np.int32)
+        # pages the last grant took and releases gave back, by class
+        # (the llm/grant span's payload; empty for a one-class family)
+        self._grant_by_class: Dict[str, int] = {}
+        self._freed_by_class: Dict[str, int] = {}
         # device-resident twins (ISSUE 4): the step reads/advances
         # these on device; the host applies incremental scatters
         # (page grants, prefills, freed-row resets) instead of
@@ -793,6 +821,28 @@ class LLMServer:
             n += sum(len(st["own"]) for st in self._chunk_state
                      if st is not None)
         return n
+
+    @property
+    def pages_in_use_by_class(self) -> Dict[str, int]:
+        """:attr:`pages_in_use` under the first class's name, and what
+        live requests hold of every window class."""
+        return {self._classes[0].name: self.pages_in_use,
+                **{r.cls.name: r.pages_in_use() for r in self._rings}}
+
+    def _put_ring_row(self, c: int, i: int):
+        """Slot ``i``'s row of window class ``c``'s ring table, host to
+        device, whole: the one update a prefill's grant, a decode
+        step's grant and a release make, so none is a new program."""
+        row = jnp.asarray(self._rings[c].bt[i])
+        self._pin(self._ring_bt_dev[c], row)
+        self._ring_bt_dev[c] = self._ring_bt_dev[c].at[i].set(row)
+
+    def _tables(self):
+        """The block tables as the programs take them: the one table,
+        or one a page class."""
+        if not self._rings:
+            return self._bt_dev
+        return (self._bt_dev, *self._ring_bt_dev)
 
     # the pool moved into the kvcache subsystem (ISSUE 5); these views
     # keep the embedded-pool names the tests and tools read
@@ -1422,6 +1472,15 @@ class LLMServer:
             return True
         return self._sched is not None and len(self._sched) > 0
 
+    def _seats_somebody(self) -> bool:
+        """The coming sweep will prefill a request: one is queued, a
+        slot is free, and no head is held back by the page budget (that
+        sweep would only fail again, every pass, and draining ahead of
+        it would take the pipelining away)."""
+        return (self._sched is None and not self._queue.empty()
+                and getattr(self, "_pending_head", None) is None
+                and any(r is None for r in self._slots))
+
     def _admit(self):
         """Fill free slots from the queue; per-slot prefill. Paged mode
         additionally requires the request's worst-case page budget
@@ -1435,6 +1494,15 @@ class LLMServer:
         it); a pass with nobody waiting has none."""
         if not self._admit_waiting():
             return
+        if self._seats_somebody():
+            # deliver before admitting: the step in flight ends within
+            # one step's time, a prefill's staging may take several, and
+            # a token that waits behind it is a late token of every
+            # live row. Drained first, the staging and the prefill fall
+            # into ONE gap a row, not two (its own phases of the pass,
+            # ahead of ``llm/admit``)
+            while self._inflight:
+                self._drain_next()
         with self._phase("llm/admit", admitted=0, prefills=0,
                          prompt_tokens=0, bucket_tokens=0) as ph:
             self._admit_args = ph.args
@@ -1571,6 +1639,12 @@ class LLMServer:
                 else:
                     self._pending_head = req
                 raise
+            if adm is not None and not all(
+                    r.admit(i, len(ids) + budget) for r in self._rings):
+                for r in self._rings:
+                    r.release(i)
+                self._kv.cancel(adm)
+                adm = None
             if adm is None:
                 peek = self._kv.peek(ids, budget)
                 if peek["pages_needed"] > self._num_pages - 1:
@@ -1691,6 +1765,8 @@ class LLMServer:
             # otherwise shrink the pool forever) nor leave the
             # client blocked until timeout
             self._kv.cancel(adm)
+            for r in self._rings:
+                r.release(i)
             self._slot_adm[i] = None
             req.error = f"{type(e).__name__}: {e}"
             req.done.set()
@@ -1729,6 +1805,16 @@ class LLMServer:
                 pri["queue_class"].labels(**{"class": cls}).set(depth)
             pri["parked"].set(self._sched.parked())
         ins["kv_pages"].set(self.pages_in_use)
+        if self._rings:
+            # a family of several page classes: each class's own gauge
+            if self._class_ins is None:
+                self._class_ins = obs.gauge(
+                    "bigdl_llm_kv_class_pages_in_use",
+                    "Physical KV pages owned by live requests, by page "
+                    "class (families that cache in several)",
+                    labelnames=("page_class",))
+            for name, n in self.pages_in_use_by_class.items():
+                self._class_ins.labels(page_class=name).set(n)
         # page 0 is the reserved trash page, never allocatable
         ins["kv_occupancy"].set(
             self.pages_in_use / max(self._num_pages - 1, 1))
@@ -1776,6 +1862,8 @@ class LLMServer:
         self._pin(row_d)
         self._bt_dev = self._bt_dev.at[i].set(row_d)
         self._lens_dev = self._lens_dev.at[i].set(T)
+        for c in range(len(self._rings)):
+            self._put_ring_row(c, i)
         if self.pipeline_depth == 1:
             _sync_barrier(self._k_pages, self._v_pages, self._last,
                           self._bt_dev, self._lens_dev)
@@ -1849,6 +1937,16 @@ class LLMServer:
             bt_d = jnp.asarray(bt_row)
             phys_d = jnp.asarray(phys)
             slots_d = jnp.asarray(slots)
+            if self._rings:
+                # a window class takes the prompt's pages of its ring
+                # now; every position is written there, a later one
+                # over an earlier one of the same slot, in order
+                for r in self._rings:
+                    r.grant(i, T)
+                bt_d = (bt_d, *(jnp.asarray(r.bt[i])
+                                for r in self._rings))
+                phys_d = (phys_d, *(jnp.asarray(
+                    r.scatter_targets(i, pos, T)) for r in self._rings))
             fork_dst = jnp.asarray(own[0] if tail else 0, jnp.int32)
             fork_src = jnp.asarray(adm.tail_src if tail else 0,
                                    jnp.int32)
@@ -1859,7 +1957,7 @@ class LLMServer:
             self._admit_args["bucket_tokens"] += bucket
         except BaseException:
             self._kv.free_owned(own)
-            raise
+            raise  # (the rings' pages go with r.release, at the caller)
         # shared epilogue; the fork copy consumed the tail source in
         # dispatch order, so the transient ref/pin drops there (the
         # donated-pool dependency chain orders any later overwrite
@@ -2835,6 +2933,14 @@ class LLMServer:
         self._pin(self._bt_dev, self._lens_dev)
         self._bt_dev = self._bt_dev.at[i].set(0)
         self._lens_dev = self._lens_dev.at[i].set(0)
+        if self._rings:
+            freed = self._freed_by_class
+            name = self._classes[0].name
+            freed[name] = freed.get(name, 0) + len(owned)
+            for c, r in enumerate(self._rings):
+                freed[r.cls.name] = freed.get(r.cls.name, 0) \
+                    + r.release(i)
+                self._put_ring_row(c, i)        # zeros: the trash page
 
     # -- lossless preemption (ISSUE 17) --------------------------------------
     def _consider_preempt(self):
@@ -3012,6 +3118,13 @@ class LLMServer:
                 return True
         with self._phase("llm/grant") as ph:
             ph.args["pages"] = self._grant_pages(disp, sargs, cargs)
+            if self._rings:
+                # by class: granted in this pass, freed since the last
+                for name, n in self._grant_by_class.items():
+                    ph.args["pages_" + name] = n
+                for name in list(self._freed_by_class):
+                    ph.args["freed_" + name] = \
+                        self._freed_by_class.pop(name)
         with self._phase("llm/dispatch") as self._dispatch_ph:
             mask = np.zeros(self.max_batch, bool)
             mask[disp] = True
@@ -3077,7 +3190,22 @@ class LLMServer:
             vals_d = jnp.asarray(vals)
             self._pin(self._bt_dev, vals_d)
             self._bt_dev = self._bt_dev.at[rows, cols].set(vals_d)
-        return len(allocs)
+        granted = len(allocs)
+        for c, r in enumerate(self._rings):
+            # a window class grants while a row's ring is filling and
+            # never after: the position written this step must have
+            # its page
+            new = 0
+            for i in disp:
+                got = len(r.grant(i, int(self._lens[i]) + 1))
+                if got:
+                    self._put_ring_row(c, i)
+                    new += got
+            self._grant_by_class[r.cls.name] = new
+            granted += new
+        if self._rings:
+            self._grant_by_class[self._classes[0].name] = len(allocs)
+        return granted
 
     def _dispatch_decode(self, disp, active, t_step: float) -> bool:
         """One plain decode pass over the page pool: every active row
@@ -3094,7 +3222,7 @@ class LLMServer:
         pdecode = _PAGED_STEP_CACHE.get(key)
         if pdecode is None:
             pdecode = _PAGED_STEP_CACHE[key] = self._build_paged_decode()
-        bt_in, lens_in = self._bt_dev, self._lens_dev
+        bt_in, lens_in = self._tables(), self._lens_dev
         last_in, key_in = self._last, self._sample_key
         out, logits, self._k_pages, self._v_pages, self._lens_dev, \
             self._sample_key = pdecode(
@@ -3113,6 +3241,14 @@ class LLMServer:
             # lens were advanced above: the step attended one fewer
             rec["host_stats"] = self._fam_host_stats(
                 self.cfg, self._lens[disp] - 1)
+        if self._rings:
+            # the allocator's own witness, a window class: the pages
+            # the step's rows hold there, and the rows
+            hs = rec.setdefault("host_stats", {})
+            hs["decode_rows_total"] = len(disp)
+            for r in self._rings:
+                hs[r.cls.name + "_pages_held_total"] = sum(
+                    len(r.owned[i]) for i in disp)
         self._pending_release = []
         return self._after_dispatch(rec, t_step)
 

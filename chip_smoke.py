@@ -182,22 +182,25 @@ def train_phase(depth: int = 50, image: int = 224, classes: int = 1000,
 def pallas_calls_in_engine_programs(pool_shape, layers) -> None:
     """Read the HLO of the executables the engine compiled: count the
     Mosaic custom calls in them, the copies of a whole page pool (a
-    step that writes new K/V in place makes none) and the slices of
-    one layer out of a quantised weight stack (a step whose INT4
-    kernel indexes the stack makes none)."""
+    step that writes new K/V in place makes none; ``pool_shape`` is
+    one shape or, for a family of several page classes, a list) and
+    the slices of one layer out of a quantised weight stack (a step
+    whose INT4 kernel indexes the stack makes none)."""
     from bigdl_tpu.llm.kvcache.write import (pool_shaped_copies,
                                              weight_slices)
     from bigdl_tpu.llm.serving import compiled_steps
     found = {}
+    shapes = pool_shape if isinstance(pool_shape, list) else [pool_shape]
     for kind, detail, fn in compiled_steps():
         for _, exe in fn.executables():
             text = exe.as_text()
             n = text.count("tpu_custom_call")
-            copies = pool_shaped_copies(text, pool_shape)
+            copies = [c for shape in shapes
+                      for c in pool_shaped_copies(text, shape)]
             slices = weight_slices(text, layers)
             fact(f"serve: {fn.name} {detail} has {n} tpu_custom_call "
                  f"site(s), {len(copies)} copies shaped like the page "
-                 f"pool {tuple(pool_shape)} and {len(slices)} slices of "
+                 f"pool {[tuple(x) for x in shapes]} and {len(slices)} slices of "
                  "one layer out of a quantised weight stack in its "
                  "compiled HLO")
             check(not copies,
@@ -316,6 +319,118 @@ def serve_phase(cfg=None, max_seq_len: int = 2048, first=FIRST_WAVE,
     fact(f"phase serve wall {time.perf_counter() - t0:.1f} s")
 
 
+#: the hybrid phase's waves: prompts on both sides of a prefill chunk
+#: (1,024 tokens) and of the ring (256 positions), decode past a wrap
+HYBRID_FIRST = ((300, 300), (5000, 48), (1100, 64), (40, 64))
+HYBRID_SECOND = ((2500, 40), (700, 32))
+
+
+def serve_hybrid_phase(first=HYBRID_FIRST, second=HYBRID_SECOND,
+                       cfg=None, num_pages: int = 6000,
+                       max_seq_len: int = 8192, max_batch: int = 32,
+                       expect_pallas: bool = True) -> None:
+    """ISSUE 31: the ``mimo_v2`` family (window and full layers in two
+    page classes, an expert-parallel share) at MiMo-V2.5's published
+    widths, layer 0 and one period, 16 of 256 experts held, bf16,
+    through ``LLMServer`` in its default configuration."""
+    import jax
+    import jax.numpy as jnp
+
+    from bigdl_tpu.llm.models import mimo
+    from bigdl_tpu.llm.serving import LLMServer, _PAGED_STEP_CACHE
+
+    t0 = time.perf_counter()
+    if cfg is None:
+        cfg = mimo.MimoConfig(
+            num_hidden_layers=7, hybrid_layer_pattern=(0, 1, 1, 1, 1, 0, 1),
+            moe_layer_freq=(0, 1, 1, 1, 1, 1, 1), experts_held=16)
+    _PAGED_STEP_CACHE.clear()       # the Llama phase's programs
+    params = jax.block_until_ready(mimo.init_params(cfg, seed=0))
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree_util.tree_leaves(params))
+    fact(f"hybrid: {cfg.num_hidden_layers} layers "
+         f"{cfg.hybrid_layer_pattern}, hidden {cfg.hidden_size}, heads "
+         f"{cfg.num_attention_heads}/{cfg.num_key_value_heads} full and /"
+         f"{cfg.swa_num_key_value_heads} window {cfg.sliding_window}, K "
+         f"{cfg.head_dim} / V {cfg.v_head_dim}, experts "
+         f"{cfg.experts_held} of {cfg.n_routed_experts} held; "
+         f"{nbytes / 2**30:.2f} GiB of bfloat16 params built on device in "
+         f"{time.perf_counter() - t0:.1f} s")
+    # the dense forward below takes the first prompt and its answer
+    model = mimo.MimoForCausalLM(cfg, params,
+                                 max_cache_len=sum(first[0]) + 8)
+    rs = np.random.RandomState(0)
+    prompts = [rs.randint(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in first + second]
+    budgets = [m for _, m in first + second]
+    # 32 slots, as the benchmark's engine: the window class's pool is
+    # then 252 MB. With 8 slots it is 63 MB, under the 128 MiB of VMEM,
+    # and XLA's memory-space assignment moves it there and back around
+    # every prefill, which the check below reads as a copy of the pool
+    # (seen on the chip and in a compile for it made without one)
+    srv = LLMServer(model, max_batch=max_batch, max_seq_len=max_seq_len,
+                    num_pages=num_pages).start()
+    try:
+        reqs = [srv.submit(p, max_new_tokens=m)
+                for p, m in zip(prompts[:len(first)], budgets)]
+        deadline = time.monotonic() + 1500
+        while not all(r.tokens for r in reqs):
+            check(time.monotonic() < deadline and
+                  not any(r.error for r in reqs),
+                  f"first wave never started decoding: "
+                  f"{[r.error for r in reqs]}")
+            time.sleep(0.005)
+        reqs += [srv.submit(p, max_new_tokens=m)
+                 for p, m in zip(prompts[len(first):],
+                                 budgets[len(first):])]
+        outs = [r.get(timeout=1500) for r in reqs]
+        while not srv.engine_idle():    # a request is done before its
+            time.sleep(0.005)           # pages are back
+        held = dict(srv.pages_in_use_by_class)
+        counters = dict(srv.step_counters)
+        pass_errors = srv.pass_errors
+        pool_shapes = [list(p.shape) for p in srv._k_pages]
+        ring = srv._rings[0].ring
+    finally:
+        srv.stop()
+    for i, (out, m) in enumerate(zip(outs, budgets)):
+        check(len(out) == m, f"request {i} returned {len(out)} ids, "
+              f"asked for {m}")
+    check(pass_errors == 0, f"{pass_errors} engine passes raised")
+    check(held == {"full": 0, "window": 0},
+          f"pages still held after every request finished: {held}")
+    k = cfg.num_experts_per_tok
+    check(counters["moe_assignments_total"]
+          + counters["moe_assignments_elsewhere_total"]
+          == k * counters["moe_token_layers_total"],
+          f"assignments here and elsewhere do not add up to {k} a "
+          f"token and expert layer: {counters}")
+    check(counters["window_pages_held_total"]
+          <= ring * counters["decode_rows_total"],
+          f"a row held more window-class pages than its ring's {ring}")
+    fact(f"hybrid: counters {counters}")
+    # the dense forward (contiguous caches, no page, no ring, no
+    # kernel) over the prompt that decoded past the ring's wrap
+    ids = np.concatenate([prompts[0], np.asarray(outs[0][:-1], np.int32)])
+    logits, _ = model(jnp.asarray(ids)[None])
+    ref = np.asarray(logits[0, len(prompts[0]) - 1:], np.float32)
+    check(bool(np.all(np.isfinite(ref))), "reference logits not finite")
+    picked = ref[np.arange(len(outs[0])), np.asarray(outs[0])]
+    margin = (ref.max(-1) - picked) / ref.std(-1)
+    fact(f"hybrid: {len(outs[0])} served tokens after a "
+         f"{len(prompts[0])}-token prompt sit at most {margin.max():.4f} "
+         f"(mean {margin.mean():.4f}) logit-sigmas below the dense "
+         f"forward's maximum; {int((margin == 0).sum())} are its argmax")
+    check(float(margin.max()) <= 1.0 and float(margin.mean()) <= 0.1,
+          f"served tokens are {margin.max():.3f} sigmas (mean "
+          f"{margin.mean():.3f}) below the dense forward's best")
+    if expect_pallas:
+        pallas_calls_in_engine_programs(
+            pool_shapes + [[s[0] * s[1]] + s[2:] for s in pool_shapes],
+            dict(enumerate(params["layers"])))
+    fact(f"phase hybrid wall {time.perf_counter() - t0:.1f} s")
+
+
 def main() -> int:
     t0 = time.perf_counter()
     import bigdl_tpu  # noqa: F401 — places the compile cache first
@@ -336,6 +451,11 @@ def main() -> int:
     fact(f"device memory in use between the phases: "
          f"{in_use / 2**20:.0f} MiB")
     serve_phase()
+    gc.collect()
+    in_use = jax.devices()[0].memory_stats()["bytes_in_use"]
+    fact(f"device memory in use between the serving phases: "
+         f"{in_use / 2**20:.0f} MiB")
+    serve_hybrid_phase()
 
     report_compiles()
     fact(f"compile cache: {cache.hits} hit(s), {cache.misses} miss(es)")

@@ -529,8 +529,8 @@ class TestLatentFamilyOnChip:
         from bigdl_tpu.llm.models import deepseek
         cfg = deepseek.DeepseekConfig(num_hidden_layers=8)
         params = jax.eval_shape(lambda: deepseek.init_params(cfg, 0))
-        pool = jax.eval_shape(lambda: deepseek.page_pools(
-            cfg, self.PAGES, self.PAGE, jnp.bfloat16)[0])
+        pool = jax.eval_shape(lambda: deepseek.page_classes(cfg)[0].pools(
+            self.PAGES, self.PAGE, jnp.bfloat16)[0])
         assert pool.shape == (8, self.PAGES, 1, 16, 640)
         return deepseek, cfg, params, pool
 
@@ -639,3 +639,184 @@ class TestLatentFamilyOnChip:
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-2,
                                    atol=2e-2 * np.abs(want).max())
         assert int(sizes.sum()) == int(live.sum()) * k
+
+
+class TestHybridFamilyOnChip:
+    """ISSUE 31: the mimo_v2 family at the published widths of
+    MiMo-V2.5's language model, layer 0 and one period (7 layers, 16 of
+    256 experts held), over the two pools the benchmark's engine holds
+    (full class 43,000 pages, window class 1 + 32 rings of 16): the
+    decode program and three prefill buckets (one pass, and two that
+    loop over chunks inside the program) compile, hold their Mosaic
+    calls and make no copy shaped like either pool; the four attention
+    kernels and the held experts' product agree with their ``jnp``
+    twins on the chip's own layout."""
+
+    PAGES, PAGE, BATCH, MAXP, RING = 43000, 16, 32, 2176, 16
+
+    def _operands(self):
+        from bigdl_tpu.llm.models import mimo
+        cfg = mimo.MimoConfig(
+            num_hidden_layers=7, hybrid_layer_pattern=(0, 1, 1, 1, 1, 0, 1),
+            moe_layer_freq=(0, 1, 1, 1, 1, 1, 1), experts_held=16)
+        params = jax.eval_shape(lambda: mimo.init_params(cfg, 0))
+        full, window = mimo.page_classes(cfg)
+        pools = (jax.eval_shape(lambda: full.pools(
+                     self.PAGES, self.PAGE, jnp.bfloat16)[0]),
+                 jax.eval_shape(lambda: window.pools(
+                     1 + self.BATCH * self.RING, self.PAGE,
+                     jnp.bfloat16)[0]))
+        assert pools[0].shape == (2, self.PAGES, 4, 16, 384)
+        assert pools[1].shape == (5, 513, 8, 16, 384)
+        return mimo, cfg, params, pools
+
+    def _holds_kernels_and_no_pool_copy(self, compiled, pools, calls):
+        from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= calls
+        for pool in pools:
+            flat = (pool.shape[0] * pool.shape[1],) + pool.shape[2:]
+            copies = pool_shaped_copies(text, pool.shape) \
+                + pool_shaped_copies(text, flat)
+            assert not copies, copies[0][:300]
+
+    def test_decode_program(self):
+        import functools
+        mimo, cfg, params, pools = self._operands()
+        B = self.BATCH
+        fn = jax.jit(functools.partial(mimo.paged_decode_step_sampled,
+                                       page=self.PAGE),
+                     static_argnums=(1,), donate_argnums=(2,))
+        compiled = fn.lower(
+            params, cfg, pools, (None, None),
+            (jax.ShapeDtypeStruct((B, self.MAXP), jnp.int32),
+             jax.ShapeDtypeStruct((B, self.RING), jnp.int32)),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, cfg.vocab_size), jnp.float32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.random.PRNGKey(0)).compile()
+        # an attention kernel a layer (7), the expert product of the six
+        # expert layers
+        self._holds_kernels_and_no_pool_copy(compiled, pools, 13)
+
+    @pytest.mark.parametrize("bucket", [256, 2048, 32768])
+    def test_prefill_program(self, bucket):
+        import functools
+        mimo, cfg, params, pools = self._operands()
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        run = jax.ShapeDtypeStruct((bucket,), jnp.int32)
+        fn = jax.jit(functools.partial(mimo.paged_prefill_ragged,
+                                       page=self.PAGE),
+                     static_argnums=(1,), donate_argnums=(2,))
+        compiled = fn.lower(
+            params, cfg, pools, (None, None),
+            jax.ShapeDtypeStruct((1, bucket), jnp.int32), i32, i32,
+            (jax.ShapeDtypeStruct((self.MAXP,), jnp.int32),
+             jax.ShapeDtypeStruct((self.RING,), jnp.int32)),
+            (run, run), run, i32, i32).compile()
+        self._holds_kernels_and_no_pool_copy(compiled, pools, 13)
+
+    @staticmethod
+    def _pool(rs, pages, hkv):
+        kv = np.zeros((pages, hkv, 16, 384), np.float32)
+        kv[..., :192] = rs.randn(pages, hkv, 16, 192)
+        kv[..., 256:] = rs.randn(pages, hkv, 16, 128)
+        return jnp.asarray(kv, jnp.bfloat16)
+
+    @pytest.mark.parametrize("window", [None, 128], ids=["full", "window"])
+    def test_decode_kernel_matches_its_twin(self, window):
+        """Rows of no cached token, inside one block of 512 cached
+        tokens, over several blocks and (the ring) past several wraps."""
+        from bigdl_tpu.llm.kernels import hybrid_attention as ha
+        rs = np.random.RandomState(0)
+        b, hq = 5, 64
+        hkv, cols = (4, 640) if window is None else (8, self.RING)
+        kv = self._pool(rs, 1 + b * cols, hkv)
+        bt = jnp.asarray(1 + np.arange(b * cols).reshape(b, cols), jnp.int32)
+        lens = jnp.asarray([0, 7, 500, 2049, 10000], jnp.int32)
+        q = np.zeros((b, hq, 256), np.float32)
+        q[..., :192] = rs.randn(b, hq, 192)
+        q = jnp.asarray(q, jnp.bfloat16)
+        with jax.default_matmul_precision("highest"):
+            want = ha.attention_decode_reference_stats(
+                q, kv, bt, lens, scale=192 ** -0.5, window=window)
+        got = ha.attention_decode_stats(q, kv, bt, lens, page_size=16,
+                                        scale=192 ** -0.5, window=window)
+        # the softmax weights are rounded to bfloat16 in front of P V
+        for g, w_, tol in zip(got, want, (1e-2, 1e-3, 1e-2)):
+            w_ = np.asarray(w_)
+            np.testing.assert_allclose(np.asarray(g), w_, rtol=tol,
+                                       atol=tol * np.abs(w_).max())
+
+    @pytest.mark.parametrize("window", [None, 128], ids=["full", "window"])
+    @pytest.mark.parametrize("off,slen", [(0, 1024), (3000, 777)])
+    def test_prefill_kernel_matches_its_twin(self, window, off, slen):
+        from bigdl_tpu.llm.kernels import hybrid_attention as ha
+        rs = np.random.RandomState(1)
+        hq, tq = 64, 1024
+        hkv, cols = (4, 256) if window is None else (8, self.RING)
+        kv = self._pool(rs, 1 + cols, hkv)
+        bt = jnp.asarray(1 + np.arange(cols)[None], jnp.int32)
+
+        def padded(shape, used, width):
+            a = np.zeros(shape + (width,), np.float32)
+            a[..., :used] = rs.randn(*shape, used)
+            return jnp.asarray(a, jnp.bfloat16)
+        q = padded((1, tq, hq), 192, 256)
+        ks, vs = padded((1, tq, hkv), 192, 256), padded((1, tq, hkv), 128,
+                                                        128)
+        sink = None if window is None else \
+            jnp.asarray(4 + rs.randn(hq), jnp.float32)
+        args = (q, ks, vs, kv, bt, jnp.asarray([off], jnp.int32),
+                jnp.asarray([slen], jnp.int32), sink)
+        with jax.default_matmul_precision("highest"):
+            want = ha.prefill_attention_reference(
+                *args, scale=192 ** -0.5, window=window)
+        got = ha.prefill_attention(*args, page_size=16, scale=192 ** -0.5,
+                                   window=window)
+        want = np.asarray(want, np.float32)[0, :slen]
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32)[0, :slen], want, rtol=2e-2,
+            atol=2e-2 * np.abs(want).max())
+
+    @pytest.mark.parametrize("t", [24, 1024])
+    def test_held_experts_product_matches_its_twin(self, t):
+        """16 of 256 experts held: decode tiles and prefill tiles."""
+        from bigdl_tpu.llm.kernels import moe
+        rs = np.random.RandomState(t)
+        k, n, held, hid, width = 8, 256, 16, 4096, 2048
+        x = jnp.asarray(rs.randn(t, hid), jnp.bfloat16)
+        # half of every token's choices among the held, so that they work
+        groups = np.stack([np.concatenate([
+            rs.permutation(held)[:k // 2],
+            held + rs.permutation(n - held)[:k // 2]]) for _ in range(t)])
+        groups = jnp.asarray(groups, jnp.int32)
+        w = jnp.asarray(rs.rand(t, k), jnp.float32)
+        live = jnp.asarray(rs.rand(t) > 0.1)
+        key = jax.random.PRNGKey(t)
+        wgu = (jax.random.normal(key, (held, hid, 2 * width), jnp.float32)
+               / 64).astype(jnp.bfloat16)
+        wd = (jax.random.normal(key, (held, width, hid), jnp.float32)
+              / 45).astype(jnp.bfloat16)
+        got, sizes = jax.jit(lambda *a: moe.grouped_ffn(
+            *a, 0, held, held=(0, held)))(x, groups, w, live, wgu, wd)
+        table = np.zeros((t, n), np.float32)
+        np.put_along_axis(table, np.asarray(groups), np.asarray(w), 1)
+        table = jnp.asarray(table * np.asarray(live)[:, None])
+
+        @jax.jit
+        def twin(x, table, wgu, wd):
+            def one(y, e):
+                gu = x.astype(jnp.float32) @ wgu[e].astype(jnp.float32)
+                act = (jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
+                    .astype(jnp.bfloat16).astype(jnp.float32)
+                return y + table[:, e, None] * (
+                    act @ wd[e].astype(jnp.float32)), None
+            with jax.default_matmul_precision("highest"):
+                return jax.lax.scan(one, jnp.zeros((t, hid), jnp.float32),
+                                    jnp.arange(held))[0]
+        want = np.asarray(twin(x, table, wgu, wd))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-2,
+                                   atol=2e-2 * np.abs(want).max())
+        assert int(sizes.sum()) == int(live.sum()) * k // 2
