@@ -11,13 +11,13 @@ kernels; on TPU the design is rebuilt for Mosaic:
   proportional to tokens in flight, not ``B × max_seq_len`` (the r3
   slot-static cache's bound — VERDICT r3 missing #1).
 - the decode kernel runs one grid step per ``(batch row, kv head,
-  page block)``; each step **async-copies ``ppb = 128 // page_size``
-  pages** from HBM into one contiguous VMEM buffer, so the score tile is
-  ``(G, 128)`` — full lane width, no sub-128 relayouts (the same reason
-  the int4 kernel stores k-major: every compute shape is lane-aligned).
-  Pages are fetched by physical id via scalar-prefetched block tables;
-  only blocks below the row's length are copied at all, so HBM traffic
-  scales with actual context, not the padded maximum.
+  page block)`` and **async-copies ``ppb = 128 // page_size`` pages** by
+  physical id (scalar-prefetched block tables) into one VMEM buffer, so
+  the score tile is ``(G, 128)``, full lane width; blocks past a row's
+  length are not copied. The latent kernel (end of the file) has one
+  grid step a ROW and walks the row's live blocks of 512 tokens itself,
+  fetching the next block, or the next row's first, into a second
+  buffer slot while it scores this one: no grid step past a length.
 - online softmax (flash-style running max/sum) accumulates across page
   blocks in VMEM scratch; GQA query groups ride the sublane dim padded
   to 8 (``Gp``).
@@ -662,73 +662,124 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
 
 # ---------------------------------------------------------------------------
 # Latent (MLA) cache: one row a token, whose first columns are the value
+#
+# One call at Kanana's cell's shapes (q (32, 32, 640) float32, 16,385 pages
+# of 16 x 640 bf16, table (32, 512), 24 live rows of 0.3-7.8k tokens, 61,076
+# in all = 130 blocks of 512; 85.9 us at 819 GB/s and 576 numbers a row),
+# by the slope of a loop of calls (tools/exp_latent_body.py; chip runs, PR 32):
+#   (a) PR 31's kernel: grid (32, 16), a block's 32 page DMAs started
+#       and awaited inside its grid step                         298.0 us
+#   (b) the same, every length zero: 512 empty grid steps         25.5
+#   (c) (a)'s DMAs with the arithmetic taken out                 196.7
+#   (d) (a)'s arithmetic on a resident buffer, no DMA            117.4
+#   the walk below (one grid step a row, two slots)              160.9
+#     its DMAs alone | its arithmetic alone               123.7 | 95.4
+#     no next row's first block fetched ahead                    174.9
+#     blocks of 256 | 768 | 1,024 tokens          220.3 | 153.6 | 146.5
+#     a block's code written once a slot (static indices)        152.4
+#     the fetch ahead started after this block's wait            193.8
+#     only a last block's live pages: a loop of their count |
+#       static groups of 8                                248.4 | 180.0
+# (c) > (d): a grid step was bound by starting and awaiting its 32 copies,
+# not by its products, and the empty steps were a twelfth. The walk runs
+# at 1.2 us a block where its copies alone take 0.95 (690 GB/s) and its
+# arithmetic 0.73: what is left is the 32 descriptors a block, started
+# before the wait and so not under the arithmetic. Larger blocks halve
+# that and read more past a row's end; 512 is kept (at 28 rows of 1.4k
+# tokens 1,024 gains 2 %, not 9).
 # ---------------------------------------------------------------------------
 
 def _latent_decode_kernel(len_ref, bt_ref, q_ref, kv_hbm, o_ref, mo_ref,
-                          lo_ref, buf, sem, acc_ref, m_ref, l_ref, *,
-                          page: int, ppb: int, pages_max: int, dv: int,
-                          scale: float):
-    """One (batch row b, block of ``ppb`` pages) step of absorbed-form
-    multi-query attention: every head scores the same ``(ppb·page, W)``
-    rows, and the value is the first ``dv`` columns of the rows just
-    read, so a page is fetched once. q_ref (1, Hp, W) VMEM; kv_hbm
-    (P, 1, page, W) in HBM; acc (Hp, dv), m/l (Hp, LANE) scratch."""
+                          lo_ref, buf, sem, walked, *, page: int, ppb: int,
+                          pages_max: int, dv: int, scale: float):
+    """One batch row ``b`` of absorbed-form multi-query attention: every
+    head scores the same ``(ppb·page, W)`` rows, and the value is the
+    first ``dv`` columns of the rows just read, so a page is fetched
+    once. The row's live blocks, ``ceil(len / (ppb·page))`` of them, are
+    walked here and not by the grid: block ``j`` is scored out of one
+    slot of ``buf`` while block ``j + 1``, or after the row's last block
+    the first block of the next row that has any, is fetched into the
+    other. ``walked[0]`` counts the blocks of the rows before this one:
+    its parity is the slot this row starts in, and a row finds its first
+    block on the way unless it is the first to have one. Every block
+    started is awaited once, by the row that scores it. q_ref (1, Hp, W)
+    VMEM; kv_hbm (P, 1, page, W) in HBM; buf (2, ppb, page, W); the
+    running sums live in the row's output blocks o (1, Hp, dv), m and l
+    (1, Hp, LANE)."""
     b = pl.program_id(0)
-    blk = pl.program_id(1)
-    nblk = pl.num_programs(1)
+    rows = pl.num_programs(0)
+    n = ppb * page
+    hp, w = q_ref.shape[1], q_ref.shape[2]
 
-    @pl.when(blk == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    @pl.when(b == 0)
+    def _first_row():
+        walked[0] = 0
 
     seq = len_ref[b]
-    base_tok = blk * (ppb * page)
+    nblk = (seq + (n - 1)) // n
+    first = walked[0]
 
-    @pl.when(base_tok < seq)
-    def _compute():
-        copies = []
+    def fetch(row, blk, slot):
         for i in range(ppb):                    # static unroll
             # a table shorter than a whole block: the pages past its
             # end are past every length too, any valid page will do
             col = jnp.minimum(blk * ppb + i, pages_max - 1)
-            pid = bt_ref[b * pages_max + col]
-            c = pltpu.make_async_copy(kv_hbm.at[pid, 0], buf.at[i], sem)
-            c.start()
-            copies.append(c)
-        for c in copies:
-            c.wait()
-        hp, w = q_ref.shape[1], q_ref.shape[2]
-        n = ppb * page
+            pid = bt_ref[row * pages_max + col]
+            pltpu.make_async_copy(kv_hbm.at[pid, 0], buf.at[slot, i],
+                                  sem.at[slot]).start()
+
+    o_ref[0] = jnp.zeros((hp, dv), jnp.float32)
+    mo_ref[0] = jnp.full((hp, LANE), -1e30, jnp.float32)
+    lo_ref[0] = jnp.zeros((hp, LANE), jnp.float32)
+
+    @pl.when((nblk > 0) & (first == 0))
+    def _nobody_fetched_it():
+        fetch(b, 0, 0)
+
+    # the next row with anything cached (``rows`` where there is none)
+    nxt = jax.lax.while_loop(
+        lambda r: (r < rows) & (len_ref[jnp.minimum(r, rows - 1)] == 0),
+        lambda r: r + 1, b + 1)
+
+    def block(j, carry):
+        slot = (first + j) % 2
+        more = j + 1 < nblk
+
+        @pl.when(more | (nxt < rows))
+        def _fetch_ahead():
+            fetch(jnp.where(more, b, nxt), jnp.where(more, j + 1, 0),
+                  1 - slot)
+
+        for i in range(ppb):
+            # a wait reads the size of its destination and the semaphore
+            pltpu.make_async_copy(kv_hbm.at[0, 0], buf.at[slot, i],
+                                  sem.at[slot]).wait()
         q = q_ref[0].astype(jnp.float32)                   # (Hp, W)
-        kv = buf[...].reshape(n, w).astype(jnp.float32)
+        kv = buf[slot].reshape(n, w).astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # (Hp, n)
-        pos = base_tok + jax.lax.broadcasted_iota(jnp.int32, (hp, n), 1)
+        pos = j * n + jax.lax.broadcasted_iota(jnp.int32, (hp, n), 1)
         s = jnp.where(pos < seq, s, -1e30)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
+        m_prev = mo_ref[0]
+        l_prev = lo_ref[0]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
         alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
         p_ = jnp.exp(s - m_new[:, :1])
         l_new = alpha * l_prev[:, :1] + jnp.sum(p_, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        o_ref[0] = o_ref[0] * alpha + jax.lax.dot_general(
             p_, kv[:, :dv], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # (Hp, dv)
-        m_ref[...] = m_new
-        l_ref[...] = jnp.broadcast_to(l_new, l_prev.shape)
+        mo_ref[0] = m_new
+        lo_ref[0] = jnp.broadcast_to(l_new, l_prev.shape)
+        return carry
 
-    @pl.when(blk == nblk - 1)
-    def _finish():
-        o_ref[0] = acc_ref[...]
-        mo_ref[0] = m_ref[...]
-        lo_ref[0] = l_ref[...]
+    jax.lax.fori_loop(0, nblk, block, 0)
+    walked[0] = first + nblk
 
 
-# cached tokens a grid step of the latent kernel fetches and scores
+# cached tokens a block of the latent kernel's walk fetches and scores
 LATENT_BLOCK_TOKENS = 512
 
 
@@ -755,32 +806,31 @@ def latent_attention_decode_stats(q, kv_pages, block_tables, lengths, *,
             f"of {LANE}")
     pages_max = block_tables.shape[1]
     ppb = max(1, min(LATENT_BLOCK_TOKENS // page, pages_max))
-    nblk = -(-pages_max // ppb)
     hp = -(-h // 8) * 8
     if hp != h:
         q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
-    row = lambda b_, k_, *_: (b_, 0, 0)
+    row = lambda b_, *_: (b_, 0, 0)
     acc, m, l = pl.pallas_call(
         functools.partial(_latent_decode_kernel, page=page, ppb=ppb,
                           pages_max=pages_max, dv=dv, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, nblk),
+            num_scalar_prefetch=2, grid=(b,),
             in_specs=[pl.BlockSpec((1, hp, w), row),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec((1, hp, dv), row),
                        pl.BlockSpec((1, hp, LANE), row),
                        pl.BlockSpec((1, hp, LANE), row)],
             scratch_shapes=[
-                pltpu.VMEM((ppb, page, w), kv_pages.dtype),
-                pltpu.SemaphoreType.DMA,
-                pltpu.VMEM((hp, dv), jnp.float32),
-                pltpu.VMEM((hp, LANE), jnp.float32),
-                pltpu.VMEM((hp, LANE), jnp.float32)]),
+                pltpu.VMEM((2, ppb, page, w), kv_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)]),
         out_shape=[jax.ShapeDtypeStruct((b, hp, dv), jnp.float32),
                    jax.ShapeDtypeStruct((b, hp, LANE), jnp.float32),
                    jax.ShapeDtypeStruct((b, hp, LANE), jnp.float32)],
+        # a row hands its successor a block on the way and the slot to
+        # find it in: the rows run in order on one core
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lengths.astype(jnp.int32),
       block_tables.reshape(-1).astype(jnp.int32), q, kv_pages)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 import deepseek_reference as ref
 from bigdl_tpu.llm.kernels import moe
@@ -158,31 +159,59 @@ def test_absorbed_attention_is_expanded_attention(params32, t):
                                np.asarray(expanded), rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("maxp,longest", [(12, 150), (80, 1150)],
-                         ids=["one_block", "three_blocks"])
-def test_latent_kernel_matches_its_twin(maxp, longest):
+def _ragged_lengths(rows=32, top=80 * 16):
+    """Ragged lengths up to the table's last token, empty rows at both
+    ends and in the middle."""
+    lens = np.random.RandomState(5).randint(1, top + 1, rows)
+    lens[[0, 9, 10, rows - 1]] = 0
+    lens[3] = top
+    return lens.tolist()
+
+
+@pytest.mark.parametrize("maxp,lens", [
+    (12, [0, 37, 150]), (80, [0, 37, 1150]),
+    # a length on a block's edge, and one token past it
+    (80, [512, 1024, 513]),
+    # an odd and an even count of live blocks: a row ends in either slot,
+    # and the next row starts in the other
+    (80, [1, 600, 700, 1100, 300, 1280]),
+    # nothing cached between two rows that have, and nowhere
+    (80, [700, 0, 0, 900]), (80, [0, 0, 0]),
+    (80, _ragged_lengths())],
+    ids=["one_block", "three_blocks", "block_edges", "both_slots",
+         "empty_between", "all_empty", "ragged_32_rows"])
+def test_latent_kernel_matches_its_twin(maxp, lens):
     """Contexts inside one block of ``LATENT_BLOCK_TOKENS`` cached
-    tokens, and over three (the running maximum and sum carried from
-    block to block)."""
+    tokens (a table shorter than a block), and over three (the running
+    maximum and sum carried from block to block); the walk's edges: a
+    row hands the next its first block and its slot, a row with nothing
+    cached starts and awaits no copy."""
     rs = np.random.RandomState(0)
-    b, h, w, dv, page, pages = 3, 5, 256, 128, 16, 100
-    assert longest <= maxp * page
-    assert (longest > 2 * pa.LATENT_BLOCK_TOKENS) == (maxp > 12)
+    b, h, w, dv, page, pages = len(lens), 5, 256, 128, 16, 100
+    assert max(lens) <= maxp * page
+    assert (maxp * page < pa.LATENT_BLOCK_TOKENS) == (maxp == 12)
     q = jnp.asarray(rs.randn(b, h, w), jnp.float32)
     pool = jnp.asarray(rs.randn(pages, 1, page, w), jnp.bfloat16)
     bt = jnp.asarray(rs.randint(1, pages, (b, maxp)), jnp.int32)
-    lens = jnp.asarray([0, 37, longest], jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     want = pa.latent_attention_reference_stats(q, pool, bt, lens, dv=dv,
                                                scale=0.07)
+    # the TPU interpreter: a copy lands when it is awaited and a buffer
+    # nobody wrote reads NaN, so a block scored before its wait, or a
+    # slot scored that no copy filled, cannot agree with the twin
     got = pa.latent_attention_decode_stats(
         q, pool, bt, lens, page_size=page, dv=dv, scale=0.07,
-        interpret=True)
+        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                        uninitialized_memory="nan"))
     for g, w_ in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
                                    rtol=1e-4, atol=1e-4)
     assert got[0].shape == (b, h, dv)
     # a row with nothing cached is the identity of the flash combine
-    assert float(got[1][0, 0]) < -9e29 and float(got[2][0, 0]) == 0.0
+    empty = np.asarray(lens) == 0
+    assert not np.asarray(got[0])[empty].any()
+    assert (np.asarray(got[1])[empty] < -9e29).all()
+    assert not np.asarray(got[2])[empty].any()
 
 
 # (4) the router ---------------------------------------------------------------

@@ -576,18 +576,33 @@ class TestLatentFamilyOnChip:
             jax.ShapeDtypeStruct((bucket,), jnp.int32), i32, i32).compile()
         self._holds_kernels_and_no_pool_copy(compiled, pool, 1)
 
-    @pytest.mark.parametrize("longest", [500, 2000],
-                             ids=["one_block", "four_blocks"])
-    def test_latent_kernel_matches_its_twin(self, longest):
+    @staticmethod
+    def _cell_lengths():
+        """32 rows as the cell's engine holds them: seven with nothing
+        cached, scattered, the others up to the table's last token,
+        block edges among them."""
+        rs = np.random.RandomState(32)
+        lens = rs.randint(1, 8192, 32)
+        lens[[0, 5, 6, 17, 30, 31, 12]] = 0
+        lens[[1, 2, 3, 4]] = [8191, 512, 513, 1024]
+        return lens.tolist()
+
+    @pytest.mark.parametrize("maxp,pages,lens", [
+        (128, 300, [0, 1, 517, 500]), (128, 300, [0, 1, 517, 2000]),
+        (512, 1 + 32 * 512, None)],
+        ids=["one_block", "four_blocks", "cell"])
+    def test_latent_kernel_matches_its_twin(self, maxp, pages, lens):
         """Contexts inside one block of ``LATENT_BLOCK_TOKENS`` (512)
-        cached tokens, and over four."""
+        cached tokens, and over four; the cell's own shape: 32 rows,
+        a table of 512 pages, lengths 0 to 8,191."""
         from bigdl_tpu.llm.kernels import paged_attention as pa
         rs = np.random.RandomState(0)
-        b, h, w, dv, pages = 4, 32, 640, 512, 300
+        lens = lens or self._cell_lengths()
+        b, h, w, dv = len(lens), 32, 640, 512
         q = jnp.asarray(rs.randn(b, h, w), jnp.float32)
         pool = jnp.asarray(rs.randn(pages, 1, self.PAGE, w), jnp.bfloat16)
-        bt = jnp.asarray(rs.randint(1, pages, (b, 128)), jnp.int32)
-        lens = jnp.asarray([0, 1, 517, longest], jnp.int32)
+        bt = jnp.asarray(rs.randint(1, pages, (b, maxp)), jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
         scale = 192 ** -0.5
         with jax.default_matmul_precision("highest"):
             want = pa.latent_attention_reference_stats(
