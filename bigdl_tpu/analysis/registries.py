@@ -379,6 +379,8 @@ METRICS.update({
         "Engine passes that carried a speculative verify chunk",
     "bigdl_llm_spec_proposed_tokens_total":
         "Draft tokens dispatched to speculative verify",
+    "bigdl_llm_state_slots_in_use":
+        "Engine slots seated in a state class (families whose cache is a fixed state a slot)",
     "bigdl_llm_ttft_seconds":
         "Engine time to first token (submit to first drained token), mergeable quantile sketch",
     "bigdl_llm_watchdog_trips_total":

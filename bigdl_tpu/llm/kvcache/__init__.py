@@ -84,8 +84,12 @@ class KVCacheManager:
     threads for shed diagnostics."""
 
     def __init__(self, num_pages: int, page_size: int,
-                 enabled: bool = False):
+                 enabled: bool = False, keeps_tokens: bool = True):
         self.pool = PagePool(num_pages, page_size)
+        # False for a family with no class that keeps every token (its
+        # cache is state a slot holds): no request needs a page, so
+        # every admission fits and nothing is ever granted
+        self.keeps_tokens = keeps_tokens
         self.page = page_size
         self.enabled = bool(enabled)
         self.index: Optional[RadixIndex] = (
@@ -252,6 +256,8 @@ class KVCacheManager:
         page from the first non-fully-shared one through the last
         decode token. The COW fork target (a mid-page match's page) is
         inside this range, so forks are pre-reserved too."""
+        if not self.keeps_tokens:
+            return 0
         full = _ceil_div(prompt_len + max_new, self.page)
         return full - matched_len // self.page
 

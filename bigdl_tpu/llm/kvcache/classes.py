@@ -22,6 +22,17 @@ not pages freed as they leave the window: the table stays ``ring``
 columns wide instead of ``max_seq_len / page``, nothing is granted or
 freed per step once it is full, and the kernels rebuild positions from
 the length, :func:`kernels.hybrid_attention.ring_positions`.)
+
+A third kind keeps **no token at all** (ISSUE 33; docs/KVCACHE.md "State
+classes"): a :class:`StateClass` is state that an engine *slot* holds,
+the same size at position 10 and at position 30,000, rewritten whole at
+every token by the family's own kernels. It has no pages and no block
+table: the engine builds ``1 + max_batch`` state rows a layer (row 0
+the trash row), seats a request in the row of its slot
+(:class:`StateLedger`), grants nothing as it decodes, and the prefill
+program that first writes a seated row takes what it holds as zero. A
+family may declare state classes beside page classes, or, as a
+retention model does, nothing else.
 """
 
 from __future__ import annotations
@@ -56,21 +67,60 @@ class PageClass:
                 else jnp.zeros(shape + (self.v_width,), dtype))
 
 
-def page_classes_of(fam_mod, cfg) -> List[PageClass]:
+@dataclasses.dataclass(frozen=True)
+class StateClass:
+    """State a slot holds, whatever its request's length: ``layers``
+    keep, each for ``heads`` heads, a matrix of ``width`` rows of
+    ``rows`` numbers (held transposed, the long axis last) and a vector
+    of ``rows``, in ``dtype``."""
+    name: str
+    layers: int
+    heads: int
+    rows: int
+    width: int
+    dtype: str = "float32"
+
+    def arrays(self, max_batch: int) -> Tuple:
+        """The class's two arrays for ``max_batch`` slots and the trash
+        row: ``(layers, 1 + max_batch, heads, width, rows)`` and
+        ``(layers, 1 + max_batch, heads, rows)``."""
+        import jax.numpy as jnp
+        shape = (self.layers, 1 + max_batch, self.heads)
+        return (jnp.zeros(shape + (self.width, self.rows), self.dtype),
+                jnp.zeros(shape + (self.rows,), self.dtype))
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one seated slot holds."""
+        return self.layers * self.heads * (self.width + 1) * self.rows \
+            * np.dtype(self.dtype).itemsize
+
+
+def every_token_class(classes) -> Optional[PageClass]:
+    """The class that keeps every token, if the family has one."""
+    first = classes[0]
+    return first if isinstance(first, PageClass) and first.keeps is None \
+        else None
+
+
+def page_classes_of(fam_mod, cfg) -> list:
     """What the family declares, or the one class of a family that does
-    not: a K and a V pool of per-head rows for every layer. The class
-    that keeps every token comes first and there is exactly one."""
+    not: a K and a V pool of per-head rows for every layer. At most one
+    class keeps every token, and it comes first; then the classes that
+    keep a window; then the state classes."""
     declare = getattr(fam_mod, "page_classes", None)
     if declare is None:
         return [PageClass("kv", cfg.num_hidden_layers,
                           cfg.num_key_value_heads, cfg.head_dim,
                           cfg.head_dim)]
     classes = list(declare(cfg))
-    if [c.keeps for c in classes].count(None) != 1 \
-            or classes[0].keeps is not None:
+    kinds = [2 if isinstance(c, StateClass) else int(c.keeps is not None)
+             for c in classes]
+    if not classes or kinds.count(0) > 1 or kinds != sorted(kinds):
         raise ValueError(
-            f"{fam_mod.__name__}.page_classes: exactly one class keeps "
-            f"every token and it comes first; got {classes}")
+            f"{fam_mod.__name__}.page_classes: at most one class keeps "
+            "every token and it comes first, then the window classes, "
+            f"then the state classes; got {classes}")
     return classes
 
 
@@ -138,3 +188,43 @@ class RingLedger:
         cols = (positions // self.page) % self.ring
         return np.where(positions < upto, self.bt[slot, cols],
                         0).astype(np.int32)
+
+
+class StateLedger:
+    """Host bookkeeping of one state class: which slot is seated in its
+    state row (slot ``i`` holds row ``1 + i``; row 0 is the trash row),
+    how often a row was seated and so taken as zero, and the bytes
+    held. There is nothing to run out of: a free slot has its row."""
+
+    def __init__(self, cls: StateClass, max_batch: int):
+        self.cls = cls
+        self.seated = [False] * max_batch
+        # how often each slot was seated: from the second time on its
+        # row held another request's state when it was taken as zero
+        self.seatings = [0] * max_batch
+        # the table the programs take, and it never changes: a slot's
+        # state row (a slot that sits a step out is sent to row 0 by
+        # the sampled step's mask, as a page table's rows are)
+        self.rows = 1 + np.arange(max_batch, dtype=np.int32)[:, None]
+
+    def seat(self, slot: int) -> int:
+        """Seat a request: its state row, which the prefill program
+        that writes it first takes as zero."""
+        if self.seated[slot]:
+            raise ValueError(f"slot {slot} is seated already")
+        self.seated[slot] = True
+        self.seatings[slot] += 1
+        return int(self.rows[slot, 0])
+
+    def release(self, slot: int) -> int:
+        """The slot's row goes back (what it holds stays until the next
+        occupant's prefill takes it as zero); returns the rows freed."""
+        was = self.seated[slot]
+        self.seated[slot] = False
+        return int(was)
+
+    def slots_in_use(self) -> int:
+        return sum(self.seated)
+
+    def bytes_held(self) -> int:
+        return self.slots_in_use() * self.cls.slot_bytes

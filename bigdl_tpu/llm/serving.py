@@ -48,7 +48,9 @@ from bigdl_tpu import observability as obs
 from bigdl_tpu import reliability
 from bigdl_tpu.llm.kernels.sampling import make_sampled_step
 from bigdl_tpu.llm.kvcache import KVCacheManager
-from bigdl_tpu.llm.kvcache.classes import RingLedger, page_classes_of
+from bigdl_tpu.llm.kvcache.classes import (PageClass, RingLedger, StateClass,
+                                           StateLedger, every_token_class,
+                                           page_classes_of)
 from bigdl_tpu.llm.kvcache.prefill import make_mixed_step, make_spec_step
 from bigdl_tpu.observability import flight
 from bigdl_tpu.observability import request_context as rc
@@ -263,7 +265,7 @@ def _sync_barrier(*arrays):
     the barrier in one transfer (kernels.sampling.fence_token).
     """
     # a one-pool family has no second pool (None: no leaf); a family
-    # with several page classes hands a tuple of pools
+    # with several classes hands a tuple of pools
     arrays = jax.tree_util.tree_leaves(arrays)
     jax.block_until_ready(arrays)
     # (the first element by index: an eager ``ravel()`` of a page pool
@@ -365,7 +367,10 @@ class LLMServer:
     paged programs (``paged_decode_step``, ``paged_prefill_ragged``:
     docs/KVCACHE.md "What a family gives the engine"), quantized or
     dense. ``max_batch`` fixes the compiled batch width;
-    ``max_seq_len`` the per-request token bound.
+    ``max_seq_len`` the per-request token bound. ``num_pages`` sizes
+    the pool of the class that keeps every token; a family with no such
+    class (its cache is state a slot holds: docs/KVCACHE.md "State
+    classes") ignores it and admits by slot alone.
 
     **Paged KV cache.** KV lives in a page pool
     ``(L, num_pages, H_kv, page_size, D)``; each request owns
@@ -513,11 +518,16 @@ class LLMServer:
         self._classes = page_classes_of(fam_mod, cfg)
         self._fam_step_stats = tuple(getattr(fam_mod, "STEP_STATS", ()))
         self._fam_host_stats = getattr(fam_mod, "host_step_stats", None)
+        self._fam_prefill_stats = getattr(fam_mod, "host_prefill_stats",
+                                          None)
         self.step_counters: Dict[str, int] = dict.fromkeys(
             self._fam_step_stats, 0)
         if self._fam_host_stats is not None:
             self.step_counters.update(dict.fromkeys(
                 self._fam_host_stats(self.cfg, np.zeros(0, np.int32)), 0))
+        if self._fam_prefill_stats is not None:
+            self.step_counters.update(dict.fromkeys(
+                self._fam_prefill_stats(self.cfg, 0, page_size), 0))
         self.max_batch = max_batch
         self.max_seq_len = min(max_seq_len, cfg.max_position_embeddings)
         self.eos_token_id = eos_token_id
@@ -624,38 +634,58 @@ class LLMServer:
         self._page = page_size
         ppb = LANE // page_size
         cap = -(-self.max_seq_len // page_size)
+        # one pool pair and one table a class. The class that keeps
+        # every token is the one ``num_pages``, ``_kv``, ``_bt`` and
+        # ``_slot_pages`` are about; a class that keeps a window is a
+        # ring (kvcache.classes.RingLedger) with a table and a ledger
+        # of its own; a state class (kvcache.classes.StateLedger) is
+        # two arrays of one row a slot and has no table to keep: slot
+        # ``i`` holds row ``1 + i``, always. A one-class family's
+        # programs get the pools and the table as arrays, as they
+        # always have; a family of several gets tuples, one entry a
+        # class. A family with no every-token class has no pages to
+        # hold: ``num_pages`` is ignored, the page ledger admits and
+        # grants nothing, and a request needs a free slot and no more.
+        self._every = every_token_class(self._classes)
+        if self._every is None:
+            cap, num_pages = ppb, 2
         self._pages_cap = -(-cap // ppb) * ppb    # kernel block mult
         # page 0 is the trash page: inactive rows and prefill padding
         # write there; no live sequence ever owns it
         self._num_pages = num_pages or (1 + max_batch * cap)
-        # one pool pair and one block table a page class. The class
-        # that keeps every token is the one ``num_pages``, ``_kv``,
-        # ``_bt`` and ``_slot_pages`` are about; a class that keeps a
-        # window is a ring (kvcache.classes.RingLedger) with a table
-        # and a ledger of its own. A one-class family's programs get
-        # the pools and the table as arrays, as they always have; a
-        # family of several gets tuples, one entry a class.
         self._rings = [RingLedger(c, page_size, max_batch)
-                       for c in self._classes if c.keeps is not None]
+                       for c in self._classes
+                       if isinstance(c, PageClass) and c.keeps is not None]
+        self._states = [StateLedger(c, max_batch) for c in self._classes
+                        if isinstance(c, StateClass)]
         pools = [c.pools(n, page_size, model.cache_dtype)
                  for c, n in zip(self._classes,
-                                 [self._num_pages]
+                                 self._if_every(self._num_pages)
                                  + [r.num_pages for r in self._rings])]
-        if self._rings:
+        pools += [s.cls.arrays(max_batch) for s in self._states]
+        self._multi = len(self._classes) > 1
+        if self._multi:
             self._k_pages, self._v_pages = (tuple(p) for p in zip(*pools))
         else:
             self._k_pages, self._v_pages = pools[0]
         self._ring_bt_dev = [jnp.asarray(r.bt) for r in self._rings]
-        if self._rings:
+        self._state_bt_dev = [jnp.asarray(s.rows) for s in self._states]
+        if self._rings or self._states:
             self.step_counters.update(
                 {"decode_rows_total": 0,
                  **{r.cls.name + "_pages_held_total": 0
                     for r in self._rings}})
+        if self._states:
+            self.step_counters.update(state_slots_held_total=0,
+                                      state_slots_zeroed_total=0)
         self._class_ins = None
-        if self._v_pages is None or self._rings:
+        self._state_ins = None
+        if self._multi or self._every is None \
+                or self._every.v_width is None:
             # one pool of another row than per-head K and V (a latent
-            # cache), or several page classes: what reads or moves
-            # pages as a K/V pair of one class refuses the family
+            # cache), several classes, or state a slot holds: what
+            # reads or moves pages as a K/V pair of one class refuses
+            # the family
             def on(arg, key):
                 return arg if arg is not None else \
                     conf.get_bool(key, False)
@@ -671,10 +701,14 @@ class LLMServer:
                 ("priority preemption (bigdl.llm.priority)",
                  on(priority, "bigdl.llm.priority.enabled"))) if yes]
             if asked:
+                n = len(self._classes)
                 how = ("one latent pool and no V pool"
-                       if self._v_pages is None else
-                       f"{len(self._classes)} page classes ("
+                       if self._every is not None and not self._multi else
+                       f"{n} class{'es' * (n > 1)} ("
                        + ", ".join(c.name for c in self._classes) + ")")
+                if self._states:
+                    how += (", state a slot holds and not pages a "
+                            "position (a state class)")
                 raise NotImplementedError(
                     f"{type(model).__name__} caches {how}; "
                     f"{', '.join(asked)} assume a K pool and a V pool "
@@ -744,7 +778,8 @@ class LLMServer:
             self._spec_backoff = conf.get_float(
                 "bigdl.llm.spec.backoff", 0.5)
         self._kv = KVCacheManager(self._num_pages, page_size,
-                                  enabled=bool(kv_on))
+                                  enabled=bool(kv_on),
+                                  keeps_tokens=self._every is not None)
         # host spill tier (ISSUE 6): constructed ONLY when enabled —
         # disabled mode must be structurally absent (no arena, no
         # migration thread, no bigdl_kvtier_* series)
@@ -826,8 +861,21 @@ class LLMServer:
     def pages_in_use_by_class(self) -> Dict[str, int]:
         """:attr:`pages_in_use` under the first class's name, and what
         live requests hold of every window class."""
-        return {self._classes[0].name: self.pages_in_use,
+        first = {} if self._every is None else \
+            {self._every.name: self.pages_in_use}
+        return {**first,
                 **{r.cls.name: r.pages_in_use() for r in self._rings}}
+
+    def _if_every(self, entry) -> list:
+        """``[entry]``, the every-token class's place in a list with
+        one entry a class, or ``[]`` for a family that has no such
+        class."""
+        return [entry] if self._every is not None else []
+
+    @property
+    def state_slots_in_use(self) -> int:
+        """Slots seated in a state class (the same in each)."""
+        return self._states[0].slots_in_use() if self._states else 0
 
     def _put_ring_row(self, c: int, i: int):
         """Slot ``i``'s row of window class ``c``'s ring table, host to
@@ -838,11 +886,11 @@ class LLMServer:
         self._ring_bt_dev[c] = self._ring_bt_dev[c].at[i].set(row)
 
     def _tables(self):
-        """The block tables as the programs take them: the one table,
-        or one a page class."""
-        if not self._rings:
-            return self._bt_dev
-        return (self._bt_dev, *self._ring_bt_dev)
+        """The tables as the programs take them: the one table, or one
+        a class (a state class's names the row of every slot)."""
+        tabs = self._if_every(self._bt_dev) + self._ring_bt_dev \
+            + self._state_bt_dev
+        return tuple(tabs) if self._multi else tabs[0]
 
     # the pool moved into the kvcache subsystem (ISSUE 5); these views
     # keep the embedded-pool names the tests and tools read
@@ -1504,7 +1552,9 @@ class LLMServer:
             while self._inflight:
                 self._drain_next()
         with self._phase("llm/admit", admitted=0, prefills=0,
-                         prompt_tokens=0, bucket_tokens=0) as ph:
+                         prompt_tokens=0, bucket_tokens=0,
+                         **({"state_zeroed": 0} if self._states
+                            else {})) as ph:
             self._admit_args = ph.args
             if self._fetch_wait:
                 self._poll_fetches()
@@ -1765,7 +1815,7 @@ class LLMServer:
             # otherwise shrink the pool forever) nor leave the
             # client blocked until timeout
             self._kv.cancel(adm)
-            for r in self._rings:
+            for r in self._rings + self._states:
                 r.release(i)
             self._slot_adm[i] = None
             req.error = f"{type(e).__name__}: {e}"
@@ -1807,6 +1857,7 @@ class LLMServer:
         ins["kv_pages"].set(self.pages_in_use)
         if self._rings:
             # a family of several page classes: each class's own gauge
+            # (a state class has no pages: its gauge is below)
             if self._class_ins is None:
                 self._class_ins = obs.gauge(
                     "bigdl_llm_kv_class_pages_in_use",
@@ -1815,6 +1866,13 @@ class LLMServer:
                     labelnames=("page_class",))
             for name, n in self.pages_in_use_by_class.items():
                 self._class_ins.labels(page_class=name).set(n)
+        if self._states:
+            if self._state_ins is None:
+                self._state_ins = obs.gauge(
+                    "bigdl_llm_state_slots_in_use",
+                    "Engine slots seated in a state class (families "
+                    "whose cache is a fixed state a slot)")
+            self._state_ins.set(self.state_slots_in_use)
         # page 0 is the reserved trash page, never allocatable
         ins["kv_occupancy"].set(
             self.pages_in_use / max(self._num_pages - 1, 1))
@@ -1852,15 +1910,16 @@ class LLMServer:
         self._pin(*pins, last, self._last, self._bt_dev, self._lens_dev)
         self._last = self._last.at[i].set(last)
         T = len(self._prompt_of(req))
-        npages = len(row_pages)
-        self._bt[i, :] = 0
-        self._bt[i, :npages] = row_pages
         self._lens[i] = T
-        row = np.zeros(self._pages_cap, np.int32)
-        row[:npages] = row_pages
-        row_d = jnp.asarray(row)
-        self._pin(row_d)
-        self._bt_dev = self._bt_dev.at[i].set(row_d)
+        if self._every is not None:
+            npages = len(row_pages)
+            self._bt[i, :] = 0
+            self._bt[i, :npages] = row_pages
+            row = np.zeros(self._pages_cap, np.int32)
+            row[:npages] = row_pages
+            row_d = jnp.asarray(row)
+            self._pin(row_d)
+            self._bt_dev = self._bt_dev.at[i].set(row_d)
         self._lens_dev = self._lens_dev.at[i].set(T)
         for c in range(len(self._rings)):
             self._put_ring_row(c, i)
@@ -1907,7 +1966,8 @@ class LLMServer:
         T = len(prompt)
         off = adm.matched_len
         koff = off // page
-        own = self._kv.alloc(-(-T // page) - koff)
+        own = self._kv.alloc(-(-T // page) - koff
+                             if self._every is not None else 0)
         try:
             row_pages = list(adm.shared_pages) + own
             tail = adm.tail_src is not None
@@ -1937,16 +1997,28 @@ class LLMServer:
             bt_d = jnp.asarray(bt_row)
             phys_d = jnp.asarray(phys)
             slots_d = jnp.asarray(slots)
-            if self._rings:
+            if self._multi or self._every is None:
                 # a window class takes the prompt's pages of its ring
                 # now; every position is written there, a later one
-                # over an earlier one of the same slot, in order
+                # over an earlier one of the same slot, in order. A
+                # state class seats the request in its slot's row (the
+                # program takes what the row holds as zero) and has
+                # nothing to scatter
                 for r in self._rings:
                     r.grant(i, T)
-                bt_d = (bt_d, *(jnp.asarray(r.bt[i])
-                                for r in self._rings))
-                phys_d = (phys_d, *(jnp.asarray(
-                    r.scatter_targets(i, pos, T)) for r in self._rings))
+                seats = [s.seat(i) for s in self._states]
+                bts = self._if_every(bt_d) \
+                    + [jnp.asarray(r.bt[i]) for r in self._rings] \
+                    + [jnp.asarray(s.rows[i]) for s in self._states]
+                physs = self._if_every(phys_d) + [
+                    jnp.asarray(r.scatter_targets(i, pos, T))
+                    for r in self._rings] + [phys_d] * len(seats)
+                bt_d, phys_d = (tuple(bts), tuple(physs)) \
+                    if self._multi else (bts[0], physs[0])
+                if seats:
+                    self.step_counters["state_slots_zeroed_total"] += 1
+                    self._admit_args["state_zeroed"] = \
+                        self._admit_args.get("state_zeroed", 0) + 1
             fork_dst = jnp.asarray(own[0] if tail else 0, jnp.int32)
             fork_src = jnp.asarray(adm.tail_src if tail else 0,
                                    jnp.int32)
@@ -1955,6 +2027,10 @@ class LLMServer:
                 toks_d, len_d, off_d, bt_d, phys_d, slots_d, fork_dst,
                 fork_src)
             self._admit_args["bucket_tokens"] += bucket
+            if self._fam_prefill_stats is not None:
+                for name, n in self._fam_prefill_stats(
+                        self.cfg, t_suf, bucket).items():
+                    self.step_counters[name] += n
         except BaseException:
             self._kv.free_owned(own)
             raise  # (the rings' pages go with r.release, at the caller)
@@ -2931,7 +3007,8 @@ class LLMServer:
         self._lens[i] = 0     # a stale id could alias a reissued
         # page and the inactive row's dummy write would clobber it
         self._pin(self._bt_dev, self._lens_dev)
-        self._bt_dev = self._bt_dev.at[i].set(0)
+        if self._every is not None:
+            self._bt_dev = self._bt_dev.at[i].set(0)
         self._lens_dev = self._lens_dev.at[i].set(0)
         if self._rings:
             freed = self._freed_by_class
@@ -2941,6 +3018,11 @@ class LLMServer:
                 freed[r.cls.name] = freed.get(r.cls.name, 0) \
                     + r.release(i)
                 self._put_ring_row(c, i)        # zeros: the trash page
+        for s in self._states:
+            # the row keeps what it holds until its next occupant's
+            # prefill takes it as zero; an empty slot is never active,
+            # so the sampled step sends it to the trash row
+            s.release(i)
 
     # -- lossless preemption (ISSUE 17) --------------------------------------
     def _consider_preempt(self):
@@ -3151,15 +3233,16 @@ class LLMServer:
         # chunk prepared, a raise here must also restore the chunk's
         # alloc/charge, or the retried pass re-prepares on top of
         # orphaned pages.
+        paged = disp if self._every is not None else ()
         try:
-            boundary = sum(1 for i in disp
+            boundary = sum(1 for i in paged
                            if int(self._lens[i]) % page == 0)
             need = boundary + (sargs["n_new"] if sargs is not None
                                else 0)
             if need:
                 self._kv.ensure_free(need)
             allocs = []
-            for i in disp:
+            for i in paged:
                 pos = int(self._lens[i])
                 if pos % page == 0:
                     pid = self._kv.take_free()  # guaranteed by reserve
@@ -3241,14 +3324,17 @@ class LLMServer:
             # lens were advanced above: the step attended one fewer
             rec["host_stats"] = self._fam_host_stats(
                 self.cfg, self._lens[disp] - 1)
-        if self._rings:
-            # the allocator's own witness, a window class: the pages
-            # the step's rows hold there, and the rows
+        if self._rings or self._states:
+            # the allocator's own witness: the step's rows, the pages
+            # they hold in a window class, the slots seated in a state
+            # class (a slot a row: a slot never released reads above)
             hs = rec.setdefault("host_stats", {})
             hs["decode_rows_total"] = len(disp)
             for r in self._rings:
                 hs[r.cls.name + "_pages_held_total"] = sum(
                     len(r.owned[i]) for i in disp)
+            if self._states:
+                hs["state_slots_held_total"] = self.state_slots_in_use
         self._pending_release = []
         return self._after_dispatch(rec, t_step)
 

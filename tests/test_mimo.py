@@ -446,7 +446,7 @@ def test_ring_ledger_grants_until_the_ring_is_full_and_releases_all():
 def test_what_moves_pages_of_one_class_refuses_the_family(params32,
                                                           feature):
     with pytest.raises(NotImplementedError,
-                       match=r"2 page classes \(full, window\)"):
+                       match=r"2 classes \(full, window\)"):
         LLMServer(_model(params32), max_batch=2, max_seq_len=64,
                   page_size=PAGE, **{feature: True})
 

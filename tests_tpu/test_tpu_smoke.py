@@ -835,3 +835,132 @@ class TestHybridFamilyOnChip:
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-2,
                                    atol=2e-2 * np.abs(want).max())
         assert int(sizes.sum()) == int(live.sum()) * k // 2
+
+
+class TestRetentionFamilyOnChip:
+    """ISSUE 33: the brumby family at Brumby-14B-Base's published
+    widths, 8 of its 40 layers, over the state arrays the benchmark's
+    engine holds (20 slots and the trash row, 5.77 GB): the decode
+    program and two prefill buckets (one pass, and one that carries the
+    state from chunk to chunk inside the program) compile, hold a
+    Mosaic call a layer and make no copy shaped like the state; the two
+    retention kernels agree with their ``jnp`` twins on the chip's own
+    layout, the decode kernel leaves the rows of dead batch rows
+    alone."""
+
+    BATCH = 20
+
+    def _operands(self):
+        from bigdl_tpu.llm.models import brumby
+        cfg = brumby.BrumbyConfig(num_hidden_layers=8)
+        params = jax.eval_shape(lambda: brumby.init_params(cfg, 0))
+        (state,) = brumby.page_classes(cfg)
+        arrays = jax.eval_shape(lambda: state.arrays(self.BATCH))
+        assert arrays[0].shape == (8, 21, 8, 128, 8320)
+        assert arrays[1].shape == (8, 21, 8, 8320)
+        return brumby, cfg, params, arrays
+
+    def _holds_kernels_and_no_state_copy(self, compiled, arrays):
+        from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 8
+        state = arrays[0]
+        flat = (state.shape[0] * state.shape[1],) + state.shape[2:]
+        copies = pool_shaped_copies(text, state.shape) \
+            + pool_shaped_copies(text, flat)
+        assert not copies, copies[0][:300]
+
+    def test_decode_program(self):
+        import functools
+        from bigdl_tpu.llm.kernels.sampling import make_sampled_step
+        brumby, cfg, params, arrays = self._operands()
+        B = self.BATCH
+        fn = jax.jit(functools.partial(
+            make_sampled_step(brumby.paged_decode_step), page=16),
+            static_argnums=(1,), donate_argnums=(2, 3))
+        compiled = fn.lower(
+            params, cfg, *arrays, jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, cfg.vocab_size), jnp.float32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.random.PRNGKey(0)).compile()
+        self._holds_kernels_and_no_state_copy(compiled, arrays)
+
+    @pytest.mark.parametrize("bucket", [256, 4096])
+    def test_prefill_program(self, bucket):
+        import functools
+        brumby, cfg, params, arrays = self._operands()
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        fn = jax.jit(functools.partial(brumby.paged_prefill_ragged, page=16),
+                     static_argnums=(1,), donate_argnums=(2, 3))
+        compiled = fn.lower(
+            params, cfg, *arrays, i32(1, bucket), i32(), i32(), i32(1),
+            i32(bucket), i32(bucket), i32(), i32()).compile()
+        self._holds_kernels_and_no_state_copy(compiled, arrays)
+
+    def _inputs(self, b, rows):
+        from bigdl_tpu.llm.kernels import retention
+        ks = iter(jax.random.split(jax.random.PRNGKey(11), 8))
+        unit = lambda x: x * jax.lax.rsqrt(
+            jnp.mean(x * x, -1, keepdims=True))
+        p = retention.state_width(128)
+        return (jax.random.normal(next(ks), (rows, 8, 128, p)),
+                30 + jnp.abs(jax.random.normal(next(ks), (rows, 8, p))),
+                unit(jax.random.normal(next(ks), (b, 8, 5, 128))
+                     ).astype(jnp.bfloat16),
+                unit(jax.random.normal(next(ks), (b, 8, 128))
+                     ).astype(jnp.bfloat16),
+                jax.random.normal(next(ks), (b, 8, 128)
+                                  ).astype(jnp.bfloat16),
+                jax.nn.log_sigmoid(4 + jax.random.normal(next(ks), (b, 8))))
+
+    @staticmethod
+    def _rel(got, want):
+        got, want = (np.asarray(a, np.float64) for a in (got, want))
+        return float(np.sqrt(((got - want) ** 2).mean()
+                             / (want ** 2).mean()))
+
+    def test_decode_kernel_matches_its_twin(self):
+        from bigdl_tpu.llm.kernels import retention
+        state, z, q, k, v, g = self._inputs(6, 7)
+        live = jnp.asarray([True, False, True, True, False, True])
+        slots = jnp.where(live, 1 + jnp.arange(6), 0).astype(jnp.int32)
+        want = jax.jit(lambda *a: retention._decode_xla(*a, 1e-6))(
+            state, z, q, k, v, g, slots, live)
+        got = jax.jit(retention.retention_decode)(
+            state, z, q, k, v, g, slots, live)
+        lv = np.asarray(live)
+        held = np.asarray(slots)[lv]
+        # the read-out is a bfloat16 product, the update float32
+        assert self._rel(np.asarray(got[0])[lv],
+                         np.asarray(want[0])[lv]) < 0.006
+        assert self._rel(np.asarray(got[1])[held],
+                         np.asarray(want[1])[held]) < 1e-5
+        assert self._rel(np.asarray(got[2])[held],
+                         np.asarray(want[2])[held]) < 1e-5
+        for row in (2, 5):          # the rows of the two dead batch rows
+            assert float(jnp.abs(got[1][row] - state[row]).max()) == 0
+
+    @pytest.mark.parametrize("fresh,n_live", [(True, 1024), (False, 700)])
+    def test_prefill_kernel_matches_its_twin(self, fresh, n_live):
+        from bigdl_tpu.llm.kernels import retention
+        state, z, q, k, v, g = self._inputs(1024, 4)
+
+        def twin(st, zz):
+            qt, kt, vt, end = retention._fold_gates(q, k, v, g, n_live, 256)
+            with jax.default_matmul_precision("highest"):
+                y, s, zn = retention._chunk_xla(
+                    jnp.where(fresh, 0, st[2]), jnp.where(fresh, 0, zz[2]),
+                    qt, kt, vt, end, 128, 1e-6, 256)
+            return y.transpose(2, 0, 1, 3), s, zn
+        want = jax.jit(twin)(state, z)
+        got = jax.jit(lambda st, zz: retention.retention_prefill_chunk(
+            st, zz, q, k, v, g, jnp.int32(2), fresh, jnp.int32(n_live)))(
+            state, z)
+        assert self._rel(np.asarray(got[0])[:n_live],
+                         np.asarray(want[0])[:n_live]) < 0.008
+        assert self._rel(got[1][2], want[1]) < 0.004
+        assert self._rel(got[2][2], want[2]) < 1e-5
+        for row in (0, 1, 3):
+            assert float(jnp.abs(got[1][row] - state[row]).max()) == 0
