@@ -12,10 +12,13 @@ exactly what they lowered to):
   anyway, so the pad costs no byte it would not cost, as the latent
   pool holds 576 numbers at 640), the value in the ``dv`` = 128 columns
   after it. A page then comes in one DMA across all its KV heads, K
-  and V together (half the copies of a K pool beside a V pool: the
-  decode kernels are bound by how many they issue), and no call pads
-  or copies a pool. Queries come padded like the keys (zero columns
-  add nothing to a score).
+  and V together (half the copies of a K pool beside a V pool), and no
+  call pads or copies a pool. The decode kernels walk a row's live
+  blocks themselves, the next block in flight while this one is scored,
+  and are bound by those copies alone: a block of 32 pages (1.57 MB)
+  every 2.15 us, 733 GB/s, with the arithmetic (1.47 us) under them
+  (the table in the decode section's header). Queries come padded like
+  the keys (zero columns add nothing to a score).
 - **``scale`` is a parameter** (``head_dim ** -0.5`` of the published
   192, not of the padded 256).
 - **A window class is a ring** of ``ring_pages(window, page)`` pages a
@@ -55,7 +58,8 @@ from jax.experimental.pallas import tpu as pltpu
 from bigdl_tpu.llm.kernels.paged_attention import LANE
 from bigdl_tpu.llm.kernels.ragged_prefill import _pow2_at_least
 
-# cached tokens a grid step of the full-class decode kernel fetches
+# cached tokens a block of the full-class decode kernel's walk fetches and
+# scores (two buffer slots of a block: 3.1 MB of VMEM)
 DECODE_BLOCK_TOKENS = 512
 # accumulator rows (kv heads x query tile x group) of the prefill kernel
 _MAX_SCRATCH_ROWS = 4096
@@ -99,6 +103,15 @@ def _flash_update(s, v2d, r0, rows, acc_ref, m_ref, l_ref):
     l_ref[r0:r0 + rows] = jnp.broadcast_to(l_new, l_prev.shape)
 
 
+def _unrolled(n: int, op):
+    """``op(i)`` for ``i`` in ``range(n)``, in a kernel: traced ONCE and
+    unrolled where the kernel is lowered, to the module a Python loop
+    gives. A decode program is traced on the serving host at every
+    start, and 64 copy starts traced one by one took longer there than
+    the rest of the body."""
+    jax.lax.fori_loop(0, n, lambda i, c: (op(i), c)[1], 0, unroll=True)
+
+
 def _scores(q2d, k2d, scale):
     return jax.lax.dot_general(
         q2d.astype(k2d.dtype), k2d, (((1,), (1,)), ((), ())),
@@ -107,65 +120,135 @@ def _scores(q2d, k2d, scale):
 
 # ---------------------------------------------------------------------------
 # decode
+#
+# One call at the cell's shapes (q (32, 64, 256) bf16, the flat full pool
+# (86,000, 4, 16, 384) bf16, table (32, 2176), 14 live rows of 0.9-22.6k
+# tokens with dead rows between them, 97,993 in all = 201 blocks of 512;
+# 306.3 us at 819 GB/s and 320 numbers a row and head), by the slope of a
+# loop of calls (tools/exp_hybrid_body.py; chip runs, PR 34). In brackets
+# 18 live rows of 0.6-34.8k, 177,896 tokens = 356 blocks (556.1 us), the
+# batch of the cell's judged steps:
+#   (a) PR 31's kernel: grid (32, 68), a block's 32 page DMAs started
+#       and awaited inside its grid step               862.0 us [1,462.0]
+#   (b) the same, every length zero: 2,176 empty grid steps       80.0
+#   (c) (a)'s DMAs with the arithmetic taken out          543.0 [934.8]
+#   (d) (a)'s arithmetic on a resident buffer, no DMA     366.9 [575.9]
+#   the walk below (one grid step a row, two slots)       432.9 [755.7]
+#     its DMAs alone | its arithmetic alone       430.9 | 295.8 [752.5 | 513.5]
+#     blocks of 768 | 1,024 tokens        435.8 | 451.2 [763.5 | 768.4]
+#     no next row's first block fetched ahead                    457.7
+#     a block's code written once a slot (static indices) 433.6 [754.8]
+#     the fetch ahead started after this block's wait            531.1
+#     two KV heads' query rows streamed through every key tile   433.3
+# (c) > (d): a grid step was bound by starting and awaiting its 32 copies
+# (2.3 us a block), not by its products (1.47), and the empty steps were
+# a tenth. The walk runs at 2.15 us a block, which is what its copies
+# alone take: 1.57 MB a block at 733 GB/s, nine tenths of the chip's
+# rate; the arithmetic is under them whole, so neither the MXU's tile
+# loads (the pair variant changes nothing) nor a slot's static indices
+# are in the way. Rows are held 384 wide for 320 read and whole blocks
+# are fetched, so 83 % of the roofline is the ceiling; the walk reads
+# 70.8 % [73.6]. Larger blocks read more past a row's end: 512 is kept.
+#
+# The window class, (32, 64, 256) over (2,565, 8, 16, 384), a ring of 16
+# pages a row, the same 14 [18] live rows (11.2 [14.4] us at 819 GB/s
+# and the window's 128 rows; whole rings of 256 rows x 384 are fetched:
+# 42 % is the ceiling):
+#   (a) 76.4 [95.1]  (b) 6.3  (c) 42.2  (d) 41.3
+#   the walk 46.3 [56.2]: its DMAs alone 36.4, its arithmetic alone 39.4;
+#     no next row's ring fetched ahead 76.3; static indices 46.1 [54.1];
+#     the fetch ahead started after the wait 47.2
+# A window row is a walk of length one: all it gains is the next live
+# row's ring in flight while this one is scored, and that is two fifths
+# of the call. What is left is the ring's 16 pages fetched for the 9 a
+# window of 128 needs, and 8 KV heads of 8 query rows on the MXU.
 # ---------------------------------------------------------------------------
 
 def _decode_kernel(len_ref, bt_ref, q_ref, kv_hbm, o_ref, mo_ref, lo_ref,
-                   buf, sem, acc_ref, m_ref, l_ref, *, page: int, ppb: int,
-                   pages_max: int, hkv: int, scale: float,
-                   window: Optional[int]):
-    """One (batch row b, block of ``ppb`` pages) step, page-major: each
-    page comes across all its KV heads, keys and values, in one DMA.
-    q_ref (1, hkv, gp, Dk); kv_hbm (P, hkv, page, Dk + Dv) stays in
-    HBM; acc (hkv·gp, Dv), m/l (hkv·gp, LANE). With ``window`` the
-    table is a ring and the one block is all of it."""
+                   buf, sem, walked, *, page: int, ppb: int, pages_max: int,
+                   hkv: int, scale: float, window: Optional[int]):
+    """One batch row ``b``, page-major: a page comes across all its KV
+    heads, keys and values, in one DMA. The row's live blocks of ``ppb``
+    pages, ``ceil(len / (ppb·page))`` of them (with ``window`` the table
+    is a ring and the one block is all of it), are walked here and not
+    by the grid: block ``j`` is scored out of one slot of ``buf`` while
+    block ``j + 1``, or after the row's last block the first block of
+    the next row that has any, is fetched into the other. ``walked[0]``
+    counts the blocks of the rows before this one: its parity is the
+    slot this row starts in, and a row finds its first block on the way
+    unless it is the first to have one. Every block started is awaited
+    once, by the row that scores it. q_ref (1, hkv, gp, Dk) VMEM; kv_hbm
+    (P, hkv, page, Dk + Dv) in HBM; buf (2, ppb, hkv, page, Dk + Dv);
+    the running sums live in the row's output blocks o (1, hkv, gp, Dv),
+    m and l (1, hkv, gp, LANE)."""
     b = pl.program_id(0)
-    blk = pl.program_id(1)
-    nblk = pl.num_programs(1)
+    rows = pl.num_programs(0)
+    n = ppb * page
+    gp, dk = q_ref.shape[2], q_ref.shape[3]
 
-    @pl.when(blk == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    @pl.when(b == 0)
+    def _first_row():
+        walked[0] = 0
 
     seq = len_ref[b]
-    n = ppb * page
-    base_tok = blk * n
+    nblk = (seq + (n - 1)) // n if window is None else jnp.minimum(seq, 1)
+    first = walked[0]
 
-    @pl.when(seq > 0 if window is not None else base_tok < seq)
-    def _compute():
-        copies = []
-        for i in range(ppb):                    # static unroll
+    def fetch(row, blk, slot):
+        def start(i):
             # a table shorter than a whole block: the pages past its
             # end are past every length too, any valid page will do
             col = jnp.minimum(blk * ppb + i, pages_max - 1)
-            pid = bt_ref[b * pages_max + col]
-            c = pltpu.make_async_copy(kv_hbm.at[pid], buf.at[i], sem)
-            c.start()
-            copies.append(c)
-        for c in copies:
-            c.wait()
-        gp, dk = q_ref.shape[2], q_ref.shape[3]
+            pid = bt_ref[row * pages_max + col]
+            pltpu.make_async_copy(kv_hbm.at[pid], buf.at[slot, i],
+                                  sem.at[slot]).start()
+        _unrolled(ppb, start)
+
+    o_ref[0] = jnp.zeros(o_ref.shape[1:], jnp.float32)
+    mo_ref[0] = jnp.full(mo_ref.shape[1:], -1e30, jnp.float32)
+    lo_ref[0] = jnp.zeros(lo_ref.shape[1:], jnp.float32)
+
+    @pl.when((nblk > 0) & (first == 0))
+    def _nobody_fetched_it():
+        fetch(b, 0, 0)
+
+    # the next row with anything cached (``rows`` where there is none)
+    nxt = jax.lax.while_loop(
+        lambda r: (r < rows) & (len_ref[jnp.minimum(r, rows - 1)] == 0),
+        lambda r: r + 1, b + 1)
+
+    def block(j, carry):
+        slot = (first + j) % 2
+        more = j + 1 < nblk
+
+        # started BEFORE this block's wait: after it, the chain wait ->
+        # start -> transfer is serial (+23 % on the chip)
+        @pl.when(more | (nxt < rows))
+        def _fetch_ahead():
+            fetch(jnp.where(more, b, nxt), jnp.where(more, j + 1, 0),
+                  1 - slot)
+
+        # a wait reads the size of its destination and the semaphore
+        _unrolled(ppb, lambda i: pltpu.make_async_copy(
+            kv_hbm.at[0], buf.at[slot, i], sem.at[slot]).wait())
         idx = jax.lax.broadcasted_iota(jnp.int32, (gp, n), 1)
         if window is None:
-            pos = base_tok + idx
-            valid = pos < seq
+            valid = j * n + idx < seq
         else:
             pos = ring_positions(idx // page, idx % page, seq, page,
                                  pages_max)
             valid = (pos >= 0) & (pos < seq) & (pos > seq - window)
-        for h in range(hkv):                    # static unroll over heads
-            kv = buf[:, h].reshape(n, buf.shape[-1])
-            s = _scores(q_ref[0, h], kv[:, :dk], scale)
-            _flash_update(jnp.where(valid, s, -1e30), kv[:, dk:], h * gp,
-                          gp, acc_ref, m_ref, l_ref)
 
-    @pl.when(blk == nblk - 1)
-    def _finish():
-        gp = q_ref.shape[2]
-        o_ref[0] = acc_ref[...].reshape(hkv, gp, acc_ref.shape[-1])
-        mo_ref[0] = m_ref[...].reshape(hkv, gp, LANE)
-        lo_ref[0] = l_ref[...].reshape(hkv, gp, LANE)
+        def head(h):
+            kv = buf[slot, :, h].reshape(n, buf.shape[-1])
+            s = _scores(q_ref[0, h], kv[:, :dk], scale)
+            _flash_update(jnp.where(valid, s, -1e30), kv[:, dk:], 0, gp,
+                          o_ref.at[0, h], mo_ref.at[0, h], lo_ref.at[0, h])
+        _unrolled(hkv, head)
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+    walked[0] = first + nblk
 
 
 def _decode_stats(q, kv_pages, block_tables, lengths, *, page_size: int,
@@ -184,35 +267,34 @@ def _decode_stats(q, kv_pages, block_tables, lengths, *, page_size: int,
         ppb = max(1, min(DECODE_BLOCK_TOKENS // page, pages_max))
     else:
         ppb = pages_max                         # the whole ring, once
-    nblk = -(-pages_max // ppb)
     g = hq // hkv
     gp = max(8, -(-g // 8) * 8)
     qg = q.reshape(b, hkv, g, dk)
     if gp != g:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    row = lambda b_, k_, *_: (b_, 0, 0, 0)
+    row = lambda b_, *_: (b_, 0, 0, 0)
     acc, m, l = pl.pallas_call(
         functools.partial(_decode_kernel, page=page, ppb=ppb,
                           pages_max=pages_max, hkv=hkv, scale=scale,
                           window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, nblk),
+            num_scalar_prefetch=2, grid=(b,),
             in_specs=[pl.BlockSpec((1, hkv, gp, dk), row),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec((1, hkv, gp, dv), row),
                        pl.BlockSpec((1, hkv, gp, LANE), row),
                        pl.BlockSpec((1, hkv, gp, LANE), row)],
             scratch_shapes=[
-                pltpu.VMEM((ppb, hkv, page, width), kv_pages.dtype),
-                pltpu.SemaphoreType.DMA,
-                pltpu.VMEM((hkv * gp, dv), jnp.float32),
-                pltpu.VMEM((hkv * gp, LANE), jnp.float32),
-                pltpu.VMEM((hkv * gp, LANE), jnp.float32)]),
+                pltpu.VMEM((2, ppb, hkv, page, width), kv_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)]),
         out_shape=[jax.ShapeDtypeStruct((b, hkv, gp, dv), jnp.float32),
                    jax.ShapeDtypeStruct((b, hkv, gp, LANE), jnp.float32),
                    jax.ShapeDtypeStruct((b, hkv, gp, LANE), jnp.float32)],
+        # a row hands its successor a block on the way and the slot to
+        # find it in: the rows run in order on one core
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret, name=name,
     )(lengths.astype(jnp.int32),
       block_tables.reshape(-1).astype(jnp.int32), qg, kv_pages)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 import mimo_reference as ref
 from bigdl_tpu.llm.kernels import hybrid_attention as ha
@@ -182,29 +183,58 @@ def _pool(rs, pages, hkv, dk=128, dv=128, used_k=24, used_v=16,
     return jnp.asarray(kv, dtype)
 
 
-@pytest.mark.parametrize("hkv", [2, 4], ids=["4kv_like", "8kv_like"])
-@pytest.mark.parametrize("window", [None, 16, 40])
-def test_decode_kernel_matches_its_twin(hkv, window):
+# what a walk inside the kernel can get wrong and a grid could not: case
+# -> (cached lengths, table columns of the full class); a block of the
+# walk is 64 pages of 8 = 512 tokens
+_WALKS = {
+    "mixed": ([0, 7, 130, 131 + 128, 300], 48),
+    "first_live_row_is_not_0": ([0, 0, 600, 30, 1100], 144),
+    "dead_rows_between_and_at_the_end": ([513, 0, 0, 1025, 0, 9, 0, 0],
+                                         144),
+    "every_row_dead": ([0, 0, 0, 0], 144),
+    "one_row": ([700], 144),                    # the check's probe
+    "whole_blocks_and_a_token_more": ([512, 513, 1024, 1025], 144),
+    # 1, 2, 3, 2, 1, 3 blocks: the starting slot flips from row to row
+    "odd_then_even_block_counts": ([512, 1000, 1100, 600, 40, 1500], 208),
+    "table_shorter_than_a_block": ([39, 0, 8, 40], 5),
+}
+
+
+@pytest.mark.parametrize(
+    "hkv,window,case",
+    [(hkv, w, "mixed") for hkv in (2, 4) for w in (None, 16, 40)]
+    + [(4 if w else 2, w, case) for case in list(_WALKS)[1:]
+       for w in (None, 16)],
+    ids=lambda v: {2: "4kv_like", 4: "8kv_like"}.get(v, str(v)))
+def test_decode_kernel_matches_its_twin(hkv, window, case):
     """Rows of no cached token, one short of a page, several pages,
-    and (ring) lengths past one and several wraps."""
+    several blocks, and (ring) lengths past one and several wraps."""
     rs = np.random.RandomState(7)
-    b, hq = 5, 8
-    cols = ha.ring_pages(window, PAGE) if window else 48
+    lens, cols = _WALKS[case]
+    b, hq = len(lens), 8
+    if window:
+        cols = ha.ring_pages(window, PAGE)
     kv = _pool(rs, 1 + b * cols, hkv)
     bt = (1 + np.arange(b * cols).reshape(b, cols)).astype(np.int32)
-    lens = np.asarray([0, 7, 130, 131 + 128, 300], np.int32)
+    lens = np.asarray(lens, np.int32)
     q = np.zeros((b, hq, 128), np.float32)
     q[..., :24] = rs.randn(b, hq, 24)
     args = (jnp.asarray(q), kv, jnp.asarray(bt), jnp.asarray(lens))
     want = ha.attention_decode_reference_stats(
         *args, scale=24 ** -0.5, window=window)
-    got = ha.attention_decode_stats(*args, page_size=PAGE,
-                                    scale=24 ** -0.5, window=window,
-                                    interpret=True)
+    # the TPU interpreter: a copy lands when it is awaited and a buffer
+    # nobody wrote reads NaN, so a block scored before its wait, or a
+    # slot scored that no copy filled, cannot agree with the twin
+    got = ha.attention_decode_stats(
+        *args, page_size=PAGE, scale=24 ** -0.5, window=window,
+        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                        uninitialized_memory="nan"))
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-5, atol=2e-5)
-    assert float(want[2][0].max()) == 0.0       # nothing cached: identity
+    dead = lens == 0                            # nothing cached: identity
+    for g, identity in zip(got, (0.0, -1e30, 0.0)):
+        assert (np.asarray(g)[dead] == np.float32(identity)).all()
 
 
 @pytest.mark.parametrize("window,sink", [(None, False), (16, True),

@@ -765,6 +765,52 @@ class TestHybridFamilyOnChip:
                                        atol=tol * np.abs(w_).max())
 
     @pytest.mark.parametrize("window", [None, 128], ids=["full", "window"])
+    def test_decode_kernel_at_the_cells_shape(self, window):
+        """ISSUE 34: the walk at the shape the cell's decode program
+        calls it with: 32 rows, a table of 2,176 columns (a ring of 16),
+        14 live rows of 0.3k-34.8k tokens, dead rows before, between
+        and after them, a row of whole blocks among them. The twin is
+        asked a live row at a time (the gather of 32 whole tables in
+        float32 would not fit)."""
+        from bigdl_tpu.llm.kernels import hybrid_attention as ha
+        rs = np.random.RandomState(2)
+        lens = np.asarray(
+            [0, 0, 15546, 0, 3621, 0, 6211, 0, 0, 0, 2774, 6391, 300, 0, 0,
+             0, 0, 0, 0, 0, 0, 0, 10283, 0, 34815, 2064, 2166, 0, 512, 2712,
+             903, 5141], np.int32)
+        b, hq = self.BATCH, 64
+        hkv, cols = (4, self.MAXP) if window is None else (8, self.RING)
+        held = np.where(lens > 0, cols, 0) if window else -(-lens // 16)
+        bt = np.zeros((b, cols), np.int32)      # past a row's pages: trash
+        free = 1 + rs.permutation(int(held.sum()))
+        for r, n in enumerate(held):
+            bt[r, :n], free = free[:n], free[n:]
+        kv = self._pool(rs, 1 + int(held.sum()), hkv)
+        q = np.zeros((b, hq, 256), np.float32)
+        q[..., :192] = rs.randn(b, hq, 192)
+        q, bt = jnp.asarray(q, jnp.bfloat16), jnp.asarray(bt)
+        got = ha.attention_decode_stats(q, kv, bt, jnp.asarray(lens),
+                                        page_size=16, scale=192 ** -0.5,
+                                        window=window)
+        got = [np.asarray(g) for g in got]
+        twin = jax.jit(functools.partial(
+            ha.attention_decode_reference_stats, scale=192 ** -0.5,
+            window=window))
+        for r in range(b):
+            if not lens[r]:
+                for g, identity in zip(got, (0.0, -1e30, 0.0)):
+                    assert (g[r] == np.float32(identity)).all(), r
+                continue
+            with jax.default_matmul_precision("highest"):
+                want = twin(q[r:r + 1], kv, bt[r:r + 1],
+                            jnp.asarray(lens[r:r + 1]))
+            for g, w_, tol in zip(got, want, (1e-2, 1e-3, 1e-2)):
+                w_ = np.asarray(w_)[0]
+                np.testing.assert_allclose(
+                    g[r], w_, rtol=tol, atol=tol * np.abs(w_).max(),
+                    err_msg=f"row {r} of {lens[r]} tokens")
+
+    @pytest.mark.parametrize("window", [None, 128], ids=["full", "window"])
     @pytest.mark.parametrize("off,slen", [(0, 1024), (3000, 777)])
     def test_prefill_kernel_matches_its_twin(self, window, off, slen):
         from bigdl_tpu.llm.kernels import hybrid_attention as ha
