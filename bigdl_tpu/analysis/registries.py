@@ -518,6 +518,12 @@ SPAN_NAMES.update({
         "completion: one lossless preemption of an in-flight decode",
     "llm/prefill":
         "prompt prefill (the uncached suffix, ragged in place) on the engine",
+    "llm/prefill_dispatch":
+        "a whole-prompt prefill's jit call and nothing else",
+    "llm/prefill_finish":
+        "a prefill's epilogue: eager table updates queued behind it",
+    "llm/prefill_stage":
+        "a whole-prompt prefill's host staging, entry to the jit call",
     "llm/queue_wait":
         "request time between submit and slot admission",
     "llm/request":
@@ -528,6 +534,8 @@ SPAN_NAMES.update({
         "completion: one speculative pass (decode rows + a verify chunk)",
     "llm/watchdog_trip":
         "completion: engine watchdog declared a stall",
+    "py/gc":
+        "completion: one garbage collection of 0.5 ms or more",
     "router/failover":
         "completion: one journal resume onto a new backend",
     "router/hedge":

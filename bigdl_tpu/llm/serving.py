@@ -295,6 +295,34 @@ def compiled_steps() -> List[tuple]:
             for key, fn in list(_PAGED_STEP_CACHE.items())]
 
 
+class _EagerTimer:
+    """``with timer:`` around the eager device updates inside a pass
+    phase (``.at[].set``, ``jnp.asarray``): each is a program of its own
+    that queues behind whatever the device is doing, so the host may
+    wait in it. Timed and not spanned: a record each would overflow the
+    ring. Reads no clock while observability is off."""
+
+    __slots__ = ("seconds", "_t0")
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._t0 = None
+
+    def __enter__(self):
+        self._t0 = time.perf_counter() if obs.enabled() else None
+
+    def __exit__(self, *exc):
+        if self._t0 is not None:
+            self.seconds += time.perf_counter() - self._t0
+        return False
+
+    def restart(self):
+        self.seconds = 0.0
+
+    def microseconds(self) -> float:
+        return round(self.seconds * 1e6, 1)
+
+
 class Request:
     """Handle returned by :meth:`LLMServer.submit`."""
 
@@ -573,6 +601,19 @@ class LLMServer:
         self.host_seconds = 0.0
         self.stall_seconds = 0.0
         self.prefill_tokens_total = 0
+        # token gaps, counted where a token is applied: every token of
+        # a request after its first closes one, and it closed BEHIND A
+        # PREFILL when a prefill (ragged, solo chunk, mixed) was
+        # dispatched since the slot's previous token. ``_prefill_seq``
+        # numbers the prefill dispatches, ``_seq_at_token`` keeps the
+        # number each slot's last token saw
+        self.token_gaps_total = 0
+        self.token_gaps_behind_prefill_total = 0
+        self._prefill_seq = 0
+        self._seq_at_token = [0] * max_batch
+        # seconds inside the eager device updates of the open
+        # ``llm/grant`` or ``llm/drain`` phase (its ``eager_us``)
+        self._eager = _EagerTimer()
         # the phase spans of the pass the engine loop is in (None when a
         # caller drives _admit/_step by hand: there is no pass then),
         # the open llm/dispatch span, which _after_dispatch closes, and
@@ -604,6 +645,7 @@ class LLMServer:
         self.spec_passes = 0
         self._spec_ins = None
         self._thread: Optional[threading.Thread] = None
+        self._gc_watched = False
         self.steps = 0
         self._ins = None     # declared lazily: see _instruments()
         # engine watchdog (ISSUE 7): a device step stalled past the
@@ -1282,6 +1324,11 @@ class LLMServer:
         # off.
         from bigdl_tpu.observability import timeseries
         self._timeseries = timeseries.acquire()
+        # the collector's pauses stop this engine's thread like any
+        # other: counted, and recorded as ``py/gc`` when long
+        self._gc_watched = obs.enabled()
+        if self._gc_watched:
+            obs.tracing.watch_gc()
         return self
 
     def stop(self, drain: bool = True, timeout: float = 30.0):
@@ -1312,6 +1359,9 @@ class LLMServer:
             from bigdl_tpu.observability import timeseries
             timeseries.release()
             self._timeseries = None
+        if self._gc_watched:
+            obs.tracing.unwatch_gc()
+            self._gc_watched = False
         if self._thread:
             self._thread.join(timeout=30)
         if self._thread is not None and self._thread.is_alive():
@@ -1906,33 +1956,39 @@ class LLMServer:
         admission's transient tail ref (consumed in program order by
         the dispatch), then hand the slot to the request. ONE copy so a
         fix to the pin set or barrier cadence cannot drift between the
-        paths."""
-        self._pin(*pins, last, self._last, self._bt_dev, self._lens_dev)
-        self._last = self._last.at[i].set(last)
-        T = len(self._prompt_of(req))
-        self._lens[i] = T
-        if self._every is not None:
-            npages = len(row_pages)
-            self._bt[i, :] = 0
-            self._bt[i, :npages] = row_pages
-            row = np.zeros(self._pages_cap, np.int32)
-            row[:npages] = row_pages
-            row_d = jnp.asarray(row)
-            self._pin(row_d)
-            self._bt_dev = self._bt_dev.at[i].set(row_d)
-        self._lens_dev = self._lens_dev.at[i].set(T)
-        for c in range(len(self._rings)):
-            self._put_ring_row(c, i)
-        if self.pipeline_depth == 1:
-            _sync_barrier(self._k_pages, self._v_pages, self._last,
-                          self._bt_dev, self._lens_dev)
-            self._pending_release.clear()
-        if adm is not None:
-            self._kv.release_transient(adm)
-        self._slot_pages[i] = own
-        self._slots[i] = req
-        self._remaining[i] = self._budget_of(req)
-        self._index_prompt(i, req)
+        paths. All of it is the ``llm/prefill_finish`` span: its eager
+        updates (``updates`` of them) queue behind the prefill just
+        dispatched, so where the host waits in one, it waits here."""
+        with obs.span("llm/prefill_finish", annotate=True, request=req.id,
+                      updates=2 + (self._every is not None)
+                      + len(self._rings)):
+            self._pin(*pins, last, self._last, self._bt_dev,
+                      self._lens_dev)
+            self._last = self._last.at[i].set(last)
+            T = len(self._prompt_of(req))
+            self._lens[i] = T
+            if self._every is not None:
+                npages = len(row_pages)
+                self._bt[i, :] = 0
+                self._bt[i, :npages] = row_pages
+                row = np.zeros(self._pages_cap, np.int32)
+                row[:npages] = row_pages
+                row_d = jnp.asarray(row)
+                self._pin(row_d)
+                self._bt_dev = self._bt_dev.at[i].set(row_d)
+            self._lens_dev = self._lens_dev.at[i].set(T)
+            for c in range(len(self._rings)):
+                self._put_ring_row(c, i)
+            if self.pipeline_depth == 1:
+                _sync_barrier(self._k_pages, self._v_pages, self._last,
+                              self._bt_dev, self._lens_dev)
+                self._pending_release.clear()
+            if adm is not None:
+                self._kv.release_transient(adm)
+            self._slot_pages[i] = own
+            self._slots[i] = req
+            self._remaining[i] = self._budget_of(req)
+            self._index_prompt(i, req)
 
     def _build_ragged_prefill(self, bucket: int):
         """Compile the family's ragged in-place prefill for ONE suffix
@@ -1960,72 +2016,90 @@ class LLMServer:
         prefill (offset 0) and every partial-prefix case, including
         tier re-prefills (a materialized fetch is indistinguishable
         from a device prefix hit by the time prefill runs). The COW
-        tail fork is a single page copy fused ahead of the layer scan."""
+        tail fork is a single page copy fused ahead of the layer scan.
+
+        Three spans tile it inside the caller's ``llm/prefill``:
+        ``llm/prefill_stage`` (everything up to the jit call: host work
+        the device waits through, the step in flight having been
+        drained ahead of the sweep), ``llm/prefill_dispatch`` (the jit
+        call and nothing else) and ``_finish_prefill``'s
+        ``llm/prefill_finish``."""
         page = self._page
-        prompt = self._prompt_of(req)
-        T = len(prompt)
-        off = adm.matched_len
-        koff = off // page
-        own = self._kv.alloc(-(-T // page) - koff
-                             if self._every is not None else 0)
+        own: List[int] = []
         try:
-            row_pages = list(adm.shared_pages) + own
-            tail = adm.tail_src is not None
-            t_suf = T - off
-            bucket = max(page, 1 << (t_suf - 1).bit_length())  # pow2
-            key = self._step_cache_key() + ("prefill_ragged", bucket)
-            fn = _PAGED_STEP_CACHE.get(key)
-            if fn is None:
-                fn = _PAGED_STEP_CACHE[key] = \
-                    self._build_ragged_prefill(bucket)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :t_suf] = prompt[off:]
-            bt_row = np.zeros(self._pages_cap, np.int32)
-            bt_row[:len(row_pages)] = row_pages
-            # scatter targets for the suffix window [off, off+bucket):
-            # token j lands in (phys[j], slots[j]); positions past the
-            # true prompt route to trash page 0
-            pos = off + np.arange(bucket)
-            phys = np.where(pos < T,
-                            bt_row[np.minimum(pos // page,
-                                              self._pages_cap - 1)],
-                            0).astype(np.int32)
-            slots = (pos % page).astype(np.int32)
-            toks_d = jnp.asarray(toks)
-            len_d = jnp.asarray(t_suf, jnp.int32)
-            off_d = jnp.asarray(off, jnp.int32)
-            bt_d = jnp.asarray(bt_row)
-            phys_d = jnp.asarray(phys)
-            slots_d = jnp.asarray(slots)
-            if self._multi or self._every is None:
-                # a window class takes the prompt's pages of its ring
-                # now; every position is written there, a later one
-                # over an earlier one of the same slot, in order. A
-                # state class seats the request in its slot's row (the
-                # program takes what the row holds as zero) and has
-                # nothing to scatter
-                for r in self._rings:
-                    r.grant(i, T)
-                seats = [s.seat(i) for s in self._states]
-                bts = self._if_every(bt_d) \
-                    + [jnp.asarray(r.bt[i]) for r in self._rings] \
-                    + [jnp.asarray(s.rows[i]) for s in self._states]
-                physs = self._if_every(phys_d) + [
-                    jnp.asarray(r.scatter_targets(i, pos, T))
-                    for r in self._rings] + [phys_d] * len(seats)
-                bt_d, phys_d = (tuple(bts), tuple(physs)) \
-                    if self._multi else (bts[0], physs[0])
-                if seats:
-                    self.step_counters["state_slots_zeroed_total"] += 1
-                    self._admit_args["state_zeroed"] = \
-                        self._admit_args.get("state_zeroed", 0) + 1
-            fork_dst = jnp.asarray(own[0] if tail else 0, jnp.int32)
-            fork_src = jnp.asarray(adm.tail_src if tail else 0,
-                                   jnp.int32)
-            self._k_pages, self._v_pages, last = fn(
-                self.model.params, self._k_pages, self._v_pages,
-                toks_d, len_d, off_d, bt_d, phys_d, slots_d, fork_dst,
-                fork_src)
+            with obs.span("llm/prefill_stage", annotate=True,
+                          request=req.id) as stage:
+                prompt = self._prompt_of(req)
+                T = len(prompt)
+                off = adm.matched_len
+                koff = off // page
+                own = self._kv.alloc(-(-T // page) - koff
+                                     if self._every is not None else 0)
+                row_pages = list(adm.shared_pages) + own
+                tail = adm.tail_src is not None
+                t_suf = T - off
+                bucket = max(page, 1 << (t_suf - 1).bit_length())  # pow2
+                key = self._step_cache_key() + ("prefill_ragged", bucket)
+                fn = _PAGED_STEP_CACHE.get(key)
+                if fn is None:
+                    fn = _PAGED_STEP_CACHE[key] = \
+                        self._build_ragged_prefill(bucket)
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :t_suf] = prompt[off:]
+                bt_row = np.zeros(self._pages_cap, np.int32)
+                bt_row[:len(row_pages)] = row_pages
+                # scatter targets for the suffix window [off,
+                # off+bucket): token j lands in (phys[j], slots[j]);
+                # positions past the true prompt route to trash page 0
+                pos = off + np.arange(bucket)
+                phys = np.where(pos < T,
+                                bt_row[np.minimum(pos // page,
+                                                  self._pages_cap - 1)],
+                                0).astype(np.int32)
+                slots = (pos % page).astype(np.int32)
+                toks_d = jnp.asarray(toks)
+                len_d = jnp.asarray(t_suf, jnp.int32)
+                off_d = jnp.asarray(off, jnp.int32)
+                bt_d = jnp.asarray(bt_row)
+                phys_d = jnp.asarray(phys)
+                slots_d = jnp.asarray(slots)
+                transfers = 8       # these six and the fork's two
+                if self._multi or self._every is None:
+                    # a window class takes the prompt's pages of its
+                    # ring now; every position is written there, a
+                    # later one over an earlier one of the same slot,
+                    # in order. A state class seats the request in its
+                    # slot's row (the program takes what the row holds
+                    # as zero) and has nothing to scatter
+                    for r in self._rings:
+                        r.grant(i, T)
+                    seats = [s.seat(i) for s in self._states]
+                    bts = self._if_every(bt_d) \
+                        + [jnp.asarray(r.bt[i]) for r in self._rings] \
+                        + [jnp.asarray(s.rows[i]) for s in self._states]
+                    physs = self._if_every(phys_d) + [
+                        jnp.asarray(r.scatter_targets(i, pos, T))
+                        for r in self._rings] + [phys_d] * len(seats)
+                    transfers += 2 * len(self._rings) + len(seats)
+                    bt_d, phys_d = (tuple(bts), tuple(physs)) \
+                        if self._multi else (bts[0], physs[0])
+                    if seats:
+                        self.step_counters[
+                            "state_slots_zeroed_total"] += 1
+                        self._admit_args["state_zeroed"] = \
+                            self._admit_args.get("state_zeroed", 0) + 1
+                fork_dst = jnp.asarray(own[0] if tail else 0, jnp.int32)
+                fork_src = jnp.asarray(adm.tail_src if tail else 0,
+                                       jnp.int32)
+                stage.args.update(bucket=bucket, transfers=transfers)
+            with obs.span("llm/prefill_dispatch", annotate=True,
+                          request=req.id, bucket=bucket,
+                          fn="llm/prefill_ragged"):
+                self._k_pages, self._v_pages, last = fn(
+                    self.model.params, self._k_pages, self._v_pages,
+                    toks_d, len_d, off_d, bt_d, phys_d, slots_d,
+                    fork_dst, fork_src)
+            self._prefill_seq += 1
             self._admit_args["bucket_tokens"] += bucket
             if self._fam_prefill_stats is not None:
                 for name, n in self._fam_prefill_stats(
@@ -2232,11 +2306,12 @@ class LLMServer:
         slots = (pos % page).astype(np.int32)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :c] = ids[off:end]
-        ops = (jnp.asarray(toks), jnp.asarray(c, jnp.int32),
-               jnp.asarray(off, jnp.int32), jnp.asarray(bt_row),
-               jnp.asarray(phys), jnp.asarray(slots),
-               jnp.asarray(new_pages[0] if tail else 0, jnp.int32),
-               jnp.asarray(adm.tail_src if tail else 0, jnp.int32))
+        with self._eager:
+            ops = (jnp.asarray(toks), jnp.asarray(c, jnp.int32),
+                   jnp.asarray(off, jnp.int32), jnp.asarray(bt_row),
+                   jnp.asarray(phys), jnp.asarray(slots),
+                   jnp.asarray(new_pages[0] if tail else 0, jnp.int32),
+                   jnp.asarray(adm.tail_src if tail else 0, jnp.int32))
         if flight.enabled:
             flight.record(
                 "chunk_charge", request_id=req.id,
@@ -2451,6 +2526,7 @@ class LLMServer:
             # the whole pass, chunk included
             self._restore_chunk_pass(cargs)
             raise
+        self._prefill_seq += 1
         self._pin(*cargs["ops"])
         self._chunk_dispatched(cargs, clast)
         self._record_mixed_pass(0, cargs, t_step)
@@ -2479,6 +2555,7 @@ class LLMServer:
         except BaseException:
             self._restore_chunk_pass(cargs)
             raise
+        self._prefill_seq += 1
         self._last = logits
         for i in disp:
             self._lens[i] += 1
@@ -2817,7 +2894,9 @@ class LLMServer:
         (mirroring the optimizer's ``_pending_loss`` drain). Slots whose
         request finished meanwhile discard their speculative token.
         Two phases of the pass: ``llm/fence_wait`` brackets the fetch
-        and nothing else, ``llm/drain`` everything after it."""
+        and nothing else, ``llm/drain`` everything after it (with the
+        token gaps it closed, those behind a prefill among them, and
+        the time inside the freed slots' eager resets)."""
         rec = self._inflight.popleft()
         t0 = time.perf_counter()
         with self._phase("llm/fence_wait"):
@@ -2829,8 +2908,16 @@ class LLMServer:
         stall = now - t0
         self.stall_seconds += stall
         with self._phase("llm/drain") as ph:
+            gaps = self.token_gaps_total
+            behind = self.token_gaps_behind_prefill_total
+            self._eager.restart()
             ph.args["requests"], ph.args["finished"] = \
                 self._retire(rec, vals, now, stall)
+            ph.args.update(
+                gaps=self.token_gaps_total - gaps,
+                gaps_behind_prefill=self.token_gaps_behind_prefill_total
+                - behind,
+                eager_us=self._eager.microseconds())
             # the family's own counts of this step: the device's,
             # fetched with its tokens (kernels.sampling.make_sampled_
             # step), and the host's from its dispatch, both counted here
@@ -2940,6 +3027,14 @@ class LLMServer:
         through this same path so EOS semantics cannot diverge."""
         req.tokens.append(tok)
         req.t_tokens.append(now)
+        seq = self._prefill_seq
+        if len(req.tokens) > 1:
+            # a gap between two fence stamps; a prefill was dispatched
+            # between them when the number moved
+            self.token_gaps_total += 1
+            if self._seq_at_token[i] != seq:
+                self.token_gaps_behind_prefill_total += 1
+        self._seq_at_token[i] = seq
         if len(req.tokens) == 1:
             req.t_first_token = time.perf_counter()  # TTFT stamp
             if self._slo is not None:
@@ -3006,18 +3101,19 @@ class LLMServer:
         self._bt[i, :] = 0    # orphaned rows must point at trash:
         self._lens[i] = 0     # a stale id could alias a reissued
         # page and the inactive row's dummy write would clobber it
-        self._pin(self._bt_dev, self._lens_dev)
-        if self._every is not None:
-            self._bt_dev = self._bt_dev.at[i].set(0)
-        self._lens_dev = self._lens_dev.at[i].set(0)
-        if self._rings:
-            freed = self._freed_by_class
-            name = self._classes[0].name
-            freed[name] = freed.get(name, 0) + len(owned)
-            for c, r in enumerate(self._rings):
-                freed[r.cls.name] = freed.get(r.cls.name, 0) \
-                    + r.release(i)
-                self._put_ring_row(c, i)        # zeros: the trash page
+        with self._eager:
+            self._pin(self._bt_dev, self._lens_dev)
+            if self._every is not None:
+                self._bt_dev = self._bt_dev.at[i].set(0)
+            self._lens_dev = self._lens_dev.at[i].set(0)
+            if self._rings:
+                freed = self._freed_by_class
+                name = self._classes[0].name
+                freed[name] = freed.get(name, 0) + len(owned)
+                for c, r in enumerate(self._rings):
+                    freed[r.cls.name] = freed.get(r.cls.name, 0) \
+                        + r.release(i)
+                    self._put_ring_row(c, i)    # zeros: the trash page
         for s in self._states:
             # the row keeps what it holds until its next occupant's
             # prefill takes it as zero; an empty slot is never active,
@@ -3170,8 +3266,10 @@ class LLMServer:
             # None = the chunk faulted (request already failed) or is
             # budget-stalled (decode continues; the chunk retries)
             with self._phase("llm/grant") as ph:
+                self._eager.restart()
                 cargs = self._prepare_chunk(ci)
                 ph.args["pages"] = len(cargs["new_pages"]) if cargs else 0
+                ph.args["eager_us"] = self._eager.microseconds()
         if cargs is None and not disp:
             if self._inflight:
                 self._drain_next()
@@ -3199,7 +3297,9 @@ class LLMServer:
                     self._drain_next()
                 return True
         with self._phase("llm/grant") as ph:
+            self._eager.restart()
             ph.args["pages"] = self._grant_pages(disp, sargs, cargs)
+            ph.args["eager_us"] = self._eager.microseconds()
             if self._rings:
                 # by class: granted in this pass, freed since the last
                 for name, n in self._grant_by_class.items():
@@ -3222,7 +3322,8 @@ class LLMServer:
         """The pass's page grant (its ``llm/grant`` phase): one page for
         every decode row at a page boundary, and a verify chunk's
         pages, into the host ledger and — one incremental scatter —
-        the device-resident block table. Returns the pages granted."""
+        the device-resident block table (timed: the phase's
+        ``eager_us``). Returns the pages granted."""
         page = self._page
         # the page for position lens[i] must exist before the step; the
         # grant is an incremental scatter into the device-resident block
@@ -3270,9 +3371,10 @@ class LLMServer:
         if allocs:
             rows, cols, vals = (np.asarray(v, np.int32)
                                 for v in zip(*allocs))
-            vals_d = jnp.asarray(vals)
-            self._pin(self._bt_dev, vals_d)
-            self._bt_dev = self._bt_dev.at[rows, cols].set(vals_d)
+            with self._eager:
+                vals_d = jnp.asarray(vals)
+                self._pin(self._bt_dev, vals_d)
+                self._bt_dev = self._bt_dev.at[rows, cols].set(vals_d)
         granted = len(allocs)
         for c, r in enumerate(self._rings):
             # a window class grants while a row's ring is filling and
@@ -3282,7 +3384,8 @@ class LLMServer:
             for i in disp:
                 got = len(r.grant(i, int(self._lens[i]) + 1))
                 if got:
-                    self._put_ring_row(c, i)
+                    with self._eager:
+                        self._put_ring_row(c, i)
                     new += got
             self._grant_by_class[r.cls.name] = new
             granted += new

@@ -76,8 +76,9 @@ def _ensure_standard_series():
         "bigdl_build_info",
         "Constant 1; the build identity lives in the labels",
         labelnames=("version", "jax_version", "backend"))
-    g.labels(version=version, jax_version=jax_version,
-             backend=backend).set(1)
+    # one identity: a scrape before the backend came up said "none"
+    g.only(version=version, jax_version=jax_version,
+           backend=backend).set(1)
     REGISTRY.gauge(
         "process_start_time_seconds",
         "Unix epoch seconds this process started").set(

@@ -257,6 +257,17 @@ class _Instrument:
                 child = self._children[key] = self._make_child()
             return child
 
+    def only(self, **kv):
+        """The child for these label values, every other child dropped:
+        for a series that states an identity, where a changed label
+        must replace the old series and not stand beside it."""
+        child = self.labels(**kv)
+        with self._lock:
+            for key in [k for k, c in self._children.items()
+                        if c is not child]:
+                del self._children[key]
+        return child
+
     def children(self) -> Iterable[Tuple[Tuple[str, ...], object]]:
         with self._lock:
             return list(self._children.items())

@@ -34,6 +34,7 @@ enters its annotation, which is the profiler's and not this ring's).
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -291,6 +292,84 @@ def add_complete(name: str, start_wall: float, dur_s: float,
 
 def export_chrome_trace(path: Optional[str] = None) -> str:
     return TRACE.export_chrome_trace(path)
+
+
+# ---------------------------------------------------------------------------
+# py/gc: the collector's pauses, counted always and recorded when long
+# ---------------------------------------------------------------------------
+
+#: a collection shorter than this is counted and leaves no record (the
+#: young generations run hundreds of times a second for microseconds; a
+#: record each would push a serving run's passes off the ring)
+GC_RECORD_SECONDS = 0.5e-3
+
+#: collections seen and the seconds they took while watched, by
+#: generation (plain numbers, like the engine's ``steps``)
+gc_collections_total = [0, 0, 0]
+gc_seconds_total = [0.0, 0.0, 0.0]
+
+_gc_lock = threading.Lock()
+_gc_watchers = 0
+# (t0, wall0, annotation) of the collection in progress: collections do
+# not nest and start and stop are reported on the collecting thread
+_gc_open = None
+
+
+def _on_gc(phase: str, info: Dict[str, int]):
+    global _gc_open
+    if phase == "start":
+        ann = None
+        if info["generation"] == 2:
+            # the old generation's sweep stops every thread for tens of
+            # milliseconds: a captured profile names the hole
+            ann = _jax_annotation("py/gc", {})
+            try:
+                if ann is not None:
+                    ann.__enter__()
+            except Exception:
+                ann = None
+        _gc_open = (time.perf_counter(), time.time(), ann)
+        return
+    opened, _gc_open = _gc_open, None
+    if opened is None:
+        return          # watched from the middle of a collection
+    t0, wall0, ann = opened
+    dur = time.perf_counter() - t0
+    if ann is not None:
+        try:
+            ann.__exit__(None, None, None)
+        except Exception:
+            pass
+    gen = info["generation"]
+    gc_collections_total[gen] += 1
+    gc_seconds_total[gen] += dur
+    if dur >= GC_RECORD_SECONDS:
+        add_complete("py/gc", wall0, dur, t0, generation=gen,
+                     collected=info["collected"])
+
+
+def watch_gc():
+    """Count every collection of the interpreter's garbage collector
+    into ``gc_collections_total`` / ``gc_seconds_total`` and record the
+    ones of ``GC_RECORD_SECONDS`` or more as ``py/gc`` on the ring (on
+    the thread that collected, which is the thread it stopped first). A
+    process may hold several watchers (an engine each): the callback is
+    installed by the first and removed with the last ``unwatch_gc``."""
+    global _gc_watchers
+    with _gc_lock:
+        _gc_watchers += 1
+        if _gc_watchers == 1:
+            gc.callbacks.append(_on_gc)
+
+
+def unwatch_gc():
+    global _gc_watchers
+    with _gc_lock:
+        if not _gc_watchers:
+            return
+        _gc_watchers -= 1
+        if not _gc_watchers:
+            gc.callbacks.remove(_on_gc)
 
 
 # ---------------------------------------------------------------------------

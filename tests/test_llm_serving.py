@@ -274,6 +274,9 @@ class TestDecodeMicrobench:
 
 PHASES = ("llm/admit", "llm/grant", "llm/dispatch", "llm/fence_wait",
           "llm/drain")
+# what tiles a whole-prompt prefill inside its ``llm/prefill`` (ISSUE 35)
+CHILDREN = ("llm/prefill_stage", "llm/prefill_dispatch",
+            "llm/prefill_finish")
 _EPS = 1e-7     # perf_counter arithmetic through float microseconds
 
 _ENGINES = {
@@ -485,9 +488,10 @@ class TestPassSpans:
 
     def test_phases_reach_a_captured_jax_profile(self, model, tmp_path):
         """No switch: whoever captures a JAX profile of a serving
-        process finds the five phases on the engine thread's line, and
-        not the enclosing ``llm/pass`` (it would be the longest host
-        event over every device-idle gap)."""
+        process finds the five phases and a prefill's three children
+        (ISSUE 35) on the engine thread's line, and not the enclosing
+        ``llm/pass`` (it would be the longest host event over every
+        device-idle gap)."""
         import glob
 
         import jax
@@ -514,7 +518,7 @@ class TestPassSpans:
                          if ev.name.startswith("llm/")}
                 if names:
                     lines.append(names)
-        assert lines == [set(PHASES)]
+        assert lines == [set(PHASES) | set(CHILDREN)]
 
     def test_span_names_pass_the_registry_gate(self):
         """The new names are registered and emitted, the span this PR
@@ -531,3 +535,199 @@ class TestPassSpans:
             ProjectIndex.scan(root, ("bigdl_tpu",))).span
         assert names <= set(emitted)
         assert "llm/decode_step" not in emitted
+
+
+# ---------------------------------------------------------------------------
+# an admission and a late token gap, named from inside (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+def _by_name(recs):
+    by = {}
+    for r in recs:
+        by.setdefault(r["name"], []).append(r)
+    return by
+
+
+class TestAdmissionSpans:
+    @pytest.fixture(scope="class")
+    def served(self, model):
+        return _serve_traced(model, "paged")
+
+    def test_three_children_tile_a_prefill(self, served):
+        """Stage, dispatch, finish: inside their ``llm/prefill``, in
+        that order, without overlap; the dispatch holds the jit call
+        and nothing else that records."""
+        srv, reqs, recs = served
+        by = _by_name(recs)
+        assert len(by["llm/prefill"]) == len(reqs)
+        for pf in by["llm/prefill"]:
+            mine = [r for r in recs if r["name"] in CHILDREN
+                    and pf["t0"] <= r["t0"] and _end(r) <= _end(pf) + _EPS]
+            assert tuple(r["name"] for r in mine) == CHILDREN
+            stage, disp, fin = mine
+            assert _end(stage) <= disp["t0"] + _EPS
+            assert _end(disp) <= fin["t0"] + _EPS
+            rid = pf["args"]["request"]
+            assert {r["args"]["request"] for r in mine} == {rid}
+            assert {r["args"]["parent"] for r in mine} == {"llm/prefill"}
+            tokens = pf["args"]["tokens"]
+            assert stage["args"]["bucket"] == disp["args"]["bucket"] \
+                == max(16, 1 << (tokens - 1).bit_length())
+            # six operands and the fork's two, one class, no state
+            assert stage["args"]["transfers"] == 8
+            assert disp["args"]["fn"] == "llm/prefill_ragged"
+            # ``_last``, the block table's row, the length
+            assert fin["args"]["updates"] == 3
+            inside = [r["name"] for r in recs if r is not disp
+                      and disp["t0"] <= r["t0"] < _end(disp)]
+            assert set(inside) <= {"xla/compile"}, inside
+        # every dispatch is one prefill the gap counters know of (the
+        # warm-up's was recorded before the ring was cleared)
+        assert srv._prefill_seq == len(by["llm/prefill_dispatch"]) + 1
+
+    def test_a_chunked_admission_records_its_finish_only(self, model):
+        """The final chunk reaches ``_finish_prefill`` from the chunk
+        path: ``llm/prefill_finish`` for every request, the other two
+        for whole-prompt prefills alone."""
+        _, reqs, recs = _serve_traced(model, "mixed")
+        by = _by_name(recs)
+        whole = len(by["llm/prefill"])
+        assert 0 < whole < len(reqs)
+        assert len(by["llm/prefill_stage"]) == whole
+        assert len(by["llm/prefill_dispatch"]) == whole
+        fins = by["llm/prefill_finish"]
+        assert sorted(f["args"]["request"] for f in fins) \
+            == sorted(r.id for r in reqs)
+        assert sum("parent" not in f["args"]
+                   or f["args"]["parent"] != "llm/prefill"
+                   for f in fins) == len(reqs) - whole
+
+    def test_every_gap_is_counted_once(self, served):
+        """Σ ``gaps`` over the drains = Σ (tokens - 1) over the
+        requests, in the ring and in the always-on counters."""
+        srv, reqs, recs = served
+        drains = _by_name(recs)["llm/drain"]
+        want = sum(len(r.tokens) - 1 for r in reqs)
+        assert sum(d["args"]["gaps"] for d in drains) == want
+        assert srv.token_gaps_total == want + 1     # the warm-up's one
+        for d in drains:
+            assert 0 <= d["args"]["gaps_behind_prefill"] \
+                <= d["args"]["gaps"] <= len(d["args"]["requests"])
+        assert sum(d["args"]["gaps_behind_prefill"] for d in drains) \
+            == srv.token_gaps_behind_prefill_total
+
+    def test_a_gap_behind_a_prefill_is_the_live_rows(self, model):
+        """One row decodes, a second request is seated: the live row's
+        next token closes the one gap behind a prefill (the scenario of
+        ``test_tokens_in_flight_are_delivered_before_a_prefill``); the
+        newcomer's own gaps, and a lone request's, hold none."""
+        from bigdl_tpu import observability as obs
+
+        srv = LLMServer(model, max_batch=2, max_seq_len=64).start()
+        try:
+            lone = srv.submit(np.array([3, 1, 4], np.int32),
+                              max_new_tokens=6)
+            lone.get(timeout=600)
+            assert srv.token_gaps_total == 5
+            assert srv.token_gaps_behind_prefill_total == 0
+            first = srv.submit(np.array([3, 1, 4, 1, 5], np.int32),
+                               max_new_tokens=40)
+            while len(first.tokens) < 4:
+                time.sleep(0.001)
+            obs.TRACE.clear()
+            second = srv.submit(np.array([2, 7, 1, 8], np.int32),
+                                max_new_tokens=4)
+            second.get(timeout=600)
+            first.get(timeout=600)
+            tid = srv._thread.ident
+        finally:
+            srv.stop()
+        assert srv.token_gaps_total == 5 + 39 + 3
+        assert srv.token_gaps_behind_prefill_total == 1
+        behind = [r for r in obs.TRACE.spans() if r["tid"] == tid
+                  and r["name"] == "llm/drain"
+                  and r["args"]["gaps_behind_prefill"]]
+        assert len(behind) == 1
+        # that drain also hands the newcomer its first token: no gap
+        assert sorted(behind[0]["args"]["requests"]) \
+            == sorted([first.id, second.id])
+        assert behind[0]["args"]["gaps"] == 1
+        assert behind[0]["args"]["gaps_behind_prefill"] == 1
+
+    @pytest.mark.parametrize("kind", ["paged", "mixed"])
+    def test_eager_time_is_on_every_grant_and_drain(self, model, served,
+                                                    kind):
+        """``eager_us``: the time inside the phase's eager device
+        updates, 0 when none ran: a grant that took no page, a drain
+        that freed no slot."""
+        _, _, recs = served if kind == "paged" \
+            else _serve_traced(model, kind)
+        by = _by_name(recs)
+        for r in by["llm/grant"] + by["llm/drain"]:
+            assert r["args"]["eager_us"] >= 0.0, r
+            assert r["args"]["eager_us"] <= r["dur"] + 1.0, r
+        for g in by["llm/grant"]:
+            if kind == "paged":
+                assert (g["args"]["eager_us"] > 0) == (g["args"]["pages"]
+                                                       > 0), g
+        for d in by["llm/drain"]:
+            assert (d["args"]["eager_us"] > 0) == (d["args"]["finished"]
+                                                   > 0), d
+
+    def test_disabled_the_new_code_reads_no_clock(self, model,
+                                                  monkeypatch):
+        """Observability off: no record, no ``gc`` callback, the eager
+        timer reads no clock; the two gap counters still count (int
+        arithmetic where the token is applied)."""
+        import gc
+
+        from bigdl_tpu import observability as obs
+        from bigdl_tpu.llm import serving
+        obs.disable()
+        try:
+            obs.TRACE.clear()
+            found = list(gc.callbacks)
+            srv = LLMServer(model, max_batch=2, max_seq_len=32)
+            reads = []
+            real = time.perf_counter
+
+            class Clock:
+                """``time`` as the eager timer sees it."""
+                @staticmethod
+                def perf_counter():
+                    reads.append(1)
+                    return real()
+
+            timer = serving._EagerTimer()
+            monkeypatch.setattr(serving, "time", Clock)
+            with timer:
+                pass
+            monkeypatch.undo()
+            assert reads == [] and timer.microseconds() == 0.0
+            srv.start()
+            try:
+                assert gc.callbacks == found
+                req = srv.submit(np.array([3, 1, 4], np.int32),
+                                 max_new_tokens=5)
+                req.get(timeout=120)
+            finally:
+                srv.stop()
+            assert gc.callbacks == found
+            assert len(obs.TRACE) == 0
+            assert srv._eager.microseconds() == 0.0
+            assert srv.token_gaps_total == 4
+            assert srv.token_gaps_behind_prefill_total == 0
+        finally:
+            obs.enable()
+
+    def test_new_span_names_pass_the_registry_gate(self):
+        import os
+
+        from bigdl_tpu.analysis import ProjectIndex, registries
+        from bigdl_tpu.analysis import registrydrift
+        names = set(CHILDREN) | {"py/gc"}
+        assert names <= set(registries.SPAN_NAMES)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        emitted = registrydrift.collect_literals(
+            ProjectIndex.scan(root, ("bigdl_tpu",))).span
+        assert names <= set(emitted)

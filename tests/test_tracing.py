@@ -393,6 +393,153 @@ class TestSpanObject:
         assert (sp.t0 is not None) == enabled
 
 
+class TestGcWatch:
+    """ISSUE 35: the collector's pauses, counted always and recorded as
+    ``py/gc`` when long; installed by a started engine and by nothing
+    else."""
+
+    @pytest.fixture
+    def tiny(self):
+        from bigdl_tpu.llm.models.llama import (LlamaConfig,
+                                                LlamaForCausalLM)
+        return LlamaForCausalLM.from_config(LlamaConfig.tiny(), seed=0,
+                                            max_cache_len=64)
+
+    @staticmethod
+    def _gc_records(generation=None):
+        return [r for r in obs.TRACE.spans() if r["name"] == "py/gc"
+                and generation in (None, r["args"]["generation"])]
+
+    def test_a_full_collection_under_a_started_server(self, tiny):
+        import gc
+        import threading
+
+        from bigdl_tpu.llm.serving import LLMServer
+        from bigdl_tpu.observability import tracing
+        found = list(gc.callbacks)
+        srv = LLMServer(tiny, max_batch=2, max_seq_len=32).start()
+        try:
+            assert gc.callbacks.count(tracing._on_gc) == 1
+            n2, s2 = (tracing.gc_collections_total[2],
+                      tracing.gc_seconds_total[2])
+            obs.TRACE.clear()
+            before = time.perf_counter()
+            gc.collect()
+            after = time.perf_counter()
+            (rec,) = self._gc_records(generation=2)
+        finally:
+            srv.stop()
+        assert gc.callbacks == found
+        assert rec["tid"] == threading.get_ident()
+        assert rec["args"]["collected"] >= 0
+        assert before <= rec["t0"] <= rec["t0"] + rec["dur"] / 1e6 <= after
+        assert rec["dur"] >= tracing.GC_RECORD_SECONDS * 1e6
+        assert tracing.gc_collections_total[2] == n2 + 1
+        assert tracing.gc_seconds_total[2] - s2 \
+            == pytest.approx(rec["dur"] / 1e6)
+
+    def test_nothing_is_installed_with_observability_off(self, tiny):
+        import gc
+
+        from bigdl_tpu.llm.serving import LLMServer
+        found = list(gc.callbacks)
+        obs.disable()
+        srv = LLMServer(tiny, max_batch=2, max_seq_len=32).start()
+        try:
+            assert gc.callbacks == found
+            gc.collect()
+        finally:
+            srv.stop()
+        assert gc.callbacks == found
+        assert self._gc_records() == []
+
+    def test_installed_by_the_first_watcher_removed_with_the_last(self):
+        import gc
+
+        from bigdl_tpu.observability import tracing
+        found = list(gc.callbacks)
+        tracing.watch_gc()
+        tracing.watch_gc()
+        assert gc.callbacks == found + [tracing._on_gc]
+        tracing.unwatch_gc()
+        assert gc.callbacks == found + [tracing._on_gc]
+        tracing.unwatch_gc()
+        assert gc.callbacks == found
+        tracing.unwatch_gc()        # one too many: nothing to remove
+        assert gc.callbacks == found
+
+    def test_a_short_collection_is_counted_and_not_recorded(
+            self, monkeypatch):
+        """The young generations run for microseconds, hundreds of
+        times a second: counted by generation, no record; the old
+        generation's sweep holds a profiler annotation from start to
+        stop."""
+        import gc
+
+        import jax
+
+        from bigdl_tpu.observability import tracing
+        # the calls below are made by hand: a real collection between
+        # them (a watcher another test's engine left) would take stamps
+        gc.disable()
+        try:
+            self._by_hand(monkeypatch, jax, tracing)
+        finally:
+            gc.enable()
+
+    def _by_hand(self, monkeypatch, jax, tracing):
+        seen = []
+
+        class Fake:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Fake)
+        stamps = iter([10.0, 10.0002, 20.0, 20.25, 30.0, 30.1])
+
+        class Clock:
+            """``time`` as ``tracing`` alone sees it."""
+            time = staticmethod(time.time)
+            perf_counter = staticmethod(lambda: next(stamps))
+
+        monkeypatch.setattr(tracing, "time", Clock)
+        n = list(tracing.gc_collections_total)
+        s = list(tracing.gc_seconds_total)
+        for gen, collected in ((0, 3), (2, 40)):
+            tracing._on_gc("start", {"generation": gen, "collected": 0,
+                                     "uncollectable": 0})
+            tracing._on_gc("stop", {"generation": gen,
+                                    "collected": collected,
+                                    "uncollectable": 0})
+        # a stop whose start was not seen (watched from mid-collection)
+        tracing._on_gc("stop", {"generation": 1, "collected": 0,
+                                "uncollectable": 0})
+        (rec,) = self._gc_records()
+        assert rec["t0"] == 20.0 and rec["dur"] == pytest.approx(0.25e6)
+        assert rec["args"] == {"generation": 2, "collected": 40}
+        assert seen == [("enter", "py/gc"), ("exit", "py/gc")]
+        assert [a - b for a, b in zip(tracing.gc_collections_total, n)] \
+            == [1, 0, 1]
+        assert [a - b for a, b in zip(tracing.gc_seconds_total, s)] \
+            == pytest.approx([0.0002, 0.0, 0.25])
+        # disabled: still counted (a watcher may outlive the switch),
+        # nothing appended
+        obs.disable()
+        obs.TRACE.clear()
+        tracing._on_gc("start", {"generation": 1, "collected": 0,
+                                 "uncollectable": 0})
+        tracing._on_gc("stop", {"generation": 1, "collected": 0,
+                                "uncollectable": 0})
+        assert len(obs.TRACE) == 0
+        assert tracing.gc_collections_total[1] == n[1] + 1
+
+
 class TestLLMTraceStitching:
     @pytest.fixture(scope="class")
     def served(self):
@@ -585,6 +732,21 @@ class TestBuildInfo:
         assert "jax_version" in dict(labels)
         assert parsed["process_start_time_seconds"][()] == \
             pytest.approx(obs.PROCESS_START_TIME)
+
+    def test_one_identity_after_the_backend_comes_up(self):
+        """A scrape made before JAX had a backend said ``backend=
+        "none"``; the next one replaces that series (it failed whenever
+        a file that renders first shared a worker with this one)."""
+        from bigdl_tpu.observability import parse_prometheus
+
+        obs.render()
+        obs.REGISTRY.gauge(
+            "bigdl_build_info", "",
+            labelnames=("version", "jax_version", "backend")).labels(
+                version="0", jax_version="0", backend="before").set(1)
+        (labels, _), = parse_prometheus(
+            obs.render())["bigdl_build_info"].items()
+        assert dict(labels)["backend"] != "before"
 
     def test_absent_when_disabled(self):
         reg = obs.MetricRegistry()
