@@ -28,11 +28,19 @@ of every pool): a dead batch row reads and writes there.
 Two entry points, each a Mosaic kernel on the chip and a plain-XLA twin
 elsewhere (the twin is what the CPU tests and the CPU engine run):
 
-- :func:`retention_decode`: one token a row. One pass over each live
-  row's state in slabs, updated **in place** (``input_output_aliases``)
-  while the slab it holds is read out for the group's query heads. The
-  grid walks the live rows first; the steps of dead rows repeat the last
-  live block's indices, which costs no DMA, and skip the arithmetic.
+- :func:`retention_decode`: one token a row. ONE pass over each live
+  row's cache (ISSUE 36): the kernel takes ``q``, ``k``, ``v``, the
+  gates and both cache arrays, walks the row's state in slabs and, for
+  the slab it holds, builds its tiles of ``phi(q)`` and ``phi(k)`` in
+  VMEM, reads the old state and the decayed normaliser out against
+  ``phi(q)`` for the group's query heads, and writes ``gamma S + phi(k)
+  v^T`` and ``gamma z + phi(k)`` back **in place**
+  (``input_output_aliases``, both arrays). It hands back the read-out's
+  numerator and denominator; the token's own term and the division are
+  left to XLA at ``(B, Hkv, group, dv)``. No ``phi`` is ever in HBM
+  and XLA touches neither cache array. The grid walks the live rows
+  first; the steps of dead rows repeat the last live block's indices,
+  which costs no DMA, and skip the arithmetic.
 - :func:`retention_prefill_chunk`: the chunked form over one row's
   chunk of a prompt, the state carried from sub-chunk to sub-chunk in
   VMEM; the band ``(q . k)^2`` and the ``phi`` products on the MXU in
@@ -44,6 +52,42 @@ Both kernel calls are jitted on their own, so that a program of eight
 layers traces and lowers a kernel once and not eight times (the prefill
 kernel's body is 65 tiles unrolled: 0.6 s a trace, a minute and a half
 of set-up over the engine's seven prefill programs).
+
+**What the decode kernel's time is made of** (ISSUE 36; my chip runs,
+PR 36, one "TPU v5 lite": ``tools/exp_retention.py`` and the builder's
+piece-at-a-time copies of the body; 20 batch rows, 8 KV heads, group 5,
+``n`` = ``dv`` = 128; ms a call of ONE layer, the Mosaic call alone, two
+rounds; the share is :func:`decode_bytes` over 819 GB/s)::
+
+    body                              slab   11 live       15 live
+    as it is                          1,664  1.305-1.307   1.661-1.663
+                                             70.6-70.7 %   75.6-75.7 %
+    as it is                          8,320  1.206-1.211   1.614-1.618
+                                             76.2-76.5 %   77.8-78.0 %
+    tiles concatenated, no scratch    8,320  1.210-1.212   1.617-1.623
+    no tiles (the scratch as it lies) 1,664  1.304-1.310   1.661-1.662
+                                      8,320  1.207-1.210   1.614-1.616
+    no normaliser                     1,664  1.305         1.660-1.664
+                                      8,320  1.207-1.214   1.613
+    neither, v's column a constant    1,664  1.300         1.657-1.659
+                                      8,320  1.205-1.214   1.609-1.622
+    ISSUE 33's kernel (phi(q), phi(k) 1,664  1.296         1.686
+    read from HBM, no normaliser)     8,320  1.233         1.649
+
+No piece of the body shows: the 65 tiles, the normaliser's row and the
+transposition of ``v`` all hide under the state's copies, and the call
+is what its block DMAs and its grid steps take (0.1 ms of 1.3 between
+440 steps of 852 KB and 88 of 4.26 MB at 11 live rows: ≈ 0.27 µs a
+step). Hence a slab of the whole row. LLO counts a step (the chip's
+compiler, no chip): 940 vector operations, 226 loads and 238 stores at
+1,664 lanes against a block the DMA needs ≈ 2,600 cycles for.
+
+**Four names a planted fault replaces.** ``benchmark/faults_brumby.py``
+swaps :func:`_phi_tile`, :data:`SQRT2`, :func:`_decay` and
+:func:`_finish` on this module while the served program is traced, and
+the benchmark's check must then fail. Both kernel bodies and the XLA
+around them reach each through the module's global at trace time: do
+not inline them, pass them as arguments, or bind them at import.
 """
 
 from __future__ import annotations
@@ -62,8 +106,10 @@ SQRT2 = math.sqrt(2.0)
 # band is (group * SUB, SUB) float32 in VMEM, and the gates' running sum
 # inside it stays far from float32's range (256 * log 0.9 = -27)
 SUB = 256
-# lanes of the state a decode grid step holds (13 of the 65 tiles)
-SLAB = 1664
+# lanes of the state a decode grid step holds: a head's whole row (all 65
+# tiles, 4.26 MB a block). A slab is whole tiles and divides the row:
+# 128, 640, 1,664 or 8,320 lanes (the table in the module's docstring)
+SLAB = 8320
 
 
 def state_width(n: int) -> int:
@@ -107,8 +153,10 @@ def _finish(num, den, n: int, eps: float):
 
 
 def _decay(gam, z):
-    """The normaliser a step on: ``gamma z``."""
-    return gam[..., None] * z
+    """The normaliser a step on: ``gamma z``. ``gam`` is one gate a row
+    of ``z``, or (inside the decode kernel) a scalar read from SMEM."""
+    gam = jnp.asarray(gam)
+    return (gam[..., None] if gam.ndim else gam) * z
 
 
 def _decode_xla(state, z, q, k, v, g, slots, live, eps):
@@ -125,44 +173,71 @@ def _decode_xla(state, z, q, k, v, g, slots, live, eps):
             state.at[slots].set(s_new), z.at[slots].set(z_new))
 
 
-def _decode_kernel(nl_ref, slot_ref, row_ref, gam_ref, pq_ref, pk_ref,
-                   v_ref, s_ref, num_ref, so_ref, *, hkv: int):
+def _decode_kernel(nl_ref, slot_ref, row_ref, gam_ref, q_ref, k_ref, v_ref,
+                   s_ref, z_ref, num_ref, den_ref, so_ref, zo_ref,
+                   pq_ref, pk_ref, *, hkv: int, ns: int):
     del slot_ref
     r, s, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n, slab = q_ref.shape[-1], s_ref.shape[-1]
+    per = slab // n                       # tiles of phi a slab
+    f32 = jnp.float32
+    roll = lambda a, d: pltpu.roll(a, n - d, 1)
 
     @pl.when(r < nl_ref[0])
     def _():
-        state = s_ref[0, 0].astype(jnp.float32)         # (dv, slab)
+        q = q_ref[0, pl.ds(h, 1)][0]                        # (rows, n)
+        # the key's row on all 8 sublanes: a whole tile to rotate
+        k = jnp.broadcast_to(k_ref[0, pl.ds(h, 1), :], (8, n))
+        q2, k2 = q * SQRT2, k * SQRT2
+        # this slab's tiles of phi(q) and phi(k), made here and never in
+        # HBM. ``_phi_tile`` wants its distance static: a branch a slab
+        for s0 in range(ns):
+            @pl.when(s == s0)
+            def _(s0=s0):
+                for j in range(per):
+                    lanes = pl.ds(j * n, n)
+                    pq_ref[:, lanes] = _phi_tile(q, q2, s0 * per + j, roll)
+                    pk_ref[:, lanes] = _phi_tile(k, k2, s0 * per + j, roll)
+
+        pq, pk = pq_ref[...], pk_ref[0:1, :]
+        gam = gam_ref[row_ref[r] * hkv + h]
+        state = s_ref[0, 0].astype(f32)                     # (dv, slab)
         part = jax.lax.dot_general(
-            pq_ref[0, 0].astype(jnp.bfloat16), state.astype(jnp.bfloat16),
+            pq.astype(jnp.bfloat16), state.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (rows, dv)
+            preferred_element_type=f32)                     # (rows, dv)
+        z_dec = _decay(gam, z_ref[0, pl.ds(h, 1), :].astype(f32))
+        dpart = jnp.broadcast_to(
+            jnp.sum(pq * z_dec, axis=1, keepdims=True), den_ref.shape[2:])
 
         @pl.when(s == 0)
         def _():
             num_ref[0, pl.ds(h, 1)] = part[None]
+            den_ref[0, pl.ds(h, 1)] = dpart[None]
 
         @pl.when(s > 0)
         def _():
             num_ref[0, pl.ds(h, 1)] += part[None]
+            den_ref[0, pl.ds(h, 1)] += dpart[None]
 
         dv = state.shape[0]
         vrow = jnp.broadcast_to(v_ref[0, pl.ds(h, 1), :], (dv, dv))
         eye = jax.lax.broadcasted_iota(jnp.int32, (dv, dv), 0) \
             == jax.lax.broadcasted_iota(jnp.int32, (dv, dv), 1)
         vcol = jnp.sum(jnp.where(eye, vrow, 0.0), axis=1, keepdims=True)
-        so_ref[0, 0] = (gam_ref[row_ref[r] * hkv + h] * state
-                        + vcol * pk_ref[0, pl.ds(h, 1), :]
-                        ).astype(so_ref.dtype)
+        so_ref[0, 0] = (gam * state + vcol * pk).astype(so_ref.dtype)
+        zo_ref[0, pl.ds(h, 1), :] = (z_dec + pk).astype(zo_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("slab", "interpret"))
-def _decode_pallas_call(state, pq, pk, v, gam, eslot, erow, n_live, *,
+def _decode_pallas_call(state, z, q, k, v, gam, eslot, erow, n_live, *,
                         slab: int, interpret: bool):
-    """The state's pass: ``state`` updated in place for the live rows,
-    and ``S_old^T phi(q)`` (B, Hkv, rows, dv) of every live row."""
-    b, hkv, rows, width = pq.shape
-    dv = state.shape[2]
+    """The pass over the live rows' cache: ``state`` and ``z`` updated in
+    place, and of every live row ``S_old^T phi(q)`` (B, Hkv, rows, dv)
+    and ``(gamma z_old) . phi(q)`` (B, Hkv, rows, 128; the sum on every
+    lane). ``q`` (B, Hkv, rows, n), ``k`` (B, Hkv, n) float32."""
+    b, hkv, rows, n = q.shape
+    dv, width = state.shape[2], state.shape[3]
     ns = width // slab
 
     def at(r, s, h, nl):
@@ -173,41 +248,51 @@ def _decode_pallas_call(state, pq, pk, v, gam, eslot, erow, n_live, *,
         s, h = at(r, s, h, nl)
         return slot[r], h, 0, s
 
-    def pq_map(r, s, h, nl, slot, row):
-        s, h = at(r, s, h, nl)
-        return row[r], h, 0, s
+    def z_map(r, s, h, nl, slot, row):
+        return slot[r], 0, at(r, s, h, nl)[0]
 
-    def pk_map(r, s, h, nl, slot, row):
-        return row[r], 0, at(r, s, h, nl)[0]
+    def by_row(*blk):
+        return pl.BlockSpec((1,) + blk, lambda r, s, h, nl, slot, row:
+                            (row[r],) + (0,) * len(blk))
 
-    def row_map(r, s, h, nl, slot, row):
-        return row[r], 0, 0
-
-    num, state = pl.pallas_call(
-        functools.partial(_decode_kernel, hkv=hkv),
+    num, den, state, z = pl.pallas_call(
+        functools.partial(_decode_kernel, hkv=hkv, ns=ns),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, ns, hkv),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, rows, slab), pq_map),
-                pl.BlockSpec((1, hkv, slab), pk_map),
-                pl.BlockSpec((1, hkv, dv), row_map),
-                pl.BlockSpec((1, 1, dv, slab), state_map)],
+                by_row(hkv, rows, n), by_row(hkv, n), by_row(hkv, dv),
+                pl.BlockSpec((1, 1, dv, slab), state_map),
+                pl.BlockSpec((1, hkv, slab), z_map)],
             out_specs=[
-                pl.BlockSpec((1, hkv, rows, dv),
-                             lambda r, s, h, nl, slot, row:
-                             (row[r], 0, 0, 0)),
-                pl.BlockSpec((1, 1, dv, slab), state_map)]),
+                by_row(hkv, rows, dv), by_row(hkv, rows, 128),
+                pl.BlockSpec((1, 1, dv, slab), state_map),
+                pl.BlockSpec((1, hkv, slab), z_map)],
+            scratch_shapes=[pltpu.VMEM((rows, slab), jnp.float32),
+                            pltpu.VMEM((8, slab), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, hkv, rows, dv), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={7: 1},
+                   jax.ShapeDtypeStruct((b, hkv, rows, 128), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct(z.shape, z.dtype)],
+        input_output_aliases={7: 2, 8: 3},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret, name="retention_decode",
-    )(n_live, eslot, erow, gam.reshape(-1), pq, pk, v, state)
-    return num, state
+    )(n_live, eslot, erow, gam.reshape(-1), q, k, v, state, z)
+    return num, den, state, z
+
+
+def _live_first(live):
+    """The batch row of each grid step, the live rows first and the
+    steps after them repeating the last live row, and how many are
+    live, (1,). They depend on ``live`` alone: XLA makes them once a
+    program, not once a layer."""
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    n_live = live.sum().astype(jnp.int32)
+    steps = jnp.minimum(jnp.arange(live.shape[0]), jnp.maximum(n_live - 1, 0))
+    return order[steps], n_live[None]
 
 
 def _decode_pallas(state, z, q, k, v, g, slots, live, eps, slab, interpret):
@@ -215,23 +300,17 @@ def _decode_pallas(state, z, q, k, v, g, slots, live, eps, slab, interpret):
     rows = -(-grp // 8) * 8
     gam = jnp.exp(g)
     qf, kf, vf = (a.astype(jnp.float32) for a in (q, k, v))
-    pq = phi(jnp.pad(qf, ((0, 0), (0, 0), (0, rows - grp), (0, 0))))
-    pk = phi(kf)
-    # live rows first; the steps after them repeat the last live row
-    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
-    n_live = live.sum().astype(jnp.int32)
-    erow = order[jnp.minimum(jnp.arange(b), jnp.maximum(n_live - 1, 0))]
-    z_dec = _decay(gam, z[slots].astype(jnp.float32))
-    den_old = jnp.einsum("bhgp,bhp->bhg", pq[:, :, :grp], z_dec)
-    num_old, state = _decode_pallas_call(
-        state, pq, pk, vf, gam, slots[erow], erow, n_live[None],
+    erow, n_live = _live_first(live)
+    num_old, den_old, state, z = _decode_pallas_call(
+        state, z, jnp.pad(qf, ((0, 0), (0, 0), (0, rows - grp), (0, 0))),
+        kf, vf, gam, slots[erow], erow, n_live,
         slab=slab, interpret=interpret)
     # the token itself, folded in: phi(q) . phi(k) = (q . k)^2
     qk2 = jnp.einsum("bhgn,bhn->bhg", qf, kf) ** 2
     num = gam[..., None, None] * num_old[:, :, :grp] \
         + qk2[..., None] * vf[:, :, None, :]
-    z = z.at[slots].set((z_dec + pk).astype(z.dtype))
-    return _finish(num, (den_old + qk2)[..., None], n, eps), state, z
+    den = den_old[:, :, :grp, :1] + qk2[..., None]
+    return _finish(num, den, n, eps), state, z
 
 
 def retention_decode(state, z, q, k, v, g, slots, live, *,
@@ -247,7 +326,11 @@ def retention_decode(state, z, q, k, v, g, slots, live, *,
     (B,) bool which of them count (a dead row names a trash row: what
     is written there and returned for it means nothing). Returns ``(y
     (B, Hkv, group, dv) float32, state, z)``; give ``state`` and ``z``
-    donated and both are updated in place."""
+    donated and both are updated in place, by the kernel itself: one
+    Mosaic call reads and writes every live row's state and normaliser
+    once, and no slot a live row does not name is touched. Elsewhere
+    than on the chip, or at a shape the kernel does not fit (``n % 128``,
+    ``P % slab``), the plain-XLA twin runs."""
     if interpret is None:
         if jax.default_backend() != "tpu" or q.shape[-1] % 128 \
                 or state.shape[-1] % slab:

@@ -251,24 +251,61 @@ def _kernel_inputs(b, n=128, dv=128, hkv=2, grp=2):
         g=jax.nn.log_sigmoid(4 + jax.random.normal(ks[5], (b, hkv))))
 
 
-def test_decode_kernel_matches_its_twin():
-    a = _kernel_inputs(3)
-    slots = jnp.asarray([3, 0, 1], jnp.int32)
-    live = jnp.asarray([True, False, True])
-    args = (a["state"], a["z"], a["q"], a["k"], a["v"], a["g"], slots, live)
+def _decode_case(slots, grp):
+    """The decode operands of ``len(slots)`` batch rows, a row live
+    where its slot is not the trash row."""
+    slots = jnp.asarray(slots, jnp.int32)
+    a = _kernel_inputs(len(slots), grp=grp)
+    return (a["state"], a["z"], a["q"], a["k"], a["v"], a["g"], slots,
+            slots > 0)
+
+
+@pytest.mark.parametrize("slots,grp,slab", [
+    ((3, 0, 1), 2, 1664),       # ISSUE 33's: one dead row between two
+    ((3, 0, 1), 5, 1664),       # Brumby's group: 5 query rows held as 8
+    ((3, 0, 1), 5, retention.SLAB),     # ... at the slab the chip runs
+    ((0, 5, 0, 0, 2, 0, 6), 2, retention.SLAB),     # ... a live row last
+    ((0, 0, 0), 2, retention.SLAB),     # no live row at all
+], ids=["one_dead", "group_5", "group_5_one_slab", "dead_between_live_last",
+        "none_live"])
+def test_decode_kernel_matches_its_twin(slots, grp, slab):
+    args = _decode_case(slots, grp)
     want = retention._decode_xla(*args, 1e-6)
-    got = retention.retention_decode(*args, interpret=True)
-    rows = np.asarray([0, 2])
+    got = retention.retention_decode(*args, slab=slab, interpret=True)
+    rows = np.flatnonzero(np.asarray(slots))
+    held = np.asarray(slots)[rows]
     # the read-out is a bfloat16 product, the update float32
     np.testing.assert_allclose(np.asarray(got[0])[rows],
                                np.asarray(want[0])[rows], atol=0.02)
     for g_, w_ in zip(got[1:], want[1:]):
-        np.testing.assert_allclose(np.asarray(g_)[[1, 3]],
-                                   np.asarray(w_)[[1, 3]], rtol=1e-5,
+        np.testing.assert_allclose(np.asarray(g_)[held],
+                                   np.asarray(w_)[held], rtol=1e-5,
                                    atol=1e-5)
-    # rows of no live batch row are not touched (row 0 is the trash row)
-    assert float(jnp.abs(got[1][2] - a["state"][2]).max()) == 0
-    assert float(jnp.abs(got[1][4] - a["state"][4]).max()) == 0
+    # the slots no live row names are not touched, bit for bit, in either
+    # array (row 0 is the trash row: what it holds means nothing)
+    idle = np.setdiff1d(np.arange(1, len(slots) + 2), held)
+    for g_, old in zip(got[1:], args[:2]):
+        assert np.array_equal(np.asarray(g_)[idle], np.asarray(old)[idle])
+
+
+@pytest.mark.parametrize("hook,fault", [
+    ("_phi_tile", "power_1"), ("SQRT2", "no_sqrt2"),
+    ("_decay", "z_not_decayed"), ("_finish", "no_normaliser")])
+def test_decode_kernel_reaches_a_hook_through_the_module(hook, fault):
+    """ISSUE 36: ``benchmark/faults_brumby.py`` replaces four names of
+    the module while the served program is traced (and drops what jit
+    remembers). The kernel's body has to look each up in the module, so
+    that the fault shows in what the KERNEL computes."""
+    from benchmark import faults_brumby
+    args = _decode_case((3, 0, 1), 5)
+    clean = retention.retention_decode(*args, interpret=True)
+    inner = getattr(retention, hook)
+    with faults_brumby.planted(fault):
+        assert getattr(retention, hook) is not inner
+        faulty = retention.retention_decode(*args, interpret=True)
+    rows = np.asarray([0, 2])
+    assert float(np.abs(np.asarray(faulty[0])[rows]
+                        - np.asarray(clean[0])[rows]).max()) > 1e-3
 
 
 @pytest.mark.parametrize("fresh,n_live", [(True, 32), (False, 19)])

@@ -910,11 +910,11 @@ class TestRetentionFamilyOnChip:
         from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
         text = compiled.as_text()
         assert text.count("tpu_custom_call") >= 8
-        state = arrays[0]
-        flat = (state.shape[0] * state.shape[1],) + state.shape[2:]
-        copies = pool_shaped_copies(text, state.shape) \
-            + pool_shaped_copies(text, flat)
-        assert not copies, copies[0][:300]
+        for a in arrays:            # the state and the normaliser
+            flat = (a.shape[0] * a.shape[1],) + a.shape[2:]
+            copies = pool_shaped_copies(text, a.shape) \
+                + pool_shaped_copies(text, flat)
+            assert not copies, copies[0][:300]
 
     def test_decode_program(self):
         import functools
@@ -968,10 +968,14 @@ class TestRetentionFamilyOnChip:
                              / (want ** 2).mean()))
 
     def test_decode_kernel_matches_its_twin(self):
+        """At the cell's shapes: 20 batch rows of which 11 are live
+        (dead rows between them, a live row last), 5 query heads a KV
+        head. The kernel updates the state AND the normaliser."""
         from bigdl_tpu.llm.kernels import retention
-        state, z, q, k, v, g = self._inputs(6, 7)
-        live = jnp.asarray([True, False, True, True, False, True])
-        slots = jnp.where(live, 1 + jnp.arange(6), 0).astype(jnp.int32)
+        state, z, q, k, v, g = self._inputs(20, 21)
+        live = jnp.asarray([i % 2 == 1 or i == 0 for i in range(20)])
+        assert int(live.sum()) == 11 and bool(live[-1])
+        slots = jnp.where(live, 1 + jnp.arange(20), 0).astype(jnp.int32)
         want = jax.jit(lambda *a: retention._decode_xla(*a, 1e-6))(
             state, z, q, k, v, g, slots, live)
         got = jax.jit(retention.retention_decode)(
@@ -985,8 +989,10 @@ class TestRetentionFamilyOnChip:
                          np.asarray(want[1])[held]) < 1e-5
         assert self._rel(np.asarray(got[2])[held],
                          np.asarray(want[2])[held]) < 1e-5
-        for row in (2, 5):          # the rows of the two dead batch rows
+        # the slots of the nine dead batch rows, both arrays
+        for row in 1 + np.flatnonzero(~lv):
             assert float(jnp.abs(got[1][row] - state[row]).max()) == 0
+            assert float(jnp.abs(got[2][row] - z[row]).max()) == 0
 
     @pytest.mark.parametrize("fresh,n_live", [(True, 1024), (False, 700)])
     def test_prefill_kernel_matches_its_twin(self, fresh, n_live):

@@ -1,10 +1,14 @@
 """The retention kernels alone on the chip (ISSUE 33): each against its
 plain-XLA twin at Brumby's widths, then timed. ``python3
-tools/exp_retention.py [--rows 20 --live 15 --slabs 640,1664,8320]``;
-prints one line a reading and writes them to
-``chiprun_out/exp_retention.json``. Here, with ``JAX_PLATFORMS=cpu
---interpret --rows 3 --live 2 --chunk 256``, it runs the kernels in
-interpret mode against the twins (no timing means anything)."""
+tools/exp_retention.py [--rows 20 --live 11,15 --slabs 640,1664,8320
+--out chiprun_out/exp_retention.json]``; prints one line a reading and
+writes them to ``--out``. A decode reading holds two times (ISSUE 36):
+``entry``, :func:`retention_decode` whole (the kernel and what XLA is
+left around it, one jit, the cache donated), and ``kernel``, the Mosaic
+call by itself; ``glue_ms`` is their difference. Here, with
+``JAX_PLATFORMS=cpu --interpret --rows 3 --live 2 --chunk 256``, it
+runs the kernels in interpret mode against the twins (no timing means
+anything)."""
 
 import argparse
 import json
@@ -41,13 +45,80 @@ def timed(fn, *args, reps=20):
     return (time.perf_counter() - t0) / reps
 
 
+def stream(fn, st, zz, reps=30):
+    """Seconds a call of ``fn(st, zz) -> (..., st, zz)``, the cache
+    donated and handed on from call to call."""
+    st, zz = st + 0, zz + 0
+    *_, st, zz = fn(st, zz)
+    jax.block_until_ready(st)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        *_, st, zz = fn(st, zz)
+    jax.block_until_ready((st, zz))
+    return (time.perf_counter() - t0) / reps
+
+
+def kernel_alone(q, k, v, g, slots, live, slab, interp):
+    """``fn(st, zz)``: the Mosaic call by itself, what XLA makes for it
+    made beforehand."""
+    grp = q.shape[2]
+    qf = jnp.pad(q.astype(jnp.float32),
+                 ((0, 0), (0, 0), (0, -grp % 8), (0, 0)))
+    erow, n_live = R._live_first(live)
+    rest = jax.block_until_ready((
+        qf, k.astype(jnp.float32), v.astype(jnp.float32), jnp.exp(g),
+        slots[erow], erow, n_live))
+    return jax.jit(lambda st, zz: R._decode_pallas_call(
+        st, zz, *rest, slab=slab, interpret=interp), donate_argnums=(0, 1))
+
+
+def decode(out, a, state, z, q, k, v, g, slots, live):
+    """Kernel against twin, then the entry whole (the kernel and what
+    XLA is left around it, one jit) beside the kernel alone."""
+    interp = a.interpret
+    lv = np.asarray(live)
+    srows = np.asarray(slots)[lv]
+    want = jax.jit(lambda *x: R._decode_xla(*x, 1e-6))(
+        state, z, q, k, v, g, slots, live)
+    for slab in [int(s) for s in a.slabs.split(",")]:
+        # the operands are arguments: closed over, XLA would fold what
+        # depends on them alone (phi, the ordering) at compile time
+        ops = (q, k, v, g, slots, live)
+        entry = lambda st, zz, *x, slab=slab: R.retention_decode(
+            st, zz, *x, slab=slab, interpret=interp)
+        got = jax.jit(entry)(state, z, *ops)
+        dead = np.setdiff1d(np.arange(1, state.shape[0]), srows)
+        r = {"y": rel(np.asarray(got[0])[lv], np.asarray(want[0])[lv]),
+             "state": rel(np.asarray(got[1])[srows],
+                          np.asarray(want[1])[srows]),
+             "z": rel(np.asarray(got[2])[srows], np.asarray(want[2])[srows]),
+             "untouched": max(
+                 float(np.abs(np.asarray(got[i])[dead]
+                              - np.asarray(old)[dead]).max())
+                 for i, old in ((1, state), (2, z))) if dead.size else 0.0}
+        if not interp:
+            moved = R.decode_bytes(int(lv.sum()), HKV, N, N)
+            whole = jax.jit(entry, donate_argnums=(0, 1))
+            for name, fn in (
+                    ("entry", lambda st, zz: whole(st, zz, *ops)),
+                    ("kernel", kernel_alone(*ops, slab, False))):
+                sec = stream(fn, state, z)
+                r[name] = {"ms": sec * 1e3, "gb_s": moved / sec / 1e9,
+                           "share_of_819": moved / sec / 819e9}
+            r["glue_ms"] = r["entry"]["ms"] - r["kernel"]["ms"]
+        out[f"decode_live_{int(lv.sum())}_slab_{slab}"] = r
+        print(f"decode live {int(lv.sum())} of {lv.size} slab {slab}: {r}",
+              flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=20)
-    ap.add_argument("--live", type=int, default=15)
+    ap.add_argument("--live", default="11,15")
     ap.add_argument("--chunk", type=int, default=1024)
     ap.add_argument("--slabs", default="640,1664,8320")
     ap.add_argument("--interpret", action="store_true")
+    ap.add_argument("--out", default="chiprun_out/exp_retention.json")
     a = ap.parse_args()
     interp = a.interpret
     out = {"device": jax.devices()[0].device_kind}
@@ -60,44 +131,10 @@ def main():
     k = unit(jax.random.normal(next(ks), (b, HKV, N))).astype(jnp.bfloat16)
     v = jax.random.normal(next(ks), (b, HKV, N)).astype(jnp.bfloat16)
     g = jnp.log(jax.nn.sigmoid(4 + jax.random.normal(next(ks), (b, HKV))))
-    live = jnp.arange(b) % 4 != 3 if a.live >= b else jnp.arange(b) < a.live
-    live = jnp.roll(live, 2)
-    slots = jnp.where(live, 1 + jnp.arange(b), 0).astype(jnp.int32)
-
-    # --- decode: kernel against twin --------------------------------
-    want = jax.jit(lambda *x: R._decode_xla(*x, 1e-6))(
-        state, z, q, k, v, g, slots, live)
-    for slab in [int(s) for s in a.slabs.split(",")]:
-        fn = jax.jit(lambda st, zz, *x, slab=slab: R._decode_pallas(
-            st, zz, *x, 1e-6, slab, interp), donate_argnums=())
-        got = fn(state, z, q, k, v, g, slots, live)
-        lv = np.asarray(live)
-        srows = np.asarray(slots)[lv]
-        r = {"y": rel(np.asarray(got[0])[lv], np.asarray(want[0])[lv]),
-             "state": rel(np.asarray(got[1])[srows],
-                          np.asarray(want[1])[srows]),
-             "z": rel(np.asarray(got[2])[srows], np.asarray(want[2])[srows]),
-             "untouched": float(np.abs(
-                 np.asarray(got[1])[1:][~lv] - np.asarray(state)[1:][~lv])
-                 .max()) if (~lv).any() else 0.0}
-        if not interp:
-            don = jax.jit(lambda st, zz, *x, slab=slab: R._decode_pallas(
-                st, zz, *x, 1e-6, slab, False)[1:], donate_argnums=(0, 1))
-            st2, z2 = state + 0, z + 0
-            jax.block_until_ready((st2, z2))
-            st2, z2 = don(st2, z2, q, k, v, g, slots, live)
-            jax.block_until_ready(st2)
-            t0 = time.perf_counter()
-            reps = 30
-            for _ in range(reps):
-                st2, z2 = don(st2, z2, q, k, v, g, slots, live)
-            jax.block_until_ready(st2)
-            sec = (time.perf_counter() - t0) / reps
-            moved = R.decode_bytes(int(lv.sum()), HKV, N, N)
-            r.update(ms=sec * 1e3, gb_s=moved / sec / 1e9,
-                     share_of_819=moved / sec / 819e9)
-        out[f"decode_slab_{slab}"] = r
-        print(f"decode slab {slab}: {r}", flush=True)
+    for n_live in [int(x) for x in a.live.split(",")]:
+        live = jnp.roll(jnp.arange(b) < n_live, 2)
+        slots = jnp.where(live, 1 + jnp.arange(b), 0).astype(jnp.int32)
+        decode(out, a, state, z, q, k, v, g, slots, live)
 
     # --- prefill chunk: kernel against twin -------------------------
     c = a.chunk
@@ -129,8 +166,8 @@ def main():
                      share_of_197=flops / sec / 197e12)
         out[f"chunk_fresh_{fresh}"] = r
         print(f"chunk {c} fresh {fresh} live {n_live}: {r}", flush=True)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/exp_retention.json", "w") as f:
+    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    with open(a.out, "w") as f:
         json.dump(out, f, indent=1)
 
 
