@@ -112,7 +112,25 @@ def dispatch(groups_of, live, n_groups: int, tm: int) -> Dispatch:
                     n_tiles.astype(jnp.int32), sizes)
 
 
-def _ffn_kernel(tg_ref, nt_ref, x_ref, wgu_ref, wd_ref, o_ref):
+ACTIVATIONS = ("swiglu", "relu2")
+
+
+def _activate(up, width: int, activation: str):
+    """The expert's nonlinearity over its first product ``up`` (...,
+    2I | I) float32: ``"swiglu"`` ``silu(gate) * up`` over ``(H, 2I)``
+    weights (gate columns, then up); ``"relu2"`` ``relu(up)^2`` over
+    ``(H, I)`` (an expert that is not gated). The one definition: the
+    kernel body and the XLA twin both call it."""
+    if activation == "swiglu":
+        return jax.nn.silu(up[..., :width]) * up[..., width:]
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    raise ValueError(f"activation {activation!r} is not one of "
+                     f"{ACTIVATIONS}")
+
+
+def _ffn_kernel(tg_ref, nt_ref, x_ref, wgu_ref, wd_ref, o_ref, *,
+                activation: str):
     i = pl.program_id(0)
 
     @pl.when(i < nt_ref[0])
@@ -120,34 +138,38 @@ def _ffn_kernel(tg_ref, nt_ref, x_ref, wgu_ref, wd_ref, o_ref):
         width = wd_ref.shape[0]
         gu = jnp.dot(x_ref[...], wgu_ref[...],
                      preferred_element_type=jnp.float32)
-        act = jax.nn.silu(gu[:, :width]) * gu[:, width:]
+        act = _activate(gu, width, activation)
         o_ref[...] = jnp.dot(act.astype(wd_ref.dtype), wd_ref[...],
                              preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("tm", "interpret", "activation"))
 def moe_expert_ffn(x_pad, w_gate_up, w_down, tile_group, n_tiles, *,
-                   tm: int, interpret: bool = False):
-    """``(M, H)`` rows in tiles of ``tm`` -> ``(M, H)`` float32: tile
-    ``i`` goes through the SwiGLU of group ``tile_group[i]`` of
-    ``w_gate_up`` (G, H, 2I: gate columns, then up) and ``w_down``
-    (G, I, H). Rows of tiles ``>= n_tiles[0]`` are left unwritten."""
+                   tm: int, interpret: bool = False,
+                   activation: str = "swiglu"):
+    """``(M, H)`` rows in tiles of ``tm`` -> ``(M, H')`` float32: tile
+    ``i`` goes through the expert ``tile_group[i]``: ``"swiglu"`` over
+    ``w_gate_up`` (G, H, 2I: gate columns, then up), ``"relu2"`` over
+    (G, H, I), then ``w_down`` (G, I, H'). Rows of tiles ``>=
+    n_tiles[0]`` are left unwritten."""
     m, h = x_pad.shape
     nt = m // tm
     width = w_down.shape[1]
+    first = w_gate_up.shape[2]
     last = lambda i, tg, n: (jnp.minimum(i, jnp.maximum(n[0] - 1, 0)), 0)
     # two buffers of one group's weights and of the row tiles, the
     # float32 intermediates, and slack
-    need = (2 * 3 * h * width * w_down.dtype.itemsize
+    need = (2 * (h * first + width * h) * w_down.dtype.itemsize
             + 2 * tm * h * (x_pad.dtype.itemsize + 4)
             + 3 * tm * 2 * width * 4 + (4 << 20))
     return pl.pallas_call(
-        _ffn_kernel,
+        functools.partial(_ffn_kernel, activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(nt,),
             in_specs=[
                 pl.BlockSpec((tm, h), last),
-                pl.BlockSpec((None, h, 2 * width),
+                pl.BlockSpec((None, h, first),
                              lambda i, tg, n: (tg[i], 0, 0)),
                 pl.BlockSpec((None, width, h),
                              lambda i, tg, n: (tg[i], 0, 0)),
@@ -161,7 +183,8 @@ def moe_expert_ffn(x_pad, w_gate_up, w_down, tile_group, n_tiles, *,
 
 
 def moe_expert_ffn_reference(x_pad, w_gate_up, w_down, tile_group,
-                             n_tiles, *, tm: int):
+                             n_tiles, *, tm: int,
+                             activation: str = "swiglu"):
     """XLA twin of :func:`moe_expert_ffn` (the path off the TPU): the
     tiles' weights are gathered, so it is for small widths. Unused
     tiles read zero."""
@@ -172,12 +195,12 @@ def moe_expert_ffn_reference(x_pad, w_gate_up, w_down, tile_group,
     x = x_pad.reshape(nt, tm, h).astype(jnp.float32)
     gu = jnp.einsum("nth,nhf->ntf", x,
                     w_gate_up[tile_group].astype(jnp.float32))
-    act = jax.nn.silu(gu[..., :width]) * gu[..., width:]
+    act = _activate(gu, width, activation)
     out = jnp.einsum("ntf,nfh->nth",
                      act.astype(w_down.dtype).astype(jnp.float32),
                      w_down[tile_group].astype(jnp.float32))
     used = jnp.arange(nt)[:, None, None] < n_tiles
-    return jnp.where(used, out, 0.0).reshape(m, h)
+    return jnp.where(used, out, 0.0).reshape(m, -1)
 
 
 def tile_rows(tokens: int) -> int:
@@ -187,11 +210,13 @@ def tile_rows(tokens: int) -> int:
 
 
 def grouped_ffn(x, groups_of, weights, live, w_gate_up, w_down, layer,
-                n_groups: int, interpret=None, held=None):
+                n_groups: int, interpret=None, held=None,
+                activation: str = "swiglu"):
     """The expert layer's sum for ``x`` (T, H): ``sum_j weights[t, j] *
-    SwiGLU_{groups_of[t, j]}(x[t])`` over the ``n_groups`` groups of
+    FFN_{groups_of[t, j]}(x[t])`` over the ``n_groups`` groups of
     layer ``layer`` in the whole-stack weights ``(L·G, H, 2I)`` /
-    ``(L·G, I, H)``. Returns ``(y (T, H) float32, group_sizes (G,))``.
+    ``(L·G, I, H)`` (``activation`` ``"swiglu"``; ``"relu2"``: ``(L·G,
+    H, I)``). Returns ``(y (T, H) float32, group_sizes (G,))``.
     Dead tokens (``live`` False) get zero and touch no group.
 
     ``held = (first, count)``: the stack holds only the groups ``first
@@ -212,13 +237,28 @@ def grouped_ffn(x, groups_of, weights, live, w_gate_up, w_down, layer,
     tg = d.tile_group + layer * n_groups
     if interpret is None and jax.default_backend() != "tpu":
         y_pad = moe_expert_ffn_reference(x_pad, w_gate_up, w_down, tg,
-                                         d.n_tiles, tm=tm)
+                                         d.n_tiles, tm=tm,
+                                         activation=activation)
     else:
         y_pad = moe_expert_ffn(x_pad, w_gate_up, w_down, tg, d.n_tiles,
-                               tm=tm, interpret=bool(interpret))
+                               tm=tm, interpret=bool(interpret),
+                               activation=activation)
     w = jnp.where(_per_assignment(live), weights.astype(jnp.float32), 0.0)
     y = jnp.where((w != 0)[..., None], w[..., None] * y_pad[d.pos], 0.0)
     return y.sum(axis=1), d.group_sizes
+
+
+def share_stats(group_sizes, live, top_k: int):
+    """What a chip that holds a share of a layer's experts counts of one
+    pass over it, (4,) int32: assignments computed here, assignments
+    left to the chips that hold the other experts, held experts with a
+    token, the fullest held expert's tokens. ``group_sizes`` (G,) is
+    :func:`grouped_ffn`'s, ``live`` the tokens that count, ``top_k``
+    the experts a token chose."""
+    here = group_sizes.sum()
+    return jnp.stack([here, live.sum() * top_k - here,
+                      (group_sizes > 0).sum(),
+                      group_sizes.max()]).astype(jnp.int32)
 
 
 def route_sigmoid(router, h, top_k: int, norm_topk: bool = True,

@@ -30,9 +30,13 @@ every token by the family's own kernels. It has no pages and no block
 table: the engine builds ``1 + max_batch`` state rows a layer (row 0
 the trash row), seats a request in the row of its slot
 (:class:`StateLedger`), grants nothing as it decodes, and the prefill
-program that first writes a seated row takes what it holds as zero. A
-family may declare state classes beside page classes, or, as a
-retention model does, nothing else.
+program that first writes a seated row takes what it holds as zero, in
+every array of the class. **A class names its arrays** (ISSUE 37): a
+retention layer a matrix a head and its normaliser, a state-space layer
+a matrix a head and the last inputs of its convolution. A family may
+declare state classes **beside page classes** (a request then holds
+pages and a slot's row at once, admitted and released together), or, as
+a retention model does, nothing else.
 """
 
 from __future__ import annotations
@@ -70,30 +74,57 @@ class PageClass:
 @dataclasses.dataclass(frozen=True)
 class StateClass:
     """State a slot holds, whatever its request's length: ``layers``
-    keep, each for ``heads`` heads, a matrix of ``width`` rows of
-    ``rows`` numbers (held transposed, the long axis last) and a vector
-    of ``rows``, in ``dtype``."""
+    keep, each, the arrays the class **names**. ``holds`` is one or two
+    ``(name, shape, dtype)``, the shape a slot's of one layer (the
+    engine hands a class's arrays to the programs where a page class's
+    K and V pools go, so there are at most two): a state-space layer
+    names a matrix a head and the convolution's last inputs.
+
+    A retention layer's two arrays have a shorthand, the class's first
+    form: ``heads``, ``rows``, ``width`` and ``dtype`` name a matrix of
+    ``width`` rows of ``rows`` numbers a head (held transposed, the long
+    axis last) and a vector of ``rows``; given them, ``holds`` is made
+    from them (so ``dataclasses.replace(cls, dtype=...)`` is another
+    precision of the same class)."""
     name: str
     layers: int
-    heads: int
-    rows: int
-    width: int
+    heads: int = 0
+    rows: int = 0
+    width: int = 0
     dtype: str = "float32"
+    holds: Tuple[Tuple[str, Tuple[int, ...], str], ...] = ()
+
+    def __post_init__(self):
+        holds = self.holds
+        if self.heads:
+            holds = (("state", (self.heads, self.width, self.rows),
+                      self.dtype),
+                     ("z", (self.heads, self.rows), self.dtype))
+        holds = tuple((str(n), tuple(int(d) for d in shape), str(dt))
+                      for n, shape, dt in holds)
+        if not 1 <= len(holds) <= 2:
+            raise ValueError(
+                f"state class {self.name!r} names {len(holds)} arrays; "
+                "a class's arrays go where a K and a V pool go: one or "
+                "two")
+        object.__setattr__(self, "holds", holds)
 
     def arrays(self, max_batch: int) -> Tuple:
-        """The class's two arrays for ``max_batch`` slots and the trash
-        row: ``(layers, 1 + max_batch, heads, width, rows)`` and
-        ``(layers, 1 + max_batch, heads, rows)``."""
+        """The class's arrays for ``max_batch`` slots and the trash
+        row, each ``(layers, 1 + max_batch) + shape``, zeros; None in
+        the second place where the class names one."""
         import jax.numpy as jnp
-        shape = (self.layers, 1 + max_batch, self.heads)
-        return (jnp.zeros(shape + (self.width, self.rows), self.dtype),
-                jnp.zeros(shape + (self.rows,), self.dtype))
+        made = tuple(jnp.zeros((self.layers, 1 + max_batch) + shape, dt)
+                     for _, shape, dt in self.holds)
+        return made + (None,) * (2 - len(made))
 
     @property
     def slot_bytes(self) -> int:
         """Bytes one seated slot holds."""
-        return self.layers * self.heads * (self.width + 1) * self.rows \
-            * np.dtype(self.dtype).itemsize
+        import jax.numpy as jnp      # knows bfloat16, as numpy does not
+        return self.layers * sum(
+            int(np.prod(shape)) * jnp.dtype(dt).itemsize
+            for _, shape, dt in self.holds)
 
 
 def every_token_class(classes) -> Optional[PageClass]:

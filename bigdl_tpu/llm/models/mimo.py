@@ -346,11 +346,7 @@ def expert_layer(lp, h, live, cfg: MimoConfig):
         h, idx, w, live, lp["experts"]["w_gate_up"],
         lp["experts"]["w_down"], 0, cfg.experts_held,
         held=(cfg.first_expert, cfg.experts_held))
-    here = sizes.sum()
-    routed = live.sum() * idx.shape[1]
-    stats = jnp.stack([here, routed - here, (sizes > 0).sum(),
-                       sizes.max()]).astype(jnp.int32)
-    return y.astype(h.dtype), stats, idx
+    return y.astype(h.dtype), moe.share_stats(sizes, live, idx.shape[1]), idx
 
 
 def _decoder(params, cfg: MimoConfig, x, attend, live):

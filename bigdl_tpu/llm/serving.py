@@ -681,11 +681,13 @@ class LLMServer:
         # ``_slot_pages`` are about; a class that keeps a window is a
         # ring (kvcache.classes.RingLedger) with a table and a ledger
         # of its own; a state class (kvcache.classes.StateLedger) is
-        # two arrays of one row a slot and has no table to keep: slot
-        # ``i`` holds row ``1 + i``, always. A one-class family's
-        # programs get the pools and the table as arrays, as they
-        # always have; a family of several gets tuples, one entry a
-        # class. A family with no every-token class has no pages to
+        # the arrays it names, one row a slot, and has no table to
+        # keep: slot ``i`` holds row ``1 + i``, always. Beside a page
+        # class a request holds its pages and its slot's row at once:
+        # admitted when both are there, released together. A one-class
+        # family's programs get the pools and the table as arrays, as
+        # they always have; a family of several gets tuples, one entry
+        # a class. A family with no every-token class has no pages to
         # hold: ``num_pages`` is ignored, the page ledger admits and
         # grants nothing, and a request needs a free slot and no more.
         self._every = every_token_class(self._classes)
@@ -1905,9 +1907,11 @@ class LLMServer:
                 pri["queue_class"].labels(**{"class": cls}).set(depth)
             pri["parked"].set(self._sched.parked())
         ins["kv_pages"].set(self.pages_in_use)
-        if self._rings:
-            # a family of several page classes: each class's own gauge
-            # (a state class has no pages: its gauge is below)
+        if self._multi and self._every is not None:
+            # a family of several classes with pages in one or more of
+            # them: each page class's own gauge (a state class has no
+            # pages: its gauge is below, and both are live for a family
+            # that declares a state class beside a page class)
             if self._class_ins is None:
                 self._class_ins = obs.gauge(
                     "bigdl_llm_kv_class_pages_in_use",
@@ -3300,6 +3304,10 @@ class LLMServer:
             self._eager.restart()
             ph.args["pages"] = self._grant_pages(disp, sargs, cargs)
             ph.args["eager_us"] = self._eager.microseconds()
+            if self._states and self._every is not None:
+                # pages granted beside seated slots: a request of such
+                # a family holds both at once
+                ph.args["state_slots"] = self.state_slots_in_use
             if self._rings:
                 # by class: granted in this pass, freed since the last
                 for name, n in self._grant_by_class.items():

@@ -244,9 +244,14 @@ def test_router_bias_chooses_and_scores_weigh():
 
 # (5) the expert product ---------------------------------------------------------
 
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
 @pytest.mark.parametrize("interpret", [None, True])
 @pytest.mark.parametrize("t,dead", [(7, (2,)), (33, ()), (600, (0, 599))])
-def test_grouped_ffn_computes_every_assignment(t, dead, interpret):
+def test_grouped_ffn_computes_every_assignment(t, dead, interpret,
+                                               activation):
+    """The kernel (interpret mode) and its XLA twin, a gated expert
+    (``swiglu`` over (H, 2I)) and one that is not (``relu2`` over (H,
+    I))."""
     rs = np.random.RandomState(t)
     k, g, hid, width, layers = 3, 6, 32, 16, 2
     x = jnp.asarray(rs.randn(t, hid), jnp.float32)
@@ -255,13 +260,16 @@ def test_grouped_ffn_computes_every_assignment(t, dead, interpret):
     w = jnp.asarray(rs.rand(t, k), jnp.float32)
     live = np.ones(t, bool)
     live[list(dead)] = False
-    wgu = jnp.asarray(rs.randn(layers * g, hid, 2 * width) * 0.2,
+    gated = activation == "swiglu"
+    wgu = jnp.asarray(rs.randn(layers * g, hid, (1 + gated) * width) * 0.2,
                       jnp.float32)
     wd = jnp.asarray(rs.randn(layers * g, width, hid) * 0.2, jnp.float32)
     y, sizes = moe.grouped_ffn(x, groups, w, jnp.asarray(live), wgu, wd, 1,
-                               g, interpret=interpret)
+                               g, interpret=interpret,
+                               activation=activation)
     gu = np.einsum("th,tkhf->tkf", x, np.asarray(wgu)[np.asarray(groups) + g])
-    act = gu[..., :width] / (1 + np.exp(-gu[..., :width])) * gu[..., width:]
+    act = gu[..., :width] / (1 + np.exp(-gu[..., :width])) \
+        * gu[..., width:] if gated else np.maximum(gu, 0) ** 2
     each = np.einsum("tkf,tkfh->tkh", act,
                      np.asarray(wd)[np.asarray(groups) + g])
     want = (np.asarray(w)[..., None] * each).sum(1) * live[:, None]

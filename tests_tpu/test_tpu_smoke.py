@@ -1016,3 +1016,172 @@ class TestRetentionFamilyOnChip:
         assert self._rel(got[2][2], want[2]) < 1e-5
         for row in (0, 1, 3):
             assert float(jnp.abs(got[1][row] - state[row]).max()) == 0
+
+
+class TestStateSpaceFamilyOnChip:
+    """ISSUE 37: the nemotron_h family at Nemotron-3-Super's published
+    widths, the first pipeline stage's 11 layers and a quarter of every
+    expert layer, over what the benchmark's engine holds (64 slots and
+    the trash row of state, 49,153 pages): the decode program and two
+    prefill buckets (one pass, and one that carries state, window and
+    pages from chunk to chunk inside the program) compile, hold a Mosaic
+    call a Mamba-2, attention and expert layer and make no copy shaped
+    like the state or the pools; the two state-space kernels and the
+    relu2 expert product agree with their ``jnp`` twins on the chip's
+    own layout, and the decode kernel leaves the rows of dead batch rows
+    alone."""
+
+    BATCH = 64
+    PAGES = 49153
+
+    def _operands(self):
+        from bigdl_tpu.llm.models import nemotron_h as nh
+        cfg = nh.NemotronHConfig(
+            num_hidden_layers=11, hybrid_override_pattern="MEMEMEM*EME",
+            experts_held=128)
+        params = jax.eval_shape(lambda: nh.init_params(cfg, 0))
+        kv, state = nh.page_classes(cfg)
+        k, v = jax.eval_shape(lambda: kv.pools(self.PAGES, 16,
+                                               jnp.bfloat16))
+        s, w = jax.eval_shape(lambda: state.arrays(self.BATCH))
+        assert s.shape == (5, 65, 128, 64, 128) and s.dtype == jnp.float32
+        assert w.shape == (5, 65, 3, 10240) and w.dtype == jnp.bfloat16
+        return nh, cfg, params, ((k, s), (v, w))
+
+    def _holds_kernels_and_no_copy(self, compiled, arrays):
+        from bigdl_tpu.llm.kvcache.write import pool_shaped_copies
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 11
+        for a in (arrays[0][0], arrays[1][0], arrays[0][1]):
+            flat = (a.shape[0] * a.shape[1],) + a.shape[2:]
+            copies = pool_shaped_copies(text, a.shape) \
+                + pool_shaped_copies(text, flat)
+            assert not copies, copies[0][:300]
+
+    def test_decode_program(self):
+        import functools
+        nh, cfg, params, (pools, others) = self._operands()
+        B = self.BATCH
+        fn = jax.jit(functools.partial(nh.paged_decode_step_sampled,
+                                       page=16),
+                     static_argnums=(1,), donate_argnums=(2, 3))
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        compiled = fn.lower(
+            params, cfg, pools, others, (i32(B, 1024), i32(B, 1)), i32(B),
+            jax.ShapeDtypeStruct((B, cfg.vocab_size), jnp.float32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jnp.float32),
+            jax.random.PRNGKey(0)).compile()
+        self._holds_kernels_and_no_copy(compiled, (pools, others))
+
+    @pytest.mark.parametrize("bucket", [256, 4096])
+    def test_prefill_program(self, bucket):
+        import functools
+        nh, cfg, params, (pools, others) = self._operands()
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        fn = jax.jit(functools.partial(nh.paged_prefill_ragged, page=16),
+                     static_argnums=(1,), donate_argnums=(2, 3))
+        compiled = fn.lower(
+            params, cfg, pools, others, i32(1, bucket), i32(), i32(),
+            (i32(1024), i32(1)), (i32(bucket), i32(bucket)), i32(bucket),
+            i32(), i32()).compile()
+        self._holds_kernels_and_no_copy(compiled, (pools, others))
+
+    @staticmethod
+    def _inputs(b, rows, seed=37):
+        ks = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+        return dict(
+            state=jax.random.normal(next(ks), (rows, 128, 64, 128)),
+            x=jax.random.normal(next(ks), (b, 128, 64)),
+            bm=jax.random.normal(next(ks), (b, 8, 128)),
+            cm=jax.random.normal(next(ks), (b, 8, 128)),
+            dt=jax.nn.softplus(jax.random.normal(next(ks), (b, 128)) - 4),
+            a=-jax.random.uniform(next(ks), (128,), minval=1.0,
+                                  maxval=16.0),
+            d=1 + 0.5 * jax.random.normal(next(ks), (128,)))
+
+    _rel = staticmethod(TestRetentionFamilyOnChip._rel)
+
+    def test_decode_kernel_matches_its_twin(self):
+        """At the cell's shapes: 64 batch rows of which 33 are live
+        (dead rows between them, a live row last)."""
+        from bigdl_tpu.llm.kernels import ssm
+        a = self._inputs(64, 65)
+        live = jnp.asarray([i % 2 == 1 or i == 0 for i in range(64)])
+        assert int(live.sum()) == 33 and bool(live[-1])
+        slots = jnp.where(live, 1 + jnp.arange(64), 0).astype(jnp.int32)
+        args = (a["state"], a["x"], a["bm"], a["cm"], a["dt"], a["a"],
+                a["d"], slots, live)
+
+        def twin(state, x, bm, cm, dt, av, d, slots, live):
+            with jax.default_matmul_precision("highest"):
+                y, s = ssm._decode_xla(state, dt[..., None] * x, bm, cm,
+                                       jnp.exp(dt * av), slots)
+            return y + d[None, :, None] * x, s
+        want = jax.jit(twin)(*args)
+        got = jax.jit(ssm.ssm_decode)(*args)
+        lv = np.asarray(live)
+        held = np.asarray(slots)[lv]
+        # the read-out is a bfloat16 product, the update float32
+        assert self._rel(np.asarray(got[0])[lv],
+                         np.asarray(want[0])[lv]) < 0.006
+        assert float(jnp.abs(got[0][~live]).max()) == 0
+        assert self._rel(np.asarray(got[1])[held],
+                         np.asarray(want[1])[held]) < 1e-5
+        for row in 1 + np.flatnonzero(~lv):
+            assert float(jnp.abs(got[1][row] - a["state"][row]).max()) == 0
+
+    @pytest.mark.parametrize("fresh,n_live", [(True, 1024), (False, 700)])
+    def test_prefill_kernel_matches_its_twin(self, fresh, n_live):
+        from bigdl_tpu.llm.kernels import ssm
+        a = self._inputs(1024, 4)
+        bm, cm = a["bm"] / 8, a["cm"] / 8
+
+        def twin(state):
+            with jax.default_matmul_precision("highest"):
+                dt, cum = ssm._running_sums(a["dt"], a["a"], n_live, 128)
+                y, s = ssm._chunk_xla(jnp.where(fresh, 0, state[2]),
+                                      dt[..., None] * a["x"], bm, cm, cum,
+                                      128)
+            return y + a["d"][None, :, None] * a["x"], s
+        want = jax.jit(twin)(a["state"])
+        got = jax.jit(lambda st: ssm.ssd_prefill_chunk(
+            st, a["x"], bm, cm, a["dt"], a["a"], a["d"], jnp.int32(2),
+            fresh, jnp.int32(n_live)))(a["state"])
+        assert self._rel(np.asarray(got[0])[:n_live],
+                         np.asarray(want[0])[:n_live]) < 0.008
+        assert self._rel(got[1][2], want[1]) < 0.004
+        for row in (0, 1, 3):
+            assert float(jnp.abs(got[1][row] - a["state"][row]).max()) == 0
+
+    @pytest.mark.parametrize("t", [64, 1024])
+    def test_latent_experts_product_matches_its_twin(self, t):
+        """22 of 512 a token, 128 held, relu2 in the 1,024-wide latent:
+        a decode batch (tiles of 16) and a prefill chunk (of 128)."""
+        from bigdl_tpu.llm.kernels import moe
+        ks = jax.random.split(jax.random.PRNGKey(t), 5)
+        x = jax.random.normal(ks[0], (t, 1024)).astype(jnp.bfloat16)
+        idx = jnp.argsort(jax.random.uniform(ks[1], (t, 512)))[:, :22] \
+            .astype(jnp.int32)
+        w = jax.random.uniform(ks[2], (t, 22))
+        up = (jax.random.normal(ks[3], (128, 1024, 2688)) / 32
+              ).astype(jnp.bfloat16)
+        down = (jax.random.normal(ks[4], (128, 2688, 1024)) / 52
+                ).astype(jnp.bfloat16)
+        live = jnp.ones(t, bool)
+        run = lambda interpret: jax.jit(
+            lambda *a: moe.grouped_ffn(
+                *a, 0, 128, held=(0, 128), activation="relu2",
+                interpret=interpret))(x, idx, w, live, up, down)
+        got, sizes = run(False)
+        tm = moe.tile_rows(t)
+        d = moe.dispatch(jnp.where(idx < 128, idx, 0), idx < 128, 128, tm)
+        x_pad = jnp.concatenate([x, jnp.zeros((1, 1024), x.dtype)])[
+            d.row_src]
+        y_pad = moe.moe_expert_ffn_reference(
+            x_pad, up, down, d.tile_group, d.n_tiles, tm=tm,
+            activation="relu2")
+        wm = jnp.where(idx < 128, w, 0.0)
+        want = (wm[..., None] * y_pad[d.pos]).sum(1)
+        assert int(sizes.sum()) == int((idx < 128).sum())
+        assert self._rel(got, want) < 0.01
