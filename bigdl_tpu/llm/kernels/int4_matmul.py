@@ -27,8 +27,9 @@ llama.cpp-family C kernels, SURVEY.md §3.4 hot loop). TPU design:
 - the q4_0 zero-point (-8) is algebraic, not elementwise:
   sum_k x_k*(q-8)*s = sum_k x_k*q*s - 8*sum_g (sum_{k in g} x_k)*s[g]
   so decode (m small) folds it into one skinny float32 product of the
-  group sums of x, taken once per call outside the kernel, with the
-  scale block as it arrives; prefill (m large, MXU-bound) subtracts 8
+  group sums of x, taken once a row tile in VMEM beside the even/odd
+  k-planes (ISSUE 38: both were XLA fusions ahead of every call), with
+  the scale block as it arrives; prefill (m large, MXU-bound) subtracts 8
   on the VPU before the product instead.
 - float16 never enters the kernel: this Mosaic build cannot load fp16
   (verified on chip before PR 1: "Unsupported cast"-class compile
@@ -111,12 +112,9 @@ and the kernel's OWN time fell too, 16.79 -> 15.97 ms a step (gate_up
 9.33 -> 8.77, o + down 5.46 -> 5.27, qkv 2.01 -> 1.93; 31.7 -> 33.3 %
 of its roofline) with the same tiles and bytes: it now streams from
 the parameter buffer and not from a temporary written a moment
-before. What is left around it: the even/odd split of the
-activations (``x[:, k0:k0+kc:2]``, a stride-2 lane gather XLA runs as
-``fusion[3584x16]`` / ``fusion[2048x16]``) costs 1.6 ms a step, more
-than attention. Not done: Llama-2's ``down_proj`` (K = 11,008: two
-chunks of g = 172, not 8-aligned) keeps the sliced 2-D path; a full-K
-block at bn = 128 would need its VMEM measured first.
+before. Not done: Llama-2's ``down_proj`` (K = 11,008: two chunks
+of g = 172, not 8-aligned) keeps the sliced 2-D path; a full-K block
+at bn = 128 would need its VMEM measured first.
 
 2 Oct 2026 ledger entry (ISSUE 30, PERF.md §5-6; v5e, Mistral-7B
 shapes, stacked form, m = 16 unless said; ``tools/exp_int4_body.py``,
@@ -185,6 +183,66 @@ the step 19.58 -> 11.34 ms, ``prefill_dev_tok_s`` 3,356 -> 6,046
 the step 19.57 -> 11.19 ms, 33.2 -> 68.8 %, 3,354 -> 5,910), and
 ``itl_p95_ms`` 25.08 -> 13.48 ms over nine same-seed pairs.
 
+15 Oct 2026 ledger entry (ISSUE 38, PERF.md §5-6; v5e, Mistral-7B
+shapes, stacked form, bn = 512, ``tools/exp_int4_body.py``): **the
+kernel takes its activations whole.** Until now XLA split each K chunk
+of x into its even and odd k-planes (``x[:, 0::2]``, ``x[:, 1::2]``, a
+stride-2 lane gather) and took the group sums ahead of every call:
+``fusion[2048x16]`` + ``fusion[3584x16]`` 1.45 ms of an 11.27 ms
+decode step, 7.9-10.8 us a call for 128-224 KB (traced pair, seed
+3800000011). The x operand is now one ``(bm, kc)`` block of x at
+``(i, chunk)`` (no slice for ``down_proj``'s chunks either), and at
+the first N tile of a row tile the kernel builds both planes and the
+group sums in VMEM (:func:`_deinterleave`), the N axis "arbitrary".
+**Step 0**, us a call at m = 16; "--vary" rolls x a row each
+iteration, without which XLA hoists the parent's split out of the
+timed loop (first column: so the parent there is its kernel alone):
+
+    form                                 gate_up  down   qkv    o
+    parent, split hoisted (no --vary)     129.9   71.1   31.8  23.0
+    parent, XLA split + sums (--vary)     138.1  109.9   39.6  29.5
+      the same, sums zeroed (")           136.9   93.7   38.4  29.8
+    no split at all (the floor, ")        128.3   66.3   28.7  20.6
+    blocks stacked by two loops (")       128.3   68.7   30.1  21.3
+    blocks stacked by a reshape (kept)    128.2   68.7   29.8  20.9
+    one product a block (no --vary)       133.5   84.0   35.0  26.5
+    x transposed, sublane stride 2 (")    133.0   81.2   33.6  25.0
+    lane-strided load                      does not lower
+
+(the two no-``--vary`` forms beside the loop form's 131.7 / 79.1 / 32.9
+/ 24.8 in that first harness; the lane-strided load: "Strided load
+with non 32-bit data", and in float32 "The last dim size is not 128";
+the transposed form ran out of scoped VMEM at m = 512 for ``down``).
+At m = 512 (``sub8``, bm = 128, --vary) gate_up parent | loops |
+reshape 768.8 | 709.3 | 713.4, down 451.0 | 384.3 | 402.0. The split
+costs the kernel 0-2.4 us a call against 9-44 us of XLA's (``down``'s
+two 7,168-lane chunks paid most: a slice, then the gather, then a
+reduce, three fusions a chunk). What the selection product does is
+exact: one 1.0 a column, bf16 operands, float32 accumulation; a -0.0
+comes out +0.0. **Set-up is part of the cost**: every program that
+holds the kernel lowers it, and on the chip machine a lowering's time
+swings with the Python stack depth it starts at (six kernels at 20
+depths: 135-1,011 ms, mean 241-244, for the parent; 166-1,045, mean
+304-306, with the loops; 162-1,054, mean 276, with the reshape, which
+nests no region under the ``pl.when``). The loop form read +3.5-4.5 s
+of warm ``setup_s`` in the cell (its warm-up 16.3-17.4 s against
+12.7-13.6), hence the reshape. Index arithmetic is shifts and masks:
+``//``, ``%`` and ``jnp.where`` lowered as 43 nested jitted functions
+a call, through ``sign``. The reshape's relayout grows with the row
+tile: scoped VMEM is raised to 32 MiB, and a (128, 7,168) chunk
+compiles in 3-4 s against 1.6 (a cold compile only).
+**In the served cell** (the loop form, which runs the same products;
+parent | change, traced seeds 3800000011 and 3800000101): the two
+fusions gone, and with them the group sums' reduces and
+``down_proj``'s chunk slices; the kernel 7.87 | 8.00 ms a step, the
+step 11.27 | 9.22 and 11.38 | 9.26 ms, ``int4_decode_roofline`` 68.90
+| 67.79 and 68.79 | 67.86 %, ``prefill_dev_tok_s`` 6,293 | 6,672 and
+6,520 | 6,944; ``itl_p95_ms`` over four same-seed pairs 13.30, 13.51,
+13.69, 13.25 | 10.95, 11.02, 11.69, 11.04 (PERF.md §6). The reshape
+form: the step 11.38 | 9.29 ms, 68.79 | 67.59 %, 6,520 | 6,807 tokens/s
+(seed 3800000101), ``itl_p95_ms`` 13.30, 13.24 | 10.88, 10.98,
+``setup_s`` 37.81, 38.99 | 38.39.
+
 ``interpret=True`` runs the same kernel on CPU for tests (SURVEY.md §4:
 golden parity against an independent implementation — here the numpy
 dequant reference).
@@ -235,39 +293,122 @@ _SLAB = 1024            # packed rows a slab: 64 scale groups, eight
                         # and every slab is traced, so not smaller
 
 
-def _group_sums(x):
-    """float32 sums of ``x`` (m, K) over each scale group: (m, K/QK).
-    What the folded zero-point needs of the activations; the same for
-    every N tile, so taken once per call, outside the kernel."""
-    m, k = x.shape
-    return x.astype(jnp.float32).reshape(m, k // QK, QK).sum(-1)
+_SEL = 256              # lanes of x one selection product de-interleaves
+_VMEM_LIMIT = 32 << 20  # scoped VMEM: the split's stacked blocks and
+                        # products beside the double-buffered blocks (a
+                        # (128, 7,168) chunk in ``corr`` needs 16.06 MB,
+                        # over the default 16; the decode kernels' code
+                        # is the same either way)
 
 
-def _x_operands(x, sub8: bool):
-    """What the kernel takes of a K chunk of activations ``(m, kc)``:
-    the even and odd k-planes and, where the zero-point is folded
-    (``corr``), the group sums."""
-    xe, xo = x[:, 0::2], x[:, 1::2]
-    return (xe, xo) if sub8 else (xe, xo, _group_sums(x))
+def _selection(rows: int, dtype):
+    """(rows, 256) 0/1: column j < 128 picks lane 2j of a block of x,
+    column 128 + j lane 2j + 1 (a last block of fewer than 256 lanes
+    leaves the columns past its own half zero)."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, _SEL), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, _SEL), 1)
+    return (r == ((c & 127) << 1) + (c >> 7)).astype(dtype)
 
 
-def _x_specs(bm: int, kc: int, sub8: bool):
-    """BlockSpecs of :func:`_x_operands`; the index maps take the
-    stacked form's scalar-prefetch operand too."""
-    specs = [pl.BlockSpec((bm, kc // 2), lambda i, j, *_: (i, 0))] * 2
+def _grouping(rows: int, g: int, first, dtype):
+    """(rows, g) 0/1: lane k of a block of x counts towards group
+    ``first + k // QK``, or, where ``first`` is None, towards every
+    group whose index is ``k // QK`` modulo ``_SEL // QK`` (the blocks
+    stacked on the sublanes share one matrix; a mask keeps each row
+    block's own groups)."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, g), 0) >> 5    # // QK
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, g), 1)
+    c = c & 7 if first is None else c - first              # % (_SEL // QK)
+    return (r == c).astype(dtype)
+
+
+def _split_scratch(bm: int, kc: int, sub8: bool):
+    """VMEM of :func:`_deinterleave`: the two k-planes and, where the
+    zero-point is folded (``corr``), the group sums."""
+    out = [pltpu.VMEM((bm, kc // 2), jnp.bfloat16)] * 2
     if not sub8:
-        specs.append(pl.BlockSpec((bm, kc // QK), lambda i, j, *_: (i, 0)))
-    return specs
+        out.append(pltpu.VMEM((bm, kc // QK), jnp.float32))
+    return out
 
 
-def _int4_kernel(xe_ref, xo_ref, *refs, sub8: bool, cdt=jnp.bfloat16):
+def _deinterleave(x_ref, xe_ref, xo_ref, xs_ref, *, cdt):
+    """``x_ref`` (bm, kc) -> its even k-plane in ``xe_ref`` and its odd
+    one in ``xo_ref`` (bm, kc/2), in VMEM, and (``xs_ref`` not None)
+    its float32 sums over each scale group in ``xs_ref`` (bm, kc/QK):
+    what the folded zero-point needs.
+
+    Each 256-lane block of x times :func:`_selection` is the block's 128
+    even lanes then its 128 odd ones: one 1.0 a column, the product
+    accumulated in float32, so each number comes out as it went in.
+    The blocks are first stacked on the sublanes, ``(bm, nb, 256) ->
+    (nb, bm, 256)`` (Mosaic moves the vregs), so that ONE product takes
+    them all and the MXU loads the selection once, not once a block;
+    the result is unstacked the same way into the planes. The group
+    sums are a second product of the same stack, by :func:`_grouping`:
+    each block's own eight groups kept by a mask and the blocks added,
+    one non-zero term each. A last block of fewer than 256 lanes (kc =
+    5,504, or a single chunk of 224) takes products of its own. No
+    loop: every region nested under the kernel's ``pl.when`` costs
+    lowering time in each program that holds the kernel (ISSUE 38)."""
+    bm, kc = x_ref.shape
+    nb, tail = divmod(kc, _SEL)
+    g = kc // QK
+    if nb:
+        s = x_ref[:, :nb * _SEL].reshape(bm, nb, _SEL).swapaxes(0, 1) \
+            .reshape(nb * bm, _SEL).astype(cdt)
+        if xs_ref is not None:
+            y = jnp.dot(s, _grouping(_SEL, g, None, cdt),
+                        preferred_element_type=jnp.float32
+                        ).reshape(nb, bm, g)
+            own = (jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, y.shape, 2) >> 3)
+            xs_ref[...] = jax.lax.reduce_sum(
+                jax.lax.select(own, y, jnp.zeros_like(y)), (0,))
+        r = jnp.dot(s, _selection(_SEL, cdt),
+                    preferred_element_type=jnp.float32
+                    ).astype(xe_ref.dtype).reshape(nb, bm, _SEL)
+        xe_ref[:, :nb * 128] = r[:, :, :128].swapaxes(0, 1) \
+            .reshape(bm, nb * 128)
+        xo_ref[:, :nb * 128] = r[:, :, 128:].swapaxes(0, 1) \
+            .reshape(bm, nb * 128)
+    if tail:
+        t = x_ref[:, nb * _SEL:].astype(cdt)
+        r = jnp.dot(t, _selection(tail, cdt),
+                    preferred_element_type=jnp.float32).astype(xe_ref.dtype)
+        xe_ref[:, nb * 128:] = r[:, :tail // 2]
+        xo_ref[:, nb * 128:] = r[:, 128:128 + tail // 2]
+        if xs_ref is not None:
+            part = jnp.dot(t, _grouping(tail, g, nb * _SEL // QK, cdt),
+                           preferred_element_type=jnp.float32)
+            xs_ref[...] = xs_ref[...] + part if nb else part
+
+
+def _x_inputs(x, bm: int, k0: int, kc: int):
+    """The activations the kernel takes for the K chunk at ``k0`` and
+    its BlockSpec (the index map takes the stacked form's
+    scalar-prefetch operand too): x itself, whose block ``(i, k0 /
+    kc)`` IS the chunk where the chunks tile K (a lane dim of whole
+    128s, or the whole K), else the chunk's slice."""
+    if k0 % kc == 0 and (kc % 128 == 0 or kc == x.shape[1]):
+        return x, pl.BlockSpec((bm, kc),
+                               lambda i, j, *_, c=k0 // kc: (i, c))
+    return x[:, k0:k0 + kc], pl.BlockSpec((bm, kc), lambda i, j, *_: (i, 0))
+
+
+def _int4_kernel(x_ref, *refs, sub8: bool, cdt=jnp.bfloat16):
     """One (bm, bn) output tile.
 
-    xe/xo: (bm, K/2) even/odd k-plane activations; xs (``corr`` only):
-    (bm, G) float32 :func:`_group_sums` of x; q: (K/2, bn) packed
-    uint8 (low nibble = even k, high = odd k); scale: (G, bn) float32.
-    ``cdt`` is the MXU operand dtype (f32 under interpret: the CPU thunk
-    cannot execute bf16 x bf16 dots).
+    x: (bm, kc) activations, whole; q: (K/2, bn) packed uint8 (low
+    nibble = even k, high = odd k); scale: (G, bn) float32; then the
+    scratch of :func:`_split_scratch`. ``cdt`` is the MXU operand dtype
+    (f32 under interpret: the CPU thunk cannot execute bf16 x bf16
+    dots).
+
+    The even and odd k-planes of x and, for ``corr``, its group sums
+    are built in VMEM (:func:`_deinterleave`) at the first N tile of a
+    row tile and serve every other: the N axis is "arbitrary", walked
+    in order (v5e has one TensorCore, so nothing runs in parallel that
+    could).
 
     K is walked in slabs of ``_SLAB`` packed rows (the last may be
     shorter) with the float32 accumulator carried, so a slab's chain
@@ -277,11 +418,16 @@ def _int4_kernel(xe_ref, xo_ref, *refs, sub8: bool, cdt=jnp.bfloat16):
     rotate: no expansion matmul, nothing rebuilt per grid step) and
     every weight is ``cdt(q * scale)``: the product taken in float32,
     rounded ONCE."""
+    q_ref, scale_ref, o_ref, xe_ref, xo_ref, *scratch = refs
+    xs_ref = None if sub8 else scratch.pop(0)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        _deinterleave(x_ref, xe_ref, xo_ref, xs_ref, *scratch, cdt=cdt)
+
     if sub8:
-        q_ref, scale_ref, o_ref = refs
         acc = jnp.zeros(o_ref.shape, jnp.float32)
     else:
-        xs_ref, q_ref, scale_ref, o_ref = refs
         # the q4_0 zero-point: -8 * sum_g (sum_{k in g} x_k) * s[g],
         # float32 sums against float32 scales
         acc = -8.0 * jnp.dot(xs_ref[:], scale_ref[:],
@@ -438,21 +584,25 @@ def _int4_matmul_jit(x, q_t, scale_t, bm: int, bn: int,
         qc = q_t[k0 // 2:(k0 + kc) // 2]
         sc = scale_t[k0 // QK:(k0 + kc) // QK]
         half, g = kc // 2, kc // QK
+        xc, xspec = _x_inputs(x, bm, k0, kc)
         part = pl.pallas_call(
             functools.partial(_int4_kernel, sub8=sub8,
                               cdt=jnp.float32 if interpret
                               else jnp.bfloat16),
             grid=(mp // bm, np_ // bn),
-            in_specs=_x_specs(bm, kc, sub8) + [
+            in_specs=[
+                xspec,
                 pl.BlockSpec((half, bn), lambda i, j: (0, j)),
                 pl.BlockSpec((g, bn), lambda i, j: (0, j)),
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+            scratch_shapes=_split_scratch(bm, kc, sub8),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
-        )(*_x_operands(x[:, k0:k0 + kc], sub8), qc, sc)
+        )(xc, qc, sc)
         out = part if out is None else out + part
     return out[:m, :n].astype(out_dtype)
 
@@ -517,24 +667,28 @@ def _int4_matmul_stacked_jit(x, q_t, scale_t, layer, bm: int, bn: int,
     out = None
     for c, (k0, kc) in enumerate(chunks):
         half, g = kc // 2, kc // QK
+        xc, xspec = _x_inputs(x, bm, k0, kc)
         part = pl.pallas_call(
             functools.partial(_int4_stacked_kernel, sub8=sub8,
                               cdt=jnp.float32 if interpret
                               else jnp.bfloat16),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(mp // bm, n // bn),
-                in_specs=_x_specs(bm, kc, sub8) + [
+                in_specs=[
+                    xspec,
                     pl.BlockSpec((None, half, bn),
                                  lambda i, j, l, c=c: (l[0], c, j)),
                     pl.BlockSpec((None, g, bn),
                                  lambda i, j, l, c=c: (l[0], c, j)),
                 ],
-                out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j))),
+                out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
+                scratch_shapes=_split_scratch(bm, kc, sub8)),
             out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
-        )(layer, *_x_operands(x[:, k0:k0 + kc], sub8), q_t, scale_t)
+        )(layer, xc, q_t, scale_t)
         out = part if out is None else out + part
     return out[:m].astype(out_dtype)
 

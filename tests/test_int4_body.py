@@ -6,7 +6,10 @@ strategies, M tiles padded and not, K in one partial slab (256), in
 whole slabs (4,096), in two chunks whose last slab is partial (11,008:
 2 x (2 x 1,024 + 704) packed rows, g = 172; 14,336: 2 x (3 x 1,024 +
 512)), N a multiple of the tile and not, the 2-D form and two
-layers of a stack (which must equal the 2-D form bit for bit)."""
+layers of a stack (which must equal the 2-D form bit for bit).
+ISSUE 38: the kernel takes each K chunk of x whole and builds its
+even and odd k-planes in VMEM; those planes against ``x[:, 0::2]``
+and ``x[:, 1::2]``, bit for bit."""
 
 import functools
 import importlib
@@ -16,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from bigdl_tpu.llm.ggml.quantize import quantize
 from bigdl_tpu.llm.kernels import (int4_matmul, int4_matmul_reference,
@@ -119,10 +123,65 @@ def test_slabs_cover_every_chunk_shape():
             assert all(r % im.HALF == 0 for r in rows)
 
 
-def test_group_sums_are_float32_sums_over_each_group():
-    x = np.random.RandomState(0).randn(5, 96).astype(np.float32)
-    got = np.asarray(im._group_sums(jnp.asarray(x, jnp.bfloat16)))
-    want = np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32)) \
-        .reshape(5, 3, 32).sum(-1)
-    assert got.dtype == np.float32
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+@pytest.mark.parametrize("kc", [96, 4096, 5504])
+def test_group_sums_are_float32_sums_over_each_group(kc):
+    """The sums the kernel builds beside the planes (``corr``): float32
+    sums of the bf16 activations over each group of 32, in another
+    order than a plain reduce, so within float32 rounding of it. A
+    chunk of whole blocks (4,096), one with a partial last block
+    (5,504) and one of a partial block alone (96)."""
+    x = np.random.RandomState(kc).randn(16, kc).astype(np.float32)
+    xb = jnp.asarray(x, jnp.bfloat16)
+    _, _, got = _planes(xb, 16)
+    want = np.asarray(xb.astype(jnp.float32)).reshape(16, -1, 32).sum(-1)
+    assert np.asarray(got).dtype == np.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                               atol=1e-6)
+
+
+def _planes(x, bm):
+    """The kernel's ``_deinterleave`` run alone under interpret, one
+    row tile a grid step: its two planes and its group sums written
+    out."""
+    mp, kc = x.shape
+
+    def kern(x_ref, xe_ref, xo_ref, xs_ref, *stack):
+        im._deinterleave(x_ref, xe_ref, xo_ref, xs_ref, *stack,
+                         cdt=jnp.float32)
+
+    plane = pl.BlockSpec((bm, kc // 2), lambda i: (i, 0))
+    return pl.pallas_call(
+        kern, grid=(mp // bm,),
+        in_specs=[pl.BlockSpec((bm, kc), lambda i: (i, 0))],
+        out_specs=[plane, plane,
+                   pl.BlockSpec((bm, kc // 32), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((mp, kc // 2), jnp.bfloat16)] * 2
+        + [jax.ShapeDtypeStruct((mp, kc // 32), jnp.float32)],
+        scratch_shapes=im._split_scratch(bm, kc, sub8=False)[3:],
+        interpret=True)(x)
+
+
+@pytest.mark.parametrize("m", [1, 16, 100, 300])
+@pytest.mark.parametrize("kc", [224, 256, 4096, 5504, 7168])
+def test_planes_are_the_even_and_odd_lanes(kc, m):
+    """Every K chunk the kernel meets: one partial block (224), one
+    whole block, 16 and 28 of them (Mistral's K and ``down_proj``'s
+    chunk), and 21 and a half (Llama-2's 5,504). The selection product
+    must hand every number on unchanged: both signs, exponents over the
+    whole normal range, the M padding's zeros. (A -0.0 comes out +0.0:
+    one 1.0 and 255 zeros a column add up to +0.0, which the weight
+    product then takes as the same zero.)"""
+    bm = im._align_bm(128, m)
+    mp = -(-m // bm) * bm
+    rs = np.random.RandomState(kc + m)
+    x = (np.where(rs.rand(mp, kc) < 0.5, -1.0, 1.0)
+         * (1.0 + rs.rand(mp, kc))
+         * np.exp2(rs.randint(-126, 64, (mp, kc)))).astype(np.float32)
+    x[m:] = 0.0
+    x = jnp.asarray(x, jnp.bfloat16)
+    xe, xo, _ = _planes(x, bm)
+    want_e, want_o = np.asarray(x[:, 0::2]), np.asarray(x[:, 1::2])
+    np.testing.assert_array_equal(np.asarray(xe).view(np.uint16),
+                                  want_e.view(np.uint16))
+    np.testing.assert_array_equal(np.asarray(xo).view(np.uint16),
+                                  want_o.view(np.uint16))

@@ -8,6 +8,7 @@ chip tests hold the engine's programs to."""
 import dataclasses
 import functools
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -288,3 +289,57 @@ def test_weight_slices_reads_the_hlo_text(body, step, found):
     assert [line.split(" = ")[0].replace("ROOT ", "").lstrip("%")
             for line in got] == found
     assert weight_slices(text, {"norm": _LAYERS["input_layernorm"]}) == []
+
+
+def strided_reads(text):
+    """The ops of a lowered (StableHLO) module that read an array at a
+    stride or through a gather: ``x[:, 0::2]`` lowers to either. A
+    Mosaic call's body is serialised into its ``backend_config``, so
+    only what stands OUTSIDE the kernel is in the text."""
+    made = re.compile(r"stablehlo\.gather|stablehlo\.slice\s.*\[[^\]]*"
+                      r"\d+:\d+:([2-9]|\d\d)")
+    return [line.strip() for line in text.splitlines() if made.search(line)]
+
+
+@pytest.mark.parametrize("line,found", [
+    ("%0 = stablehlo.slice %arg0 [0:16, 0:512:2] : (tensor<16x512xbf16>)"
+     " -> tensor<16x256xbf16>", True),
+    ('%8 = "stablehlo.gather"(%arg0, %7) <{dimension_numbers = '
+     "#stablehlo.gather<offset_dims = [0]>}>", True),
+    ("%1 = stablehlo.slice %arg0 [0:16, 3:40] : (tensor<16x512xbf16>) "
+     "-> tensor<16x37xbf16>", False),
+])
+def test_strided_reads_reads_the_text(line, found):
+    """The parent's even/odd split (a stride-2 slice, or the gather jnp
+    makes of ``x[:, 0::2]``) is found; a K chunk's contiguous slice is
+    not."""
+    assert bool(strided_reads("module {\n  " + line + "\n}")) == found
+
+
+@pytest.mark.parametrize("form", ["2d", "stack"])
+@pytest.mark.parametrize("mode", ["corr", "sub8"])
+@pytest.mark.parametrize("k,max_bk", [
+    (224, 8192),      # one chunk, a partial selection block
+    (512, 256),       # two chunks, each a block of x in place
+    (320, 256),       # two chunks of 160 lanes: sliced (the stack: 2-D)
+])
+def test_no_split_outside_the_kernel(form, mode, k, max_bk, monkeypatch):
+    """ISSUE 38's witness: ``int4_matmul`` lowered for the TPU (no chip
+    needed) holds its Mosaic calls, one a K chunk, and nothing beside
+    them that reads the activations at a stride."""
+    monkeypatch.setattr(im, "_MAX_BK", max_bk)
+    x = jax.ShapeDtypeStruct((16, k), jnp.float32)
+    q = jax.ShapeDtypeStruct((L, k // 2, 384), jnp.uint8)
+    s = jax.ShapeDtypeStruct((L, k // 32, 384), jnp.float32)
+    if form == "stack":
+        traced = jax.jit(lambda x, q, s, l: int4_matmul(
+            x, q, s, layer=l, mode=mode)).trace(
+                x, q, s, jax.ShapeDtypeStruct((), jnp.int32))
+    else:
+        traced = jax.jit(functools.partial(int4_matmul, mode=mode)).trace(
+            x, jax.ShapeDtypeStruct(q.shape[1:], q.dtype),
+            jax.ShapeDtypeStruct(s.shape[1:], s.dtype))
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == \
+        len(im._chunk_k(k))
+    assert strided_reads(text) == []

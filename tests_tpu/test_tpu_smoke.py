@@ -515,6 +515,46 @@ class TestInt4BodyOnChip:
         assert apart < 0.02
 
 
+class TestInt4SplitOnChip:
+    """ISSUE 38: the kernel's even/odd planes and group sums, built in
+    VMEM by the selection product on the MXU (bf16 operands, float32
+    accumulation), against ``x[:, 0::2]``, ``x[:, 1::2]`` bit for bit
+    and the float32 group sums, at the served chunks: K = 4,096 and
+    ``down_proj``'s 7,168, a decode tile and a prefill tile."""
+
+    @pytest.mark.parametrize("m", [16, 128])
+    @pytest.mark.parametrize("kc", [4096, 7168, 5504])
+    def test_planes_and_sums(self, kc, m):
+        import importlib
+        from jax.experimental import pallas as pl
+        im = importlib.import_module("bigdl_tpu.llm.kernels.int4_matmul")
+
+        def kern(x_ref, xe_ref, xo_ref, xs_ref, *stack):
+            im._deinterleave(x_ref, xe_ref, xo_ref, xs_ref, *stack,
+                             cdt=jnp.bfloat16)
+
+        x = jnp.asarray(np.random.RandomState(kc + m).randn(m, kc),
+                        jnp.bfloat16)
+        plane = jax.ShapeDtypeStruct((m, kc // 2), jnp.bfloat16)
+        xe, xo, xs = pl.pallas_call(
+            kern, grid=(1,),
+            in_specs=[pl.BlockSpec((m, kc), lambda i: (0, 0))],
+            out_specs=[pl.BlockSpec((m, kc // 2), lambda i: (0, 0))] * 2
+            + [pl.BlockSpec((m, kc // 32), lambda i: (0, 0))],
+            out_shape=[plane, plane,
+                       jax.ShapeDtypeStruct((m, kc // 32), jnp.float32)],
+            scratch_shapes=im._split_scratch(m, kc, sub8=False)[3:])(x)
+        np.testing.assert_array_equal(
+            np.asarray(xe).view(np.uint16),
+            np.asarray(x[:, 0::2]).view(np.uint16))
+        np.testing.assert_array_equal(
+            np.asarray(xo).view(np.uint16),
+            np.asarray(x[:, 1::2]).view(np.uint16))
+        want = np.asarray(x, np.float32).reshape(m, -1, 32).sum(-1)
+        np.testing.assert_allclose(np.asarray(xs), want, rtol=1e-6,
+                                   atol=1e-5)
+
+
 class TestLatentFamilyOnChip:
     """ISSUE 27: the deepseek_v3 family at the published widths of
     Kanana-2-30B-A3B, 8 layers, over the pool the benchmark's engine

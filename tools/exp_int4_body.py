@@ -25,6 +25,23 @@ at ``(16, 4096) x (4096, 28672)`` (gate_up) and ``(16, 14336) x
                ``probe_1plane``, ``probe_nomul`` with one piece taken
                out; ``<body>@512`` runs a body of this file at bn = 512
 
+ISSUE 38 (step 0 of the split inside the kernel): ``parent`` with
+``$EXP_PARENT`` the checkout whose kernel takes the even/odd planes
+from XLA is the split outside (p); ``new`` the kernel's own form (the
+blocks of x stacked by a reshape, one selection product);
+``new:split=<form>`` swaps the kernel's ``_deinterleave`` for a form
+of this file
+(``SPLITS``: ``block``, one selection product a 256-lane block in a
+``fori_loop``; ``transpose``, x transposed into float32 VMEM and its
+rows read at stride 2; ``strided``, a lane-strided load, which Mosaic
+refuses; ``loops``, the blocks stacked and dealt out by two
+``fori_loop``s in place of the kernel's reshape; ``none``, no split,
+the floor); ``parent:sums=zero`` hands the
+parent's kernel zeros for the group sums, so that ``parent`` less it
+is what their XLA reduce costs. ``--vary`` rolls the operands a row
+each iteration: without it XLA hoists the parent's split out of the
+timed loop.
+
 ``--count`` needs no chip: it compiles each body for a described v5e
 with Mosaic's dump on and counts the vector operations of one grid
 step in the final LLO (run with ``JAX_PLATFORMS=cpu``). Results go to
@@ -184,6 +201,110 @@ BODIES = {
 }
 
 
+# -- the even/odd split, forms the kernel does not take (ISSUE 38) -------
+
+def _planes(bm, kc, sub8):
+    return _KERNEL_SCRATCH(bm, kc, sub8)[:2 if sub8 else 3]
+
+
+def _no_sums(xs_ref):
+    """The forms below build the planes alone: zeros for the group
+    sums (``corr`` reads wrong then; the time is what they are for)."""
+    if xs_ref is not None:
+        xs_ref[...] = jnp.zeros(xs_ref.shape, F32)
+
+
+def split_block(x_ref, xe_ref, xo_ref, xs_ref, *, cdt):
+    """One selection product a 256-lane block: the MXU loads the
+    selection kc/256 times."""
+    _no_sums(xs_ref)
+    bm, kc = x_ref.shape
+    sel = im._selection(256, cdt)
+
+    def body(b, carry):
+        r = _dot(x_ref[:, pl.ds(pl.multiple_of(b * 256, 256), 256)]
+                 .astype(cdt), sel).astype(BF16)
+        at = pl.ds(pl.multiple_of(b * 128, 128), 128)
+        xe_ref[:, at] = r[:, :128]
+        xo_ref[:, at] = r[:, 128:]
+        return carry
+    jax.lax.fori_loop(0, kc // 256, body, 0)
+
+
+def split_transpose(x_ref, xe_ref, xo_ref, xs_ref, t_ref, *, cdt):
+    """x transposed into float32 VMEM, its rows read at stride 2 and
+    the planes transposed back (the XLU and strided sublane loads)."""
+    del cdt
+    _no_sums(xs_ref)
+    h = xe_ref.shape[1]
+    t_ref[...] = x_ref[...].astype(F32).T
+    xe_ref[...] = t_ref[pl.ds(0, h, stride=2), :].T.astype(BF16)
+    xo_ref[...] = t_ref[pl.ds(1, h, stride=2), :].T.astype(BF16)
+
+
+def split_strided(x_ref, xe_ref, xo_ref, xs_ref, *, cdt):
+    """A lane-strided load: "not implemented: Strided load with non
+    32-bit data" (and in float32, "The last dim size is not 128")."""
+    del cdt
+    _no_sums(xs_ref)
+    h = xe_ref.shape[1]
+    xe_ref[...] = x_ref[:, pl.ds(0, h, stride=2)]
+    xo_ref[...] = x_ref[:, pl.ds(1, h, stride=2)]
+
+
+def split_loops(x_ref, xe_ref, xo_ref, xs_ref, s_ref, *, cdt):
+    """The first form the kernel took: the blocks gathered into a VMEM
+    stack and dealt out to the planes by two ``fori_loop``s of dynamic
+    256-lane slices, the same two products between; whole blocks only.
+    As fast at decode and faster at prefill than the reshape, but its
+    two loops, nested under the ``pl.when``, made each lowering slower
+    (+3.5-4.5 s of warm ``setup_s`` in the cell)."""
+    bm, kc = x_ref.shape
+    nb, g = kc // 256, kc // QK
+
+    def gather(b, carry):
+        s_ref[pl.ds(pl.multiple_of(b * bm, bm), bm), :] = \
+            x_ref[:, pl.ds(pl.multiple_of(b * 256, 256), 256)]
+        return carry
+
+    def deal(b, carry):
+        r = s_ref[pl.ds(pl.multiple_of(b * bm, bm), bm), :]
+        at = pl.ds(pl.multiple_of(b * 128, 128), 128)
+        xe_ref[:, at] = r[:, :128]
+        xo_ref[:, at] = r[:, 128:]
+        return carry
+
+    jax.lax.fori_loop(0, nb, gather, 0)
+    s = s_ref[...].astype(cdt)
+    if xs_ref is not None:
+        y = _dot(s, im._grouping(256, g, None, cdt)).reshape(nb, bm, g)
+        own = (jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+               == jax.lax.broadcasted_iota(jnp.int32, y.shape, 2) >> 3)
+        xs_ref[...] = jax.lax.reduce_sum(
+            jax.lax.select(own, y, jnp.zeros_like(y)), (0,))
+    s_ref[...] = _dot(s, im._selection(256, cdt)).astype(BF16)
+    jax.lax.fori_loop(0, nb, deal, 0)
+
+
+def split_none(x_ref, xe_ref, xo_ref, xs_ref, *, cdt):
+    """No split at all: the planes hold whatever VMEM held (the floor
+    the other forms are measured against; the products are wrong)."""
+    _no_sums(xs_ref)
+
+
+_KERNEL_SCRATCH = im._split_scratch
+
+SPLITS = {
+    "block": (split_block, _planes),
+    "loops": (split_loops, lambda bm, kc, sub8: _planes(bm, kc, sub8)
+              + [pltpu.VMEM((kc // 256 * bm, 256), BF16)]),
+    "none": (split_none, _planes),
+    "transpose": (split_transpose, lambda bm, kc, sub8: _planes(
+        bm, kc, sub8) + [pltpu.VMEM((kc, bm), F32)]),
+    "strided": (split_strided, _planes),
+}
+
+
 def _parent_module():
     """``int4_matmul.py`` of another checkout, under another name."""
     import importlib.util
@@ -213,16 +334,26 @@ def variant_call(name, m, k, n, bn=256):
         opts = dict(kv.split("=") for kv in name.partition(":")[2].split(",")
                     if kv)
 
+        patch = {"_SLAB": int(opts.get("slab", getattr(mod, "_SLAB", 0)))}
+        if "split" in opts:
+            patch["_deinterleave"], patch["_split_scratch"] = \
+                SPLITS[opts["split"]]
+        if opts.get("sums") == "zero":
+            patch["_group_sums"] = lambda x: jnp.zeros(
+                (x.shape[0], x.shape[1] // QK), F32)
+
         def run_mod(x, q, scale, layer):
-            keep = getattr(mod, "_SLAB", None)
-            mod._SLAB = int(opts.get("slab", keep or 0))
+            keep = {k: getattr(mod, k, None) for k in patch}
+            for k, v in patch.items():
+                setattr(mod, k, v)
             try:
                 return mod._int4_matmul_stacked_jit.__wrapped__(
                     x, q, scale, layer, bm=128,
                     bn=int(opts.get("bn", bn)), interpret=False,
                     out_dtype=F32, mode="auto")
             finally:
-                mod._SLAB = keep
+                for k, v in keep.items():
+                    setattr(mod, k, v)
         return (lambda x: x), run_mod
     if "@" in name:                   # "dma@512": the N tile
         name, bn = name.split("@")[0], int(name.split("@")[1])
@@ -343,17 +474,24 @@ def mk_stack(key, k, n):
     return q, s * sign
 
 
-def slope(run, ops, q, scale, iters):
+def slope(run, ops, q, scale, iters, vary=False):
     """Per-call device time of ``run(ops, q, scale, layer)``: slope of a
     fori_loop between iters/4 and iters, best of 3, the layer walking
-    the stack."""
+    the stack. ``vary``: the operands rolled by a row every iteration
+    (XLA hoists work on loop-invariant activations out of the loop:
+    the split ahead of the parent's kernel among it), so that what XLA
+    does to x runs once a call, behind a producer, as in the model."""
     def loop_for(n_it):
         @jax.jit
         def loop(ops, q, scale):
-            def body(i, acc):
+            def body(i, carry):
+                acc, ops = carry
                 lyr = (i % L).astype(jnp.int32).reshape(1)
-                return acc + run(ops, q, scale, lyr)[:, :128].sum()
-            return jax.lax.fori_loop(0, n_it, body, F32(0))
+                acc = acc + run(ops, q, scale, lyr)[:, :128].sum()
+                if vary:
+                    ops = jax.tree.map(lambda a: jnp.roll(a, 1, 0), ops)
+                return acc, ops
+            return jax.lax.fori_loop(0, n_it, body, (F32(0), ops))[0]
         return loop
     pts = []
     for n_it in (iters // 4, iters):
@@ -390,6 +528,8 @@ def main():
     ap.add_argument("--iters", type=int, default=400)
     ap.add_argument("--m", type=int, default=16,
                     help="rows of x; only new / parent take m > 16")
+    ap.add_argument("--vary", action="store_true",
+                    help="roll the operands a row every iteration")
     ap.add_argument("--out", default="exp_int4_body.json")
     args = ap.parse_args()
     names = args.variants.split(";")
@@ -422,7 +562,7 @@ def main():
                 ops = jax.jit(prep)(x)
                 got = jax.jit(run)(ops, q, s, lyr)
                 err = float(jnp.abs(got - ref).max() / jnp.abs(ref).max())
-                us = slope(run, ops, q, s, args.iters) * 1e6
+                us = slope(run, ops, q, s, args.iters, args.vary) * 1e6
                 res = {"us": round(us, 2), "roofline_pct":
                        round(100 * floor_us / us, 1),
                        "err_vs_f32": round(err, 5)}
