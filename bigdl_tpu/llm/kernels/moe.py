@@ -143,6 +143,39 @@ def _ffn_kernel(tg_ref, nt_ref, x_ref, wgu_ref, wd_ref, o_ref, *,
                              preferred_element_type=jnp.float32)
 
 
+def _ffn_kernel_split(tg_ref, nt_ref, x_ref, *refs, activation: str):
+    """:func:`_ffn_kernel` over a slice of the expert's width: grid step
+    ``(i, j)`` adds row tile ``i``'s part through columns ``j`` of the
+    intermediate to its output (float32, the same row block for every
+    ``j``)."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    *w_refs, wd_ref, o_ref = refs
+
+    @pl.when(i < nt_ref[0])
+    def _():
+        x = x_ref[...]
+        up = [jnp.dot(x, w[...], preferred_element_type=jnp.float32)
+              for w in w_refs]
+        act = _activate(jnp.concatenate(up, -1) if len(up) > 1 else up[0],
+                        wd_ref.shape[0], activation)
+        part = jnp.dot(act.astype(wd_ref.dtype), wd_ref[...],
+                       preferred_element_type=jnp.float32)
+
+        @pl.when(j == 0)
+        def _first():
+            o_ref[...] = part
+
+        @pl.when(j > 0)
+        def _more():
+            o_ref[...] += part
+
+
+# VMEM a kernel's double-buffered expert weights may take before the
+# intermediate is split over a second grid axis (v5e has 128 MiB)
+WHOLE_EXPERT_VMEM = 120 << 20
+SPLIT_EXPERT_VMEM = 48 << 20
+
+
 @functools.partial(jax.jit,
                    static_argnames=("tm", "interpret", "activation"))
 def moe_expert_ffn(x_pad, w_gate_up, w_down, tile_group, n_tiles, *,
@@ -163,6 +196,10 @@ def moe_expert_ffn(x_pad, w_gate_up, w_down, tile_group, n_tiles, *,
     need = (2 * (h * first + width * h) * w_down.dtype.itemsize
             + 2 * tm * h * (x_pad.dtype.itemsize + 4)
             + 3 * tm * 2 * width * 4 + (4 << 20))
+    if need > WHOLE_EXPERT_VMEM:
+        return _expert_ffn_split(x_pad, w_gate_up, w_down, tile_group,
+                                 n_tiles, tm=tm, interpret=interpret,
+                                 activation=activation)
     return pl.pallas_call(
         functools.partial(_ffn_kernel, activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -180,6 +217,55 @@ def moe_expert_ffn(x_pad, w_gate_up, w_down, tile_group, n_tiles, *,
             dimension_semantics=("arbitrary",), vmem_limit_bytes=need),
         interpret=interpret,
     )(tile_group, n_tiles.reshape(1), x_pad, w_gate_up, w_down)
+
+
+def _expert_ffn_split(x_pad, w_gate_up, w_down, tile_group, n_tiles, *,
+                      tm: int, interpret: bool, activation: str):
+    """:func:`moe_expert_ffn` for an expert too wide for two buffers of
+    its weights (GLM-5's 6,144 x 2 x 2,048 + 2,048 x 6,144: 75 MB): the
+    intermediate in slices of ``ti`` columns on a second grid axis, each
+    slice's gate and up columns (two views of ``w_gate_up``) and its rows
+    of ``w_down``; the output row tile is the running sum over the
+    slices. A row tile streams its expert's weights once; the tiles past
+    the last used one copy nothing, as in the whole form."""
+    m, h = x_pad.shape
+    nt = m // tm
+    width = w_down.shape[1]
+    per = (2 if activation == "swiglu" else 1) * h + h      # a column
+    ti = width
+    while 2 * per * ti * w_down.dtype.itemsize > SPLIT_EXPERT_VMEM \
+            and ti % 256 == 0:
+        ti //= 2
+    nj = width // ti
+    last = lambda i, n: jnp.minimum(i, jnp.maximum(n[0] - 1, 0))
+    # a tile past the last used one keeps the last used block of every
+    # operand (its group's last slice): no copy, as the whole form's
+    part = lambda i, j, n: jnp.where(i < n[0], j, nj - 1)
+    w_specs = [pl.BlockSpec((None, h, ti),
+                            lambda i, j, tg, n, o=o: (tg[i], 0,
+                                                      o + part(i, j, n)))
+               for o in ((0, nj) if activation == "swiglu" else (0,))]
+    need = (2 * per * ti * w_down.dtype.itemsize
+            + 2 * tm * h * (x_pad.dtype.itemsize + 4)
+            + 4 * tm * 2 * ti * 4 + (4 << 20))
+    return pl.pallas_call(
+        functools.partial(_ffn_kernel_split, activation=activation),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nt, nj),
+            in_specs=[pl.BlockSpec((tm, h),
+                                   lambda i, j, tg, n: (last(i, n), 0))]
+            + w_specs
+            + [pl.BlockSpec((None, ti, h),
+                            lambda i, j, tg, n: (tg[i], part(i, j, n), 0))],
+            out_specs=pl.BlockSpec((tm, h),
+                                   lambda i, j, tg, n: (last(i, n), 0))),
+        out_shape=jax.ShapeDtypeStruct((m, h), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=need),
+        interpret=interpret,
+    )(tile_group, n_tiles.reshape(1), x_pad,
+      *([w_gate_up] * len(w_specs)), w_down)
 
 
 def moe_expert_ffn_reference(x_pad, w_gate_up, w_down, tile_group,
@@ -211,7 +297,7 @@ def tile_rows(tokens: int) -> int:
 
 def grouped_ffn(x, groups_of, weights, live, w_gate_up, w_down, layer,
                 n_groups: int, interpret=None, held=None,
-                activation: str = "swiglu"):
+                activation: str = "swiglu", shared: int = 0):
     """The expert layer's sum for ``x`` (T, H): ``sum_j weights[t, j] *
     FFN_{groups_of[t, j]}(x[t])`` over the ``n_groups`` groups of
     layer ``layer`` in the whole-stack weights ``(L·G, H, 2I)`` /
@@ -222,14 +308,28 @@ def grouped_ffn(x, groups_of, weights, live, w_gate_up, w_down, layer,
     ``held = (first, count)``: the stack holds only the groups ``first
     .. first + count - 1`` of the ``groups_of`` numbering (``n_groups``
     is then ``count``); an assignment outside them is computed on
-    another chip and is left out here, whatever its token."""
+    another chip and is left out here, whatever its token. ``shared``
+    (with ``held``): the last ``shared`` columns of ``groups_of`` are
+    experts every chip holds (a family's shared experts, numbered ``0
+    .. shared - 1`` in a block of their own), the stack's groups after
+    the held ones."""
     t, h = x.shape
     if held is not None:
         first, count = held
-        mine = (groups_of >= first) & (groups_of < first + count)
-        live = _per_assignment(live) & mine
-        groups_of = jnp.where(mine, groups_of - first, 0)
-        n_groups = count
+        if shared:
+            k = groups_of.shape[1] - shared
+            routed = groups_of[:, :k]
+            mine = (routed >= first) & (routed < first + count)
+            kept = jnp.concatenate([jnp.where(mine, routed - first, 0),
+                                    count + groups_of[:, k:]], 1)
+            mine = jnp.concatenate([mine, jnp.ones((t, shared), bool)], 1)
+            live = _per_assignment(live) & mine
+            groups_of = kept
+        else:
+            mine = (groups_of >= first) & (groups_of < first + count)
+            live = _per_assignment(live) & mine
+            groups_of = jnp.where(mine, groups_of - first, 0)
+        n_groups = count + shared
     tm = tile_rows(t)
     d = dispatch(groups_of, live, n_groups, tm)
     x_ext = jnp.concatenate([x, jnp.zeros((1, h), x.dtype)])

@@ -2,8 +2,9 @@
 process of its own, the cell's traffic file rewritten with that rate for
 the run and put back after it (run it on the chip's disposable copy).
 ``python3 tools/sweep_rate.py <cell> <seconds> <seed> <rate> ...``; the
-``#`` lines of every run go to ``chiprun_out/sweep_<cell>.txt``, the
-result lines to stdout. This process never touches JAX."""
+``#`` lines of every run (and the last lines of its stderr where it
+exits non-zero) go to ``chiprun_out/sweep_<cell>.txt``, the result
+lines to stdout. This process never touches JAX."""
 
 import json
 import os
@@ -29,17 +30,23 @@ def main():
             mix["rate_per_s"] = float(rate)
             with open(path, "w") as f:
                 json.dump(mix, f, indent=1)
-            out = subprocess.run(
+            done = subprocess.run(
                 [sys.executable, "benchmark/run.py", "--workload", cell,
                  "--seed", seed, "--seconds", seconds, "--trace", "0"],
-                cwd=ROOT, capture_output=True, text=True).stdout
-            log.write(f"=== rate {rate}\n{out}\n")
+                cwd=ROOT, capture_output=True, text=True)
+            out = done.stdout
+            # a run that exits without its result line says why here
+            err = "" if done.returncode == 0 else "\n".join(
+                done.stderr.strip().splitlines()[-12:])
+            log.write(f"=== rate {rate}\n{out}\n{err}\n")
             log.flush()
             keep = [ln for ln in out.splitlines() if any(
                 k in ln for k in ("itl ms", "waiting for a first",
                                   "tokens seen", "state rows"))]
             print(f"=== rate {rate}\n" + "\n".join(keep) + "\n"
-                  + out.strip().splitlines()[-1], flush=True)
+                  + (out.strip().splitlines() or [""])[-1]
+                  + ("\n" + err if err else ""),
+                  flush=True)
     finally:
         with open(path, "w") as f:
             f.write(kept)
