@@ -1,7 +1,7 @@
 """That each limit of a ``glm_moe_dsa`` configuration's ``correct`` can
 fail, and where its floors lie. On the chip, at the
 configuration's widths: an ``LLMServer`` with the cell's engine and the
-driver's own check on it (``serve_glm5.serve_for_check`` then
+cell's own check on it (``serve_glm5.serve_for_check`` then
 ``compare_served``, as a run of the cell makes them): clean on
 ``--served`` weight seeds (the floors; every run of the cell reads them
 too, on its own seed), and on the first seed once more for each fault
@@ -15,7 +15,7 @@ out correct.
 
 ``--rejudge FILE`` runs nothing: it holds the readings a run kept in
 ``FILE`` (``chiprun_out/check_glm5.json``) to the limits the
-configuration file states NOW, with the driver's own ``judge``, and
+configuration file states NOW, with ``serve_glm5.judge``, and
 exits 1 likewise; for limits that were chosen from those readings.
 """
 
@@ -34,7 +34,7 @@ CONFIG = "glm5_bf16_ep16"
 
 def served_phase(cfg, config, sizes, engine, seeds: int, faults,
                  first_seed: int = 500, keep=None):
-    """The driver's check on a fresh server: clean on ``seeds`` seeds
+    """The cell's check on a fresh server: clean on ``seeds`` seeds
     (``first_seed``, then 7,907 apart), and with each fault planted on
     the first seed's weights."""
     import contextlib

@@ -1,5 +1,5 @@
 """Deliberate faults planted in the SERVED program of a ``glm_moe_dsa``
-configuration, to show that the driver's comparison
+configuration, to show that the cell's comparison
 (``drivers/serve_glm5``) comes out ``correct: false`` for each. Never
 for a result: ``benchmark/check_glm5.py`` (chip, published widths; CPU
 with ``--rehearse``) and ``benchmark/tests/test_glm5_cell.py`` are the

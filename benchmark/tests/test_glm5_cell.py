@@ -262,7 +262,7 @@ def test_the_traffic_file():
     assert top <= CONFIG["engine"]["max_seq_len"]
 
 
-def test_the_planted_faults_are_the_issues():
+def test_the_planted_faults_are_the_named_ones():
     assert faults_glm5.FAULTS == (
         "current_never_selected", "top2047", "relu_dropped",
         "index_key_unrotated", "latent_row_wrong_page",
@@ -276,7 +276,7 @@ def test_the_planted_faults_are_the_issues():
 def test_rehearsal_of_the_cell(trace):
     """``--rehearse`` on the CPU at the tiny widths of the
     configuration's ``rehearse`` block: the same files and control
-    flow, ``correct`` from the driver's whole check, no device value."""
+    flow, ``correct`` from the cell's whole check, no device value."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
