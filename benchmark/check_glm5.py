@@ -2,10 +2,12 @@
 fail, and where its floors lie. On the chip, at the
 configuration's widths: an ``LLMServer`` with the cell's engine and the
 cell's own check on it (``serve_glm5.serve_for_check`` then
-``compare_served``, as a run of the cell makes them): clean on
-``--served`` weight seeds (the floors; every run of the cell reads them
-too, on its own seed), and on the first seed once more for each fault
-of ``faults_glm5.FAULTS`` (or those ``--faults`` names) planted in the
+``compare_served``, as a run of the cell makes them, with the request
+the check follows in the window served here instead, beside
+``company`` rows, at its own length): clean on ``--served`` weight
+seeds (the floors; every run of the cell reads them too, on its own
+seed), and on the first seed once more for each fault of
+``faults_glm5.FAULTS`` (or those ``--faults`` names) planted in the
 SERVED PROGRAM. Exits 1 if a clean check fails or a planted fault comes
 out correct.
 
@@ -30,17 +32,17 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 CONFIG = "glm5_bf16_ep16"
+CELL = "glm5_longctx_steady"
 
 
 def served_phase(cfg, config, sizes, engine, seeds: int, faults,
-                 first_seed: int = 500, keep=None):
+                 first_seed: int = 500, keep=None, *, mix, seconds, scale):
     """The cell's check on a fresh server: clean on ``seeds`` seeds
     (``first_seed``, then 7,907 apart), and with each fault planted on
-    the first seed's weights."""
+    the first seed's weights. The request it follows is the one a run
+    of the cell on that seed would (``serve_glm5.check_target``)."""
     import contextlib
     import gc
-
-    import jax
 
     from benchmark import faults_glm5
     from benchmark.drivers import serve_glm5 as drv
@@ -59,7 +61,10 @@ def served_phase(cfg, config, sizes, engine, seeds: int, faults,
                 DeepseekForCausalLM(cfg, params, max_cache_len=128),
                 **engine).start()
             try:
-                took = drv.serve_for_check(srv, cfg, seed, sizes)
+                took = drv.serve_for_check(
+                    srv, cfg, seed, sizes, mix=mix, scale=scale,
+                    target=drv.check_target(mix, seed, seconds,
+                                            cfg.vocab_size, scale, sizes))
                 errors = srv.pass_errors
             finally:
                 srv.stop()
@@ -87,7 +92,6 @@ def served_phase(cfg, config, sizes, engine, seeds: int, faults,
                 if keep:
                     keep(out)
         del params
-        jax.clear_caches()
         if keep:
             keep(out)
     out["ok"] = all(not c["failed"] for c in out["clean"]) and all(
@@ -137,8 +141,10 @@ def main(argv) -> int:
         return 3
     reh = config["rehearse"] if args.rehearse else {}
     cfg = drv.model_config(config, reh.get("model", {}))
-    sizes = {**config["reference_check"], **reh.get("reference_check", {})}
+    sizes = drv.check_sizes(config, args.rehearse)
     engine = {**config["engine"], **reh.get("engine", {})}
+    man = mf.load()
+    mix = mf.traffic_of(mf.cell(man, CELL))
     faults = faults_glm5.FAULTS if args.faults is None else \
         tuple(f for f in args.faults.split(",") if f)
     os.makedirs("chiprun_out", exist_ok=True)
@@ -149,7 +155,9 @@ def main(argv) -> int:
         with open(args.keep, "w") as f:
             json.dump(out, f)
     served = served_phase(cfg, config, sizes, engine, args.served, faults,
-                          args.first_seed, keep)
+                          args.first_seed, keep, mix=mix,
+                          seconds=float(man["run_seconds"]),
+                          scale=float(reh.get("length_scale", 1.0)))
     keep(served)
     print(json.dumps(served), flush=True)
     return 0 if served["ok"] else 1

@@ -7,26 +7,32 @@ measured from the caller's side. The window loop is
 
 ``correct`` is ``serve.py``'s (no failed or wrong request, no pass
 error, no program first used inside the window, the kernel programs
-hold their Mosaic calls) and, after the window, at the run's widths, a
-chain from what the ENGINE served to the float32 reference
-(``benchmark/reference_glm5.py``). One prompt long enough that the
-selection binds (``reference_check.prompt_tokens``, at least 4,096) is
-served while other rows decode beside it; :class:`Tap` keeps the logits
-row the engine computed at its prefill and at each of its decode steps,
-and, right after its last step, the request's pages of the index-key
-pool. The engine then goes, and the comparisons are computed beside the
-weights alone (:func:`compare_served`).
+hold their Mosaic calls) and a chain from what the ENGINE served to the
+float32 reference (``benchmark/reference_glm5.py``) at the window's own
+sizes. The check follows ONE request of the window (:func:`check_target`:
+the first due inside it whose prompt lies past the mix's median, in the
+prefill bucket above it): :class:`Tap` keeps, by reference and with no
+device work, the logits row the engine computed at its prefill and at
+each of its first ``served_tokens`` decode steps, in the window's own
+batches. After the window, that prompt with those tokens is served again
+(one prefill of ``prompt + served_tokens`` positions) for
+``reserved_tokens`` more while ``company`` short rows decode beside it,
+and right after its last step the tap takes its pages of the index-key
+pool. The engine then goes, and the reference runs ONCE over the whole
+sequence, beside the weights alone (:func:`compare_served`).
 
-- (a) logits: every served token within the configuration's tolerance
-  of the float32 reference's maximum (``reference.margins``), and the
-  engine's rows within a limit of the reference's in the median over
-  the positions (``row_distance``). What lives only in the served path
-  (the chunked sparse prefill, the three decode parts, the page writers
-  and tables) is held here.
-- (b) selection: at ``probe_positions`` positions of the served answer
-  and in every layer, the served decode path's own scoring kernel and
-  selection (``sparse_attention.index_scores`` and ``dsa_select``) run
-  over the index keys the ENGINE cached for the request, with the
+- (a) logits: every token the window and the second serving drew lies
+  within the configuration's tolerance of the float32 reference's
+  maximum (``reference.margins``), and the engine's rows lie within a
+  limit of the reference's in the median over those positions
+  (``row_distance``). What lives only in the served path (the chunked
+  sparse prefill, the three decode parts at the window's contexts, the
+  page writers and tables) is held here.
+- (b) selection: at ``probe_positions`` positions spread over the tokens
+  both servings drew, and in every layer, the served decode path's own
+  scoring kernel and selection (``sparse_attention.index_scores`` and
+  ``dsa_select``) run over the index keys the ENGINE cached for the
+  request (by the second serving's prefill and steps), with the
   reference's indexer query and head weights at that position; of the
   positions the reference selects there, the share the program selects
   (the least over the (position, layer) pairs); the position ``t``
@@ -35,17 +41,20 @@ weights alone (:func:`compare_served`).
   within a limit of their float64 form over the same cached keys and
   queries (:func:`score_error`).
 - (c) experts: of the experts the reference chooses for a token and
-  layer, the program's dense bfloat16 forward chooses the
-  configuration's share (``serve_nemotron_h.experts_in_common``), and
-  the program's ``route`` on the very inputs the reference's router was
-  given agrees on all but exact ties, with the weights to a part in ten
-  thousand.
-- (d) the engine's books: the served tokens are the argmax of the rows
-  it computed; more than ``company`` rows were live at each of its
-  steps; the sparse counters (``index_ctx_tokens_total``,
-  ``sparse_selected_tokens_total``) moved by what the tap saw
-  dispatched; the assignments computed here are fewer than
-  ``num_experts_per_tok`` for every token and expert layer, and some.
+  layer (of the sequence's first ``EXPERT_PREFIX``), the program's dense
+  bfloat16 forward chooses the configuration's share
+  (``serve_nemotron_h.experts_in_common``), and the program's ``route``
+  on the very inputs the reference's router was given agrees on all but
+  exact ties, with the weights to a part in ten thousand.
+- (d) the engine's books: the rows of the window's request were taken
+  (in a run of the cell; ``benchmark/check_glm5.py`` serves that request
+  itself, beside ``company`` rows); the served tokens are the argmax of
+  the rows computed; more than ``company`` rows were live at each step
+  of the second serving; the sparse counters
+  (``index_ctx_tokens_total``, ``sparse_selected_tokens_total``) moved
+  by what the tap saw dispatched then; the assignments computed here
+  are fewer than ``num_experts_per_tok`` for every token and expert
+  layer, and some.
 
 ``benchmark/check_glm5.py`` plants faults in the served program to see
 each one fail.
@@ -53,18 +62,22 @@ each one fail.
 
 from __future__ import annotations
 
+import functools
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
-from benchmark import reference, reference_glm5, weights_glm5
+from benchmark import reference, reference_glm5, traffic, weights_glm5
 from benchmark.drivers import serve_mimo
-from benchmark.drivers.serve_deepseek import row_distance
+from benchmark.drivers.serve import PAGE
+from benchmark.drivers.serve_deepseek import row_distance, scheduled_requests
 from benchmark.drivers.serve_nemotron_h import _peak_gb, experts_in_common
 from benchmark.reference_deepseek import router_on_reference_inputs
 
 COUNTED = ("index_ctx_tokens_total", "sparse_selected_tokens_total")
+# the tokens whose experts the program's dense forward is compared on
+EXPERT_PREFIX = 4096
 
 
 def model_config(config: Dict, override: Dict):
@@ -78,41 +91,70 @@ def seeded_params(cfg, seed: int, config: Dict):
         int(config["weights_router_seed"]))
 
 
+def check_target(mix: Dict, seed: int, seconds: float, vocab: int,
+                 scale: float, sizes: Dict) -> Optional[Dict]:
+    """The request of the run's schedule the check follows: of those due
+    inside the window (else in the lead-in) whose prompt is longer than
+    the mix's median and leaves room, in the prefill bucket above the
+    median, for ``served_tokens + reserved_tokens`` more, the shortest
+    (the reference's cost grows with the square of its length), the
+    earliest of equals; None where no request does. The schedule is the
+    one ``run_window`` sends (``scheduled_requests``: the sizes and
+    their order are the mix's, the ids ``seed``'s)."""
+    reqs = scheduled_requests(mix, seed, seconds, vocab, scale)
+    median = int(traffic.quantile_grid(mix["prompt"], 1, scale)[0])
+    bucket = 1 << median.bit_length()
+    span = int(sizes["served_tokens"]) + int(sizes["reserved_tokens"])
+    fits = [r for r in reqs if median < len(r["prompt"]) <= bucket - span
+            and r["max_new"] >= int(sizes["served_tokens"])]
+    inside = [r for r in fits if r["due"] >= float(mix["lead_in_s"])]
+    return min(inside or fits, key=lambda r: (len(r["prompt"]), r["due"]),
+               default=None)
+
+
 class Tap:
     """What the engine computed for ONE request (the one whose prompt is
-    ``prompt``), taken on the engine thread where the engine holds it.
-    ``rows``: the last-position logits row of its prefill, then its row
-    of every decode step (row ``k`` is what served token ``k`` was
-    sampled from). ``live``: rows each of those steps advanced. Right
-    after its last step, ``keys``: its pages of the index-key pool,
-    (layers, pages, page, Di), gathered through the engine's own table,
-    ``cached_len`` positions of them written. ``seen`` sums, over every
-    decode dispatch while the tap is on, what the engine's sparse
-    counters count."""
+    ``prompt``), taken on the engine thread where the engine holds it,
+    by reference: no device work while the tap is on. ``rows``: the
+    last-position logits row of its prefill, then its row of every
+    decode step (row ``k`` is what served token ``k`` was sampled from),
+    the first ``keep`` of them where ``keep`` is given. ``live``: rows
+    each of those steps advanced. With ``keys``, right after its last
+    step, its pages of the index-key pool, (layers, pages, page, Di),
+    gathered through the engine's own table (``cached_len`` positions
+    of them written), and ``seen``: over every decode dispatch while the
+    tap is on, what the engine's sparse counters count."""
 
-    def __init__(self, srv, cfg, prompt: np.ndarray):
+    def __init__(self, srv, cfg, prompt: np.ndarray, keep: int = None,
+                 keys: bool = False):
         import jax
         import jax.numpy as jnp
         self.rows: List = []
         self.live: List[int] = []
+        self.req = None
         self.keys, self.cached_len = None, 0
         self.seen = dict.fromkeys(COUNTED + ("steps",), 0)
         self._srv = srv
         finish, after = srv._finish_prefill, srv._after_dispatch
         lay, topk = cfg.num_hidden_layers, cfg.index_topk
 
-        def mine(req) -> bool:
-            q = req.prompt_ids
-            return q.shape == prompt.shape and bool((q == prompt).all())
+        def full() -> bool:
+            return keep is not None and len(self.rows) >= keep
 
         def finish_prefill(i, req, row_pages, own, last, *a, **k):
-            if mine(req):
-                self.rows.append(last)
+            q = req.prompt_ids
+            if self.req is None and q.shape == prompt.shape \
+                    and bool((q == prompt).all()):
+                self.req = req
+                self.rows.append((last, None))
             return finish(i, req, row_pages, own, last, *a, **k)
 
         def after_dispatch(rec, t0):
-            if rec.get("fn") == "llm/decode_paged":
-                pairs = rec["pairs"]
+            if rec.get("fn") != "llm/decode_paged" or (
+                    not keys and (self.req is None or full())):
+                return after(rec, t0)
+            pairs = rec["pairs"]
+            if keys:
                 # lens were advanced at the dispatch: a row scored its
                 # cached positions and itself, ``lens`` of them
                 scored = np.asarray([srv._lens[i] for i, _ in pairs])
@@ -120,18 +162,18 @@ class Tap:
                 self.seen["sparse_selected_tokens_total"] += int(
                     np.minimum(scored, topk).sum()) * lay
                 self.seen["steps"] += 1
-                for i, req in pairs:
-                    if mine(req):
-                        self.rows.append(srv._last[i])
-                        self.live.append(len(pairs))
-                        if srv._remaining[i] == 0:      # its last step
-                            self.cached_len = int(srv._lens[i])
-                            pages = -(-self.cached_len // srv._page)
-                            self.keys = jnp.stack([
-                                jax.lax.dynamic_index_in_dim(
-                                    srv._v_pages, pid, 1, False)[:, 0]
-                                for pid in srv._bt[i, :pages].tolist()],
-                                axis=1)
+            for i, req in pairs:
+                if req is not self.req or full():
+                    continue
+                self.rows.append((srv._last, i))
+                self.live.append(len(pairs))
+                if keys and srv._remaining[i] == 0:      # its last step
+                    self.cached_len = int(srv._lens[i])
+                    pages = -(-self.cached_len // srv._page)
+                    self.keys = jnp.stack([
+                        jax.lax.dynamic_index_in_dim(
+                            srv._v_pages, pid, 1, False)[:, 0]
+                        for pid in srv._bt[i, :pages].tolist()], axis=1)
             return after(rec, t0)
 
         srv._finish_prefill, srv._after_dispatch = \
@@ -139,57 +181,120 @@ class Tap:
 
     def close(self):
         """Take the tap off and let go of the engine. Returns the logits
-        rows (served tokens + 1, vocab) float32."""
+        rows (rows taken, vocab) float32."""
         del self._srv._finish_prefill, self._srv._after_dispatch
         self._srv = None
         if self.keys is not None:
             self.keys = np.asarray(self.keys)
-        return np.stack([np.asarray(r, np.float32) for r in self.rows]) \
-            if self.rows else np.zeros((0, 0), np.float32)
+        rows = [np.asarray(a, np.float32) if i is None
+                else np.asarray(a[i], np.float32) for a, i in self.rows]
+        self.rows = []
+        return np.stack(rows) if rows else np.zeros((0, 0), np.float32)
 
 
-def serve_for_check(srv, cfg, seed: int, sizes: Dict) -> Dict:
-    """The served half of the check: ``prompt_tokens`` served for
-    ``served_tokens`` while ``company`` others decode beside it
-    (prompts of its own prefill bucket, so no program is new; each
-    outlives it), with the tap on; everything the comparison wants of
-    the engine taken to the host, so that the engine and its pools can
-    go before the float32 reference is computed."""
+class WindowTap:
+    """A :class:`Tap` on the check's request of the window, put on the
+    engine when the window's first request is sent (the warm-up's
+    requests go before it): ``submit`` stands in for ``serve.py``'s
+    ``_submit`` while ``run_window`` runs."""
+
+    def __init__(self, cfg, target: Optional[Dict], keep: int):
+        self.cfg, self.target, self.keep = cfg, target, keep
+        self.tap: Optional[Tap] = None
+
+    def submit(self, inner):
+        def submit(srv, obs, r, due):
+            if self.tap is None and self.target is not None:
+                self.tap = Tap(srv, self.cfg, self.target["prompt"],
+                               keep=self.keep)
+            return inner(srv, obs, r, due)
+        return submit
+
+
+def _with_company(srv, prompt: np.ndarray, new: int, company: int,
+                  lengths, vocab: int, rs) -> List[int]:
+    """Serve ``prompt`` for ``new`` tokens while ``company`` short
+    requests (``lengths``: the least and most tokens) decode beside it,
+    each outliving it."""
+    others = [srv.submit(
+        rs.randint(0, vocab, rs.randint(lengths[0], lengths[1] + 1))
+        .astype(np.int32), max_new_tokens=new + 2 * company + 16)
+        for _ in range(company)]
+    deadline = time.perf_counter() + 600
+    while others and time.perf_counter() < deadline and \
+            not all(o.tokens or o.done.is_set() for o in others):
+        time.sleep(0.005)
+    served = srv.submit(prompt, max_new_tokens=new).get(timeout=600)
+    for o in others:
+        o.get(timeout=600)
+    return list(served)
+
+
+def serve_for_check(srv, cfg, seed: int, sizes: Dict, *, mix: Dict,
+                    scale: float, target: Optional[Dict],
+                    window: Optional[WindowTap] = None) -> Dict:
+    """The served half of the check. The first rows: the window's tap
+    on ``target`` or, where the window took none (``check_glm5.py``),
+    ``target`` served here for ``served_tokens`` beside ``company``
+    rows. Then ``target``'s prompt and those tokens served again for
+    ``reserved_tokens`` beside ``company`` rows (short prompts of the
+    mix's first prefill bucket), tapped with its index keys. Everything
+    the comparison wants of the engine is taken to the host, so that the
+    engine and its pools can go before the float32 reference is
+    computed."""
     t0 = time.perf_counter()
-    n, new = int(sizes["prompt_tokens"]), int(sizes["served_tokens"])
+    new, more = int(sizes["served_tokens"]), int(sizes["reserved_tokens"])
     company = int(sizes["company"])
     rs = np.random.RandomState(seed % (2 ** 31))
     vocab = cfg.vocab_size
-    prompt = rs.randint(0, vocab, n).astype(np.int32)
-    low = max(2, (1 << (n - 1).bit_length()) // 2 + 1)
+    first_bucket = traffic.prefill_buckets(mix, PAGE, scale)[0]
+    short = (first_bucket // 2 + 1, first_bucket)
 
     def idle(seconds):
         deadline = time.perf_counter() + seconds
         while not srv.engine_idle() and time.perf_counter() < deadline:
             time.sleep(0.01)
     idle(300)
-    tap = Tap(srv, cfg, prompt)
+    if target is None:
+        raise ValueError("the schedule holds no request the check can "
+                         "follow")
+    prompt = target["prompt"]
+    tap = window.tap if window is not None else None
+    from_window = tap is not None and tap.req is not None
+    if from_window:
+        tokens = list(tap.req.tokens)
+        first_live = list(tap.live)
+        first = tap.close()
+    else:
+        if tap is not None:
+            tap.close()
+        tap = Tap(srv, cfg, prompt, keep=new)
+        try:
+            tokens = _with_company(srv, prompt, new, company, short, vocab,
+                                   rs)
+            idle(600)
+        finally:
+            first_live = list(tap.live)
+            first = tap.close()
+    again = np.concatenate([prompt, np.asarray(tokens[:new], np.int32)])
+    tap = Tap(srv, cfg, again, keys=True)
     before = dict(srv.step_counters)
-    others = [srv.submit(
-        rs.randint(0, vocab, rs.randint(low, n + 1)).astype(np.int32),
-        max_new_tokens=2 * new + company) for _ in range(company)]
-    deadline = time.perf_counter() + 600
-    while others and time.perf_counter() < deadline and \
-            not all(o.tokens or o.done.is_set() for o in others):
-        time.sleep(0.005)
     try:
-        served = srv.submit(prompt, max_new_tokens=new).get(timeout=600)
-        for o in others:
-            o.get(timeout=600)
+        served = _with_company(srv, again, more, company, short, vocab, rs)
         idle(600)
         time.sleep(0.1)             # the last steps in flight drained
     finally:
         rows = tap.close()
     counted = {k: srv.step_counters[k] - before[k] for k in COUNTED}
-    return {"t0": t0, "prompt": prompt, "new": new, "served": served,
-            "rows": rows[:new], "tap": tap, "counted": counted,
-            "company": company, "counters": dict(srv.step_counters),
-            "probe_positions": int(sizes.get("probe_positions", 8)),
+    return {"t0": t0, "prompt": prompt, "new": new, "more": more,
+            "first_tokens": tokens[:new], "first_rows": first[:new],
+            "first_live": first_live, "from_window": from_window,
+            "window_expected": window is not None,
+            "continued": tokens[new:new + more],
+            "served": served, "rows": rows[:more], "tap": tap,
+            "counted": counted, "company": company,
+            "counters": dict(srv.step_counters),
+            "probe_positions": int(sizes["probe_positions"]),
             "peak_gb": _peak_gb()}
 
 
@@ -260,11 +365,15 @@ def score_error(keys: np.ndarray, positions, q, wi, scores):
 
 def dense_choices(cfg, params, ids):
     """The experts the program's own dense bfloat16 forward chooses over
-    ``ids`` (T,): (expert layers, T, k)."""
+    the first ``EXPERT_PREFIX`` of ``ids`` (T,): (expert layers, T', k).
+    A token's experts depend on no later token, so a prefix is compared
+    as it stands; the whole sequence's dense forward does not fit beside
+    the weights at the check's lengths."""
     import jax
     import jax.numpy as jnp
 
     from bigdl_tpu.llm.models import deepseek
+    ids = ids[:EXPERT_PREFIX]
     t = len(ids)
     span = -(-t // deepseek.ATTN_KEY_BLOCK) * deepseek.ATTN_KEY_BLOCK
 
@@ -302,6 +411,7 @@ def judge(r: Dict, config: Dict) -> Dict[str, bool]:
         lim.update(config["rehearse"].get("limits", {}))
     return {
         "d": bool(r["rows_taken"] and r["tokens_are_argmax_of_rows"]
+                  and r["first_from_window"] == r["window_expected"]
                   and r["rows_live_min"] > r["company"]
                   and r["counters_agree"] and r["token_layers"] > 0
                   and 0 < r["assignments"]
@@ -326,15 +436,19 @@ def judge(r: Dict, config: Dict) -> Dict[str, bool]:
 
 def compare_served(cfg, params, took: Dict, config: Dict, say) -> Dict:
     """The comparing half: what :func:`serve_for_check` took against the
-    float32 reference."""
-    t0, prompt, new, served, rows, tap, counted = (
-        took[k] for k in ("t0", "prompt", "new", "served", "rows", "tap",
-                          "counted"))
+    float32 reference, computed once over the prompt, the first
+    ``served_tokens`` and the second serving's tokens."""
+    t0, prompt, new, more, served, rows, tap, counted = (
+        took[k] for k in ("t0", "prompt", "new", "more", "served", "rows",
+                          "tap", "counted"))
+    first, tokens = took["first_rows"], took["first_tokens"]
     n = len(prompt)
+    n2 = n + len(tokens)
     # the last served token was fed (its row and key cached) but nothing
     # drawn after it: the selections are probed up to it, the logits
     # stop before it
-    ids = np.concatenate([prompt, np.asarray(served, np.int32)])
+    ids = np.concatenate([prompt, np.asarray(tokens, np.int32),
+                          np.asarray(served, np.int32)])
     t = len(ids)
     positions = tuple(int(p) for p in np.linspace(
         n, t - 1, took["probe_positions"]).round())
@@ -343,38 +457,53 @@ def compare_served(cfg, params, took: Dict, config: Dict, say) -> Dict:
     logits, chosen = reference_glm5.glm5_logits(
         cfg, params, ids, routing=routing, selections=selections,
         probe=positions)
-    ref = logits[n - 1:n - 1 + new]
-    taken = len(served) == new and rows.shape == ref.shape \
+    ref = np.concatenate([logits[n - 1:n - 1 + len(tokens)],
+                          logits[n2 - 1:n2 - 1 + len(served)]])
+    drawn = np.asarray(list(tokens) + list(served))
+    taken = len(tokens) == new and len(served) == more \
+        and first.shape[0] == new and rows.shape[0] == more \
         and tap.keys is not None and tap.cached_len == t
-    nothing = np.full(new, np.inf)
-    ref_dist = row_distance(rows, ref) if taken else nothing
-    m = reference.margins(ref, served) if len(served) == new else nothing
+    got = np.concatenate([first, rows]) if taken else None
+    nothing = np.full(new + more, np.inf)
+    ref_dist = row_distance(got, ref) if taken else nothing
+    m = reference.margins(ref, drawn) if len(drawn) == new + more \
+        else nothing
     agree, exact, own, err = np.zeros((1, 1)), False, 0.0, np.inf
     if taken:
         wi = np.stack([s[2] for s in selections])
-        got, scores, q = probe_selection(
+        sel, scores, q = probe_selection(
             cfg, tap.keys, positions, np.stack([s[1] for s in selections]),
             wi)
         err = score_error(tap.keys, positions, q, wi, scores)
-        want = np.stack([s[0][list(positions)] for s in selections])
-        got = got[..., :t]
-        agree = (got & want).sum(-1) / np.maximum(want.sum(-1), 1)
-        exact = bool((got.sum(-1) == np.minimum(
+        want = np.stack([s[0] for s in selections])
+        sel = sel[..., :t]
+        agree = (sel & want).sum(-1) / np.maximum(want.sum(-1), 1)
+        exact = bool((sel.sum(-1) == np.minimum(
             cfg.index_topk, np.asarray(positions) + 1)[None]).all())
         # the position a query is at: selected by both or by neither
         at, rows_ = np.asarray(positions), np.arange(len(positions))
-        own = float((got[:, rows_, at] == want[:, rows_, at]).mean())
-    same = experts_in_common(chosen, dense_choices(cfg, params, ids))
+        own = float((sel[:, rows_, at] == want[:, rows_, at]).mean())
+    same = experts_in_common(chosen[:, :EXPERT_PREFIX],
+                             dense_choices(cfg, params, ids))
     r_share, w_off = router_on_reference_inputs(
         program_router(cfg), params, routing)
     c = took["counters"]
+    cont = took["continued"]
     r = {"rows_taken": bool(taken),
          "rehearsal_widths": cfg.hidden_size != config["hidden_size"],
+         "first_from_window": bool(took["from_window"]),
+         "window_expected": bool(took["window_expected"]),
+         "prompt_tokens": n, "sequence_tokens": t,
          "tokens_are_argmax_of_rows": bool(
-             taken and (rows.argmax(-1) == np.asarray(served)).all()),
+             taken and (got.argmax(-1) == drawn).all()),
+         "first_rows_live_min": min(took["first_live"], default=0),
+         "first_rows_live_max": max(took["first_live"], default=0),
          "rows_live_min": min(tap.live, default=0),
          "rows_live_max": max(tap.live, default=0),
          "company": took["company"],
+         "second_serving_continues_first": float(np.mean(
+             np.asarray(cont) == np.asarray(served[:len(cont)])))
+         if cont and len(served) >= len(cont) else None,
          "counters_agree": bool(tap.seen["steps"] > 0 and all(
              counted[k] == tap.seen[k] for k in counted)),
          "counted": counted, "dispatched": dict(tap.seen),
@@ -386,6 +515,8 @@ def compare_served(cfg, params, took: Dict, config: Dict, say) -> Dict:
          "margin_sigma_mean": float(np.mean(m)),
          "reference_distance_median": float(np.median(ref_dist)),
          "reference_distance_max": float(np.max(ref_dist)),
+         "first_distance_median": float(np.median(ref_dist[:new])),
+         "second_distance_median": float(np.median(ref_dist[new:])),
          "probe_positions": list(positions),
          "selection_agreement_min": float(agree.min()),
          "selection_agreement_mean": float(agree.mean()),
@@ -403,24 +534,32 @@ def compare_served(cfg, params, took: Dict, config: Dict, say) -> Dict:
 
     def word(k):
         return "ok" if ok[k] else "FAILED"
-    say(f"reference: (d) the engine's books: {len(rows)} logits rows for "
-        f"the served request (its tokens "
+    where = "the window's" if r["first_from_window"] else "the check's"
+    say(f"reference: (d) the engine's books: {len(first)} logits rows of "
+        f"{where} request ({r['first_rows_live_min']}-"
+        f"{r['first_rows_live_max']} rows live at its steps) and "
+        f"{len(rows)} of its second serving (its tokens "
         f"{'are' if r['tokens_are_argmax_of_rows'] else 'ARE NOT'} their "
         f"argmax; {r['rows_live_min']}-{r['rows_live_max']} rows live at "
-        f"its steps, more than {r['company']} wanted); sparse counters "
-        f"{counted} against {tap.seen} dispatched; {r['assignments']} "
-        f"assignments computed here over {r['token_layers']} (token, "
-        f"expert layer) pairs of {r['experts_per_token']} each; peak "
-        f"device memory {took['peak_gb']} GB when served -> {word('d')}")
-    say(f"reference: (a) logits: {new} served tokens after a {n}-token "
-        f"prompt lie at most {r['margin_sigma_max']:.4f} (mean "
+        f"its steps, more than {r['company']} wanted; it continues the "
+        f"first serving's tokens in {r['second_serving_continues_first']} "
+        f"of them); sparse counters {counted} against {tap.seen} "
+        f"dispatched; {r['assignments']} assignments computed here over "
+        f"{r['token_layers']} (token, expert layer) pairs of "
+        f"{r['experts_per_token']} each; peak device memory "
+        f"{took['peak_gb']} GB when served -> {word('d')}")
+    say(f"reference: (a) logits: after a {n}-token prompt, {new} tokens "
+        f"{where} serving drew and {more} more of the second serving (a "
+        f"{n2}-token prompt) lie at most {r['margin_sigma_max']:.4f} (mean "
         f"{r['margin_sigma_mean']:.4f}) logit-sigmas below the float32 "
-        f"reference's maximum; {int((m == 0).sum())}/{new} are its "
+        f"reference's maximum; {int((m == 0).sum())}/{new + more} are its "
         f"argmax; tolerance {config['reference_tolerance_sigma']}; the "
         f"engine's rows lie {r['reference_distance_median']:.5f} (median; "
-        f"max {r['reference_distance_max']:.4f}) of a row's spread from "
-        f"the reference's, at most "
-        f"{config['reference_distance_median_max']} -> {word('a')}")
+        f"first serving {r['first_distance_median']:.5f}, second "
+        f"{r['second_distance_median']:.5f}; max "
+        f"{r['reference_distance_max']:.4f}) of a row's spread from the "
+        f"reference's, at most {config['reference_distance_median_max']} "
+        f"-> {word('a')}")
     say(f"reference: (b) selection: at positions {list(positions)} of "
         f"the {t} and in every layer the served scoring kernel and "
         f"selection over the engine's cached index keys keep "
@@ -470,9 +609,33 @@ def report_family(say, win: Dict, cfg, srv) -> None:
         f"{srv.pages_in_use_by_class}")
 
 
+def check_sizes(config: Dict, rehearse: bool) -> Dict:
+    """The check's sizes: ``reference_check``, the rehearsal's over it."""
+    reh = config.get("rehearse", {}) if rehearse else {}
+    return {**config["reference_check"], **reh.get("reference_check", {})}
+
+
 def run(ctx: Dict) -> Dict:
+    """``run_window`` with the check's tap on the window: the server is
+    built inside it, so the tap goes on at the window's first send
+    (``serve_mimo``'s ``_submit``, stood in for while the window runs)."""
+    from unittest import mock
+
     from bigdl_tpu.llm.models.deepseek import DeepseekForCausalLM
-    return serve_mimo.run_window(
-        ctx, model_config=model_config, seeded_params=seeded_params,
-        model_class=DeepseekForCausalLM, serve_for_check=serve_for_check,
-        compare_served=compare_served, report_family=report_family)
+    config, mix = ctx["config"], ctx["mix"]
+    reh = config.get("rehearse", {}) if ctx["rehearse"] else {}
+    cfg = model_config(config, reh.get("model", {}))
+    scale = float(reh.get("length_scale", 1.0))
+    sizes = check_sizes(config, ctx["rehearse"])
+    target = check_target(mix, ctx["seed"], ctx["seconds"], cfg.vocab_size,
+                          scale, sizes)
+    window = WindowTap(cfg, target, int(sizes["served_tokens"]))
+    with mock.patch.object(serve_mimo, "_submit",
+                           window.submit(serve_mimo._submit)):
+        return serve_mimo.run_window(
+            ctx, model_config=model_config, seeded_params=seeded_params,
+            model_class=DeepseekForCausalLM,
+            serve_for_check=functools.partial(
+                serve_for_check, mix=mix, scale=scale, target=target,
+                window=window),
+            compare_served=compare_served, report_family=report_family)

@@ -68,10 +68,12 @@ def planted(fault: str, cfg=None):
             return mask_of(scores, seen, k, n_blocks, kb)
         patch.enter_context(mock.patch.object(ds, "dsa_mask", off_diagonal))
     elif fault == "top2047":
-        patch.enter_context(mock.patch.object(
-            sa, "dsa_select",
-            lambda s, cur, lens, what=None, *, k: select(
-                s, cur, lens, what, k=k - 1)))
+        def fewer(s, cur, lens, what=None, *, k):
+            # the lowest-scored of the k kept is dropped: the shapes the
+            # kernels are compiled for stay
+            rows, ok = select(s, cur, lens, what, k=k)
+            return rows, ok.at[:, k - 1].set(False)
+        patch.enter_context(mock.patch.object(sa, "dsa_select", fewer))
         patch.enter_context(mock.patch.object(
             ds, "dsa_mask", lambda sc, seen, k, n, kb: mask_of(
                 sc, seen, k - 1, n, kb)))

@@ -13,8 +13,9 @@ no kernel and none of the program's forward code. RoPE rotates the
 stored pairs ``(2i, 2i+1)`` as pairs (the program de-interleaves them
 first). Only the *layout* of the program's parameters is taken from it
 (``bigdl_tpu/llm/models/deepseek.py``: which array is which). A layer at
-a time and a block of queries at a time, so that it fits beside the
-weights at the published widths.
+a time, a group of heads at a time and a block of queries at a time, so
+that a sequence of some 30,000 tokens fits beside the weights at the
+published widths.
 
 Per layer, pre-norm residual, RMSNorm: ``c_q = RMSNorm(h W_qa)``, ``q =
 c_q W_qb`` -> heads of ``nope | rope``; ``[c | k_r] = h W_kva``, ``c``
@@ -32,13 +33,20 @@ chip holds are computed, plus the shared expert.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# queries the reference scores and attends at a time
-QUERY_BLOCK = 256
+# queries the reference selects for at a time (their (Q, Hi, T) indexer
+# products), and attends at a time (their (G, Q, T) scores)
+SELECT_BLOCK = 64
+QUERY_BLOCK = 128
+# heads whose expanded K and V (T, G, nope + rope | v) exist at a time
+HEAD_GROUP = 8
+# tokens an FFN takes at a time
+ROW_BLOCK = 4096
 
 
 def _rms(x, w, eps):
@@ -51,13 +59,15 @@ def _w(wd):
     return wd["w"].astype(jnp.float32).T
 
 
-def _rope_pairs(x, theta, interleaved=True):
-    """x (T, H, D) at positions 0..T-1: pairs ``(2i, 2i+1)`` rotated by
-    ``pos * theta^(-2i/D)`` (``interleaved``), else halves ``(i, i +
-    D/2)``."""
+def _rope_pairs(x, theta, interleaved=True, positions=None):
+    """x (T, H, D) at ``positions`` (0..T-1 where not given): pairs
+    ``(2i, 2i+1)`` rotated by ``pos * theta^(-2i/D)`` (``interleaved``),
+    else halves ``(i, i + D/2)``."""
     t, _, d = x.shape
+    if positions is None:
+        positions = jnp.arange(t)
     inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
+    ang = positions.astype(jnp.float32)[:, None] * inv
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     if interleaved:
         a, b = x[..., 0::2], x[..., 1::2]
@@ -68,37 +78,50 @@ def _rope_pairs(x, theta, interleaved=True):
 
 
 def _swiglu(x, w_gate_up, w_down):
-    gu = x @ w_gate_up.astype(jnp.float32)
-    gate, up = jnp.split(gu, 2, axis=-1)
-    return (jax.nn.silu(gate) * up) @ w_down.astype(jnp.float32)
+    """A block of ``ROW_BLOCK`` tokens at a time."""
+    t = x.shape[0]
+
+    def block(xb):
+        gate, up = jnp.split(xb @ w_gate_up.astype(jnp.float32), 2, axis=-1)
+        return (jax.nn.silu(gate) * up) @ w_down.astype(jnp.float32)
+    out = jax.lax.map(block, _blocks(x, ROW_BLOCK))
+    return out.reshape(-1, out.shape[-1])[:t]
 
 
 def _select(scores, topk):
-    """(Q, S) float32, ``-inf`` where not allowed -> (Q, S) bool: each
-    query's ``topk`` highest finite scores, ties to the lower position
-    (a stable sort of the negated scores)."""
+    """(Q, S) float32, ``-inf`` where not allowed -> (Q, min(topk, S))
+    int32: each query's ``topk`` highest finite scores by position, ties
+    to the lower position (a stable sort of the negated scores), -1 past
+    the positions a query may see."""
     order = jnp.argsort(-scores, axis=-1, stable=True)[:, :topk]
-    rows = jnp.arange(scores.shape[0])[:, None]
     keep = jnp.take_along_axis(scores, order, -1) > -jnp.inf
-    return jnp.zeros(scores.shape, bool).at[rows, order].set(keep)
+    return jnp.where(keep, order, -1).astype(jnp.int32)
+
+
+def _blocks(a, size):
+    """``a`` (T, ...) padded to whole blocks of ``size`` rows ->
+    (T / size, size, ...)."""
+    pad = -a.shape[0] % size
+    a = jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+    return a.reshape((-1, size) + a.shape[1:])
 
 
 def _attention(x, lp, *, nh, nope, rope, vd, lora, eps, theta, topk, hi,
                di, index_interleave):
-    """The stream after the layer's attention, and each query's
-    selection (T, T) bool."""
+    """The stream after the layer's attention, each query's selection
+    (T, min(topk, T)) positions (-1 where it sees fewer), and the
+    indexer's queries and head weights. First the indexer scores and
+    selects, a block of queries at a time; then the attention runs a
+    group of heads at a time (their K and V expanded for every token)
+    and, inside a group, a block of queries at a time, each query's
+    softmax over exactly the positions it selected."""
     t = x.shape[0]
+    pos = jnp.arange(t)
     h = _rms(x, lp["input_layernorm"], eps)
     c_q = _rms(h @ _w(lp["q_a_proj"]), lp["q_a_layernorm"], eps)
-    q = (c_q @ _w(lp["q_b_proj"])).reshape(t, nh, nope + rope)
-    q = jnp.concatenate([q[..., :nope], _rope_pairs(q[..., nope:], theta)],
-                        -1)
     ckr = h @ _w(lp["kv_a_proj"])
     c = _rms(ckr[:, :lora], lp["kv_a_layernorm"], eps)
     k_r = _rope_pairs(ckr[:, None, lora:], theta)
-    kv = (c @ _w(lp["kv_b_proj"])).reshape(t, nh, nope + vd)
-    k = jnp.concatenate([kv[..., :nope],
-                         jnp.broadcast_to(k_r, (t, nh, rope))], -1)
     # the indexer
     ip = lp["indexer"]
     qi = (c_q @ _w(ip["wq_b"])).reshape(t, hi, di)
@@ -114,38 +137,66 @@ def _attention(x, lp, *, nh, nope, rope, vd, lora, eps, theta, topk, hi,
                                       index_interleave)[:, 0],
                           ki[:, rope:]], -1)
     wi = (h @ _w(ip["weights_proj"])) * (hi ** -0.5 * di ** -0.5)
-    qb = min(t, QUERY_BLOCK)
-    pad = -t % qb
-    pos = jnp.arange(t + pad)
 
-    def block(args):
-        qq, qqi, wwi, p = args
+    def select(args):
+        qqi, wwi, p = args
         sc = jnp.einsum("qhd,sd->qhs", qqi, ki)
         sc = jnp.einsum("qhs,qh->qs", jnp.maximum(sc, 0.0), wwi)
-        causal = jnp.arange(t)[None, :] <= p[:, None]
-        sel = _select(jnp.where(causal, sc, -jnp.inf), topk)
-        s = jnp.einsum("qhd,khd->hqk", qq, k) * (nope + rope) ** -0.5
-        pr = jax.nn.softmax(jnp.where(sel[None], s, -jnp.inf), axis=-1)
-        return jnp.einsum("hqk,khd->qhd", pr, kv[..., nope:]), sel
+        return _select(jnp.where(pos[None, :] <= p[:, None], sc, -jnp.inf),
+                       topk)
+    chosen = jax.lax.map(select, (_blocks(qi, SELECT_BLOCK),
+                                  _blocks(wi, SELECT_BLOCK),
+                                  _blocks(pos, SELECT_BLOCK)))
+    chosen = chosen.reshape(-1, chosen.shape[-1])
 
-    def padded(a):
-        return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)).reshape(
-            (-1, qb) + a.shape[1:])
-    a, sel = jax.lax.map(block, (padded(q), padded(qi), padded(wi),
-                                 pos.reshape(-1, qb)))
-    a = a.reshape(t + pad, nh * vd)[:t]
-    return x + a @ _w(lp["o_proj"]), sel.reshape(t + pad, t)[:t], qi, wi
+    g = math.gcd(nh, HEAD_GROUP)
+    # each group's columns of q_b and kv_b and its rows of o, stacked
+    w_qb = _w(lp["q_b_proj"])
+    w_qb = w_qb.reshape(-1, nh // g, g * (nope + rope)).transpose(1, 0, 2)
+    w_kvb = _w(lp["kv_b_proj"]).reshape(lora, nh // g, g * (nope + vd))
+    w_kvb = w_kvb.transpose(1, 0, 2)
+    w_o = _w(lp["o_proj"]).reshape(nh // g, g * vd, -1)
+
+    def group(out, w):
+        wq, wkv, wo = w
+        kv = (c @ wkv).reshape(t, g, nope + vd)
+        k = jnp.concatenate([kv[..., :nope],
+                             jnp.broadcast_to(k_r, (t, g, rope))], -1)
+        v = kv[..., nope:]
+
+        def attend(args):
+            cq, sel, p = args
+            q = (cq @ wq).reshape(-1, g, nope + rope)
+            q = jnp.concatenate([q[..., :nope], _rope_pairs(
+                q[..., nope:], theta, positions=p)], -1)
+            rows = jnp.arange(sel.shape[0])[:, None]
+            seen = jnp.zeros((sel.shape[0], t + 1), bool).at[
+                rows, jnp.where(sel >= 0, sel, t)].set(True)[:, :t]
+            s = jnp.einsum("qhd,khd->hqk", q, k) * (nope + rope) ** -0.5
+            pr = jax.nn.softmax(jnp.where(seen[None], s, -jnp.inf), axis=-1)
+            return jnp.einsum("hqk,khd->qhd", pr, v)
+        a = jax.lax.map(attend, (_blocks(c_q, QUERY_BLOCK),
+                                 _blocks(chosen, QUERY_BLOCK),
+                                 _blocks(pos, QUERY_BLOCK)))
+        return out + a.reshape(-1, g * vd)[:t] @ wo, None
+    out, _ = jax.lax.scan(group, x, (w_qb, w_kvb, w_o))
+    return out, chosen[:t], qi, wi
 
 
 _STATIC = ("nh", "nope", "rope", "vd", "lora", "eps", "theta", "topk", "hi",
            "di", "index_interleave")
 
 
-def _probed(sel, qi, wi, probe):
-    """The selections of every query, and the indexer's query and head
-    weights at the ``probe`` positions (a tuple)."""
+def _probed(chosen, qi, wi, probe):
+    """At the ``probe`` positions (a tuple): each one's selection (P, T)
+    bool, the indexer's query and its head weights."""
     at = jnp.asarray(probe, jnp.int32)
-    return sel, qi[at], wi[at]
+    t = qi.shape[0]
+    sel = chosen[at]
+    seen = jnp.zeros((len(probe), t + 1), bool).at[
+        jnp.arange(len(probe))[:, None], jnp.where(sel >= 0, sel, t)].set(
+            True)[:, :t]
+    return seen, qi[at], wi[at]
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("probe",))
@@ -201,8 +252,8 @@ def glm5_logits(cfg, params, ids, routing=None, selections=None,
     ``bigdl_tpu.llm.models.deepseek.init_params``. A list given as
     ``routing`` receives, per expert layer, ``(router input (T, H),
     chosen experts (T, k), their weights (T, k))``; one given as
-    ``selections``, per layer, ``(each query's selection (T, T) bool,
-    the indexer's queries (P, Hi, Di) and head weights (P, Hi) at the
+    ``selections``, per layer, ``(the selection (P, T) bool, the
+    indexer's queries (P, Hi, Di) and head weights (P, Hi) of the
     positions ``probe``)``, float32."""
     kw = dict(nh=cfg.num_attention_heads, nope=cfg.qk_nope_head_dim,
               rope=cfg.qk_rope_head_dim, vd=cfg.v_head_dim,
@@ -235,7 +286,7 @@ def glm5_logits(cfg, params, ids, routing=None, selections=None,
         keep(sel)
         chosen.append(np.asarray(idx))
         if routing is not None:
-            routing.append((h, np.asarray(idx), np.asarray(w)))
+            routing.append((np.asarray(h), np.asarray(idx), np.asarray(w)))
     logits = _head(x, params["norm"], params["lm_head"],
                    eps=float(cfg.rms_norm_eps))
     return np.asarray(logits), np.stack(chosen)

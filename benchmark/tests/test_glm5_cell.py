@@ -36,7 +36,7 @@ APPENDED = ("engine_host_ms.itl", "decode_step_dev_ms.itl",
             "engine_pass_host_ms.itl", "engine_admit_ms.itl",
             "engine_itl_p95_ms.itl", "engine_stage_ms.itl",
             "engine_prefill_finish_ms.itl", "gaps_behind_prefill_pct.itl",
-            "py_gc_ms.itl", "moe_experts_touched.itl")
+            "py_gc_ms.itl", "moe_experts_touched.itl", "moe_ffn_roofline")
 MAN = mf.load()
 CONFIG = mf.config_of(MAN, mf.cell(MAN, CELL))
 MODEL = drv.model_config(CONFIG, {})
@@ -197,6 +197,14 @@ def test_readers_on_a_hand_built_run():
         100 * step / hbm / 0.015)
     assert read["dsa_selected_pct.itl"] == pytest.approx(
         100 * 2048 / 22_001)
+    # the expert layer (Kanana's reader): the held experts with a token,
+    # 7 a layer, and a layer's shared expert and router over all 256, a
+    # step of the kernel's 4 ms
+    read["moe_ffn_roofline"] = mf.reader_of("moe_ffn_roofline").read(
+        run, "moe_ffn_roofline")
+    experts = 28 * 75_497_472 + 4 * (75_497_472 + 2 * 6144 * 256 + 4 * 256)
+    assert read["moe_ffn_roofline"] == pytest.approx(
+        100 * experts / hbm / 0.004)
     for v in read.values():
         assert 0 < v < 100
 

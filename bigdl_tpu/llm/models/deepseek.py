@@ -58,13 +58,16 @@ expanded form under the selection mask, in query and key blocks. A
 configuration without ``index_topk`` (Kanana's) traces what it always
 has: the code below branches on the configuration in Python.
 
-**An expert-parallel share** (``held_experts``; the ``mimo`` and
-``nemotron_h`` families call it ``experts_held``, and the benchmark's
-reader of their expert kernel tells their models by that name): the
-stack holds the routed experts ``first_expert .. first_expert +
-held_experts - 1`` and the shared ones; the router chooses among all
-``n_routed_experts`` and an assignment held elsewhere adds nothing here
-(``kernels.moe.grouped_ffn(held=...)``).
+**An expert-parallel share** (``held_experts``): the stack holds the
+routed experts ``first_expert .. first_expert + held_experts - 1`` and
+the shared ones; the router chooses among all ``n_routed_experts`` and
+an assignment held elsewhere adds nothing here
+(``kernels.moe.grouped_ffn(held=...)``). The ``mimo`` and ``nemotron_h``
+families' ``experts_held`` is what
+``benchmark/layers/held_experts_ffn_roofline.py`` tells their models
+by, and its bytes leave out the shared experts this family's layer
+holds beside the routed ones: this family's expert product is read by
+``moe_ffn_roofline``, which counts them.
 """
 
 from __future__ import annotations
@@ -86,6 +89,9 @@ from bigdl_tpu.llm.models.llama import _linear, mlp, rms_norm, rope
 ATTN_QUERY_BLOCK = 256
 # keys a sparse prefill block scores and attends at a time
 ATTN_KEY_BLOCK = 1024
+# tokens of a prompt one pass of the layers takes where the selection
+# binds (the prefill program loops over a longer prompt's chunks itself)
+PREFILL_CHUNK = 1024
 
 
 @dataclasses.dataclass
@@ -120,10 +126,6 @@ class DeepseekConfig:
     # the chip's share of every expert layer (None: all of them)
     first_expert: int = 0
     held_experts: Optional[int] = None
-    # tokens of a prompt one pass of the layers takes where the
-    # selection binds (the prefill program loops over a longer prompt's
-    # chunks itself)
-    prefill_chunk: int = 1024
 
     def __post_init__(self):
         if self.held_experts is not None and not (
@@ -190,7 +192,7 @@ class DeepseekConfig:
             routed_scaling_factor=2.5, rms_norm_eps=1e-5,
             max_position_embeddings=512, q_lora_rank=48, index_topk=16,
             index_n_heads=8, index_head_dim=16, first_expert=4,
-            held_experts=4, prefill_chunk=32)
+            held_experts=4)
         keys.update(over)
         return cls(**keys)
 
@@ -933,7 +935,7 @@ def paged_prefill_ragged(params, cfg: DeepseekConfig, kv_pages, _none,
 def _dsa_prefill(params, cfg: DeepseekConfig, kv_pages, idx_pages, toks,
                  length, offset, bt_row, phys, slots, *, page: int):
     """:func:`paged_prefill_ragged` where the selection binds. The
-    bucket is taken ``prefill_chunk`` tokens at a time INSIDE the
+    bucket is taken ``PREFILL_CHUNK`` tokens at a time INSIDE the
     program, as many chunks as ``length`` needs: a chunk's layer reads
     the row's latent rows and index keys so far through its table (one
     gather of its pages), puts the chunk's own beside them, scores every
@@ -946,7 +948,7 @@ def _dsa_prefill(params, cfg: DeepseekConfig, kv_pages, idx_pages, toks,
     f32)``."""
     from bigdl_tpu.llm.kvcache.write import write_kv_run
     bucket = toks.shape[1]
-    chunk = min(bucket, cfg.prefill_chunk)
+    chunk = min(bucket, PREFILL_CHUNK)
     num_pages = kv_pages.shape[1]
     pmax = bt_row.shape[0]
     # the positions a chunk may see: the bucket's, or the table's,

@@ -39,7 +39,11 @@ def _drop_executables():
 
 @pytest.fixture(autouse=True, scope="module")
 def _leave_no_executables():
-    yield
+    """Prefill chunks of 32 tokens, so that the prompts here take two
+    and three; the programs traced with them go with the module."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ds, "PREFILL_CHUNK", 32)
+        yield
     jax.clear_caches()
 
 
