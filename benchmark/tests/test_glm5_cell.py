@@ -23,6 +23,7 @@ from benchmark import faults_glm5  # noqa: E402
 from benchmark import manifest as mf  # noqa: E402
 from benchmark import peaks_glm5 as pk  # noqa: E402
 from benchmark import traffic  # noqa: E402
+from benchmark.drivers import serve_deepseek  # noqa: E402
 from benchmark.drivers import serve_glm5 as drv  # noqa: E402
 from benchmark.layers import _sparse_slice  # noqa: E402
 
@@ -268,6 +269,27 @@ def test_the_traffic_file():
     top = int(lens.max()) + int(traffic.quantile_grid(mix["output"],
                                                       256).max())
     assert top <= CONFIG["engine"]["max_seq_len"]
+
+
+def test_the_traced_slice_outlasts_the_prefill_the_window_opens_with():
+    """A module counts only if it starts inside the traced slice, and one
+    prefill holds the chip: a slice inside it reads no decode step. The
+    order deals a prompt of 45,694 tokens at the window's opening; by the
+    prefill time the order was picked with (40 ms + tokens / 50,000 s +
+    9.45e-6 tokens^2 ms, which over-counts the chip's 16.5 s for it) the
+    slice ends long after, and holds arrivals whose prefills it reads."""
+    mix = json.load(open(os.path.join(ROOT, mf.traffic_path(
+        "longctx_steady"))))
+    reqs = serve_deepseek.scheduled_requests(mix, 725585098, 51, 19_360, 1.0)
+    lead, t = mix["lead_in_s"], CONFIG["trace"]
+    start = min(t["start_s"], 51 / 4)
+    end = start + min(t["slice_s"], 51 / 2)
+    first = next(r for r in reqs if r["due"] >= lead)
+    n = len(first["prompt"])
+    assert first["due"] == lead and n > 40_000
+    prefill_s = 0.040 + n / 50_000 + 9.45e-9 * n * n
+    assert end >= prefill_s + 10
+    assert sum(lead <= r["due"] < lead + end for r in reqs) >= 4
 
 
 def test_the_planted_faults_are_the_named_ones():
