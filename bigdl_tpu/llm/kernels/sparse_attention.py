@@ -13,11 +13,13 @@ A decode step runs three named parts a layer:
   ``(Hi, D) x (D, n)``, then ``ReLU`` and the weighted sum over the
   heads: ``I[s] = sum_j w_j relu(q_j . k_s)``, float32, for positions
   ``0 .. len - 1``.
-- :func:`dsa_select` (a jitted top-k: a stable sort): the current token's
-  score at position ``len`` beside the cached ones, everything past it
-  ``-inf``, the ``k`` largest kept; a row of ``len + 1 <= k`` keeps
-  everything. Ties go to the lower position, as ``lax.top_k`` breaks
-  them. Exact: an approximate top-k is another result.
+- :func:`dsa_select` (:func:`dsa_topk_decode`, Pallas: a bisection for
+  each live row's ``k``-th score, then a compaction of the kept entries,
+  no sort): the current token's score at position ``len`` beside the
+  cached ones, nothing past it read, the ``k`` largest kept; a row of
+  ``len + 1 <= k`` keeps everything. Ties go to the lower position, as
+  ``lax.top_k`` breaks them. Exact: an approximate top-k is another
+  result.
 - :func:`sparse_latent_attention` (Pallas): absorbed-form multi-query
   attention over the selected rows, gathered from the latent pool by
   the pool rows the selection carried (:func:`pool_rows`,
@@ -204,18 +206,10 @@ def index_score_rows(q, w, keys):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def dsa_select(scores, current, lengths, payload=None, *, k: int):
-    """``scores`` (B, S) of the cached positions (only ``< lengths``
-    are read), ``current`` (B,) the score of the token at position
-    ``lengths`` -> ``(what (B, k) int32, selected (B, k) bool)``: the
-    ``min(k, len + 1)`` highest-scored positions ``<= len``, the current
-    token competing like a cached one; ties go to the lower position.
-    ``what`` is each selected position, or its entry of ``payload`` (B,
-    S) int32 where one is given (the decode step's: the pool row a
-    position is cached in), carried through the same sort so that no
-    gather follows it. ``selected`` is False where a row has fewer than
-    ``k``. ``lax.top_k``'s form: a stable sort of the negated scores
-    with the payload beside them."""
+def dsa_select_reference(scores, current, lengths, payload=None, *, k: int):
+    """XLA twin of :func:`dsa_topk_decode` (:func:`dsa_select`'s
+    contract), ``lax.top_k``'s form: a stable sort of the negated scores
+    with the payload beside them, the kept entries in score order."""
     pos = jnp.arange(scores.shape[1], dtype=jnp.int32)[None]
     lens = lengths.astype(jnp.int32)[:, None]
     s = jnp.where(pos < lens, scores, -jnp.inf)
@@ -227,12 +221,301 @@ def dsa_select(scores, current, lengths, payload=None, *, k: int):
     return what[:, :k], neg[:, :k] < jnp.inf
 
 
+# positions the selection kernel takes at a time: one (8, 128) tile; its
+# loops take TOPK_GROUP tiles a step, independent work the scheduler can
+# interleave (a lone tile's chain of cross-lane moves waits on itself)
+TOPK_TILE = 8 * LANE
+TOPK_GROUP = 4
+_SIGN = -(1 << 31)
+
+
+def _order_key(bits):
+    """int32 bit patterns of float32 scores -> int32 keys in the scores'
+    order: :func:`select_mask`'s uint32 key with its sign bit flipped
+    (negatives have every bit but the sign flipped, the rest stay), and
+    -0.0 given 0.0's key, as the sort compares them."""
+    flip = (bits >> 31) & 0x7FFFFFFF
+    return jax.lax.select(bits == _SIGN, jnp.zeros_like(bits), bits ^ flip)
+
+
+def _tile_scans(masks, tri, sub):
+    """``masks``, each (8, 128) bool over a tile's positions row by row
+    -> for each: (the exclusive count within each row, the count in the
+    rows before each row, each row's count, all (8, 128) int32; the
+    tile's count, a scalar). The counts within the rows of all the tiles
+    are one product with the triangle ``tri`` (128, 128) on the MXU (0
+    and 1 are exact in bfloat16, their sums in float32)."""
+    shape = masks[0].shape
+    zero = jnp.zeros(shape, jnp.int32)
+    ones = [jax.lax.select(m, jnp.ones(shape, jnp.int32), zero)
+            for m in masks]
+    inc = jax.lax.dot_general(
+        jnp.concatenate(ones, axis=0).astype(jnp.float32).astype(tri.dtype),
+        tri, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+    out = []
+    for j, x in enumerate(ones):
+        row_inc = inc[8 * j:8 * (j + 1)]
+        rows = jnp.broadcast_to(row_inc[:, LANE - 1:], shape)
+        upto = rows
+        for sh in (1, 2, 4):
+            upto = upto + jax.lax.select(sub >= sh, pltpu.roll(upto, sh, 0),
+                                         zero)
+        out.append((row_inc - x, upto - rows, rows, upto[7, 0]))
+    return out
+
+
+def _topk_kernel(len_ref, cur_ref, s_ref, *rest, k: int, has_payload: bool,
+                 widen: bool, place: bool = True):
+    """One batch row ``b``: the ``keep = min(k, len + 1)`` highest keys of
+    positions ``<= len`` (``cur_ref[b]``, the current token's, at
+    ``len``), their payloads written to the row's output in position
+    order. A row with ``len + 1 <= k`` copies its first ``len + 1``.
+    Else over the row's live tiles, ``TOPK_GROUP`` at a time: (a) the
+    keys, positions past ``len`` set below every score (never read by
+    value: what lies there may be NaN); (b) the ``keep``-th largest key
+    ``thr``, two bits a pass from the top, counting keys at or above
+    three candidates; (c) the kept entries, ``key >= thr`` where no tie
+    at ``thr`` must be left out, else ``key > thr`` and the first ``keep
+    - #{key > thr}`` ties in position order, moved to the left of their
+    row by the bits of the count of entries dropped before each (a move
+    by 1, 2, 4, ... lanes: kept entries keep their order and never
+    meet), rotated to the lane the row's first kept entry goes to, and
+    merged into the output at their window. s_ref (1, R, 128) float32
+    and p_ref (1, R, 128) int32 a whole row; o_ref (1, KR, 128) int32;
+    key_ref (R, 128) int32. ``widen``: interpret mode on the CPU, whose
+    products take no bfloat16 operands into float32 (the triangle is
+    float32 there). ``place=False`` stops after (b), ``thr`` in the
+    output's first row (``tools/exp_dsa_decode.py`` times the parts
+    apart)."""
+    if has_payload:
+        p_ref, o_ref, key_ref = rest
+    else:
+        (o_ref, key_ref), p_ref = rest, None
+    b = pl.program_id(0)
+    ln = len_ref[b]
+    keep = jnp.minimum(ln + 1, k)
+    kr = o_ref.shape[1]
+    shape = (8, LANE)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    flat = (sub << 7) | lane
+    zero = jnp.zeros(shape, jnp.int32)
+    one = jnp.ones(shape, jnp.int32)
+
+    def tile_rows(t):
+        return pl.ds(pl.multiple_of(t << 3, 8), 8)
+
+    def payload(t):
+        if p_ref is None:
+            return (t << 10) + flat
+        return p_ref[0, tile_rows(t), :]
+
+    @pl.when(ln < k)
+    def _everything():
+        for t in range(kr // 8):
+            v = zero
+            if (t + 1) * 8 <= s_ref.shape[1]:
+                v = jax.lax.select((t << 10) + flat <= ln, payload(t), zero)
+            o_ref[0, t * 8:(t + 1) * 8, :] = v
+
+    @pl.when(ln >= k)
+    def _select():
+        group = TOPK_GROUP
+        lg = group.bit_length() - 1                 # a power of two
+        groups = ((ln >> 10) + group) >> lg
+        cur = jnp.full(shape, cur_ref[b], jnp.int32)
+        below = jnp.full(shape, _SIGN, jnp.int32)
+
+        def keys(g, c):
+            for j in range(group):
+                t = (g << lg) + j
+                pos = (t << 10) + flat
+                bits = jax.lax.bitcast_convert_type(
+                    s_ref[0, tile_rows(t), :], jnp.int32)
+                key = jax.lax.select(pos < ln, _order_key(bits), below)
+                key_ref[tile_rows(t), :] = jax.lax.select(pos == ln, cur,
+                                                          key)
+            return c
+
+        jax.lax.fori_loop(0, groups, keys, 0)
+
+        def counts(*tests):
+            """The number of keys each of ``tests`` holds for."""
+            def one_group(g, accs):
+                accs = list(accs)
+                for j in range(group):
+                    key = key_ref[tile_rows((g << lg) + j), :]
+                    for i, test in enumerate(tests):
+                        accs[i * group + j] = accs[i * group + j] + \
+                            jax.lax.select(test(key), one, zero)
+                return tuple(accs)
+            accs = jax.lax.fori_loop(0, groups, one_group,
+                                     (zero,) * (len(tests) * group))
+            return [jnp.sum(sum(accs[i * group:(i + 1) * group]))
+                    for i in range(len(tests))]
+
+        def digits(i, thr):
+            at = 30 - 2 * i
+            got = counts(*(
+                lambda key, c=(thr | (d << at)) ^ _SIGN: key >= c
+                for d in (1, 2, 3)))
+            d = sum(jnp.where(c >= keep, 1, 0) for c in got)
+            return thr | (d << at)
+
+        thr = jax.lax.fori_loop(0, 16, digits, jnp.int32(0)) ^ _SIGN
+        if not place:
+            o_ref[0, 0:8, :] = jnp.full(shape, thr, jnp.int32)
+            return
+        above, at_or_above = counts(lambda key: key > thr,
+                                    lambda key: key >= thr)
+        room = keep - above
+        gone = jnp.full(shape, -LANE, jnp.int32)    # no bit under 128 set
+        rowid = jax.lax.broadcasted_iota(jnp.int32, (kr, LANE), 0)
+        tri = (jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
+               <= jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1)
+               ).astype(jnp.float32 if widen else jnp.bfloat16)
+        o_ref[0] = jnp.zeros((kr, LANE), jnp.int32)
+
+        def merge(in_order: bool):
+            """Every tile's kept entries into the output; ``in_order``:
+            the ties at ``thr`` taken in position order up to
+            ``room``."""
+            def one_group(g, carry):
+                done, ties = carry
+                out = o_ref[0]
+                ts = [(g << lg) + j for j in range(group)]
+                key = [key_ref[tile_rows(t), :] for t in ts]
+                live = [(t << 10) + flat <= ln for t in ts]
+                if in_order:
+                    tie = [lv & (ky == thr) for lv, ky in zip(live, key)]
+                    kept = []
+                    for lv, ky, ti, (in_row, before, _, n_tie) in zip(
+                            live, key, tie, _tile_scans(tie, tri, sub)):
+                        kept.append((lv & (ky > thr)) | (
+                            ti & (ties + before + in_row < room)))
+                        ties = ties + n_tie
+                else:
+                    kept = [lv & (ky >= thr) for lv, ky in zip(live, key)]
+                for t, kp, (in_row, before, in_rows, n_kept) in zip(
+                        ts, kept, _tile_scans(kept, tri, sub)):
+                    # each row's kept entries to its left, in order
+                    v = payload(t)
+                    s = jax.lax.select(kp, lane - in_row, gone)
+                    sh = 1
+                    while sh < LANE:
+                        v2 = pltpu.roll(v, LANE - sh, 1)
+                        s2 = pltpu.roll(s, LANE - sh, 1)
+                        come = (s2 & sh) != 0
+                        v = jax.lax.select(come, v2, v)
+                        s = jax.lax.select(come, s2, jax.lax.select(
+                            (s & sh) == 0, s, gone))
+                        sh <<= 1
+                    # rotated to the lane the row's first goes to
+                    dest = done + before
+                    at = dest & (LANE - 1)
+                    sh = 1
+                    while sh < LANE:
+                        v = jax.lax.select((at & sh) != 0,
+                                           pltpu.roll(v, sh, 1), v)
+                        sh <<= 1
+                    win = jax.lax.select(
+                        ((lane - at) & (LANE - 1)) < in_rows,
+                        (dest >> 7) + jax.lax.select(lane < at, one, zero),
+                        -one)
+                    for r in range(8):
+                        wr = jnp.broadcast_to(win[r:r + 1], (kr, LANE))
+                        out = jax.lax.select(rowid == wr, jnp.broadcast_to(
+                            v[r:r + 1], (kr, LANE)), out)
+                    done = done + n_kept
+                o_ref[0] = out
+                return done, ties
+
+            jax.lax.fori_loop(0, groups, one_group,
+                              (jnp.int32(0), jnp.int32(0)))
+
+        @pl.when(at_or_above == keep)
+        def _no_tie_left_out():
+            merge(False)
+
+        @pl.when(at_or_above != keep)
+        def _ties_in_order():
+            merge(True)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def dsa_topk_decode(scores, current, lengths, payload=None, *, k: int,
+                    interpret: bool = False):
+    """:func:`dsa_select`'s contract without a sort (Pallas): one grid
+    step a batch row, the exact ``k``-th largest score by bisection over
+    the 32 bits of an order-preserving integer key of the scores, then
+    the kept entries compacted, all in the row's live tiles (see
+    :func:`_topk_kernel`). The kept entries come in POSITION order, not
+    score order (no consumer needs an order: the sparse kernel takes a
+    softmax over them, ``fold_current`` asks only whether the current
+    token is among them); entries past ``min(k, len + 1)`` are 0."""
+    b, s = scores.shape
+    span = TOPK_GROUP * TOPK_TILE
+    sp = -(-s // span) * span
+    kout = -(-k // TOPK_TILE) * TOPK_TILE
+    lens = lengths.astype(jnp.int32)
+    cur = _order_key(jax.lax.bitcast_convert_type(
+        current.astype(jnp.float32), jnp.int32))
+    args = [scores.astype(jnp.float32)]
+    if payload is not None:
+        args.append(payload.astype(jnp.int32))
+    args = [jnp.pad(a, ((0, 0), (0, sp - s))).reshape(b, sp // LANE, LANE)
+            for a in args]
+    row = lambda b_, *_: (b_, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(_topk_kernel, k=k, has_payload=payload is not None,
+                          widen=bool(interpret)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[pl.BlockSpec((1, sp // LANE, LANE), row)
+                      for _ in args],
+            out_specs=pl.BlockSpec((1, kout // LANE, LANE), row),
+            scratch_shapes=[pltpu.VMEM((sp // LANE, LANE), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((b, kout // LANE, LANE), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="dsa_topk_decode",
+        interpret=interpret,
+    )(lens, cur, *args)
+    keep = jnp.minimum(lens + 1, k)[:, None]
+    return (out.reshape(b, kout)[:, :k],
+            jnp.arange(k, dtype=jnp.int32)[None] < keep)
+
+
+def dsa_select(scores, current, lengths, payload=None, *, k: int,
+               interpret=None):
+    """``scores`` (B, S) of the cached positions (only ``< lengths``
+    are read), ``current`` (B,) the score of the token at position
+    ``lengths`` -> ``(what (B, k) int32, selected (B, k) bool)``: the
+    ``min(k, len + 1)`` highest-scored positions ``<= len``, the current
+    token competing like a cached one; ties go to the lower position.
+    ``what`` is each selected position, or its entry of ``payload`` (B,
+    S) int32 where one is given (the decode step's: the pool row a
+    position is cached in), so that no gather follows. ``selected`` is
+    True on exactly the first ``min(k, len + 1)`` entries. Which order
+    the kept entries come in is the form's own. Backend dispatch:
+    :func:`dsa_topk_decode` on TPU (position order), the sort
+    :func:`dsa_select_reference` elsewhere (score order)."""
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return dsa_select_reference(scores, current, lengths, payload,
+                                        k=k)
+        interpret = False
+    return dsa_topk_decode(scores, current, lengths, payload, k=k,
+                           interpret=interpret)
+
+
 def select_mask(scores, k: int, *, n_blocks=None, block=None):
     """(Q, S) float32 scores, ``-inf`` where a query may not look ->
     (Q, S) bool of each query's ``k`` highest (all its finite ones
     where that is fewer); ties to the lower position, as
-    :func:`dsa_select`'s. Prefill's form, for many queries at once: no
-    sort. Each row's ``k``-th largest score is found exactly by
+    :func:`dsa_select`'s. Prefill's form, for many queries at once, in
+    XLA: no sort. Each row's ``k``-th largest score is found exactly by
     bisection over the 32 bits of an order-preserving integer form of
     the scores, a pass a bit, counting only the first ``n_blocks`` key
     blocks of ``block`` (every score after them is ``-inf``); the ties

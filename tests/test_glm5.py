@@ -9,6 +9,7 @@ and a Kanana-shaped configuration that builds none of it.
 """
 
 import dataclasses
+import functools
 import itertools
 
 import jax
@@ -206,6 +207,134 @@ def test_selection_against_argsort_over_ties(k):
     for r in range(9):
         got2[r, np.asarray(pos[r])[np.asarray(ok[r])]] = True
     np.testing.assert_array_equal(got2, want)
+
+
+# the selection kernel (interpret mode, unwritten memory NaN) against the
+# sort and against a plain stable argsort; scores past each row's length
+# are NaN, as the scoring walk may leave them
+_NAN_PAST = pltpu.InterpretParams(uninitialized_memory="nan")
+
+
+def _topk_case(case):
+    """(scores (B, S) NaN past each length, current (B,), lengths, k)."""
+    rs = np.random.RandomState(_TOPK_CASES.index(case))
+    k, s_len = 64, 2048
+    if case == "lengths_about_k":
+        lens = [0, 1, k - 1, k, k + 1, 2047]
+    elif case == "ragged_tiles":
+        k, s_len = 300, 4096
+        lens = [4095, 2500, 1023, 1024, 1025, 301, 0]
+    else:
+        lens = [700, 1500, 2047, 90]
+    s = rs.randn(len(lens), s_len).astype(np.float32)
+    cur = rs.randn(len(lens)).astype(np.float32)
+    if case == "ties_tenth":
+        s, cur = np.round(s, 1), np.round(cur, 1)
+    elif case == "flat_run":
+        s[:, 50:1400] = 0.25
+        cur[:] = 0.25
+    elif case == "current_above":
+        cur[:] = 99.0
+    elif case == "current_below_kth":
+        cur[:] = -99.0
+    elif case == "current_tied_lower":
+        # the current token ties the cached score at position 3 (one
+        # kept, near the top): ties go to the lower position
+        s[:, 3] = 5.0
+        cur[:] = 5.0
+        s[:, 4:k] = 6.0
+    elif case == "signed_zeros":
+        s = np.where(rs.rand(*s.shape) < 0.5, -0.0, 0.0).astype(np.float32)
+        s[:, ::7] = rs.randn(len(lens), len(range(0, s_len, 7)))
+        cur[:] = -0.0
+    for r, n in enumerate(lens):
+        s[r, n:] = np.nan
+    return s, cur, np.asarray(lens, np.int32), k
+
+
+_TOPK_CASES = ["lengths_about_k", "ragged_tiles", "ties_tenth", "flat_run",
+               "current_above", "current_below_kth", "current_tied_lower",
+               "signed_zeros"]
+
+
+def _kept_sets(what, ok):
+    return [sorted(np.asarray(w)[np.asarray(o)].tolist())
+            for w, o in zip(what, ok)]
+
+
+@pytest.mark.parametrize("table", [False, True], ids=["positions",
+                                                      "pool_rows"])
+@pytest.mark.parametrize("case", _TOPK_CASES)
+def test_topk_kernel_selects_the_sorts_set(case, table):
+    """``dsa_topk_decode`` keeps exactly the set the stable sort keeps
+    (and a plain argsort over the same row with the current token at
+    ``len``), carrying each position's pool row through a permuted
+    table where one is given, and marks exactly ``min(k, len + 1)``
+    entries selected."""
+    s, cur, lens, k = _topk_case(case)
+    b, n = s.shape
+    pay = (np.random.RandomState(1).permutation(b * n).reshape(b, n)
+           .astype(np.int32) if table else None)
+    args = (jnp.asarray(s), jnp.asarray(cur), jnp.asarray(lens),
+            None if pay is None else jnp.asarray(pay))
+    got = sa.dsa_topk_decode(*args, k=k, interpret=_NAN_PAST)
+    want = sa.dsa_select_reference(*args, k=k)
+    assert _kept_sets(*got) == _kept_sets(*want)
+    np.testing.assert_array_equal(np.asarray(got[1]).sum(1),
+                                  np.minimum(lens + 1, k))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    row = np.where(np.arange(n)[None] < lens[:, None], s, -np.inf)
+    row[np.arange(b), lens] = cur
+    plain = _argsort_selection(row.astype(np.float32), k)
+    for r in range(b):
+        pos = np.flatnonzero(plain[r])
+        assert _kept_sets(*got)[r] == sorted(
+            (pos if pay is None else pay[r, pos]).tolist())
+
+
+def test_topk_kernel_keeps_position_order():
+    """The kernel's kept entries come in ascending position, the sort's
+    in descending score."""
+    s, cur, lens, k = _topk_case("ragged_tiles")
+    pos, ok = sa.dsa_topk_decode(jnp.asarray(s), jnp.asarray(cur),
+                                 jnp.asarray(lens), k=k, interpret=True)
+    for r in range(len(lens)):
+        kept = np.asarray(pos[r])[np.asarray(ok[r])]
+        assert len(kept) == min(k, lens[r] + 1)
+        assert (np.diff(kept) > 0).all()
+
+
+def test_decode_step_with_the_topk_kernel(params32, monkeypatch):
+    """A whole decode step (three layers over random float32 pools,
+    rows past and under the 16 kept, a dead row) with the kernel in
+    interpret mode gives the sort's logits and writes the same pools, to
+    float32 rounding."""
+    rs = np.random.RandomState(5)
+    page, pmax = 16, 8
+    lens = np.asarray([100, 9, 0, 127, 16], np.int32)
+    b = len(lens)
+    pages = 1 + b * pmax
+    lay = CFG.num_hidden_layers
+    kv = jnp.asarray(rs.randn(lay, pages, 1, page, CFG.latent_width),
+                     jnp.float32)
+    ik = jnp.asarray(rs.randn(lay, pages, 1, page, CFG.index_head_dim),
+                     jnp.float32)
+    bt = jnp.asarray(1 + rs.permutation(b * pmax).reshape(b, pmax),
+                     jnp.int32)
+    toks = jnp.asarray(rs.randint(0, CFG.vocab_size, b), jnp.int32)
+
+    def step():
+        return ds.paged_decode_step(params32, CFG, kv, ik, bt,
+                                    jnp.asarray(lens), toks, page=page)
+
+    want = step()
+    monkeypatch.setattr(sa, "dsa_select", functools.partial(
+        sa.dsa_select, interpret=True))
+    got = step()
+    # the same sums in another order: float32 rounding apart
+    for x, y in zip(got[:3], want[:3]):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5,
+                                   atol=1e-5)
 
 
 # (4) the sparse attention ----------------------------------------------------

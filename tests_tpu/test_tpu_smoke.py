@@ -1225,3 +1225,45 @@ class TestStateSpaceFamilyOnChip:
         want = (wm[..., None] * y_pad[d.pos]).sum(1)
         assert int(sizes.sum()) == int((idx < 128).sum())
         assert self._rel(got, want) < 0.01
+
+
+class TestSparseSelectionOnChip:
+    """The decode step's top-2,048 (``dsa_topk_decode``, Mosaic) against
+    the stable sort at GLM-5's served shape (24 slots of 53,248
+    positions, live rows of 1 to 49k tokens, dead rows, NaN past each
+    length) and over ties: the same kept set, in position order."""
+
+    S, K = 53248, 2048
+
+    def _check(self, s, cur, lens, pay):
+        from bigdl_tpu.llm.kernels import sparse_attention as sa
+        args = (jnp.asarray(s), jnp.asarray(cur), jnp.asarray(lens),
+                jnp.asarray(pay))
+        got, ok = sa.dsa_topk_decode(*args, k=self.K)
+        want, wok = sa.dsa_select_reference(*args, k=self.K)
+        np.testing.assert_array_equal(np.asarray(ok), np.asarray(wok))
+        for r in range(len(lens)):
+            g = np.asarray(got[r])[np.asarray(ok[r])]
+            w = np.asarray(want[r])[np.asarray(wok[r])]
+            assert sorted(g.tolist()) == sorted(w.tolist()), r
+            order = np.argsort(pay[r])
+            pos = order[np.searchsorted(pay[r][order], g)]
+            assert (np.diff(pos) > 0).all(), r
+
+    @pytest.mark.parametrize("scores", ["normal", "tenths", "flat_run"])
+    def test_matches_the_sort(self, scores):
+        rs = np.random.RandomState(3)
+        lens = np.asarray([32000, 0, 22000, 2047, 2048, 49151, 1]
+                          + [0] * 17, np.int32)
+        s = rs.randn(len(lens), self.S).astype(np.float32)
+        cur = rs.randn(len(lens)).astype(np.float32)
+        if scores == "tenths":
+            s, cur = np.round(s, 1), np.round(cur, 1)
+        elif scores == "flat_run":
+            s[:, 1000:30000] = 0.5
+            cur[:] = 0.5
+        for r, n in enumerate(lens):
+            s[r, n:] = np.nan
+        pay = rs.permutation(len(lens) * self.S).reshape(
+            len(lens), self.S).astype(np.int32)
+        self._check(s, cur, lens, pay)

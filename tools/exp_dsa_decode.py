@@ -6,19 +6,25 @@ the batch dead), timed over ``--steps`` steps and traced once
 (``benchmark/trace_reduce``: device time by op, exclusive), then each
 decode part alone: the scoring kernel, the selection (``lax.top_k`` and
 the bisection form prefill uses), the gather of the selected rows and
-the sparse kernel.
+the sparse kernel. The selection is timed in its three forms: the sort
+(``dsa_select_reference``), the bisection prefill uses, and the kernel
+``dsa_topk_decode`` (its threshold alone, and whole: the compaction is
+their difference), with the kernel's kept sets checked against the
+sort's (``topk_exact``); the whole step is timed with the sort too.
 
-    python3 tools/exp_dsa_decode.py [--rows 18] [--tokens 22000]
+    python3 tools/exp_dsa_decode.py [--rows 18] [--tokens 22000] [--groups 2,8]
     JAX_PLATFORMS=cpu python3 tools/exp_dsa_decode.py --tiny
 
 Writes its table to stdout and
 ``chiprun_out/exp_dsa_decode_<rows>x<tokens>.json``."""
 
 import argparse
+import functools
 import json
 import os
 import sys
 import time
+from unittest import mock
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -39,6 +45,8 @@ def main():
     ap.add_argument("--tokens", type=int, default=22000)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--groups", default="",
+                    help="TOPK_GROUP values to time the kernel at too")
     a = ap.parse_args()
 
     import jax
@@ -57,6 +65,7 @@ def main():
     cfg = drv.model_config(config, reh.get("model", {}))
     eng = {**config["engine"], **reh.get("engine", {})}
     b, page = eng["max_batch"], eng["page_size"]
+    a.rows = min(a.rows, b)
     tokens = min(a.tokens, eng["max_seq_len"] - 8) if not a.tiny else 300
     pmax = -(-eng["max_seq_len"] // page)
     pmax = -(-pmax // 8) * 8
@@ -85,18 +94,27 @@ def main():
     bt, lens = jnp.asarray(bt), jnp.asarray(lens)
     toks = jnp.asarray(rs.randint(0, cfg.vocab_size, b), jnp.int32)
 
-    step = jax.jit(lambda p, k, v, bt, lens, toks: ds.paged_decode_step(
-        p, cfg, k, v, bt, lens, toks, page=page), donate_argnums=(1, 2))
-    out = step(params, kv, ik, bt, lens, toks)
-    kv, ik = out[1], out[2]
-    jax.block_until_ready(kv)
-    t0 = time.perf_counter()
-    for _ in range(a.steps):
-        out = step(params, kv, ik, bt, lens, toks)
+    def step_time(select):
+        nonlocal kv, ik
+        with mock.patch.object(sa, "dsa_select", select):
+            step = jax.jit(lambda p, k, v, bt, lens, toks:
+                           ds.paged_decode_step(p, cfg, k, v, bt, lens,
+                                                toks, page=page),
+                           donate_argnums=(1, 2))
+            out = step(params, kv, ik, bt, lens, toks)
         kv, ik = out[1], out[2]
-    jax.block_until_ready(kv)
-    step_ms = (time.perf_counter() - t0) / a.steps * 1e3
-    res = {"rows": a.rows, "tokens": tokens, "step_ms_wall": step_ms}
+        jax.block_until_ready(kv)
+        t0 = time.perf_counter()
+        for _ in range(a.steps):
+            out = step(params, kv, ik, bt, lens, toks)
+            kv, ik = out[1], out[2]
+        jax.block_until_ready(kv)
+        return step, (time.perf_counter() - t0) / a.steps * 1e3
+
+    _, sort_ms = step_time(sa.dsa_select_reference)
+    step, step_ms = step_time(sa.dsa_select)
+    res = {"rows": a.rows, "tokens": tokens, "step_ms_wall": step_ms,
+           "step_ms_wall_sort": sort_ms}
     if not a.tiny:
         with trace_reduce.record() as tdir:
             for _ in range(5):
@@ -119,12 +137,30 @@ def main():
     scores = sa.index_scores(q, w, iflat, bt, lens, page_size=page)
     cur = jnp.zeros(b)
     k = cfg.index_topk
+    at = sa.pool_rows(bt, lens, page=page)
+    interpret = True if a.tiny else False
+    topk = sa.dsa_topk_decode.__wrapped__
+
+    def threshold(s, c, ln, a_):
+        with mock.patch.object(sa, "_topk_kernel", functools.partial(
+                sa._topk_kernel, place=False)):
+            return topk(s, c, ln, a_, k=k, interpret=interpret)
     parts = {
         "index_scores": _timed(jax.jit(
             lambda q, w, kp, bt, ln: sa.index_scores(
                 q, w, kp, bt, ln, page_size=page)), q, w, iflat, bt, lens),
-        "dsa_select_top_k": _timed(jax.jit(lambda s, c, ln: sa.dsa_select(
-            s, c, ln, k=k)), scores, cur, lens),
+        "dsa_select_sort": _timed(jax.jit(
+            lambda s, c, ln: sa.dsa_select_reference(s, c, ln, k=k)),
+            scores, cur, lens),
+        "topk_kernel_threshold": _timed(jax.jit(threshold), scores, cur,
+                                        lens, at),
+        "topk_kernel": _timed(jax.jit(
+            lambda s, c, ln, a_: sa.dsa_topk_decode(
+                s, c, ln, a_, k=k, interpret=interpret)), scores, cur, lens,
+            at),
+        "topk_kernel_positions": _timed(jax.jit(
+            lambda s, c, ln: sa.dsa_topk_decode(
+                s, c, ln, k=k, interpret=interpret)), scores, cur, lens),
         "select_mask_bisection": _timed(jax.jit(lambda s: sa.select_mask(
             s, k)), scores),
     }
@@ -140,11 +176,26 @@ def main():
         return idx, jnp.arange(1, k + 1)[None] <= cs[:, -1:]
     parts["dsa_select_bisection_positions"] = _timed(
         jax.jit(bisect_select), scores, cur, lens)
-    at = sa.pool_rows(bt, lens, page=page)
-    parts["dsa_select_pool_rows"] = _timed(jax.jit(
-        lambda s, c, ln, a: sa.dsa_select(s, c, ln, a, k=k)), scores, cur,
-        lens, at)
-    pos, ok = sa.dsa_select(scores, cur, lens, at, k=k)
+    parts["dsa_select_sort_pool_rows"] = _timed(jax.jit(
+        lambda s, c, ln, a_: sa.dsa_select_reference(s, c, ln, a_, k=k)),
+        scores, cur, lens, at)
+    parts["topk_kernel_compaction"] = (parts["topk_kernel"]
+                                       - parts["topk_kernel_threshold"])
+    for g in (int(x) for x in a.groups.split(",") if x):
+        with mock.patch.object(sa, "TOPK_GROUP", g):
+            parts[f"topk_kernel_group{g}"] = _timed(jax.jit(
+                lambda s, c, ln, a_: topk(s, c, ln, a_, k=k,
+                                          interpret=interpret)),
+                scores, cur, lens, at)
+            parts[f"topk_kernel_threshold_group{g}"] = _timed(
+                jax.jit(threshold), scores, cur, lens, at)
+    pos, ok = sa.dsa_select_reference(scores, cur, lens, at, k=k)
+    got, got_ok = sa.dsa_topk_decode(scores, cur, lens, at, k=k,
+                                     interpret=interpret)
+    res["topk_exact"] = all(
+        sorted(np.asarray(x)[np.asarray(xo)].tolist())
+        == sorted(np.asarray(y)[np.asarray(yo)].tolist())
+        for x, xo, y, yo in zip(got, got_ok, pos, ok))
     kv_rows = kv.reshape(-1, kv.shape[-1])
     parts["gather_selected"] = _timed(jax.jit(sa.gather_selected), kv_rows,
                                       pos)
