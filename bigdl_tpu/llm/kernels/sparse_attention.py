@@ -21,11 +21,13 @@ A decode step runs three named parts a layer:
   ``lax.top_k`` breaks them. Exact: an approximate top-k is another
   result.
 - :func:`sparse_latent_attention` (Pallas): absorbed-form multi-query
-  attention over the selected rows, gathered from the latent pool by
-  the pool rows the selection carried (:func:`pool_rows`,
-  :func:`gather_selected`: an XLA gather of whole rows; a Mosaic DMA of
-  one 16-bit row of a page is not aligned to the pool's tiling), scored
-  a block of 512 at a time with a running softmax. Returns the
+  attention over the selected rows, which it fetches from the latent
+  pool in HBM itself by the pool rows the selection carried
+  (:func:`pool_rows`, :func:`gather_selected`): one copy a counted
+  entry of a live row, of the 8-row group that holds its row (Mosaic
+  refuses a copy of one 16-bit row: the pool's HBM tile is 8 rows),
+  the next block fetched while this one is scored, 512 rows a block
+  with a running softmax, each entry's 7 other rows masked. Returns the
   flash-style partial ``(acc, m, l)`` over the cached rows it was
   given; the current token is folded in by the caller only where it
   was selected.
@@ -37,7 +39,7 @@ runs off the TPU.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,8 +51,12 @@ from bigdl_tpu.llm.kernels.paged_attention import LANE
 # cached index keys a block of the scoring walk fetches (32 pages of 16
 # at 128 bfloat16 numbers: 128 KB)
 INDEX_BLOCK_TOKENS = 512
-# selected rows the sparse attention scores at a time
+# pool rows the sparse attention scores at a time
 SPARSE_BLOCK_ROWS = 512
+# latent rows one of its copies brings: the group of 8 that holds the
+# selected row. The pool's HBM tile is 8 bfloat16 rows (two to a 32-bit
+# word); Mosaic refuses a copy of fewer
+FETCH_ROWS = 8
 
 
 # ---------------------------------------------------------------------------
@@ -563,86 +569,186 @@ def pool_rows(block_tables, lengths, *, page: int):
     return jnp.where(pos == lengths[:, None], -1, rows).astype(jnp.int32)
 
 
+class SelectedRows(NamedTuple):
+    """What :func:`gather_selected` hands the attention: the pool's rows
+    ``pool`` (L · P · page, W) and the selected ones ``rows`` (B, K)
+    int32, none negative. The kernel fetches them itself; the twin
+    gathers them."""
+    pool: jax.Array
+    rows: jax.Array
+
+
 def gather_selected(kv_rows, rows):
     """The latent rows ``rows`` (B, K) (pool rows, :func:`pool_rows`'s
     numbering offset to the layer; a negative one reads row 0, masked by
-    the caller) of the pool viewed ``(L · P · page, W)``: (B, K, W) in
-    the pool's dtype, one XLA gather of whole rows."""
-    return kv_rows[jnp.maximum(rows, 0)]
+    the caller) of the pool viewed ``(L · P · page, W)``, as
+    :func:`sparse_latent_stats` takes them: the one place where a step's
+    selection meets the pool. Nothing is copied here: the Mosaic kernel
+    fetches the rows from the pool in HBM, the XLA twin gathers them."""
+    return SelectedRows(kv_rows, jnp.maximum(rows, 0).astype(jnp.int32))
 
 
-def _sparse_kernel(q_ref, kv_ref, bias_ref, o_ref, mo_ref, lo_ref, *,
-                   blk: int, dv: int, scale: float):
-    """One batch row: ``(Hp, W)`` queries against its ``K`` gathered
-    rows, ``blk`` at a time with a running softmax; ``bias`` 0 where a
-    row counts and ``-1e30`` where it does not. The value is a row's
-    first ``dv`` columns."""
-    hp = q_ref.shape[1]
-    k = kv_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32)
-    acc = jnp.zeros((hp, dv), jnp.float32)
-    m = jnp.full((hp, 1), -1e30, jnp.float32)
-    l = jnp.zeros((hp, 1), jnp.float32)
-    for j in range(k // blk):                    # static unroll
-        kv = kv_ref[0, j * blk:(j + 1) * blk, :].astype(jnp.float32)
+def _sparse_kernel(n_ref, grp_ref, q_ref, bias_ref, pool_hbm, o_ref, mo_ref,
+                   lo_ref, buf, sem, walked, *, k: int, dv: int,
+                   scale: float, score: bool = True):
+    """One batch row ``b``: ``(Hp, W)`` queries against the groups that
+    hold its first ``n`` selected rows (``n_ref[b]``: one past the last
+    that counts), ``E`` entries a block with a running softmax. An entry
+    is one copy: the ``FETCH_ROWS``-row group of the pool that holds its
+    row (``grp_ref``), into its place in one of two buffer slots. A
+    block's ``E`` copies are issued unrolled, with no branch (an entry
+    that does not count, past ``n`` in the row's last block or the
+    current token's, fetches group 0 and is masked), share the slot's
+    semaphore and are awaited by one wait of the slot's bytes. The next
+    block, or after the row's last the next live row's first, is
+    fetched while this one is scored (``walked[0]`` counts the blocks of
+    the rows before this one). A block scores all ``E · FETCH_ROWS``
+    rows in hand; ``bias`` (1, 1, K · FETCH_ROWS) is 0 on the lane of
+    each counted entry's own row and ``-1e30`` on every other, whose
+    weight is then 0. A row with ``n == 0`` fetches nothing and writes
+    the identity. ``score=False`` stops after the waits
+    (``tools/exp_dsa_decode.py`` times the fetch alone)."""
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    ent = buf.shape[1]
+    lg = ent.bit_length() - 1                   # a power of two
+    blk = ent * FETCH_ROWS
+    hp, w = q_ref.shape[1], q_ref.shape[2]
+
+    @pl.when(b == 0)
+    def _first_row():
+        walked[0] = 0
+
+    nblk = (n_ref[b] + (ent - 1)) >> lg
+    first = walked[0]
+
+    def fetch(row, j, slot):
+        base = row * k + (j << lg)
+        for i in range(ent):                    # static unroll
+            pltpu.make_async_copy(pool_hbm.at[grp_ref[base + i]],
+                                  buf.at[slot, i], sem.at[slot]).start()
+
+    @pl.when((nblk > 0) & (first == 0))
+    def _nobody_fetched_it():
+        fetch(b, 0, 0)
+
+    nxt = jax.lax.while_loop(
+        lambda r: (r < rows) & (n_ref[jnp.minimum(r, rows - 1)] == 0),
+        lambda r: r + 1, b + 1)
+    q = q_ref[0]
+
+    def block(j, carry):
+        acc, m, l = carry
+        slot = (first + j) & 1
+        more = j + 1 < nblk
+
+        @pl.when(more | (nxt < rows))
+        def _fetch_ahead():
+            fetch(jnp.where(more, b, nxt), jnp.where(more, j + 1, 0),
+                  1 - slot)
+
+        # a wait takes its destination's bytes off the semaphore: the
+        # block's E copies at once
+        pltpu.make_async_copy(pool_hbm.at[pl.ds(0, ent)], buf.at[slot],
+                              sem.at[slot]).wait()
+        if not score:
+            return carry
+        kv = buf[slot].astype(jnp.float32).reshape(blk, w)
+        bias = bias_ref[0, :, pl.ds(pl.multiple_of(j * blk, blk), blk)]
         s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * scale + bias_ref[0, :, j * blk:(j + 1) * blk]
+        s = s * scale + bias
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        p_ = jnp.exp(s - m_new)
+        p_ = jnp.where(bias == 0.0, jnp.exp(s - m_new), 0.0)
         l = alpha * l + jnp.sum(p_, axis=1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
             p_, kv[:, :dv], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m = m_new
+        return acc, m_new, l
+
+    acc, m, l = jax.lax.fori_loop(0, nblk, block, (
+        jnp.zeros((hp, dv), jnp.float32),
+        jnp.full((hp, 1), -1e30, jnp.float32),
+        jnp.zeros((hp, 1), jnp.float32)))
+    walked[0] = first + nblk
     o_ref[0] = acc
     mo_ref[0] = jnp.broadcast_to(m, (hp, LANE))
     lo_ref[0] = jnp.broadcast_to(l, (hp, LANE))
 
 
-@functools.partial(jax.jit, static_argnames=("dv", "scale", "interpret"))
-def sparse_latent_attention(q, rows, counts, *, dv: int, scale: float,
-                            interpret: bool = False):
-    """Absorbed-form attention of ``q`` (B, H, W) float32 over gathered
-    latent rows ``rows`` (B, K, W) of which ``counts`` (B, K) bool
-    count. Returns ``(acc (B, H, dv) float32 unnormalised, m (B, H), l
-    (B, H))``, the identity ``(0, -1e30, 0)`` where nothing counts."""
+@functools.partial(jax.jit, static_argnames=("dv", "scale", "interpret",
+                                             "score"))
+def sparse_latent_attention(q, kv_rows, rows, counts, *, dv: int,
+                            scale: float, interpret: bool = False,
+                            score: bool = True):
+    """Absorbed-form attention of ``q`` (B, H, W) float32 over the rows
+    ``rows`` (B, K) int32 of the pool ``kv_rows`` (R, W) in HBM, of which
+    ``counts`` (B, K) bool count, fetched by the kernel
+    (:func:`_sparse_kernel`). Returns ``(acc (B, H, dv) float32
+    unnormalised, m (B, H), l (B, H))``, the identity ``(0, -1e30, 0)``
+    where nothing counts."""
     b, h, w = q.shape
-    k = rows.shape[1]
-    blk = min(SPARSE_BLOCK_ROWS, k)
-    if w % LANE or dv % LANE or k % blk or blk % 16:
-        raise ValueError(f"queries {q.shape} / rows {rows.shape}: want W "
-                         f"and dv multiples of {LANE}, K of 16")
+    r_all = kv_rows.shape[0]
+    ent = SPARSE_BLOCK_ROWS // FETCH_ROWS
+    if w % LANE or dv % LANE or r_all % FETCH_ROWS:
+        raise ValueError(f"queries {q.shape} / pool {kv_rows.shape}: want W "
+                         f"and dv multiples of {LANE}, rows of "
+                         f"{FETCH_ROWS}")
     hp = -(-h // 8) * 8
     if hp != h:
         q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
-    bias = jnp.where(counts, 0.0, -1e30).astype(jnp.float32)[:, None]
-    row = lambda b_: (b_, 0, 0)
-    # two buffers of a row's K rows and its queries, the float32 blocks
-    need = (2 * k * w * rows.dtype.itemsize + 2 * hp * w * 4
-            + 4 * blk * (w + hp) * 4 + (4 << 20))
+    k = -(-rows.shape[1] // ent) * ent
+    pad = ((0, 0), (0, k - rows.shape[1]))
+    rows = jnp.pad(rows.astype(jnp.int32), pad)
+    counts = jnp.pad(counts, pad)
+    n = jnp.max(jnp.where(counts, jnp.arange(1, k + 1, dtype=jnp.int32), 0),
+                axis=1)
+    lg = FETCH_ROWS.bit_length() - 1
+    grp = jnp.where(counts, rows >> lg, 0).reshape(-1)
+    own = jnp.where(counts, rows & (FETCH_ROWS - 1), -1)
+    lane = jnp.arange(k * FETCH_ROWS, dtype=jnp.int32)[None]
+    bias = jnp.where(jnp.repeat(own, FETCH_ROWS, axis=1)
+                     == (lane & (FETCH_ROWS - 1)), 0.0, -1e30
+                     ).astype(jnp.float32)[:, None]
+    pool = kv_rows.reshape(r_all // FETCH_ROWS, FETCH_ROWS, w)
+    row = lambda b_, *_: (b_, 0, 0)
+    blk = ent * FETCH_ROWS
+    # the two slots (an 8-row bfloat16 group takes a 16-row tile of
+    # VMEM), the queries, a row's bias (a 1-row block takes 8), the
+    # float32 block
+    need = (2 * ent * 16 * w * kv_rows.dtype.itemsize + 2 * hp * w * 4
+            + 2 * 8 * k * FETCH_ROWS * 4 + 4 * blk * (w + hp) * 4
+            + (4 << 20))
     acc, m, l = pl.pallas_call(
-        functools.partial(_sparse_kernel, blk=blk, dv=dv, scale=scale),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, hp, w), row),
-                  pl.BlockSpec((1, k, w), row),
-                  pl.BlockSpec((1, 1, k), row)],
-        out_specs=[pl.BlockSpec((1, hp, dv), row),
-                   pl.BlockSpec((1, hp, LANE), row),
-                   pl.BlockSpec((1, hp, LANE), row)],
+        functools.partial(_sparse_kernel, k=k, dv=dv, scale=scale,
+                          score=score),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[pl.BlockSpec((1, hp, w), row),
+                      pl.BlockSpec((1, 1, k * FETCH_ROWS), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, hp, dv), row),
+                       pl.BlockSpec((1, hp, LANE), row),
+                       pl.BlockSpec((1, hp, LANE), row)],
+            scratch_shapes=[
+                pltpu.VMEM((2, ent, FETCH_ROWS, w), kv_rows.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)]),
         out_shape=[jax.ShapeDtypeStruct((b, hp, dv), jnp.float32),
                    jax.ShapeDtypeStruct((b, hp, LANE), jnp.float32),
                    jax.ShapeDtypeStruct((b, hp, LANE), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",), vmem_limit_bytes=need),
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=need),
+        name="sparse_latent_attention",
         interpret=interpret,
-    )(q, rows, bias)
+    )(n, grp, q, bias, pool)
     return acc[:, :h], m[:, :h, 0], l[:, :h, 0]
 
 
 def sparse_latent_reference(q, rows, counts, *, dv: int, scale: float):
-    """XLA twin of :func:`sparse_latent_attention` (same contract)."""
+    """XLA twin of :func:`sparse_latent_attention` (same contract) over
+    the selected rows gathered, ``rows`` (B, K, W)."""
     kv = rows.astype(jnp.float32)
     s = jnp.einsum("bhw,bkw->bhk", q.astype(jnp.float32), kv) * scale
     s = jnp.where(counts[:, None], s, -1e30)
@@ -653,16 +759,18 @@ def sparse_latent_reference(q, rows, counts, *, dv: int, scale: float):
     return acc, m, jnp.sum(p, axis=-1)
 
 
-def sparse_latent_stats(q, rows, counts, *, dv: int, scale: float,
-                        interpret: Optional[bool] = None):
-    """Backend dispatch: Mosaic kernel on TPU, XLA elsewhere."""
+def sparse_latent_stats(q, sel: SelectedRows, counts, *, dv: int,
+                        scale: float, interpret: Optional[bool] = None):
+    """Backend dispatch over :func:`gather_selected`'s rows: the Mosaic
+    kernel, which fetches them, on TPU; the XLA gather and twin
+    elsewhere."""
     if interpret is None:
         if jax.default_backend() != "tpu":
-            return sparse_latent_reference(q, rows, counts, dv=dv,
-                                           scale=scale)
+            return sparse_latent_reference(q, sel.pool[sel.rows], counts,
+                                           dv=dv, scale=scale)
         interpret = False
-    return sparse_latent_attention(q, rows, counts, dv=dv, scale=scale,
-                                   interpret=interpret)
+    return sparse_latent_attention(q, sel.pool, sel.rows, counts, dv=dv,
+                                   scale=scale, interpret=interpret)
 
 
 def fold_current(acc, m, l, q, row, take, *, dv: int, scale: float):

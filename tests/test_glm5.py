@@ -381,6 +381,77 @@ def test_sparse_attention_is_dense_attention_under_the_mask(interpret):
         np.testing.assert_allclose(got[r], want, rtol=1e-4, atol=1e-5)
 
 
+def _selected(case, seed=11):
+    """A batch of four slots of a bfloat16 pool 640 wide, cached through
+    a permuted block table, and the rows of each that the decode step's
+    selection keeps (top-192, the sort's form: the current token's -1
+    wherever its score falls): ``case`` in slot 0, a dead slot, a row of
+    200 tokens, a dead slot. Returns ``(q, kv_rows, rows, counts)``."""
+    rs = np.random.RandomState(seed)
+    b, h, w, page, maxp, k = 4, 8, 640, 16, 16, 192
+    ln = {"k_minus_1": k - 1, "k": k, "k_plus_1": k + 1}.get(case, 230)
+    lens = np.asarray([0 if case == "len0" else ln, 0, 200, 0])
+    pool = jnp.asarray(rs.randn(1 + b * maxp, 1, page, w), jnp.bfloat16)
+    bt = jnp.asarray(1 + rs.permutation(b * maxp).reshape(b, maxp),
+                     jnp.int32)
+    scores = rs.randn(b, maxp * page).astype(np.float32)
+    current = np.full(b, -9.0, np.float32)
+    if case == "current_in_the_middle":
+        current[0] = np.sort(scores[0, :ln])[ln - k // 2]
+    if case == "run_in_a_page":
+        scores[0, 36:44] = 9.0              # positions 32-47: one page
+    if case == "run_across_pages":
+        scores[0, 44:52] = 9.0              # pages 2 and 3, not adjacent
+    rows, ok = sa.dsa_select_reference(
+        jnp.asarray(scores), jnp.asarray(current), jnp.asarray(lens),
+        sa.pool_rows(bt, jnp.asarray(lens), page=page), k=k)
+    counts = ok & (rows >= 0)
+    if case == "dead":
+        counts = counts.at[0].set(False)
+    q = jnp.asarray(rs.randn(b, h, w), jnp.float32)
+    return q, pool.reshape(-1, w), rows, counts
+
+
+def _fetched(q, kv_rows, rows, counts):
+    return sa.sparse_latent_stats(
+        q, sa.gather_selected(kv_rows, rows), counts, dv=512, scale=0.05,
+        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                        uninitialized_memory="nan"))
+
+
+@pytest.mark.parametrize("case", [
+    "dead", "len0", "k_minus_1", "k", "k_plus_1", "current_in_the_middle",
+    "run_in_a_page", "run_across_pages"])
+def test_the_kernel_fetches_the_rows_the_gather_took(case):
+    """The sparse kernel fetching the selected rows from the pool itself
+    (interpret mode; a copy awaited late, or a lane no copy filled, would
+    read NaN) against the XLA gather of the same rows and the twin."""
+    q, kv_rows, rows, counts = _selected(case)
+    got = _fetched(q, kv_rows, rows, counts)
+    want = sa.sparse_latent_reference(q, kv_rows[jnp.maximum(rows, 0)],
+                                      counts, dv=512, scale=0.05)
+    for x, y in zip(got, want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5,
+                                   atol=1e-5)
+    live = np.asarray(counts.any(axis=1))
+    assert live.tolist() == [case not in ("dead", "len0"), False, True,
+                             False]
+    assert not np.asarray(got[2])[~live].any()
+    if case == "current_in_the_middle":
+        assert 0 < np.flatnonzero(np.asarray(rows[0]) < 0)[0] < 191
+
+
+def test_a_row_of_the_wrong_page_reaches_the_fetching_kernel():
+    """``benchmark/faults_glm5``'s ``latent_row_wrong_page`` shifts the
+    rows ``gather_selected`` hands on: the kernel's copies follow it."""
+    from benchmark import faults_glm5
+    q, kv_rows, rows, counts = _selected("k_plus_1")
+    clean = np.asarray(_fetched(q, kv_rows, rows, counts)[0])
+    with faults_glm5.planted("latent_row_wrong_page"):
+        shifted = np.asarray(_fetched(q, kv_rows, rows, counts)[0])
+    assert np.abs(shifted - clean).max() > 0.1 * np.abs(clean).max()
+
+
 # (5) the expert share --------------------------------------------------------
 
 def test_the_shares_add_up():

@@ -1267,3 +1267,55 @@ class TestSparseSelectionOnChip:
         pay = rs.permutation(len(lens) * self.S).reshape(
             len(lens), self.S).astype(np.int32)
         self._check(s, cur, lens, pay)
+
+
+class TestSparseFetchOnChip:
+    """GLM-5's sparse latent attention (``sparse_latent_attention``,
+    Mosaic), which fetches the selected rows from the pool itself, at
+    the served widths (24 slots, 64 heads, rows 640 wide, 2,048
+    selected) against float64 attention over the same rows gathered on
+    the host: live rows of every kind beside dead ones."""
+
+    B, H, W, DV, K = 24, 64, 640, 512, 2048
+
+    def test_matches_attention_over_the_gathered_rows(self):
+        from bigdl_tpu.llm.kernels import sparse_attention as sa
+        rs = np.random.RandomState(5)
+        pool = rs.randn(5 * 3001 * 16, self.W).astype(np.float32)
+        pool = jnp.asarray(pool, jnp.bfloat16)
+        rows = np.zeros((self.B, self.K), np.int32)
+        counts = np.zeros((self.B, self.K), bool)
+        # (kept entries, where the current token's -1 sits)
+        kinds = [(2048, 2047), (2048, 700), (1, 0), (2047, 2046),
+                 (40, None), (2048, None), (513, 512), (0, None)]
+        for r, (keep, cur) in enumerate(kinds):
+            # distinct rows, as a step's positions are; a run of eight
+            # neighbours across a group where there are that many
+            run = (8 * rs.randint(len(pool) // 8 - 1) + 4
+                   + np.arange(min(keep, 8)))
+            rest = rs.choice(np.setdiff1d(np.arange(len(pool)), run),
+                             keep - len(run), replace=False)
+            rows[r, :keep] = np.sort(np.concatenate([run, rest]))
+            counts[r, :keep] = True
+            if cur is not None:
+                rows[r, cur], counts[r, cur] = -1, False
+        q = rs.randn(self.B, self.H, self.W).astype(np.float32)
+        sel = sa.gather_selected(pool.reshape(-1, self.W), jnp.asarray(rows))
+        acc, m, l = jax.jit(functools.partial(
+            sa.sparse_latent_stats, dv=self.DV, scale=0.0625))(
+            jnp.asarray(q), sel, jnp.asarray(counts))
+        kv = np.asarray(pool, np.float64)
+        for r in range(self.B):
+            live = counts[r]
+            if not live.any():
+                assert np.all(np.asarray(acc[r]) == 0)
+                assert np.all(np.asarray(l[r]) == 0)
+                assert np.all(np.asarray(m[r]) == -1e30)
+                continue
+            x = kv[rows[r][live]]
+            s = q[r].astype(np.float64) @ x.T * 0.0625
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want = (p @ x[:, :self.DV]) / p.sum(-1, keepdims=True)
+            got = np.asarray(acc[r]) / np.asarray(l[r])[:, None]
+            rel = np.abs(got - want).max() / np.abs(want).max()
+            assert rel < 0.01, (r, rel)

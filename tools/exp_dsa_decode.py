@@ -5,12 +5,17 @@ decode step at the configuration's widths on made-up weights and pools
 the batch dead), timed over ``--steps`` steps and traced once
 (``benchmark/trace_reduce``: device time by op, exclusive), then each
 decode part alone: the scoring kernel, the selection (``lax.top_k`` and
-the bisection form prefill uses), the gather of the selected rows and
-the sparse kernel. The selection is timed in its three forms: the sort
-(``dsa_select_reference``), the bisection prefill uses, and the kernel
-``dsa_topk_decode`` (its threshold alone, and whole: the compaction is
-their difference), with the kernel's kept sets checked against the
-sort's (``topk_exact``); the whole step is timed with the sort too.
+the bisection form prefill uses) and the sparse kernel, which fetches
+the selected rows itself: whole, and with its scoring stubbed (the
+fetch alone), beside the XLA gather of the rows that it replaced; its
+result is checked against the twin over gathered rows
+(``sparse_exact``: the largest difference), and the selected entries a
+copy's group holds are counted (``entries_a_group``). The selection is
+timed in its three forms: the sort (``dsa_select_reference``), the
+bisection prefill uses, and the kernel ``dsa_topk_decode`` (its
+threshold alone, and whole: the compaction is their difference), with
+the kernel's kept sets checked against the sort's (``topk_exact``); the
+whole step is timed with the sort too.
 
     python3 tools/exp_dsa_decode.py [--rows 18] [--tokens 22000] [--groups 2,8]
     JAX_PLATFORMS=cpu python3 tools/exp_dsa_decode.py --tiny
@@ -80,8 +85,8 @@ def main():
         if s.size < 5e7 else jnp.zeros(s.shape, s.dtype) + 0.01
         for k, s in zip(keys, leaves)])
     lay = cfg.num_hidden_layers
-    kv = jnp.zeros((lay, num_pages, 1, page, cfg.latent_width),
-                   jnp.bfloat16)
+    kv = jax.random.normal(jax.random.PRNGKey(1), (
+        lay, num_pages, 1, page, cfg.latent_width)).astype(jnp.bfloat16)
     ik = jax.random.normal(key, (lay, num_pages, 1, page,
                                  cfg.index_head_dim)).astype(jnp.bfloat16)
     rs = np.random.RandomState(0)
@@ -197,15 +202,33 @@ def main():
         == sorted(np.asarray(y)[np.asarray(yo)].tolist())
         for x, xo, y, yo in zip(got, got_ok, pos, ok))
     kv_rows = kv.reshape(-1, kv.shape[-1])
-    parts["gather_selected"] = _timed(jax.jit(sa.gather_selected), kv_rows,
-                                      pos)
-    rows = sa.gather_selected(kv_rows, pos)
+    parts["xla_gather"] = _timed(jax.jit(
+        lambda kv_, r: kv_[jnp.maximum(r, 0)]), kv_rows, pos)
     qq = jax.random.normal(key, (b, cfg.num_attention_heads,
                                  cfg.latent_width))
-    parts["sparse_latent"] = _timed(jax.jit(
-        lambda q, r, c: sa.sparse_latent_stats(
-            q, r, c, dv=cfg.kv_lora_rank, scale=cfg.attn_scale)), qq, rows,
-        ok)
+    cached = ok & (pos >= 0)
+    dv, scale = cfg.kv_lora_rank, cfg.attn_scale
+    if not a.tiny:      # the tiny widths are no multiple of a lane
+        sparse = functools.partial(sa.sparse_latent_attention.__wrapped__,
+                                   dv=dv, scale=scale)
+        parts["sparse_latent_fetch_only"] = _timed(jax.jit(
+            lambda q, kv_, r, c: sparse(q, kv_, r, c, score=False)), qq,
+            kv_rows, pos, cached)
+    stats = jax.jit(lambda q, kv_, r, c: sa.sparse_latent_stats(
+        q, sa.gather_selected(kv_, r), c, dv=dv, scale=scale))
+    parts["sparse_latent"] = _timed(stats, qq, kv_rows, pos, cached)
+    got = stats(qq, kv_rows, pos, cached)
+    want = sa.sparse_latent_reference(qq, kv_rows[jnp.maximum(pos, 0)],
+                                      cached, dv=dv, scale=scale)
+    live = np.asarray(cached.any(axis=1))
+    res["sparse_exact"] = [float(np.max(np.abs(x - y)) / max(
+        1.0, np.max(np.abs(y)))) for x, y in (
+        (np.asarray(x)[live], np.asarray(y)[live]) for x, y in zip(
+            got, want))]
+    grp = np.asarray(jnp.where(cached, pos // sa.FETCH_ROWS, -1))
+    res["entries_a_group"] = float(np.mean([
+        (g >= 0).sum() / max(1, len(np.unique(g[g >= 0])))
+        for g in grp if (g >= 0).any()]))
     res["parts_ms_one_layer"] = parts
     print(json.dumps(res, indent=1), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
